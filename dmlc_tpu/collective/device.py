@@ -24,12 +24,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dmlc_tpu import obs
 from dmlc_tpu.obs.device_telemetry import h2d_meter, instrumented_jit
-from dmlc_tpu.utils.jax_compat import axis_size, shard_map
-
 from dmlc_tpu.utils.logging import DMLCError
 
 
@@ -87,8 +87,14 @@ def pbitor(x, axis: str = "dp"):
     """Cross-replica bitwise OR (rabit op::BitOR, in-graph). XLA has no
     OR all-reduce primitive, so shards are gathered and folded over the
     gathered dim — order-insensitive, so the result is bit-identical to
-    the socket tree's fold regardless of topology."""
-    return _bitor_reduce(jax.lax.all_gather(x, axis_name=axis), axis=0)
+    the socket tree's fold regardless of topology. The gather is a psum
+    of one-hot slots (each position has exactly one non-zero
+    contributor, so the integer sum is exact): unlike ``all_gather``,
+    whose result shard_map types as varying, a psum result is invariant
+    over ``axis`` and may leave through a replicated ``out_specs``."""
+    slots = jnp.zeros((axis_size(axis),) + x.shape, x.dtype)
+    slots = slots.at[jax.lax.axis_index(axis)].set(x)
+    return _bitor_reduce(jax.lax.psum(slots, axis), axis=0)
 
 
 def bucketed_psum(tree, axis="dp", bucket: bool = True):

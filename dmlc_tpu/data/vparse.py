@@ -572,20 +572,21 @@ def token_boundary_masks(a: np.ndarray):
     return starts, ends
 
 
-def pallas_token_spans(a: np.ndarray) -> Optional[tuple]:
+def pallas_token_spans(a: np.ndarray,
+                       interpret: bool = False) -> Optional[tuple]:
     """Token spans via the Pallas boundary kernel when the
-    ``DMLC_TPU_PALLAS`` knob asks for it and a jax backend is usable;
-    None → caller stays on the numpy tokenizer. The kernel only computes
+    ``DMLC_TPU_PALLAS`` knob asks for it; None (knob off) → caller stays
+    on the numpy tokenizer. With the knob on the kernel compiles for the
+    TPU and a backend Mosaic cannot target raises — asking for the
+    kernel never silently falls back (``interpret=True`` is the explicit
+    interpreter request the CPU tests pass). The kernel only computes
     the boundary masks (the data-parallel part); offset extraction stays
     in numpy — flatnonzero has no fixed-shape device analog."""
     import os
 
     if os.environ.get("DMLC_TPU_PALLAS", "") not in ("1", "parse"):
         return None
-    try:
-        from dmlc_tpu.ops.pallas_kernels import tokenize_boundaries
+    from dmlc_tpu.ops.pallas_kernels import tokenize_boundaries
 
-        starts_mask, ends_mask = tokenize_boundaries(a)
-    except Exception:
-        return None
+    starts_mask, ends_mask = tokenize_boundaries(a, interpret=interpret)
     return np.flatnonzero(starts_mask), np.flatnonzero(ends_mask) + 1

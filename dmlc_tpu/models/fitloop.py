@@ -45,7 +45,7 @@ from typing import Optional
 
 from dmlc_tpu import obs
 from dmlc_tpu.device.feed import stall_breakdown
-from dmlc_tpu.obs import audit, goodput
+from dmlc_tpu.obs import audit, goodput, xla_cost
 from dmlc_tpu.obs.metrics import metrics_enabled
 from dmlc_tpu.obs.watchdog import make_watchdog
 from dmlc_tpu.params.knobs import device_telemetry_enabled, step_sample_n
@@ -67,7 +67,11 @@ class FitLoopObs:
             "dmlc_fit_loss_value", "last epoch mean loss", model=model)
         self.h_epoch = self.reg.histogram(
             "dmlc_fit_epoch_ns", "wall time per epoch", model=model)
-        self.ledger = goodput.ledger(self.reg)
+        # the fit owns a device, so its roofline reads that device's
+        # published peaks (knob overrides win; an unknown kind gives no
+        # MFU at all)
+        self.ledger = goodput.ledger(
+            self.reg, ceilings=xla_cost.device_peaks())
         self.watchdog = make_watchdog(self.reg)
         # determinism audit: the model digest chain + numeric sentinel
         # (the shared no-op child when DMLC_TPU_AUDIT is off)

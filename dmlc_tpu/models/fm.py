@@ -17,9 +17,8 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from dmlc_tpu.utils.jax_compat import shard_map
 
 from dmlc_tpu.collective.device import bucketed_psum
 from dmlc_tpu.models.linear import (

@@ -23,9 +23,8 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from dmlc_tpu.utils.jax_compat import shard_map
 
 from dmlc_tpu.collective.device import bucketed_psum
 from dmlc_tpu.obs.device_telemetry import instrumented_jit
@@ -152,30 +151,26 @@ def _resolve_pallas(use_pallas: Optional[bool], layout: str,
         use_pallas = os.environ.get("DMLC_TPU_PALLAS", "0") == "1"
     if not use_pallas:
         return False
-    from dmlc_tpu.ops import pallas_kernels
-    from dmlc_tpu.ops.objectives import OBJECTIVES
-
     if layout == "dense":
-        check(
-            pallas_kernels.available and objective in OBJECTIVES,
-            "pallas path unavailable for this configuration",
-        )
+        from dmlc_tpu.ops.objectives import OBJECTIVES
+
+        check(objective in OBJECTIVES,
+              "pallas path unavailable for this objective")
         return "dense"
-    check(pallas_kernels.available,
-          "pallas path unavailable for this configuration")
     return "spmv"
 
 
 def _build_local_grads(objective: str, layout: str, num_features: int,
-                       use_pallas: bool):
+                       use_pallas: bool, pallas_interpret: bool = False):
     """The per-shard gradient core: f(params, batch) -> (gw, gb, loss_sum,
     weight_sum), no cross-device communication. ONE definition feeds every
     sync flavor — the in-graph SPMD step, the single-device step, and the
     legacy host-allreduce twin — so their local math is identical by
-    construction (the parity suites lean on this)."""
-    # Mosaic only targets TPU; elsewhere (CPU meshes in tests, the
-    # dryrun_multichip virtual devices) the kernel runs interpreted.
-    pallas_interpret = jax.default_backend() != "tpu"
+    construction (the parity suites lean on this).
+
+    The Pallas kernels compile for the backend they are on (Mosaic
+    targets the TPU and fails loudly elsewhere); ``pallas_interpret`` is
+    the caller's explicit request for interpreter mode (CPU tests)."""
 
     def _local_grads(params, batch):
         label = batch["label"]
@@ -234,6 +229,15 @@ def _build_local_grads(objective: str, layout: str, num_features: int,
     return _local_grads
 
 
+def _on_mesh(tree, mesh: Mesh) -> bool:
+    """Whether ``tree``'s (first) leaf is already placed on ``mesh`` —
+    every leaf of a params tree is placed together (shard_params, or the
+    step's own outputs), so one leaf speaks for the tree."""
+    leaf = jax.tree_util.tree_leaves(tree)[0]
+    sharding = getattr(leaf, "sharding", None)
+    return isinstance(sharding, NamedSharding) and sharding.mesh == mesh
+
+
 def _build_apply(learning_rate: float, l2: float, momentum: float):
     """The SGD update: f(params, velocity, gw, gb, wsum) with the grads
     already reduced. Shared across sync flavors like _build_local_grads."""
@@ -269,6 +273,7 @@ def make_linear_train_step(
     use_pallas: Optional[bool] = None,
     donate_batch: bool = False,
     param_specs=None,
+    pallas_interpret: bool = False,
 ):
     """Build the jitted allreduce-SGD step.
 
@@ -288,8 +293,10 @@ def make_linear_train_step(
     (ops/pallas_kernels.fused_linear_grads); on the csr layout it routes
     the margin SpMV's row reduce through the COO segment-sum kernel
     (ops/spmv.spmv_pallas) while the feature-direction scatter stays on
-    XLA. Measured at parity with XLA's own fusion on v5e (BASELINE.md) —
-    XLA stays the default.
+    XLA. XLA stays the default. The kernels compile through Mosaic for
+    the TPU; ``pallas_interpret=True`` runs them in the Pallas
+    interpreter instead (how the CPU tests drive this path — never
+    inferred from the backend).
 
     ``donate_batch=True`` donates ALL step inputs — params, velocity, and
     the batch arrays: the H2D landing buffers are released to XLA the
@@ -306,7 +313,7 @@ def make_linear_train_step(
         check(num_features > 0, "csr layout requires num_features")
     use_pallas = _resolve_pallas(use_pallas, layout, objective)
     _local_grads = _build_local_grads(objective, layout, num_features,
-                                      use_pallas)
+                                      use_pallas, pallas_interpret)
     _apply = _build_apply(learning_rate, l2, momentum)
 
     if mesh is None:
@@ -375,7 +382,22 @@ def make_linear_train_step(
         step, "linear.step",
         donate_argnums=(0, 1, 2) if donate_batch else (0, 1),
     )
-    return _suppress_donation_warnings(fn) if donate_batch else fn
+    if donate_batch:
+        fn = _suppress_donation_warnings(fn)
+
+    def placed_step(params, velocity, batch):
+        # The step returns mesh-placed params; an unplaced tree coming in
+        # (fresh jnp.zeros, a load()) types differently — its avals carry
+        # no mesh — and would trace and compile the same bucket a second
+        # time. Place it first, as LinearLearner does from step zero; a
+        # tree already on the mesh costs one leaf check.
+        if not _on_mesh(params, mesh):
+            params = shard_params(params, mesh, specs=param_specs)
+        if not _on_mesh(velocity, mesh):
+            velocity = shard_params(velocity, mesh, specs=param_specs)
+        return fn(params, velocity, batch)
+
+    return placed_step
 
 
 def make_hostsync_train_step(
@@ -386,6 +408,7 @@ def make_hostsync_train_step(
     layout: str = "dense",
     num_features: int = 0,
     use_pallas: Optional[bool] = None,
+    pallas_interpret: bool = False,
 ):
     """The legacy host-round-trip twin of the mesh SPMD step: local grads
     on device, ONE fused ``collective.allreduce`` over the active host
@@ -407,7 +430,8 @@ def make_hostsync_train_step(
         check(num_features > 0, "csr layout requires num_features")
     use_pallas = _resolve_pallas(use_pallas, layout, objective)
     local = instrumented_jit(
-        _build_local_grads(objective, layout, num_features, use_pallas),
+        _build_local_grads(objective, layout, num_features, use_pallas,
+                           pallas_interpret),
         "linear.hostsync_grads",
     )
     apply_fn = instrumented_jit(

@@ -17,7 +17,7 @@ from typing import Optional
 
 import numpy as np
 
-from dmlc_tpu.utils.logging import DMLCError
+from dmlc_tpu.utils.logging import DMLCError, log_warning
 
 _OK = 0
 _EOVERFLOW = -1
@@ -174,7 +174,10 @@ def _try_build(force: bool = False) -> None:
     .so, and runs at most once per process. ``force`` adds -B: an
     EXISTING .so that failed to load (stale ABI surviving a git pull) can
     carry a fresh mtime, so a timestamp-based make would consider it up
-    to date and leave it broken."""
+    to date and leave it broken. A ``make`` that ran and failed is logged
+    (once — this runs once per process) with the compiler's last stderr
+    line: the package then carries on with the pure-Python twins, and the
+    log says why."""
     global _build_attempted
     if _build_attempted:
         return
@@ -193,12 +196,20 @@ def _try_build(force: bool = False) -> None:
 
         with open(lock_path, "w") as lock:
             fcntl.flock(lock, fcntl.LOCK_EX)
-            subprocess.run(
+            proc = subprocess.run(
                 ["make", "-C", cpp_dir] + (["-B"] if force else []),
-                capture_output=True, timeout=120, check=False,
+                capture_output=True, text=True, timeout=120, check=False,
             )
-    except (OSError, subprocess.TimeoutExpired, ImportError):
-        pass
+    except (OSError, subprocess.TimeoutExpired, ImportError) as err:
+        log_warning("native build did not run (%s: %s); using the "
+                    "pure-Python parsers", type(err).__name__, err)
+        return
+    if proc.returncode != 0:
+        tail = (proc.stderr or "").strip().splitlines()
+        log_warning(
+            "native build failed (make -C %s exited %d): %s; using the "
+            "pure-Python parsers", cpp_dir, proc.returncode,
+            tail[-1] if tail else "no compiler output")
 
 
 def _expected_abi_version() -> int:

@@ -11,11 +11,12 @@ module lights up the device side on the same registry:
   The trick is that jit traces the wrapped Python body exactly once per
   cache miss, so a counter bump inside the body IS a compile counter — no
   private jax APIs. This turns FixedShapePool's one-trace-per-bucket design
-  claim into a live invariant. Each compiling call also hands the executable
+  claim into a live invariant. Each compiling call also hands its arguments
   to obs/xla_cost.py (``note_compile``) which caches the compiled program's
   cost/memory analytics per (fn, bucket shape) — compile-time only, never
-  per step, and ``jitted.lower`` reuses the cached trace so the recompile
-  sentinel itself is not perturbed.
+  per step; ``jitted.lower(...).compile()`` there returns the executable
+  the call just built (no second trace, no second XLA compile), so the
+  recompile sentinel itself is not perturbed.
 - ``sample()`` — per-device HBM gauges from ``device.memory_stats()``
   (``dmlc_device_hbm_bytes{device=}``; graceful no-op on CPU backends where
   the runtime reports nothing) plus a live-buffer census over
@@ -44,7 +45,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, Optional
 
-from dmlc_tpu.obs import flight
+from dmlc_tpu.obs import flight, xla_cost
 from dmlc_tpu.obs.metrics import Registry, registry
 from dmlc_tpu.params.knobs import device_telemetry_enabled, hbm_poll_s
 
@@ -146,17 +147,9 @@ class InstrumentedJit:
         self.calls += 1
         if self.compiles != before:
             self._h_compile_ns.observe(time.monotonic_ns() - t0)
-            try:
-                from dmlc_tpu.obs import xla_cost
-
-                xla_cost.note_compile(
-                    self.fn_name, self._jitted, args, kwargs, reg=self._reg)
-            except Exception:  # noqa: BLE001 - analytics never kill a step
-                logger.debug(
-                    "xla cost extraction failed for %s",
-                    self.fn_name,
-                    exc_info=True,
-                )
+            # never raises: a failed extraction warns once for this site
+            xla_cost.note_compile(
+                self.fn_name, self._jitted, args, kwargs, reg=self._reg)
             if self.calls > self.warmup_calls:
                 self._m_recompiles.inc()
                 flight.record_event(
@@ -201,10 +194,17 @@ def instrumented_jit(
     no per-dispatch branch: the disabled hot path is exactly the
     uninstrumented one (allocation-free, pinned by test like the PR 7
     flow-id discipline). The knob is read once, here, at build time.
-    """
-    if not device_telemetry_enabled():
-        import jax
 
+    Every learner step and device collective is built here, so this is
+    also where the persistent compile cache is placed
+    (utils/jax_compat.py) before the first compile.
+    """
+    import jax
+
+    from dmlc_tpu.utils.jax_compat import place_compile_cache
+
+    place_compile_cache()
+    if not device_telemetry_enabled():
         return jax.jit(fn, **jit_kwargs)
     return InstrumentedJit(fn, name, warmup_calls=warmup_calls, **jit_kwargs)
 
@@ -520,6 +520,4 @@ def reset() -> None:
         _poller_started = False
     with _capture_lock:
         _capturing = False
-    from dmlc_tpu.obs import xla_cost
-
     xla_cost.reset()

@@ -49,20 +49,23 @@ Roofline ceilings (MB/s unless noted), merged over
 - ``step_mbps``   — device-step byte-rate ceiling
   (``DMLC_TPU_STEP_PEAK_MBPS``, default 0 = unknown; set it from the
   model's measured FLOP rate to get step utilization)
-- ``ici_gbps``    — per-direction per-link ICI peak in GB/s
-  (``DMLC_TPU_ICI_PEAK_GBPS``, default 45 — same knob
+- ``ici_gbps``    — per-chip interconnect peak in GB/s (the device's
+  published figure, ``xla_cost.DEVICE_PEAKS``;
+  ``DMLC_TPU_ICI_PEAK_GBPS`` overrides; 0 = unknown — same source
   bench_collective.py scores against)
 
-Those ceilings are all *measured-probe* style (a bench tier, a feed
-probe, a spec sheet). The compiled-step cost records (obs/xla_cost.py)
+The compiled-step cost records (obs/xla_cost.py)
 add the *model-based* pair: the window's flop/byte estimate is steps ×
 the hot step's per-call XLA analytics (``dmlc_xla_flops{fn=}`` /
 ``dmlc_xla_bytes_accessed{fn=}`` over the ``*.step``/``*.step_mp``
 sites, read from the ``current`` snapshot — gauges, so never from a
-clamped delta), scored against ``peak_flops``
-(``DMLC_TPU_PEAK_FLOPS``, default = the measured matmul probe) and
-``hbm_gbps`` (``DMLC_TPU_PEAK_HBM_GBPS``, default = the measured
-streaming probe). When computable the verdict gains ``mfu`` (model
+clamped delta), scored against ``peak_flops`` and ``hbm_gbps`` — the
+published peaks of the device kind (``xla_cost.device_peaks()``, which
+a device-owning caller passes through ``ceilings=``; the fit loop and
+bench do), or the ``DMLC_TPU_PEAK_FLOPS`` / ``DMLC_TPU_PEAK_HBM_GBPS``
+overrides. A process with no device and no override (the tracker's
+roll-up) has no peak and reports no MFU — never a made-up one. When
+computable the verdict gains ``mfu`` (model
 FLOP utilization ∈ (0, 1]), ``hbm_fraction``, and a ``compute`` block
 naming device_step's model-predicted floor seconds next to its
 measured budget — all keys absent otherwise, so surfaces that render
@@ -178,8 +181,10 @@ def progress_counters(delta: Dict[str, float]) -> Dict[str, float]:
 
 
 def default_ceilings() -> Dict[str, float]:
-    """Roofline ceilings from the env knobs (see module docstring);
-    callers overlay measured values (``device_feed_probe_gbps``)."""
+    """Roofline ceilings from the env knobs alone (see module
+    docstring; 0 = unknown) — importable without jax. Callers overlay
+    measured values (``device_feed_probe_gbps``) and, where they own a
+    device, its published peaks (``xla_cost.device_peaks()``)."""
     return {
         "parse_mbps": knobs.parse_peak_mbps(),
         "h2d_mbps": 0.0,
@@ -274,17 +279,13 @@ def _finish(stages: Dict[str, float], counters: Dict[str, float],
     }
     # model-based roofline: the window's XLA flop/byte estimate (steps ×
     # per-step compiled-program analytics, injected by attribute() or
-    # summed across ranks by rolled()) against the peak knobs, with the
-    # measured probes standing in for unset knobs. All three keys stay
-    # absent when nothing is computable — conditional surfaces key off
-    # their presence.
+    # summed across ranks by rolled()) against the peak ceilings. All
+    # three keys stay absent when nothing is computable — no analyzed
+    # step, or no known peak — conditional surfaces key off their
+    # presence.
     xla_flops = counters.get("xla_flops", 0.0)
     if xla_flops > 0.0:
         peak = float(ceil.get("peak_flops", 0.0) or 0.0)
-        if peak <= 0.0:
-            from dmlc_tpu.obs import xla_cost
-
-            peak = xla_cost.probed_peak_flops()
         if peak > 0.0:
             out["mfu"] = round(min(1.0, xla_flops / wall_s / peak), 4)
             out["compute"] = {
@@ -296,10 +297,6 @@ def _finish(stages: Dict[str, float], counters: Dict[str, float],
     xla_bytes = counters.get("xla_bytes", 0.0)
     if xla_bytes > 0.0:
         gbps = float(ceil.get("hbm_gbps", 0.0) or 0.0)
-        if gbps <= 0.0:
-            from dmlc_tpu.obs import xla_cost
-
-            gbps = xla_cost.probed_hbm_gbps()
         if gbps > 0.0:
             out["hbm_fraction"] = round(
                 min(1.0, xla_bytes / wall_s / (gbps * 1e9)), 4)

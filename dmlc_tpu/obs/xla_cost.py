@@ -6,11 +6,15 @@ obs plane blind past the jit boundary: FLOPs executed, HBM bytes moved,
 and ICI collective traffic all happen inside one opaque dispatch. This
 module restores that visibility *at compile time, never per step*: when
 an :class:`~dmlc_tpu.obs.device_telemetry.InstrumentedJit` site compiles
-a new (fn, bucket-shape) signature, :func:`note_compile` re-lowers the
-same arguments (``jitted.lower(...)`` reads cached jaxprs and argument
-avals only — it does not re-trace the Python body, so the recompile
-sentinel is untouched; verified against donated/deleted buffers) and
-reads the compiled executable's analytics:
+a new (fn, bucket-shape) signature, :func:`note_compile` asks jit for
+the executable the call has just built: ``jitted.lower(*args)`` on the
+same argument objects reads only their avals and shardings (which a
+donated, deleted array keeps), hits jit's trace and lowering caches, and
+``.compile()`` on that cached lowering returns the executable already
+attached to it — no second trace (the recompile sentinel is untouched)
+and no second XLA compile (``extract_ms`` on each record is the proof:
+milliseconds, not the site's compile time). It then reads the
+executable's analytics:
 
 - ``compiled.cost_analysis()`` → per-call ``flops`` and ``bytes
   accessed`` (``dmlc_xla_flops{fn=}``,
@@ -26,18 +30,19 @@ reads the compiled executable's analytics:
 
 Records are cached per (fn, bucket signature): a bucket that has been
 analyzed once is never re-extracted (pinned by test), so steady-state
-training pays nothing. Every probe is wrapped in try/except — a backend
-without ``cost_analysis`` (or an opaque analysis shape) degrades to
-absent gauges, never a crash. Under ``DMLC_TPU_METRICS=0`` the hook
-returns immediately.
+training pays nothing. Analytics never kill a step: a probe that fails
+leaves its gauge absent and logs ONE warning naming the jit site — never
+a silent absence. Under ``DMLC_TPU_METRICS=0`` the hook returns
+immediately.
 
 The same records feed the model-based roofline: obs/goodput.py turns
-steps × per-step flops into an MFU verdict against
-``DMLC_TPU_PEAK_FLOPS`` / ``DMLC_TPU_PEAK_HBM_GBPS`` (or the measured
-:func:`probed_peak_flops` / :func:`probed_hbm_gbps` defaults), the
-``/xla`` status endpoint and ``obs-report --xla`` render the per-site
-tables, and bench's detail artifact carries the ``xla`` section plus
-``sgd_mfu`` (sentry-gated higher-is-better).
+steps × per-step flops into an MFU verdict against the device's
+published peaks (:data:`DEVICE_PEAKS`, keyed by ``device_kind``;
+``DMLC_TPU_PEAK_FLOPS`` / ``DMLC_TPU_PEAK_HBM_GBPS`` /
+``DMLC_TPU_ICI_PEAK_GBPS`` override), the ``/xla`` status endpoint and
+``obs-report --xla`` render the per-site tables, and bench's detail
+artifact carries the ``xla`` section plus ``sgd_mfu`` (sentry-gated
+higher-is-better).
 """
 
 from __future__ import annotations
@@ -49,6 +54,7 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 from dmlc_tpu.obs.metrics import Registry, metrics_enabled, registry
+from dmlc_tpu.params import knobs
 
 logger = logging.getLogger("dmlc_tpu.obs.xla_cost")
 
@@ -62,8 +68,8 @@ __all__ = [
     "sites_from_flat",
     "step_costs",
     "detail_section",
-    "probed_peak_flops",
-    "probed_hbm_gbps",
+    "DEVICE_PEAKS",
+    "device_peaks",
     "reset",
 ]
 
@@ -72,15 +78,21 @@ _lock = threading.Lock()
 # the LATEST bucket per site while counting all of them
 _records: Dict[Tuple[str, str], Dict[str, Any]] = {}
 _extractions = 0
+# (fn, probe) pairs whose failure has been logged — once each
+_warned: set = set()
 
 #: the gauge fields every record carries (and the flat-metric parser reads)
 FIELDS = ("flops", "bytes_accessed", "peak_bytes", "collective_bytes")
 
 
 def bucket_signature(args: tuple, kwargs: Optional[dict] = None) -> str:
-    """Shape/dtype signature of one call's argument tree — the cache key
-    half that distinguishes FixedShapePool buckets. Non-array leaves
-    contribute their type name only (their values do not retrace)."""
+    """Shape/dtype/placement signature of one call's argument tree — the
+    cache key half that distinguishes what jit compiles separately:
+    FixedShapePool buckets, and the same shapes placed over a mesh (a
+    4-chip SPMD step is a different program from the one-chip step with
+    equal shapes, and its record carries the collective bytes).
+    Non-array leaves contribute their type name only (their values do
+    not retrace)."""
     import jax
 
     parts: List[str] = []
@@ -89,9 +101,12 @@ def bucket_signature(args: tuple, kwargs: Optional[dict] = None) -> str:
         dtype = getattr(leaf, "dtype", None)
         if shape is None or dtype is None:
             parts.append(type(leaf).__name__)
-        else:
-            parts.append(
-                "%s[%s]" % (dtype, ",".join(str(d) for d in shape)))
+            continue
+        sig = "%s[%s]" % (dtype, ",".join(str(d) for d in shape))
+        spec = getattr(getattr(leaf, "sharding", None), "spec", None)
+        if spec is not None:  # mesh-placed: NamedSharding
+            sig += "@%s%s" % (dict(leaf.sharding.mesh.shape), tuple(spec))
+        parts.append(sig)
     return ";".join(parts)
 
 
@@ -130,22 +145,31 @@ def collective_bytes_from_hlo(hlo_text: str) -> float:
     return total
 
 
-def _extract(jitted, args: tuple, kwargs: dict) -> Dict[str, float]:
-    """One executable's analytics, each probe independently best-effort."""
+def _warn_once(fn_name: str, probe: str, err: BaseException) -> None:
+    """One warning per (jit site, probe): the gauge that probe feeds is
+    absent for this site and the log says why."""
+    with _lock:
+        if (fn_name, probe) in _warned:
+            return
+        _warned.add((fn_name, probe))
+    logger.warning(
+        "xla cost %s unavailable for jit site %s: %s: %s",
+        probe, fn_name, type(err).__name__, err)
+
+
+def _extract(fn_name: str, jitted, args: tuple,
+             kwargs: dict) -> Dict[str, float]:
+    """One executable's analytics; a probe that fails leaves its field
+    out (and warns once for the site) rather than reporting a zero."""
     compiled = jitted.lower(*args, **kwargs).compile()
-    out = {field: 0.0 for field in FIELDS}
+    out: Dict[str, float] = {}
     try:
         analysis = compiled.cost_analysis()
-        if isinstance(analysis, (list, tuple)):
-            # older jax returns one dict per partition; they agree for
-            # SPMD programs, so the first speaks for the site
-            analysis = analysis[0] if analysis else {}
-        if isinstance(analysis, dict):
-            out["flops"] = max(0.0, float(analysis.get("flops", 0.0) or 0.0))
-            out["bytes_accessed"] = max(
-                0.0, float(analysis.get("bytes accessed", 0.0) or 0.0))
-    except Exception:
-        logger.debug("cost_analysis unavailable", exc_info=True)
+        out["flops"] = max(0.0, float(analysis.get("flops", 0.0) or 0.0))
+        out["bytes_accessed"] = max(
+            0.0, float(analysis.get("bytes accessed", 0.0) or 0.0))
+    except Exception as err:  # noqa: BLE001 - analytics never kill a step
+        _warn_once(fn_name, "cost_analysis", err)
     try:
         mem = compiled.memory_analysis()
         peak = 0.0
@@ -155,42 +179,48 @@ def _extract(jitted, args: tuple, kwargs: dict) -> Dict[str, float]:
         # donated buffers alias an argument onto an output: counted once
         peak -= float(getattr(mem, "alias_size_in_bytes", 0) or 0)
         out["peak_bytes"] = max(0.0, peak)
-    except Exception:
-        logger.debug("memory_analysis unavailable", exc_info=True)
+    except Exception as err:  # noqa: BLE001
+        _warn_once(fn_name, "memory_analysis", err)
     try:
         out["collective_bytes"] = collective_bytes_from_hlo(
             compiled.as_text())
-    except Exception:
-        logger.debug("hlo text unavailable", exc_info=True)
+    except Exception as err:  # noqa: BLE001
+        _warn_once(fn_name, "hlo text", err)
     return out
 
 
 def _set_gauges(fn_name: str, rec: Dict[str, Any],
                 reg: Optional[Registry] = None) -> None:
+    """One gauge per field the extraction produced — a probe that failed
+    leaves its gauge absent for the site, never a zero."""
     reg = reg if reg is not None else registry()
-    reg.gauge(
-        "dmlc_xla_flops",
-        "per-call FLOPs of the latest compiled bucket per jit site "
-        "(XLA cost_analysis)", fn=fn_name,
-    ).set(float(rec.get("flops", 0.0)))
-    reg.gauge(
-        "dmlc_xla_bytes_accessed",
-        "per-call memory traffic of the latest compiled bucket per jit "
-        "site (XLA cost_analysis 'bytes accessed')", fn=fn_name,
-    ).set(float(rec.get("bytes_accessed", 0.0)))
-    reg.gauge(
-        "dmlc_xla_peak_bytes",
-        "compiled-program peak bytes per jit site (memory_analysis: "
-        "argument+output+temp+code, donation aliases counted once)",
-        fn=fn_name,
-    ).set(float(rec.get("peak_bytes", 0.0)))
-    reg.gauge(
-        "dmlc_xla_collective_bytes",
-        "per-call bytes produced by in-graph collectives per jit site "
-        "(summed from the optimized HLO's all-reduce/all-gather/"
-        "reduce-scatter/collective-permute/all-to-all result shapes)",
-        fn=fn_name,
-    ).set(float(rec.get("collective_bytes", 0.0)))
+    if "flops" in rec:
+        reg.gauge(
+            "dmlc_xla_flops",
+            "per-call FLOPs of the latest compiled bucket per jit site "
+            "(XLA cost_analysis)", fn=fn_name,
+        ).set(float(rec["flops"]))
+    if "bytes_accessed" in rec:
+        reg.gauge(
+            "dmlc_xla_bytes_accessed",
+            "per-call memory traffic of the latest compiled bucket per jit "
+            "site (XLA cost_analysis 'bytes accessed')", fn=fn_name,
+        ).set(float(rec["bytes_accessed"]))
+    if "peak_bytes" in rec:
+        reg.gauge(
+            "dmlc_xla_peak_bytes",
+            "compiled-program peak bytes per jit site (memory_analysis: "
+            "argument+output+temp+code, donation aliases counted once)",
+            fn=fn_name,
+        ).set(float(rec["peak_bytes"]))
+    if "collective_bytes" in rec:
+        reg.gauge(
+            "dmlc_xla_collective_bytes",
+            "per-call bytes produced by in-graph collectives per jit site "
+            "(summed from the optimized HLO's all-reduce/all-gather/"
+            "reduce-scatter/collective-permute/all-to-all result shapes)",
+            fn=fn_name,
+        ).set(float(rec["collective_bytes"]))
 
 
 def note_compile(fn_name: str, jitted, args: tuple,
@@ -202,26 +232,22 @@ def note_compile(fn_name: str, jitted, args: tuple,
     Runs only when a call actually compiled, and extracts at most once
     per (fn, bucket signature) — a signature already analyzed returns
     its cached record with no lowering, no compile, no gauge write.
-    Returns the record, or None when metrics are off or every probe
-    failed (absent gauges, never a crash)."""
+    ``args`` are the objects the call just consumed (donated arrays
+    included: only their avals and shardings are read). Never raises:
+    a failure returns None after one warning naming the site."""
     if not metrics_enabled():
         return None
     kwargs = kwargs or {}
-    try:
-        key = (fn_name, bucket_signature(args, kwargs))
-    except Exception:
-        logger.debug("bucket signature failed for %s", fn_name,
-                     exc_info=True)
-        return None
-    with _lock:
-        rec = _records.get(key)
-    if rec is not None:
-        return rec
     t0 = time.monotonic_ns()
     try:
-        costs = _extract(jitted, args, kwargs)
-    except Exception as err:
-        logger.debug("xla cost extraction failed for %s: %s", fn_name, err)
+        key = (fn_name, bucket_signature(args, kwargs))
+        with _lock:
+            rec = _records.get(key)
+        if rec is not None:
+            return rec
+        costs = _extract(fn_name, jitted, args, kwargs)
+    except Exception as err:  # noqa: BLE001 - analytics never kill a step
+        _warn_once(fn_name, "extraction", err)
         return None
     rec = dict(costs, fn=fn_name, bucket=key[1],
                extract_ms=round((time.monotonic_ns() - t0) / 1e6, 3))
@@ -302,87 +328,49 @@ def detail_section() -> Dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
-# measured peaks: the auto-probed defaults behind DMLC_TPU_PEAK_FLOPS /
-# DMLC_TPU_PEAK_HBM_GBPS (knob > 0 wins; these run once per process,
-# lazily, only when a model-based verdict is actually requested)
+# published peaks: the one table every roofline denominator comes from
 # ---------------------------------------------------------------------------
 
-_probe_lock = threading.Lock()
-_peak_flops_probe: Optional[float] = None
-_hbm_gbps_probe: Optional[float] = None
+#: Per-chip peaks keyed by ``jax.Device.device_kind``, in the units of the
+#: goodput ceilings they fill (``peak_flops`` FLOP/s, ``hbm_gbps`` and
+#: ``ici_gbps`` GB/s). v5e — Google Cloud documentation, "TPU v5e": 197
+#: TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,
+        "hbm_gbps": 819.0,
+        "ici_gbps": 200.0,
+    },
+}
 
 
-def _best_seconds(fn, arg, repeats: int = 3) -> float:
-    import jax
-
-    jax.block_until_ready(fn(arg))  # compile + warm outside the timing
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(arg))
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-
-def probed_peak_flops() -> float:
-    """Measured matmul FLOP rate (FLOP/s), probed once per process: a
-    256×256 f32 matmul timed best-of-3. A *measured* ceiling, so MFU
-    against it reads as "fraction of what this backend demonstrably
-    sustains"; 0.0 when the probe fails (MFU then stays absent)."""
-    global _peak_flops_probe
-    with _probe_lock:
-        if _peak_flops_probe is not None:
-            return _peak_flops_probe
-    val = 0.0
-    try:
+def device_peaks(device_kind: Optional[str] = None) -> Dict[str, float]:
+    """Roofline peaks for this process's device: the :data:`DEVICE_PEAKS`
+    row for ``device_kind`` (default: ``jax.devices()[0].device_kind``),
+    with ``DMLC_TPU_PEAK_FLOPS`` / ``DMLC_TPU_PEAK_HBM_GBPS`` /
+    ``DMLC_TPU_ICI_PEAK_GBPS`` winning where set. A kind that is not in
+    the table and not overridden yields NO key — callers then report no
+    MFU / HBM fraction / ICI utilization at all, never a made-up one.
+    Initializes the jax backend unless ``device_kind`` is passed, so only
+    processes that own a device call it bare."""
+    if device_kind is None:
         import jax
-        import jax.numpy as jnp
 
-        n = 256
-        a = jnp.ones((n, n), jnp.float32)
-        best = _best_seconds(jax.jit(lambda x: x @ x), a)
-        if best > 0:
-            val = 2.0 * n ** 3 / best
-    except Exception:
-        logger.debug("peak-flops probe failed", exc_info=True)
-    with _probe_lock:
-        if _peak_flops_probe is None:
-            _peak_flops_probe = val
-        return _peak_flops_probe
-
-
-def probed_hbm_gbps() -> float:
-    """Measured device memory bandwidth (GB/s), probed once per process:
-    a 32 MiB f32 element-wise pass (read + write) timed best-of-3; 0.0
-    when the probe fails (the HBM fraction then stays absent)."""
-    global _hbm_gbps_probe
-    with _probe_lock:
-        if _hbm_gbps_probe is not None:
-            return _hbm_gbps_probe
-    val = 0.0
-    try:
-        import jax
-        import jax.numpy as jnp
-
-        x = jnp.ones(8 * 1024 * 1024, jnp.float32)  # 32 MiB
-        best = _best_seconds(jax.jit(lambda v: v * 1.0000001), x)
-        if best > 0:
-            val = 2.0 * x.size * 4 / best / 1e9
-    except Exception:
-        logger.debug("hbm-bandwidth probe failed", exc_info=True)
-    with _probe_lock:
-        if _hbm_gbps_probe is None:
-            _hbm_gbps_probe = val
-        return _hbm_gbps_probe
+        device_kind = jax.devices()[0].device_kind
+    peaks = dict(DEVICE_PEAKS.get(device_kind, {}))
+    for key, override in (("peak_flops", knobs.peak_flops()),
+                          ("hbm_gbps", knobs.peak_hbm_gbps()),
+                          ("ici_gbps", knobs.ici_peak_gbps())):
+        if override > 0.0:
+            peaks[key] = override
+    return peaks
 
 
 def reset() -> None:
     """Forget process-level state (tests): records, the extraction
-    counter, and both measured-peak probes."""
-    global _extractions, _peak_flops_probe, _hbm_gbps_probe
+    counter, and which failures were already logged."""
+    global _extractions
     with _lock:
         _records.clear()
+        _warned.clear()
         _extractions = 0
-    with _probe_lock:
-        _peak_flops_probe = None
-        _hbm_gbps_probe = None

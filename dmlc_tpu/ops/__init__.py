@@ -32,6 +32,11 @@ from dmlc_tpu.ops.sequence_parallel import (
     zigzag_shard,
     zigzag_unshard,
 )
+from dmlc_tpu.utils.jax_compat import place_compile_cache
+
+# the kernels in this package are bare ``jax.jit`` sites (they compile at
+# first call, not at import): place the persistent compile cache now
+place_compile_cache()
 
 __all__ = [
     "spmv",

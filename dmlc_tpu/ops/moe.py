@@ -28,9 +28,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from dmlc_tpu.utils.jax_compat import shard_map
 
 from dmlc_tpu.utils.logging import check
 

@@ -29,9 +29,14 @@ to a lane multiple (128) by the wrapper, and the row tile to a sublane
 multiple. Padded rows carry weight 0, padded features carry x == w == 0, so
 both are arithmetic no-ops (the same invariant as device/csr.py padding).
 
-Opt-in: models/linear.py uses it when DMLC_TPU_PALLAS=1 (or use_pallas=True)
-— measured on-par with XLA's fusion for small feature dims, it exists as the
-template for wider fused steps (FM interactions, multi-tower).
+Opt-in: models/linear.py uses it when DMLC_TPU_PALLAS=1 (or use_pallas=True);
+it exists as the template for wider fused steps (FM interactions,
+multi-tower).
+
+Every kernel here compiles through Mosaic for the TPU it runs on
+(``interpret=False``, the default) and fails loudly on a backend Mosaic
+cannot target. Interpreter mode is something a caller passes — the CPU
+tests do — never something this module infers from the backend.
 """
 
 from __future__ import annotations
@@ -41,17 +46,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
 from dmlc_tpu.ops.objectives import margin_loss_grad
-
-try:  # pallas ships with jax; keep the module importable without it
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    available = True
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
-    available = False
 
 _LANE = 128
 _TILE_B = 512
@@ -239,11 +237,13 @@ def coo_segment_sum(contrib, row_ids, num_rows: int, interpret: bool = False):
             pl.BlockSpec((_SEG_TILE_E, 1), lambda j, k: (k, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, _SEG_TILE_R), lambda j, k: (j, 0),
+        # one lane-major [1, rpad] row, tiled along the lanes: Mosaic
+        # wants a block's second-to-last dim divisible by 8 or equal to
+        # the array's — a (1, TILE_R) block of a [tiles, TILE_R] array is
+        # neither (refused on the v5e), of a [1, rpad] array it is
+        out_specs=pl.BlockSpec((1, _SEG_TILE_R), lambda j, k: (0, j),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct(
-            (rpad // _SEG_TILE_R, _SEG_TILE_R), jnp.float32
-        ),
+        out_shape=jax.ShapeDtypeStruct((1, rpad), jnp.float32),
         cost_estimate=pl.CostEstimate(
             # each (row tile, entry tile) pair compares + masked-adds
             flops=2 * (rpad // _SEG_TILE_R) * epad,
@@ -311,16 +311,14 @@ def _tokenize_call(cur, prv, nxt, interpret: bool = False):
     )(cur, prv, nxt)
 
 
-def tokenize_boundaries(a, interpret=None):
+def tokenize_boundaries(a, interpret: bool = False):
     """(starts_mask, ends_mask) bool arrays for libsvm tokens over the
     uint8 chunk ``a`` — the Pallas variant of
     ``vparse.token_boundary_masks``, used when ``DMLC_TPU_PALLAS`` is
-    ``1``/``parse``. ``interpret=None`` auto-selects interpreter mode off
-    TPU (Mosaic targets TPU only)."""
+    ``1``/``parse``. Compiles for the TPU by default; ``interpret=True``
+    is the caller's explicit request for the Pallas interpreter."""
     import numpy as np
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n = int(a.size)
     if n == 0:
         empty = np.zeros(0, dtype=bool)

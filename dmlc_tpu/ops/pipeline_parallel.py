@@ -26,9 +26,9 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size, pcast
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from dmlc_tpu.utils.jax_compat import axis_size, pcast, shard_map
 
 from dmlc_tpu.utils.logging import check
 

@@ -25,8 +25,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-from dmlc_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def expand_row_ids(offsets, nnz: int):

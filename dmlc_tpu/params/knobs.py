@@ -184,9 +184,10 @@ add five more:
 - ``DMLC_TPU_STEP_PEAK_MBPS`` — roofline ceiling for the device step's
   byte rate in MB/s (default 0 = unknown; set from the model's measured
   FLOP rate)
-- ``DMLC_TPU_ICI_PEAK_GBPS`` — per-direction per-link ICI peak in GB/s
-  (default 45; the same figure bench_collective.py scores utilization
-  against)
+- ``DMLC_TPU_ICI_PEAK_GBPS`` — override for the per-chip interconnect
+  peak in GB/s (default 0 = the device kind's published figure in
+  ``obs.xla_cost.DEVICE_PEAKS``; the same figure bench_collective.py
+  scores utilization against)
 
 The compiled-step cost attribution layer (obs/xla_cost.py, see
 docs/observability.md "Compiled-step cost attribution") adds three
@@ -195,11 +196,12 @@ more:
 - ``DMLC_TPU_STEP_SAMPLE_N`` — device-step latency sampling stride:
   every N-th step gets a ``block_until_ready`` and a
   ``dmlc_step_device_ms`` observation (default 64; 0 = never)
-- ``DMLC_TPU_PEAK_FLOPS`` — model-based roofline peak in FLOP/s for the
-  MFU verdict (default 0 = use the measured matmul probe)
-- ``DMLC_TPU_PEAK_HBM_GBPS`` — model-based memory-bandwidth peak in
-  GB/s for the achieved-HBM-fraction verdict (default 0 = use the
-  measured streaming probe)
+- ``DMLC_TPU_PEAK_FLOPS`` — override for the roofline peak in FLOP/s
+  behind the MFU verdict (default 0 = the device kind's published peak,
+  ``obs.xla_cost.DEVICE_PEAKS``; an unknown kind gives no MFU)
+- ``DMLC_TPU_PEAK_HBM_GBPS`` — override for the memory-bandwidth peak
+  in GB/s behind the achieved-HBM-fraction verdict (default 0 = the
+  device kind's published peak, as above)
 
 Baked columnar shards (io/shard.py + tools/bake.py, see
 docs/pipeline.md "Baked shards & global shuffle") add three more:
@@ -536,10 +538,11 @@ def step_peak_mbps() -> float:
 
 
 def ici_peak_gbps() -> float:
-    """Per-direction per-link ICI peak bandwidth in GB/s
-    (``DMLC_TPU_ICI_PEAK_GBPS``, default 45 — the figure
-    bench_collective.py scores utilization against)."""
-    return max(0.0, float(get_env("DMLC_TPU_ICI_PEAK_GBPS", 45.0)))
+    """Override for the per-chip interconnect peak in GB/s
+    (``DMLC_TPU_ICI_PEAK_GBPS``, default 0 = the device kind's published
+    figure, ``obs.xla_cost.DEVICE_PEAKS``) — what bench_collective.py
+    and the goodput collective roofline score utilization against."""
+    return max(0.0, float(get_env("DMLC_TPU_ICI_PEAK_GBPS", 0.0)))
 
 
 def step_sample_n() -> int:
@@ -552,19 +555,18 @@ def step_sample_n() -> int:
 
 
 def peak_flops() -> float:
-    """Model-based roofline peak in FLOP/s (``DMLC_TPU_PEAK_FLOPS``,
-    default 0 = auto: the measured matmul probe
-    ``obs.xla_cost.probed_peak_flops`` stands in). The MFU verdict is
-    window FLOPs (steps × per-step XLA flops) over this ceiling."""
+    """Override for the roofline peak in FLOP/s (``DMLC_TPU_PEAK_FLOPS``,
+    default 0 = the device kind's published peak,
+    ``obs.xla_cost.device_peaks``). The MFU verdict is window FLOPs
+    (steps × per-step XLA flops) over this ceiling."""
     return max(0.0, float(get_env("DMLC_TPU_PEAK_FLOPS", 0.0)))
 
 
 def peak_hbm_gbps() -> float:
-    """Model-based device-memory-bandwidth peak in GB/s
-    (``DMLC_TPU_PEAK_HBM_GBPS``, default 0 = auto: the measured
-    streaming probe ``obs.xla_cost.probed_hbm_gbps`` stands in). The
-    achieved-HBM-fraction verdict is window bytes accessed over this
-    ceiling."""
+    """Override for the device-memory-bandwidth peak in GB/s
+    (``DMLC_TPU_PEAK_HBM_GBPS``, default 0 = the device kind's published
+    peak, ``obs.xla_cost.device_peaks``). The achieved-HBM-fraction
+    verdict is window bytes accessed over this ceiling."""
     return max(0.0, float(get_env("DMLC_TPU_PEAK_HBM_GBPS", 0.0)))
 
 
@@ -743,8 +745,5 @@ KNOWN_KNOBS = (
     # bench harness
     "DMLC_TPU_BENCH_DETAIL",
     "DMLC_TPU_BENCH_DIR",
-    "DMLC_TPU_BENCH_PROBE_ATTEMPTS",
-    "DMLC_TPU_BENCH_PROBE_TIMEOUT",
     "DMLC_TPU_BENCH_SOCKET_WORLD",
-    "DMLC_TPU_HARVEST_DIR",
 )

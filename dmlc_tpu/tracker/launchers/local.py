@@ -4,18 +4,30 @@ Spawns num_workers + num_servers subprocesses, each with the DMLC_* env
 contract (DMLC_TASK_ID, DMLC_ROLE, DMLC_JOB_CLUSTER=local — local.py:12-23)
 and a per-task retry loop honoring ``--max-attempts`` / ``DMLC_NUM_ATTEMPT``
 (local.py:25-44).
+
+This launcher is for CPU worlds on one host (the socket engine, tests,
+data-service fleets). Every task gets the SAME environment, so on a host
+with TPU chips N jax workers would all try to open the same chips, and a
+chip belongs to one process: measured on the v5e host, every worker after
+the first dies at backend init with "Unable to initialize backend 'tpu':
+ABORTED: Internal error when accessing libtpu multi-process lockfile",
+which names no cause. ``submit`` refuses that launch up front instead.
+Chip training is one process per host over a ``Mesh`` — ``--cluster=tpu``.
 """
 
 from __future__ import annotations
 
+import glob
+import importlib.util
 import os
 import subprocess
 import threading
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 from dmlc_tpu.resilience.preempt import EXIT_PREEMPTED
 from dmlc_tpu.tracker.launchers.common import task_env
 from dmlc_tpu.tracker.rendezvous import submit_with_tracker
+from dmlc_tpu.utils.logging import DMLCError
 
 #: relaunch-after-preemption ceiling: exit-75 restarts do not consume
 #: --max-attempts (a preempted task did nothing wrong), but an unbounded
@@ -23,9 +35,48 @@ from dmlc_tpu.tracker.rendezvous import submit_with_tracker
 MAX_PREEMPT_RELAUNCHES = 32
 
 
+def _tpu_chip_nodes() -> List[str]:
+    """Device nodes of this host's TPU chips (``/dev/accel*`` on older
+    generations, numbered ``/dev/vfio`` groups on v5e), if libtpu is
+    installed to drive them. The tracker parent imports no jax, so it
+    looks at the host, not at ``jax.devices()``."""
+    if importlib.util.find_spec("libtpu") is None:
+        return []
+    return sorted(glob.glob("/dev/accel*")
+                  + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def chip_contention(nproc: int, env: Dict[str, str]) -> Optional[str]:
+    """Why ``nproc`` tasks launched with ``env`` cannot all start on this
+    host, or None. They can when there is one of them, when the host has
+    no TPU, or when ``JAX_PLATFORMS`` keeps them off it."""
+    if nproc <= 1:
+        return None
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.lower().split(","):
+        return None
+    nodes = _tpu_chip_nodes()
+    if not nodes:
+        return None
+    return (
+        "--cluster=local would start %d processes with one environment on "
+        "a host with TPU chips (%s); a chip belongs to one process, so "
+        "every jax worker after the first fails at backend init (libtpu "
+        "lockfile). For chip training use --cluster=tpu (one worker per "
+        "host, all chips through one Mesh); for a CPU socket-engine world "
+        "set JAX_PLATFORMS=cpu (--env JAX_PLATFORMS=cpu)"
+        % (nproc, ", ".join(nodes))
+    )
+
+
 def submit(args) -> None:
     nrepeat = args.max_attempts or int(os.environ.get("DMLC_NUM_ATTEMPT", 1))
     cmd = " ".join(args.command)
+    why = chip_contention(
+        args.num_workers + (getattr(args, "spares", 0) or 0),
+        {**os.environ, **args.env_map})
+    if why:
+        raise DMLCError(why)
     threads: List[threading.Thread] = []
 
     def run_task(task_id: int, role: str, envs: Dict[str, object],

@@ -1,54 +1,42 @@
-"""Version-compat shims for the jax API surface this package uses.
+"""What this package asks of the installed jax beyond its API: where
+compiled programs are kept between processes.
 
-``shard_map`` was promoted from ``jax.experimental.shard_map`` to
-``jax.shard_map`` in newer jax releases; the keyword signature this
-package uses (``mesh=``, ``in_specs=``, ``out_specs=``) is identical in
-both homes, so resolving the symbol once here keeps every mesh code
-path working across the versions the container may carry.
+Written for the one installation there is (jax/jaxlib 0.9.0):
+``jax.shard_map``, ``jax.lax.axis_size`` and ``jax.lax.pcast`` are
+imported from their real homes at the use sites, with no version shims.
+
+Compiling is seconds per shape on a TPU and every process starts cold, so
+the persistent compilation cache is on for every entry point that imports
+the jitting parts of the package (``dmlc_tpu.ops``, and anything built on
+``obs.instrumented_jit``: the learners, the device collectives, bench,
+the examples, the tools). The directory is part of the cache key, so it
+must not move between runs: never a tempfile, pid or time-derived path.
 """
 
 from __future__ import annotations
 
+import os
+
 import jax
 
-try:
-    shard_map = jax.shard_map  # jax >= 0.6
-except AttributeError:  # pragma: no cover - depends on installed jax
-    import functools
-
-    from jax.experimental import shard_map as _esm
-
-    @functools.wraps(_esm.shard_map)
-    def shard_map(f, **kwargs):
-        # newer callers say check_vma; the experimental API calls the same
-        # thing check_rep
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        # the experimental rewrite machinery chokes on symbolic-Zero
-        # cotangents (grad through a shard_map whose aux output is
-        # unused); skipping the replication check sidesteps it and only
-        # costs the rep-based transpose optimization
-        kwargs.setdefault("check_rep", False)
-        return _esm.shard_map(f, **kwargs)
+#: ``<checkout>/.jax_cache`` (gitignored) — used when the environment
+#: names no directory
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 
-def pcast(x, axis_name, *, to):
-    """``jax.lax.pcast`` where available (the explicit replicated→varying
-    cast newer check-vma shard_map requires); identity on older jax,
-    whose shard_map tracks replication implicitly."""
-    cast = getattr(jax.lax, "pcast", None)
-    if cast is None:
-        return x
-    return cast(x, axis_name, to=to)
+def place_compile_cache() -> str:
+    """Decide where jax's persistent compilation cache lives; returns the
+    directory. With ``JAX_COMPILATION_CACHE_DIR`` set, jax's own handling
+    of it is all there is and nothing is set here; unset, the cache goes
+    to :data:`REPO_CACHE_DIR`. Idempotent and cheap (one config read), so
+    it is called wherever the package is about to compile."""
+    if jax.config.jax_compilation_cache_dir is None:
+        jax.config.update("jax_compilation_cache_dir", REPO_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir
 
 
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` where available; otherwise the classic
-    ``psum(1, axis)`` idiom (constant-folded at trace time)."""
-    size = getattr(jax.lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-__all__ = ["shard_map", "axis_size", "pcast"]
+__all__ = ["REPO_CACHE_DIR", "place_compile_cache"]

@@ -38,10 +38,6 @@ def main() -> int:
     args = ap.parse_args()
 
     import jax
-
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        jax.config.update("jax_platforms", plat)
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P

@@ -254,19 +254,14 @@ print("ci_checks: device-resident smoke OK "
       "(bit-identical fit, zero post-warmup recompiles)")
 EOF
 
-# Pallas sparse-step parity: the COO segment-sum kernel (interpret mode
-# off-TPU) vs XLA's scatter spmv on exactly-representable f32 data —
-# sums are integers, so ANY reduction order must produce identical bits.
+# Pallas sparse-step parity: the COO segment-sum kernel (interpret mode,
+# passed explicitly) vs XLA's scatter spmv on exactly-representable f32
+# data — sums are integers, so ANY reduction order must produce
+# identical bits.
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python - <<'EOF'
 import sys
 
 import numpy as np
-
-from dmlc_tpu.ops import pallas_kernels
-
-if not pallas_kernels.available:
-    print("ci_checks: pallas spmv parity SKIPPED (pallas unavailable)")
-    sys.exit(0)
 
 import jax.numpy as jnp
 

@@ -43,21 +43,24 @@ class TestDeviceTier:
                else "engine_reduce_single_process_gbps")
         assert out[key] > 0
 
-    def test_algo_estimator_tpu_branch(self, monkeypatch):
-        """The ICI-utilization estimator (unreachable on CPU meshes) as a
-        pure function: ring algo volume 2(n-1)/n × size, utilization =
-        achieved / peak."""
+    def test_algo_estimator_scores_against_a_known_peak(self):
+        """The ICI-utilization estimator as a pure function: ring algo
+        volume 2(n-1)/n × size, utilization = achieved / the device's
+        interconnect peak — and NO utilization when the peak is unknown
+        (a device kind outside xla_cost.DEVICE_PEAKS, such as this CPU
+        mesh), never a made-up denominator."""
         from bench_collective import allreduce_algo_metrics
 
         n, nbytes, dt = 8, 32 << 20, 0.001
-        monkeypatch.setenv("DMLC_TPU_ICI_PEAK_GBPS", "45")
-        out = allreduce_algo_metrics(n, nbytes, dt, "tpu")
+        out = allreduce_algo_metrics(n, nbytes, dt, ici_gbps=200.0)
         algo = 2 * (n - 1) / n * nbytes
         assert out["psum_algo_gbps"] == round(algo / dt / 1e9, 3)
         assert out["psum_ici_utilization"] == round(
-            (algo / dt) / 45e9, 3)
+            (algo / dt) / 200e9, 3)
         assert "psum_ici_utilization" not in allreduce_algo_metrics(
-            n, nbytes, dt, "cpu")
+            n, nbytes, dt)
+        assert "psum_ici_utilization" not in \
+            bench_collective.device_psum_metrics(payload_mb=1.0, iters=2)
 
     def test_grad_bucket_tier(self):
         out = bench_collective.grad_bucket_metrics(iters=2)

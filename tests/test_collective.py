@@ -229,7 +229,7 @@ class TestDeviceCollectives:
         import jax
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from dmlc_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         from dmlc_tpu.collective import psum
 
@@ -262,7 +262,7 @@ class TestDeviceCollectives:
         import jax
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from dmlc_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         from dmlc_tpu.collective import ppermute_next
 

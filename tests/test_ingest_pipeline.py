@@ -358,19 +358,40 @@ class TestFixedShapePool:
         return G()
 
     def test_recycles_only_after_transfer_done(self):
+        """The guard is asked once, at retire: landed → reused; still in
+        flight → dropped for good (a guard kept for later could be a
+        donated, deleted array whose is_ready raises forever)."""
         pool = FixedShapePool(recycle=True)
         a = pool.acquire(64, np.float32)
         ready = [False]
         pool.retire([a], [self._guard(lambda: ready[0])])
-        b = pool.acquire(64, np.float32)  # guard not ready → fresh buffer
+        ready[0] = True  # landing later does not resurrect the buffer
+        b = pool.acquire(64, np.float32)
         assert b is not a
-        ready[0] = True
-        c = pool.acquire(64, np.float32)  # drained → the retired buffer
-        assert c is a
+        pool.retire([b], [self._guard(lambda: True)])
+        c = pool.acquire(64, np.float32)  # landed at retire → reused
+        assert c is b
         stats = pool.stats()
         assert stats == {"shapes": 1, "allocated": 2, "reused": 1,
-                         "retired": 1, "double_retired": 0,
-                         "outstanding": 2, "pending_retire": 0}
+                         "retired": 1, "dropped": 1, "double_retired": 0,
+                         "outstanding": 1}
+
+    def test_donated_guard_cannot_wedge_the_pool(self):
+        """A real donated jax array raises from is_ready — which is why
+        retire must run BEFORE the consumer's donating step (the feed's
+        _deliver order), and why nothing is queued behind a guard."""
+        x = jax.device_put(np.ones(8, np.float32))
+        jax.jit(lambda v: v + 1, donate_argnums=0)(x)
+        assert x.is_deleted()
+        with pytest.raises(Exception, match="deleted"):
+            x.is_ready()
+        pool = FixedShapePool(recycle=True)
+        live = jax.device_put(np.ones(8, np.float32))
+        jax.block_until_ready(live)
+        for _ in range(4):  # retire-before-donate keeps recycling
+            buf = pool.acquire(8, np.float32)
+            pool.retire([buf], [live])
+        assert pool.stats()["reused"] == 3
 
     def test_no_recycle_mode_only_accounts_shapes(self):
         pool = FixedShapePool(recycle=False)
@@ -381,12 +402,14 @@ class TestFixedShapePool:
         assert pool.stats()["reused"] == 0
         assert pool.shape_keys == {((8, 4), np.dtype(np.float32).str)}
 
-    def test_retired_backlog_is_bounded(self):
+    def test_in_flight_retires_are_dropped_not_queued(self):
         pool = FixedShapePool(recycle=True)
-        for _ in range(pool.MAX_RETIRED + 10):
+        for _ in range(40):
             buf = pool.acquire(16, np.int32)
             pool.retire([buf], [self._guard(lambda: False)])
-        assert pool.stats()["pending_retire"] == pool.MAX_RETIRED
+        stats = pool.stats()
+        assert stats["dropped"] == 40 and stats["reused"] == 0
+        assert stats["outstanding"] == 0  # dropped buffers are not a leak
 
     def test_double_retire_is_rejected(self):
         """A buffer offered back twice must not be queued twice — two

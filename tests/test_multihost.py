@@ -329,7 +329,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from dmlc_tpu.parallel import data_parallel_mesh
-from dmlc_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 mesh = data_parallel_mesh()
 total = jax.jit(shard_map(

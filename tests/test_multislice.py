@@ -19,7 +19,7 @@ from dmlc_tpu.models.linear import (
     make_linear_train_step,
 )
 from dmlc_tpu.parallel import make_multislice_mesh
-from dmlc_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _mesh_2x4():

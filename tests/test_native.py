@@ -249,3 +249,33 @@ def test_abi_version_gate_tracks_header():
     assert native._BOUND_ABI == versions[0], (
         "cpp/dmlc_tpu.h ABI bumped without updating native._BOUND_ABI"
     )
+
+
+def test_failed_make_is_logged_with_the_compilers_last_line(monkeypatch):
+    """A `make` that ran and failed must not vanish into
+    capture_output: one warning names the exit code and the compiler's
+    last stderr line, then the package carries on with the Python twins."""
+    import subprocess
+    import types
+
+    from dmlc_tpu.utils import logging as dlog
+
+    def fake_run(cmd, **kwargs):
+        assert cmd[0] == "make" and kwargs.get("check") is False
+        return types.SimpleNamespace(
+            returncode=2, stdout="",
+            stderr="parse.cc: In function 'int f()':\n"
+                   "parse.cc:12:3: error: 'nope' was not declared\n"
+                   "make: *** [Makefile:13: libdmlc_tpu.so] Error 1\n")
+
+    seen = []
+    monkeypatch.setattr(subprocess, "run", fake_run)
+    monkeypatch.setattr(native, "_build_attempted", False)
+    dlog.set_log_sink(lambda severity, msg: seen.append((severity, msg)))
+    try:
+        native._try_build()
+    finally:
+        dlog.set_log_sink(None)
+    assert len(seen) == 1 and seen[0][0] == "WARNING"
+    assert "exited 2" in seen[0][1]
+    assert "make: *** [Makefile:13: libdmlc_tpu.so] Error 1" in seen[0][1]

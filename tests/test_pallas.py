@@ -1,18 +1,15 @@
 """Pallas fused train-step kernel (ops/pallas_kernels.py).
 
-Runs in interpret mode on the CPU test platform (Mosaic targets TPU only);
-on real TPU the same kernel compiles — parity + perf vs XLA's fusion was
-measured on v5e (see BASELINE.md "Pallas fused step").
+Every call here passes ``interpret=True`` explicitly: Mosaic targets the
+TPU only, and product code never infers interpreter mode from the
+backend. The same kernels compile with ``interpret=False`` on the chip in
+``chip_smoke.py``'s kernel phase.
 """
 
 import numpy as np
 import pytest
 
 from dmlc_tpu.ops import pallas_kernels
-
-pytestmark = pytest.mark.skipif(
-    not pallas_kernels.available, reason="pallas unavailable"
-)
 
 
 def _reference(objective, x, y, wgt, w, b):
@@ -142,7 +139,8 @@ def test_csr_model_step_with_pallas_matches_xla():
         params = init_linear_params(nfeat)
         velocity = {"w": jnp.zeros(nfeat), "b": jnp.zeros(())}
         step = make_linear_train_step(
-            None, layout="csr", num_features=nfeat, use_pallas=use_pallas
+            None, layout="csr", num_features=nfeat, use_pallas=use_pallas,
+            pallas_interpret=True,
         )
         for _ in range(3):
             params, velocity, metrics = step(params, velocity, batch)
@@ -173,7 +171,8 @@ def test_model_step_with_pallas_matches_xla():
         params = init_linear_params(f)
         velocity = {"w": jnp.zeros(f), "b": jnp.zeros(())}
         step = make_linear_train_step(
-            None, layout="dense", use_pallas=use_pallas
+            None, layout="dense", use_pallas=use_pallas,
+            pallas_interpret=True,
         )
         params, velocity, metrics = step(params, velocity, batch)
         outs[use_pallas] = (np.asarray(params["w"]),

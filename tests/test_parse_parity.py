@@ -504,33 +504,32 @@ class TestBackendsEndToEnd:
 
 class TestPallasTokenizer:
     """The Pallas boundary kernel matches vparse.token_boundary_masks
-    byte-for-byte (interpret mode off-TPU)."""
+    byte-for-byte (interpret mode, passed explicitly: Mosaic targets the
+    TPU only)."""
 
     def test_mask_parity(self):
-        pallas = pytest.importorskip("jax.experimental.pallas")
         from dmlc_tpu.ops import pallas_kernels
 
-        if not pallas_kernels.available:
-            pytest.skip("pallas unavailable")
         r = random.Random(77)
         alphabet = b"0123456789.:-+e \t\r\nqid"
         for size in (0, 1, 127, 128, 129, 4096, 33000):
             data = bytes(r.choice(alphabet) for _ in range(size))
             a = np.frombuffer(data, dtype=np.uint8)
             ns, ne = vparse.token_boundary_masks(a)
-            ps, pe = pallas_kernels.tokenize_boundaries(a)
+            ps, pe = pallas_kernels.tokenize_boundaries(a, interpret=True)
             np.testing.assert_array_equal(ns, ps)
             np.testing.assert_array_equal(ne, pe)
 
     def test_gated_span_helper(self, monkeypatch):
         monkeypatch.setenv("DMLC_TPU_PALLAS", "parse")
         a = np.frombuffer(b"1 2:3 4:5\n0 6:7\n", dtype=np.uint8)
-        spans = vparse.pallas_token_spans(a)
-        if spans is None:
-            pytest.skip("pallas path unavailable on this host")
-        starts, ends = spans
+        starts, ends = vparse.pallas_token_spans(a, interpret=True)
         sm, em = vparse.token_boundary_masks(a)
         np.testing.assert_array_equal(starts, np.flatnonzero(sm))
         np.testing.assert_array_equal(ends, np.flatnonzero(em) + 1)
+        # asking for the kernel where Mosaic cannot target the backend
+        # fails loudly — never a silent return to the numpy tokenizer
+        with pytest.raises(Exception):
+            vparse.pallas_token_spans(a)
         monkeypatch.setenv("DMLC_TPU_PALLAS", "0")
         assert vparse.pallas_token_spans(a) is None

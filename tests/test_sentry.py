@@ -1,10 +1,12 @@
 """Perf sentry (obs/sentry.py), the bench-gate CLI, obs-report --diff,
 and the scripts/ci_checks.sh wiring.
 
-The real BENCH_r*.json artifacts in the repo root double as fixtures:
-the recorded r05 numbers must pass the gate, a synthetic 20% headline
-regression on top of them must fail it (the acceptance contract the
-tolerance defaults were tuned against).
+The history fixtures are built under ``tmp_path`` from the synthetic
+record the sentry itself defines (``sentry.SMOKE_HISTORY``), written in
+the driver's ``BENCH_r*.json`` shape: the newest record must pass the
+gate, a synthetic 20% headline regression on top of it must fail it (the
+acceptance contract the tolerance defaults were tuned against). No
+record in the repo root is a test input — records are not fixtures.
 """
 
 import json
@@ -18,7 +20,28 @@ from dmlc_tpu.obs import flight, sentry
 from dmlc_tpu.tools import bench_gate, obs_report
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_GLOB = os.path.join(REPO, "BENCH_r*.json")
+
+
+def _driver_record(n, parsed):
+    """One round in the driver's artifact shape; ``parsed`` None = the
+    round printed no summary line (a truncated tail)."""
+    return {"n": n, "cmd": "python bench.py", "rc": 0,
+            "tail": "" if parsed is None else json.dumps(parsed) + "\n",
+            "parsed": parsed}
+
+
+@pytest.fixture
+def bench_history(tmp_path):
+    """BENCH_r01..r05.json under tmp_path from sentry.SMOKE_HISTORY, with
+    one summary-less round (r04) in the middle; returns (glob, paths)."""
+    rounds = list(sentry.SMOKE_HISTORY)
+    rounds.insert(3, None)
+    paths = []
+    for n, parsed in enumerate(rounds, start=1):
+        path = tmp_path / ("BENCH_r%02d.json" % n)
+        path.write_text(json.dumps(_driver_record(n, parsed)))
+        paths.append(str(path))
+    return os.path.join(str(tmp_path), "BENCH_r*.json"), paths
 
 
 class TestGateMath:
@@ -134,10 +157,11 @@ class TestGateMath:
 
 
 class TestLoadRecords:
-    def test_null_parsed_round_yields_no_record(self):
-        # r04 recorded no summary line; it must not poison the series
-        recs = sentry.load_record(os.path.join(REPO, "BENCH_r04.json"))
-        assert recs == []
+    def test_null_parsed_round_yields_no_record(self, bench_history):
+        # a round that recorded no summary line must not poison the series
+        _glob, paths = bench_history
+        assert sentry.load_record(paths[3]) == []
+        assert len(sentry.load_records(paths)) == len(paths) - 1
 
     def test_driver_shape_and_jsonl_detail(self, tmp_path):
         p = tmp_path / "detail.json"
@@ -156,21 +180,20 @@ class TestBenchGateCLI:
         assert bench_gate.main(["--smoke"]) == 0
         assert "OK" in capsys.readouterr().out
 
-    def test_real_r05_history_passes(self, capsys):
-        rc = bench_gate.main([
-            "--fresh", os.path.join(REPO, "BENCH_r05.json"),
-            "--history", BENCH_GLOB,
-        ])
+    def test_newest_round_passes_its_history(self, bench_history, capsys):
+        glob_, paths = bench_history
+        rc = bench_gate.main(["--fresh", paths[-1], "--history", glob_])
         assert rc == 0
         assert "within tolerance" in capsys.readouterr().out
 
-    def test_synthetic_20pct_regression_fails(self, tmp_path, capsys):
-        obj = json.load(open(os.path.join(REPO, "BENCH_r05.json")))
+    def test_synthetic_20pct_regression_fails(self, bench_history, tmp_path,
+                                              capsys):
+        glob_, paths = bench_history
+        obj = json.load(open(paths[-1]))
         obj["parsed"]["value"] = round(obj["parsed"]["value"] * 0.8, 1)
-        bad = tmp_path / "BENCH_bad.json"
+        bad = tmp_path / "fresh_bad.json"
         bad.write_text(json.dumps(obj))
-        rc = bench_gate.main(["--fresh", str(bad),
-                              "--history", BENCH_GLOB])
+        rc = bench_gate.main(["--fresh", str(bad), "--history", glob_])
         assert rc == 1
         out = capsys.readouterr().out
         assert "higgs_libsvm_ingest" in out and "regression" in out
@@ -182,8 +205,8 @@ class TestBenchGateCLI:
             ["--history", str(tmp_path / "nothing_*.json")])
         assert rc == 2
 
-    def test_fresh_defaults_to_history_tail(self, capsys):
-        assert bench_gate.main(["--history", BENCH_GLOB]) == 0
+    def test_fresh_defaults_to_history_tail(self, bench_history, capsys):
+        assert bench_gate.main(["--history", bench_history[0]]) == 0
 
     def test_fresh_without_history_is_advisory(self, tmp_path, capsys):
         # first bench round: a fresh record but an empty history window

@@ -178,6 +178,11 @@ class TestGradients:
 
 
 class TestPallasFlashLocal:
+    """The wrapper around the Mosaic kernel, with spies (Mosaic lowers on
+    the TPU only and this suite is pinned to the CPU); the real kernel
+    compiles and is compared with ``full_attention`` at T=2048 causal in
+    ``chip_smoke.py``'s kernels phase."""
+
     def test_layout_adapter(self, monkeypatch):
         """The wrapper transposes [B,T,H,D] <-> [B,H,T,D] around the kernel
         and passes sm_scale; verified with a spy standing in for the Mosaic
@@ -215,20 +220,6 @@ class TestPallasFlashLocal:
             np.asarray(out),
             np.asarray(full_attention(q, k, v, causal=True)),
             rtol=2e-4, atol=2e-5,
-        )
-
-    @pytest.mark.skipif(
-        jax.default_backend() != "tpu", reason="Mosaic lowers on TPU only"
-    )
-    def test_on_chip_matches_xla(self):
-        rng = np.random.RandomState(8)
-        q, k, v = _qkv(rng, b=1, t=1024, h=2, d=128)
-        from dmlc_tpu.ops.sequence_parallel import make_pallas_flash_local
-
-        out = jax.jit(make_pallas_flash_local(causal=True))(q, k, v)
-        want = full_attention(q, k, v, causal=True)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(want), rtol=2e-2, atol=2e-2
         )
 
     def test_auto_blocks_divide_awkward_t(self, monkeypatch):

@@ -24,7 +24,7 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dmlc_tpu.collective import device as dev
-from dmlc_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 WORLD = 2
 
@@ -136,11 +136,9 @@ class TestThreeWayParity:
         ref_hex, ref_dtype = socket_results[case]
         from contextlib import nullcontext
 
-        from jax.experimental import enable_x64
-
         # f64 cases need x64 on for the device paths; the socket engine
         # reduces in native numpy and needs nothing
-        ctx = enable_x64() if dtype == np.float64 else nullcontext()
+        ctx = jax.enable_x64(True) if dtype == np.float64 else nullcontext()
         with ctx:
             got_engine = _engine_reduce(op, stacked)
             got_spmd = _spmd_allreduce(op, stacked)

@@ -289,6 +289,30 @@ class TestLocalEndToEnd:
         assert proc.returncode != 0
         assert "tracker is still waiting" in proc.stderr
 
+    def test_chip_contention_is_refused_with_the_cause(self, monkeypatch):
+        """N local tasks sharing one environment on a TPU host would all
+        open the same chips (a chip belongs to one process; on the v5e
+        every worker after the first dies on a libtpu lockfile error that
+        names no cause) — the launcher refuses up front and says why."""
+        from dmlc_tpu.tracker.launchers import local
+        from dmlc_tpu.utils.logging import DMLCError
+
+        monkeypatch.setattr(local, "_tpu_chip_nodes",
+                            lambda: ["/dev/vfio/0", "/dev/vfio/1"])
+        why = local.chip_contention(2, {"JAX_PLATFORMS": "tpu,cpu"})
+        assert "a chip belongs to one process" in why
+        assert "--cluster=tpu" in why and "JAX_PLATFORMS=cpu" in why
+        assert local.chip_contention(2, {}) is not None  # jax default
+        # one process, a CPU-pinned world, or no chips: nothing to refuse
+        assert local.chip_contention(1, {}) is None
+        assert local.chip_contention(4, {"JAX_PLATFORMS": "cpu"}) is None
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        args = parse(["--cluster", "local", "-n", "2", "true"])
+        with pytest.raises(DMLCError, match="a chip belongs to one process"):
+            local.submit(args)
+        monkeypatch.setattr(local, "_tpu_chip_nodes", lambda: [])
+        assert local.chip_contention(4, {}) is None
+
     def test_local_launcher_retry(self, tmp_path):
         """A task failing on attempt 0 succeeds on retry (local.py:25-44).
 

@@ -505,15 +505,57 @@ def _sites():
 
 
 # ---------------------------------------------------------------------------
-# ceiling probes
+# published peaks: one table keyed by device_kind, knobs override, an
+# unknown kind yields nothing
 # ---------------------------------------------------------------------------
 
 
-class TestProbes:
-    def test_probes_positive_and_cached(self):
-        f1 = xla_cost.probed_peak_flops()
-        assert f1 > 0
-        assert xla_cost.probed_peak_flops() == f1  # cached, no re-run
-        g1 = xla_cost.probed_hbm_gbps()
-        assert g1 > 0
-        assert xla_cost.probed_hbm_gbps() == g1
+class TestDevicePeaks:
+    @pytest.fixture(autouse=True)
+    def _no_overrides(self, monkeypatch):
+        for knob in ("DMLC_TPU_PEAK_FLOPS", "DMLC_TPU_PEAK_HBM_GBPS",
+                     "DMLC_TPU_ICI_PEAK_GBPS"):
+            monkeypatch.delenv(knob, raising=False)
+
+    def test_known_kind_gets_published_peaks(self):
+        # Google Cloud "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+        # 1,600 Gbit/s (= 200 GB/s) interconnect
+        assert xla_cost.device_peaks("TPU v5 lite") == {
+            "peak_flops": 197e12, "hbm_gbps": 819.0, "ici_gbps": 200.0}
+
+    def test_override_wins(self, monkeypatch):
+        monkeypatch.setenv("DMLC_TPU_PEAK_FLOPS", "1e12")
+        monkeypatch.setenv("DMLC_TPU_ICI_PEAK_GBPS", "45")
+        peaks = xla_cost.device_peaks("TPU v5 lite")
+        assert peaks["peak_flops"] == 1e12 and peaks["ici_gbps"] == 45.0
+        assert peaks["hbm_gbps"] == 819.0  # not overridden: the table
+        # an override also serves a kind the table does not know
+        assert xla_cost.device_peaks("cpu") == {
+            "peak_flops": 1e12, "ici_gbps": 45.0}
+
+    def test_unknown_kind_has_no_peaks_and_no_verdict(self):
+        # this process's own device is the CPU backend: not in the table
+        assert xla_cost.device_peaks() == {}
+        assert xla_cost.device_peaks("TPU v99") == {}
+        flat = _step_window()
+        att = goodput.attribute(flat, 2.0, ceilings=xla_cost.device_peaks(),
+                                current=flat)
+        # absent, never made up — and no probe program ran to invent one
+        assert "mfu" not in att and "compute" not in att
+        assert "hbm_fraction" not in att
+        assert att["roofline"]["collective"]["utilization"] is None
+
+    def test_fit_loop_scores_against_its_device(self, monkeypatch):
+        """FitLoopObs hands the device's peaks to its ledger: with a
+        peak known the window gains mfu, on the bare CPU backend it
+        stays absent."""
+        from dmlc_tpu.models.fitloop import FitLoopObs
+
+        for peak, expect in ((None, False), ("1e6", True)):
+            if peak:
+                monkeypatch.setenv("DMLC_TPU_PEAK_FLOPS", peak)
+            reg = Registry()
+            fl = FitLoopObs("linear", reg=reg)
+            reg.gauge("dmlc_xla_flops", fn="linear.step").set(2e3)
+            win = fl.end_epoch(0, 50, 0, 0.5)
+            assert ("mfu" in win) is expect
