@@ -207,7 +207,10 @@ int64_t ingest_fetch_batch_coo_sharded(void* handle, float* labels,
 
 /* Telemetry: out[0]=bytes_read, [1]=chunks, [2]=reader_io_ns,
  * [3]=reader_wait_ns, [4]=parse_ns, [5]=worker_wait_ns,
- * [6]=consumer_wait_ns (SURVEY §5.1 per-stage timers). */
+ * [6]=consumer_wait_ns (SURVEY §5.1 per-stage timers), [7]=reader_cpu_ns,
+ * [8]=parse_cpu_ns (CPU time the reader thread and the parse workers used,
+ * CLOCK_THREAD_CPUTIME_ID; wall time is [2] and [4]). Fills min(n, 9)
+ * slots: a caller that passes the older n=7 is served as before. */
 void ingest_stats(void* handle, double* out, int32_t n);
 int64_t ingest_bytes_read(void* handle);
 void ingest_close(void* handle);

@@ -26,6 +26,7 @@
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
+#include <ctime>
 #include <deque>
 #include <map>
 #include <memory>
@@ -107,6 +108,32 @@ inline int64_t NowNs() {
              std::chrono::steady_clock::now().time_since_epoch())
       .count();
 }
+
+// CPU time the calling thread has used (not wall time: a blocked thread
+// does not advance it).
+inline int64_t ThreadCpuNs() {
+  timespec ts;
+  clock_gettime(CLOCK_THREAD_CPUTIME_ID, &ts);
+  return static_cast<int64_t>(ts.tv_sec) * 1000000000 + ts.tv_nsec;
+}
+
+// Adds the thread's own CPU time to a shared counter: made on the thread
+// it meters, Tick() after each unit of work, once more when it leaves.
+class ThreadCpuMeter {
+ public:
+  explicit ThreadCpuMeter(std::atomic<int64_t>* sink)
+      : sink_(sink), last_(ThreadCpuNs()) {}
+  ~ThreadCpuMeter() { Tick(); }
+  void Tick() {
+    int64_t now = ThreadCpuNs();
+    sink_->fetch_add(now - last_);
+    last_ = now;
+  }
+
+ private:
+  std::atomic<int64_t>* sink_;
+  int64_t last_;
+};
 
 inline bool is_eol(char c) { return c == '\n' || c == '\r'; }
 
@@ -390,11 +417,14 @@ class Pipeline {
   void Start() {
     if (!push_mode_) {
       reader_ = std::thread([this] {
+        ThreadCpuMeter cpu(&reader_cpu_ns_);
+        reader_cpu_ = &cpu;
         try {
           ReaderMain();
         } catch (const std::bad_alloc&) {
           Fail(kEOom);
         }
+        reader_cpu_ = nullptr;
       });
     }
     for (int i = 0; i < nthread_; ++i) {
@@ -844,7 +874,7 @@ class Pipeline {
   // Per-stage counters for bench/diagnosis (SURVEY §5.1): where does wall
   // time go between reading, parsing and the consumer?
   void Stats(double* out, int32_t n) const {
-    double vals[7] = {
+    double vals[9] = {
         static_cast<double>(bytes_read_.load()),
         static_cast<double>(chunk_count_.load()),
         static_cast<double>(reader_io_ns_.load()),
@@ -852,8 +882,10 @@ class Pipeline {
         static_cast<double>(parse_ns_.load()),
         static_cast<double>(worker_wait_ns_.load()),
         static_cast<double>(consumer_wait_ns_.load()),
+        static_cast<double>(reader_cpu_ns_.load()),
+        static_cast<double>(parse_cpu_ns_.load()),
     };
-    for (int32_t i = 0; i < n && i < 7; ++i) out[i] = vals[i];
+    for (int32_t i = 0; i < n && i < 9; ++i) out[i] = vals[i];
   }
 
   int64_t BytesRead() const { return bytes_read_.load(); }
@@ -1313,10 +1345,14 @@ class Pipeline {
     }
     work_.push_back(chunk);
     cv_work_.notify_one();
+    if (reader_cpu_ != nullptr) reader_cpu_->Tick();
     return true;
   }
 
   void FinishReader(int64_t nchunks) {
+    // before the workers can learn the reader is done: a consumer that
+    // sees the end of the pass reads a complete count
+    if (reader_cpu_ != nullptr) reader_cpu_->Tick();
     std::lock_guard<std::mutex> lk(mu_);
     total_chunks_ = nchunks;
     reader_done_ = true;
@@ -1336,6 +1372,7 @@ class Pipeline {
 
   // ---- worker side ----------------------------------------------------
   void WorkerMain() {
+    ThreadCpuMeter cpu(&parse_cpu_ns_);
     for (;;) {
       Chunk* chunk = nullptr;
       {
@@ -1367,6 +1404,7 @@ class Pipeline {
         rc = kEOom;
       }
       parse_ns_.fetch_add(NowNs() - t0);
+      cpu.Tick();  // before the block is handed on, as in FinishReader
       chunk_count_.fetch_add(1);
       bytes_read_.fetch_add(chunk->len());
       ReleaseChunk(chunk);
@@ -1616,6 +1654,13 @@ class Pipeline {
   std::atomic<int64_t> parse_ns_{0};
   std::atomic<int64_t> worker_wait_ns_{0};
   std::atomic<int64_t> consumer_wait_ns_{0};
+  // CPU time of the reader thread and of the parse workers, counted by
+  // the threads themselves (ThreadCpuMeter)
+  std::atomic<int64_t> reader_cpu_ns_{0};
+  std::atomic<int64_t> parse_cpu_ns_{0};
+  // the reader thread's meter, touched by that thread only (null in push
+  // mode, where the caller is the reader)
+  ThreadCpuMeter* reader_cpu_ = nullptr;
   std::atomic<int64_t> chunk_count_{0};
 
   std::thread reader_;
@@ -1891,7 +1936,9 @@ int64_t ingest_fetch_batch_coo_sharded(void* handle, float* labels,
 }
 
 // Per-stage counters: out[0]=bytes_read, [1]=chunks, [2]=reader_io_ns,
-// [3]=reader_wait_ns, [4]=parse_ns, [5]=worker_wait_ns, [6]=consumer_wait_ns.
+// [3]=reader_wait_ns, [4]=parse_ns, [5]=worker_wait_ns, [6]=consumer_wait_ns,
+// [7]=reader_cpu_ns, [8]=parse_cpu_ns (thread CPU time, not wall). Fills
+// min(n, 9) slots, so a caller with the older 7-slot buffer stays whole.
 void ingest_stats(void* handle, double* out, int32_t n) {
   static_cast<Pipeline*>(handle)->Stats(out, n);
 }

@@ -416,6 +416,14 @@ void test_pipeline_batch_staging() {
   ingest_stats(h, stats, 7);
   CHECK_TRUE(stats[0] == static_cast<double>(content.size()));
   CHECK_TRUE(stats[4] > 0);  // parse_ns
+  // the two CPU slots appended after the first seven; an old-length
+  // buffer (above) is not written past its end
+  double wide[10] = {0, 0, 0, 0, 0, 0, 0, 0, 0, -1.0};
+  ingest_stats(h, wide, 10);
+  CHECK_TRUE(wide[6] == stats[6]);
+  CHECK_TRUE(wide[7] > 0);  // reader_cpu_ns
+  CHECK_TRUE(wide[8] > 0);  // parse_cpu_ns
+  CHECK_TRUE(wide[9] == -1.0);
   ingest_close(h);
 
   // COO sweep with an overflow probe, then close mid-stage

@@ -426,6 +426,21 @@ class DeviceFeed:
             "device_put dispatch calls (one per batched pytree put; "
             "per-array regressions show up as dispatches/batch > 1)",
             feed=fid)
+        # CPU time the feed's two threads burn, counted by the threads
+        # themselves (time.thread_time_ns, one read a batch each): the
+        # producer's covers re-batch/staging, the consumer's everything
+        # between two deliveries — dispatch, the fit loop, the step's
+        # launch. The native reader and parse workers count theirs in
+        # parser.stats() (reader_cpu_ns / parse_cpu_ns).
+        self._m_cpu = {
+            stage: reg.histogram(
+                "dmlc_stage_cpu_ns",
+                "per-batch thread CPU time by pipeline stage", stage=stage)
+            for stage in ("feed_producer", "consumer")
+        }
+        # passes over the source this feed has begun: with a batch's
+        # place in its pass, the batch id every feed span carries
+        self._pass = 0
         # device-resident fast path (DMLC_TPU_DEVICE_RESIDENT): parsed
         # RowBlock parts emit straight into pooled staging (pad-in-place,
         # device/csr.emit_to_bucket) instead of materialize+pad — python
@@ -501,6 +516,10 @@ class DeviceFeed:
             producer = self._host_batches_resident()
         else:
             producer = self._host_batches_python()
+        # sync mode runs this on the consumer's thread, whose own count
+        # already covers it
+        cpu = None if self._sync_host else self._m_cpu["feed_producer"]
+        cpu_at = time.thread_time_ns()
         while True:
             faultpoint("device.feed")
             t0 = time.monotonic_ns()
@@ -511,6 +530,10 @@ class DeviceFeed:
             finally:
                 self._stage["host_batch_ns"].observe(
                     time.monotonic_ns() - t0)
+                if cpu is not None:
+                    now = time.thread_time_ns()
+                    cpu.observe(now - cpu_at)
+                    cpu_at = now
             yield item
 
     def _host_batches_python(self) -> Iterator:
@@ -565,12 +588,13 @@ class DeviceFeed:
             for sid in seqs:
                 self._ack_seq(sid)
 
-    def _emit_resident(self, pending, flows, seqs):
+    def _emit_resident(self, pending, flows, seqs, bidx):
         """Finalize one accumulated container straight into pooled
         staging — the device-resident single copy (no ``to_block``
         concatenate, no second pad copy)."""
         spec = self.spec
-        with obs.span("stage", rows=len(pending)):
+        with obs.span("stage", rows=len(pending), pass_=self._pass,
+                      batch=bidx):
             for fid in flows:
                 obs.flow_step(fid, "chunk")
             if spec.layout == "csr":
@@ -633,8 +657,8 @@ class DeviceFeed:
                     pending.push_block(block.slice(start, start + take))
                     start += take
                 self._audit.note_batch(bidx, pending)
+                yield self._emit_resident(pending, flows, seqs, bidx)
                 bidx += 1
-                yield self._emit_resident(pending, flows, seqs)
                 pending = RowBlockContainer()
                 flows = []
                 seqs = []
@@ -642,7 +666,7 @@ class DeviceFeed:
                 pending.push_block(block.slice(start, n))
         if len(pending) and not spec.drop_remainder:
             self._audit.note_batch(bidx, pending)
-            yield self._emit_resident(pending, flows, seqs)
+            yield self._emit_resident(pending, flows, seqs, bidx)
             seqs = []
         if seqs and self._ack is not None:
             # chunks whose rows only reached a dropped remainder still
@@ -793,12 +817,13 @@ class DeviceFeed:
                 for k, v in arrays.items()
             }
 
-    def _to_device(self, block, flows=()):
+    def _to_device(self, block, flows=(), nbatch=0):
         """→ (device batch, staging buffers to retire — () when the host
         arrays came from the native pipeline or no pooled path).
         ``flows``: flow ids of the chunks in ``block`` — stepped inside
         the ``stage`` span so the pool staging slice joins the arrow
-        chain (python paths only; native batches carry no flows)."""
+        chain (python paths only; native batches carry no flows).
+        ``nbatch``: the batch's place in its pass, for that span."""
         spec = self.spec
         if isinstance(block, tuple):  # native dense batch, pre-densified
             x, labels, weights, rows = block
@@ -824,7 +849,8 @@ class DeviceFeed:
             return out, (block.x, block.labels, block.weights)
         if spec.layout == "dense":
             check(spec.num_features > 0, "dense layout requires num_features")
-            with obs.span("stage", rows=len(block)):
+            with obs.span("stage", rows=len(block), pass_=self._pass,
+                          batch=nbatch):
                 for fid in flows:
                     obs.flow_step(fid, "chunk")
                 x, labels, weights = block_to_dense(
@@ -839,7 +865,8 @@ class DeviceFeed:
             return out, (x, labels, weights)
         if spec.layout == "csr":
             shards = self._shards
-            with obs.span("stage", rows=len(block)):
+            with obs.span("stage", rows=len(block), pass_=self._pass,
+                          batch=nbatch):
                 for fid in flows:
                     obs.flow_step(fid, "chunk")
                 if shards > 1:
@@ -919,28 +946,36 @@ class DeviceFeed:
         window = self._prefetch
         pending = deque()
         it = iter(self._host_iter)
+        npass = self._pass
         nbatch = 0
-        ndelivered = 0
+        cpu = self._m_cpu["consumer"]
+        cpu_at = time.thread_time_ns()
 
         def _consume(entry):
-            nonlocal ndelivered
+            nonlocal cpu_at
             batch = self._deliver(entry)
             flows = entry[2]
             t2 = time.monotonic_ns()
             # the consume span covers the yield: its duration IS the time
             # the consumer held the batch (generator suspended). The
-            # thread-local current flow is set for that same window so
-            # fit-loop spans (train_step, collective ops) can mark the
-            # in-flight chunk; flow_end fires inside the span, closing
-            # the arrow chain on the consume slice.
-            with obs.span("consume", batch=ndelivered):
+            # thread-local current flow and batch id are set for that same
+            # window so fit-loop spans (train_step, collective ops) can
+            # mark the in-flight chunk and batch; flow_end fires inside
+            # the span, closing the arrow chain on the consume slice.
+            span = obs.span("consume", pass_=npass, batch=entry[4])
+            live = span is not obs.NOOP_SPAN
+            with span:
                 if flows:
                     obs.set_current_flow(flows[0])
+                if live:
+                    obs.set_current_batch(npass, entry[4])
                 try:
                     yield batch
                 finally:
                     if flows:
                         obs.set_current_flow(0)
+                    if live:
+                        obs.set_current_batch(None)
                     for fid in flows:
                         obs.flow_end(fid, "chunk")
             self._stage["consume_ns"].observe(time.monotonic_ns() - t2)
@@ -950,10 +985,14 @@ class DeviceFeed:
                 # exactly-once ack frontier
                 for sid in entry[3]:
                     self._ack_seq(sid)
-            ndelivered += 1
+            # this thread's CPU time since the last delivery: dispatches,
+            # the consumer's loop body, the step's launch
+            now = time.thread_time_ns()
+            cpu.observe(now - cpu_at)
+            cpu_at = now
 
         while True:
-            with obs.span("feed_batch", batch=nbatch):
+            with obs.span("feed_batch", pass_=npass, batch=nbatch):
                 t0 = time.monotonic_ns()
                 try:
                     block = next(it)
@@ -970,13 +1009,14 @@ class DeviceFeed:
                 t1 = time.monotonic_ns()
                 flows = getattr(block, "flow_ids", ())
                 seqs = getattr(block, "seq_ids", ())
-                with obs.span("dispatch", batch=nbatch):
+                with obs.span("dispatch", pass_=npass, batch=nbatch):
                     for fid in flows:
                         obs.flow_step(fid, "chunk")
-                    batch_bufs = self._to_device(block, flows)
+                    batch_bufs = self._to_device(block, flows, nbatch)
                     # async dispatch; the entry keeps the chunk ids so
-                    # _consume can close flows and ack seqs on delivery
-                    pending.append(batch_bufs + (flows, seqs))
+                    # _consume can close flows and ack seqs on delivery,
+                    # and the batch's number for its consume span
+                    pending.append(batch_bufs + (flows, seqs, nbatch))
                 self._stage["dispatch_ns"].observe(time.monotonic_ns() - t1)
                 self._m_batches.inc()
                 # row accounting across block shapes: native dense tuple
@@ -1017,17 +1057,21 @@ class DeviceFeed:
         return out
 
     def before_first(self) -> None:
-        self._host_iter.close()
-        self._parser.before_first()
-        # registry metrics are monotonic (Prometheus semantics); stats()
-        # windows them against this baseline so it always describes the
-        # current epoch, aligned with the native pipeline's per-reopen
-        # counters
-        self._epoch_base = {
-            key: hist.sum for key, hist in self._stage.items()
-        }
-        self._epoch_base["batches"] = self._m_batches.value
-        self._host_iter.before_first()
+        with obs.span("feed_restart", pass_=self._pass + 1):
+            self._host_iter.close()
+            self._parser.before_first()
+            # registry metrics are monotonic (Prometheus semantics);
+            # stats() windows them against this baseline so it always
+            # describes the current epoch, aligned with the native
+            # pipeline's per-reopen counters
+            self._epoch_base = {
+                key: hist.sum for key, hist in self._stage.items()
+            }
+            self._epoch_base["batches"] = self._m_batches.value
+            # the producer is stopped: the next pass's spans, its
+            # thread's included, read the new number
+            self._pass += 1
+            self._host_iter.before_first()
 
     @property
     def bytes_read(self) -> int:

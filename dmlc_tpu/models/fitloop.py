@@ -19,19 +19,14 @@ Usage::
         for batch in feed:
             ...
             fl.note_step()
-        fl.end_epoch(epoch, nstep, t0, loss, feed=feed,
-                     log_every=log_every)
+        fl.finish_epoch(epoch, nstep, t0, acc, history, feed=feed,
+                        log_every=log_every)
 
-It also owns the sampled device-step latency probe: every
-``DMLC_TPU_STEP_SAMPLE_N``-th step the loop calls
-:meth:`FitLoopObs.sample_latency` on the step's output, which times one
-``jax.block_until_ready`` drain and records ``dmlc_step_device_ms`` —
-the dispatch-to-drain latency of the compiled step (a
-block-after-dispatch approximation of device step time; on an async
-backend it includes whatever the dispatch queue still held). The other
-N−1 steps pay one integer increment and no sync, pinned by test; with
-device telemetry or metrics off the stride is 0 and the call is a bare
-attribute read.
+The loop never waits for the device inside a pass: the one wait is the
+pass's loss read-back in :meth:`FitLoopObs.finish_epoch`, under the
+``loss_readback`` span; the bookkeeping after it is ``epoch_close``.
+Device time per step comes from the profile (the benchmark's
+``step_device_ms``), not from a host-side sync.
 
 Under ``DMLC_TPU_METRICS=0`` the registry hands back no-op children and
 the ledger/watchdog collapse to the shared no-op child, so the hot path
@@ -46,9 +41,7 @@ from typing import Optional
 from dmlc_tpu import obs
 from dmlc_tpu.device.feed import stall_breakdown
 from dmlc_tpu.obs import audit, goodput, xla_cost
-from dmlc_tpu.obs.metrics import metrics_enabled
 from dmlc_tpu.obs.watchdog import make_watchdog
-from dmlc_tpu.params.knobs import device_telemetry_enabled, step_sample_n
 from dmlc_tpu.utils.logging import log_info
 
 
@@ -76,43 +69,24 @@ class FitLoopObs:
         # determinism audit: the model digest chain + numeric sentinel
         # (the shared no-op child when DMLC_TPU_AUDIT is off)
         self.audit = audit.auditor()
-        # device-step latency sampling stride: 0 (telemetry or metrics
-        # off, or DMLC_TPU_STEP_SAMPLE_N=0) disarms sample_latency down
-        # to one attribute read per step — read once, here, never per
-        # dispatch
-        self._sample_n = (
-            step_sample_n()
-            if device_telemetry_enabled() and metrics_enabled() else 0)
-        self._sampled = 0
-        self._h_step_ms = self.reg.histogram(
-            "dmlc_step_device_ms",
-            "sampled dispatch-to-drain latency of the optimizer step "
-            "(block_until_ready on every DMLC_TPU_STEP_SAMPLE_N-th "
-            "step's output)",
-            model=model) if self._sample_n else None
 
     def note_step(self, n: int = 1) -> None:
         """Hot-path progress marker (one no-op call under
         ``DMLC_TPU_METRICS=0``)."""
         self.ledger.note_step(n)
 
-    def sample_latency(self, out) -> None:
-        """Sampled device-step latency: on every ``_sample_n``-th call,
-        time one ``jax.block_until_ready(out)`` and record
-        ``dmlc_step_device_ms``. Every other call is one increment and
-        one modulo — no sync, no allocation (pinned by test); disarmed
-        entirely (one attribute read) when the stride is 0."""
-        n = self._sample_n
-        if not n:
-            return
-        self._sampled += 1
-        if self._sampled % n:
-            return
-        import jax
-
-        t0 = time.monotonic_ns()
-        jax.block_until_ready(out)
-        self._h_step_ms.observe((time.monotonic_ns() - t0) / 1e6)
+    def finish_epoch(self, epoch: int, nstep: int, t0_ns: int, acc,
+                     history: list, **end_epoch_kw) -> float:
+        """The epoch boundary of the streaming learners (linear, FM):
+        read the pass's mean loss back from ``acc`` (an
+        ``EpochMetrics``) — the one point where the loop waits for the
+        device to drain, under the ``loss_readback`` span — append it to
+        ``history`` and close the epoch (:meth:`end_epoch`)."""
+        with obs.span("loss_readback", model=self.model, epoch=epoch):
+            loss = acc.mean_loss()
+        history.append(loss)
+        self.end_epoch(epoch, nstep, t0_ns, loss, **end_epoch_kw)
+        return loss
 
     def end_epoch(self, epoch: int, nstep: int, t0_ns: int,
                   loss: Optional[float], feed=None,
@@ -135,31 +109,32 @@ class FitLoopObs:
         exported audit state describes the *closed* epoch and a resume
         re-arms the chains exactly where an uninterrupted run would
         be."""
-        self.h_epoch.observe(time.monotonic_ns() - t0_ns)
-        self.m_steps.inc(nstep)
-        self.m_epochs.inc()
-        if loss is not None:
-            self.g_loss.set(loss)
-        nonfinite = self.audit.note_model(epoch, loss, params)
-        win = self.ledger.tick()
-        if win is not None:
-            win["nonfinite"] = nonfinite
-            self.watchdog.observe(win)
-        if log_every and (epoch + 1) % log_every == 0:
-            parts = ["%s epoch %d" % (self.model, epoch)]
+        with obs.span("epoch_close", model=self.model, epoch=epoch):
+            self.h_epoch.observe(time.monotonic_ns() - t0_ns)
+            self.m_steps.inc(nstep)
+            self.m_epochs.inc()
             if loss is not None:
-                parts.append("loss %.6f" % loss)
-            if feed is not None:
-                parts.append(stall_breakdown(feed.stats()))
+                self.g_loss.set(loss)
+            nonfinite = self.audit.note_model(epoch, loss, params)
+            win = self.ledger.tick()
             if win is not None:
-                parts.append("goodput %.2f binding=%s" % (
-                    win["goodput"]["ratio"], win["binding"]))
-            log_info("%s", " ".join(parts))
-        obs.export_epoch(self.reg)
-        # roll AFTER the export/publish so the epoch's full data chains
-        # rode the heartbeat; this also runs the epoch-over-epoch
-        # self-check (first divergence writes the replay bundle)
-        self.audit.roll_epoch(epoch)
-        if snapshotter is not None and snap_state is not None:
-            snapshotter.capture(epoch, snap_state)
+                win["nonfinite"] = nonfinite
+                self.watchdog.observe(win)
+            if log_every and (epoch + 1) % log_every == 0:
+                parts = ["%s epoch %d" % (self.model, epoch)]
+                if loss is not None:
+                    parts.append("loss %.6f" % loss)
+                if feed is not None:
+                    parts.append(stall_breakdown(feed.stats()))
+                if win is not None:
+                    parts.append("goodput %.2f binding=%s" % (
+                        win["goodput"]["ratio"], win["binding"]))
+                log_info("%s", " ".join(parts))
+            obs.export_epoch(self.reg)
+            # roll AFTER the export/publish so the epoch's full data chains
+            # rode the heartbeat; this also runs the epoch-over-epoch
+            # self-check (first divergence writes the replay bundle)
+            self.audit.roll_epoch(epoch)
+            if snapshotter is not None and snap_state is not None:
+                snapshotter.capture(epoch, snap_state)
         return win

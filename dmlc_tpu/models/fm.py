@@ -61,32 +61,40 @@ FM_PARTITION_RULES = ((r"^(w|b|v)$", P()),)
 
 
 def _fm_forward_grads(params, batch, objective: str, num_features: int):
-    """Local (unreduced) grads + loss sums for one COO batch shard."""
+    """Local (unreduced) grads + loss sums for one COO batch shard.
+
+    The ``step.*`` scopes name the step's phases in the compiled
+    program's metadata (shared with models/linear.py), so a device
+    profile can be read by phase; they change no operation."""
     label = batch["label"]
     weight = batch["weight"]
     values = batch["values"]
     indices = batch["indices"]
-    # offsets → row ids on device (local per shard under shard_map)
-    row_ids = expand_row_ids(batch["offsets"], values.shape[0])
     num_rows = label.shape[0]
 
-    v_e = jnp.take(params["v"], indices, axis=0)  # [nnz, K]
-    xv = values[:, None] * v_e  # [nnz, K]
-    s = jax.ops.segment_sum(xv, row_ids, num_segments=num_rows)  # [B, K]
-    q = jax.ops.segment_sum(xv * xv, row_ids, num_segments=num_rows)
-    linear = spmv(values, indices, row_ids, params["w"], num_rows)
-    margin = params["b"] + linear + 0.5 * jnp.sum(s * s - q, axis=-1)
-
-    loss, gmargin = _margin_grad(objective, margin, label)
-    wg = weight * gmargin  # [B]
-
-    gw = spmv_transpose(values, indices, row_ids, wg, num_features)
-    gb = jnp.sum(wg)
-    # dv[i,k]: per entry x_e * (s[r,k] − x_e v[i,k]), scaled by wg[r]
-    s_e = jnp.take(s, row_ids, axis=0)  # [nnz, K]
-    dv_entry = (wg[row_ids] * values)[:, None] * (s_e - xv)
-    gv = jax.ops.segment_sum(dv_entry, indices, num_segments=num_features)
-    return gw, gb, gv, jnp.sum(weight * loss), jnp.sum(weight)
+    with jax.named_scope("step.gather"):
+        # offsets → row ids on device (local per shard under shard_map)
+        row_ids = expand_row_ids(batch["offsets"], values.shape[0])
+        v_e = jnp.take(params["v"], indices, axis=0)  # [nnz, K]
+    with jax.named_scope("step.forward"):
+        xv = values[:, None] * v_e  # [nnz, K]
+        s = jax.ops.segment_sum(xv, row_ids, num_segments=num_rows)  # [B, K]
+        q = jax.ops.segment_sum(xv * xv, row_ids, num_segments=num_rows)
+        linear = spmv(values, indices, row_ids, params["w"], num_rows)
+        margin = params["b"] + linear + 0.5 * jnp.sum(s * s - q, axis=-1)
+        loss, gmargin = _margin_grad(objective, margin, label)
+        loss_sum = jnp.sum(weight * loss)
+    with jax.named_scope("step.backward"):
+        wg = weight * gmargin  # [B]
+        gb = jnp.sum(wg)
+        # dv[i,k]: per entry x_e * (s[r,k] − x_e v[i,k]), scaled by wg[r]
+        s_e = jnp.take(s, row_ids, axis=0)  # [nnz, K]
+        dv_entry = (wg[row_ids] * values)[:, None] * (s_e - xv)
+    with jax.named_scope("step.scatter"):
+        gw = spmv_transpose(values, indices, row_ids, wg, num_features)
+        gv = jax.ops.segment_sum(
+            dv_entry, indices, num_segments=num_features)
+    return gw, gb, gv, loss_sum, jnp.sum(weight)
 
 
 def make_fm_train_step(
@@ -111,6 +119,7 @@ def make_fm_train_step(
     touch a batch after its step (DeviceFeed loops, FMLearner)."""
     check(num_features > 0, "num_features required")
 
+    @jax.named_scope("step.update")
     def _apply(params, gw, gb, gv, wsum):
         denom = jnp.maximum(wsum, 1e-12)
         return {
@@ -269,17 +278,14 @@ class FMLearner:
             with obs.span("epoch", model="fm", epoch=epoch):
                 for batch in feed:
                     self._ensure(self.param.num_features)
-                    with obs.span("train_step", model="fm", step=nstep):
+                    with obs.span("train_step", model="fm", step=nstep,
+                                  **obs.current_batch()):
                         obs.flow_step(obs.current_flow(), "chunk")
                         self.params, metrics = self._step(
                             self.params, step_batch(batch, "csr")
                         )
                     acc.add(metrics)
                     fl.note_step()
-                    # every DMLC_TPU_STEP_SAMPLE_N-th step: one timed
-                    # block_until_ready -> dmlc_step_device_ms (no sync
-                    # on the other N-1 steps)
-                    fl.sample_latency(metrics)
                     nstep += 1
                     if snapshotter is not None and preempt.poll():
                         preempted = True
@@ -288,10 +294,8 @@ class FMLearner:
                 snapshotter.finalize()
                 raise Preempted(
                     "preempted in epoch %d after %d steps" % (epoch, nstep))
-            loss = acc.mean_loss()
-            history.append(loss)
-            fl.end_epoch(
-                epoch, nstep, t0, loss, feed=feed,
+            fl.finish_epoch(
+                epoch, nstep, t0, acc, history, feed=feed,
                 log_every=log_every, params=self.params,
                 snapshotter=snapshotter,
                 snap_state=(None if snapshotter is None else

@@ -170,7 +170,36 @@ def _build_local_grads(objective: str, layout: str, num_features: int,
 
     The Pallas kernels compile for the backend they are on (Mosaic
     targets the TPU and fails loudly elsewhere); ``pallas_interpret`` is
-    the caller's explicit request for interpreter mode (CPU tests)."""
+    the caller's explicit request for interpreter mode (CPU tests).
+
+    The ``step.*`` scopes name the step's phases in the compiled
+    program's metadata (shared with models/fm.py), so a device profile
+    can be read by phase; they change no operation."""
+
+    def _forward(params, batch):
+        if layout == "dense":
+            return batch["x"] @ params["w"] + params["b"], None
+        # the batch carries CSR offsets (small H2D payload); expand to
+        # per-entry row ids here, on device. Under the mesh shard_map
+        # the shapes are per-shard local, so the same expansion yields
+        # local row ids from the shard's local offsets.
+        row_ids = expand_row_ids(
+            batch["offsets"], batch["values"].shape[0]
+        )
+        if use_pallas == "spmv":
+            from dmlc_tpu.ops.spmv import spmv_pallas
+
+            linear = spmv_pallas(
+                batch["values"], batch["indices"], row_ids,
+                params["w"], batch["label"].shape[0],
+                interpret=pallas_interpret,
+            )
+        else:
+            linear = spmv(
+                batch["values"], batch["indices"], row_ids,
+                params["w"], batch["label"].shape[0],
+            )
+        return linear + params["b"], row_ids
 
     def _local_grads(params, batch):
         label = batch["label"]
@@ -186,43 +215,21 @@ def _build_local_grads(objective: str, layout: str, num_features: int,
             # the XLA path (no silent upcast of bf16 params mid-training)
             return (gw.astype(params["w"].dtype),
                     gb.astype(params["b"].dtype), loss_sum, wsum)
-        if layout == "dense":
-            margin = batch["x"] @ params["w"] + params["b"]
-        else:
-            # the batch carries CSR offsets (small H2D payload); expand to
-            # per-entry row ids here, on device. Under the mesh shard_map
-            # the shapes are per-shard local, so the same expansion yields
-            # local row ids from the shard's local offsets.
-            row_ids = expand_row_ids(
-                batch["offsets"], batch["values"].shape[0]
-            )
-            if use_pallas == "spmv":
-                from dmlc_tpu.ops.spmv import spmv_pallas
-
-                margin = spmv_pallas(
-                    batch["values"], batch["indices"], row_ids,
-                    params["w"], label.shape[0],
-                    interpret=pallas_interpret,
-                ) + params["b"]
+        with jax.named_scope("step.forward"):
+            margin, row_ids = _forward(params, batch)
+            loss, gmargin = _margin_grad(objective, margin, label)
+            loss_sum = jnp.sum(weight * loss)
+        with jax.named_scope("step.backward"):
+            wg = weight * gmargin
+            gb = jnp.sum(wg)
+        with jax.named_scope("step.scatter"):
+            if layout == "dense":
+                gw = batch["x"].T @ wg
             else:
-                margin = spmv(
-                    batch["values"],
-                    batch["indices"],
-                    row_ids,
-                    params["w"],
-                    label.shape[0],
-                ) + params["b"]
-        loss, gmargin = _margin_grad(objective, margin, label)
-        wg = weight * gmargin
-        if layout == "dense":
-            gw = batch["x"].T @ wg
-        else:
-            gw = spmv_transpose(
-                batch["values"], batch["indices"], row_ids, wg,
-                num_features,
-            )
-        gb = jnp.sum(wg)
-        loss_sum = jnp.sum(weight * loss)
+                gw = spmv_transpose(
+                    batch["values"], batch["indices"], row_ids, wg,
+                    num_features,
+                )
         weight_sum = jnp.sum(weight)
         return gw, gb, loss_sum, weight_sum
 
@@ -242,6 +249,7 @@ def _build_apply(learning_rate: float, l2: float, momentum: float):
     """The SGD update: f(params, velocity, gw, gb, wsum) with the grads
     already reduced. Shared across sync flavors like _build_local_grads."""
 
+    @jax.named_scope("step.update")
     def _apply(params, velocity, gw, gb, wsum):
         denom = jnp.maximum(wsum, 1e-12)
         gw = gw / denom + l2 * params["w"]
@@ -814,7 +822,8 @@ class LinearLearner:
                     self._ensure(feed.spec.num_features, layout)
                     # train_step closes the chunk's arrow chain: the feed
                     # set the thread's current flow around this yield
-                    with obs.span("train_step", model="linear", step=nstep):
+                    with obs.span("train_step", model="linear", step=nstep,
+                                  **obs.current_batch()):
                         obs.flow_step(obs.current_flow(), "chunk")
                         self.params, self.velocity, metrics = self._step(
                             self.params, self.velocity,
@@ -822,10 +831,6 @@ class LinearLearner:
                         )
                     acc.add(metrics)
                     fl.note_step()
-                    # every DMLC_TPU_STEP_SAMPLE_N-th step: one timed
-                    # block_until_ready -> dmlc_step_device_ms (no sync
-                    # on the other N-1 steps)
-                    fl.sample_latency(metrics)
                     nstep += 1
                     if log_every and nstep % log_every == 0:
                         log_info(
@@ -845,10 +850,8 @@ class LinearLearner:
                     "preempted in epoch %d after %d steps; last committed "
                     "snapshot epoch %d"
                     % (epoch, nstep, snapshotter.committed_epoch))
-            loss = acc.mean_loss()
-            history.append(loss)
-            fl.end_epoch(
-                epoch, nstep, t0, loss, feed=feed,
+            fl.finish_epoch(
+                epoch, nstep, t0, acc, history, feed=feed,
                 log_every=log_every, params=self.params,
                 snapshotter=snapshotter,
                 snap_state=(None if snapshotter is None else

@@ -793,16 +793,24 @@ class IngestPipeline:
         return labels, weights, indices, values, row_ids, offsets, int(rows)
 
     def stats(self) -> dict:
-        """Per-stage counters (SURVEY §5.1 pipeline timers)."""
-        out = np.zeros(7, dtype=np.float64)
-        self._lib.ingest_stats(self._handle, _ptr(out), 7)
+        """Per-stage counters (SURVEY §5.1 pipeline timers): wall time
+        per stage, and the CPU time the reader thread and the parse
+        workers used, counted by those threads (``*_cpu_ns``)."""
+        out = np.zeros(9, dtype=np.float64)
+        self._lib.ingest_stats(self._handle, _ptr(out), 9)
         keys = ("bytes_read", "chunks", "reader_io_ns", "reader_wait_ns",
-                "parse_ns", "worker_wait_ns", "consumer_wait_ns")
+                "parse_ns", "worker_wait_ns", "consumer_wait_ns",
+                "reader_cpu_ns", "parse_cpu_ns")
         return {k: (int(v) if k in ("bytes_read", "chunks") else float(v))
                 for k, v in zip(keys, out)}
 
     @property
     def bytes_read(self) -> int:
+        # a closed pipeline has no handle to ask: the garbage collector
+        # may finalize this object before the parser that owns it, whose
+        # own teardown then reads the count (a NULL handle is a crash)
+        if not self._handle:
+            return 0
         return int(self._lib.ingest_bytes_read(self._handle))
 
     def close(self) -> None:

@@ -13,7 +13,9 @@ bottleneck diagnosis and auto-tuning):
   :func:`flow_end` — causal dataflow arrows (Chrome-trace flow events)
   connecting a chunk's io→parse→stage→dispatch→consume journey across
   threads and ranks; :func:`current_flow` / :func:`set_current_flow`
-  carry the in-flight chunk's id through the fit loop
+  carry the in-flight chunk's id through the fit loop, and
+  :func:`current_batch` / :func:`set_current_batch` the batch's
+  ``(pass_, batch)`` identity
 - exporters — JSONL / Prometheus textfile / log-sink summary, driven at
   epoch boundaries by :func:`export_epoch` via ``DMLC_TPU_METRICS_EXPORT``
 - :func:`cross_host_snapshot` / :func:`report_skew` — per-host
@@ -68,7 +70,9 @@ from dmlc_tpu.obs.metrics import (
     registry,
 )
 from dmlc_tpu.obs.trace import (
+    NOOP_SPAN,
     clear as clear_trace,
+    current_batch,
     current_flow,
     events as trace_events,
     flow_end,
@@ -76,6 +80,7 @@ from dmlc_tpu.obs.trace import (
     flow_step,
     flush as flush_trace,
     new_flow,
+    set_current_batch,
     set_current_flow,
     span,
     step_span,
@@ -89,12 +94,15 @@ __all__ = [
     "registry",
     "span",
     "step_span",
+    "NOOP_SPAN",
     "new_flow",
     "flow_start",
     "flow_step",
     "flow_end",
     "current_flow",
     "set_current_flow",
+    "current_batch",
+    "set_current_batch",
     "trace_events",
     "clear_trace",
     "flush_trace",

@@ -34,7 +34,16 @@ Optional jax bridging: with ``DMLC_TPU_TRACE_JAX=1`` each span also enters
 a ``jax.profiler.TraceAnnotation`` (and ``step_span`` a
 ``StepTraceAnnotation``) when the running jax exposes them, so the same
 span names show up inside an XLA profiler capture next to the device
-timeline. Absent jax or the API, the bridge silently stays off.
+timeline. The span's args go with it (``TraceAnnotation(name, **args)``)
+and land as the event's stats in the ``.xplane.pb``; the event's name
+stays bare. The two classes are looked up once, by the first bridged
+span. Absent jax or the API, the bridge silently stays off.
+
+Batch identity: ``DeviceFeed`` numbers a batch ``(pass_, batch)`` — its
+pass over the source and the batch's place in that pass — on ``stage``,
+``feed_batch``, ``dispatch`` and ``consume``, and leaves the pair in a
+thread-local around the consume yield (:func:`set_current_batch`), which
+the fit loop's ``train_step`` span reads back (:func:`current_batch`).
 
 Flow events: ``new_flow()`` allocates a job-unique flow id and
 ``flow_start/flow_step/flow_end`` emit Chrome-trace flow events
@@ -82,16 +91,24 @@ def anchor_unix_ns() -> int:
     return _ANCHOR_UNIX_NS
 
 
+# (TraceAnnotation, StepTraceAnnotation) of the running jax, looked up by
+# the first bridged span; (None, None) when jax or the API is absent
+_bridge: Optional[Tuple] = None
+
+
 def _jax_annotation_cls(step: bool = False):
     if os.environ.get("DMLC_TPU_TRACE_JAX") != "1":
         return None
-    try:
-        import jax.profiler as _jp
-    except Exception:
-        return None
-    return getattr(
-        _jp, "StepTraceAnnotation" if step else "TraceAnnotation", None
-    )
+    global _bridge
+    if _bridge is None:
+        try:
+            import jax.profiler as _jp
+        except Exception:
+            _bridge = (None, None)
+        else:
+            _bridge = (getattr(_jp, "TraceAnnotation", None),
+                       getattr(_jp, "StepTraceAnnotation", None))
+    return _bridge[1 if step else 0]
 
 
 class _NoopSpan:
@@ -195,7 +212,7 @@ def span(name: str, **args):
         return NOOP_SPAN
     _ensure_atexit()
     cls = _jax_annotation_cls()
-    annot = cls(name) if cls is not None else None
+    annot = cls(name, **args) if cls is not None else None
     return _Span(name, args, annot)
 
 
@@ -206,7 +223,7 @@ def step_span(step_num: int, name: str = "step", **args):
         return NOOP_SPAN
     _ensure_atexit()
     cls = _jax_annotation_cls(step=True)
-    annot = cls(name, step_num=step_num) if cls is not None else None
+    annot = cls(name, step_num=step_num, **args) if cls is not None else None
     return _Span(name, dict(args, step=step_num), annot)
 
 
@@ -306,6 +323,24 @@ def set_current_flow(fid: int) -> None:
 def current_flow() -> int:
     """This thread's ambient flow id (0 when none is set)."""
     return getattr(_FLOW_TLS, "fid", 0)
+
+
+_NO_BATCH: Dict = {}
+
+
+def set_current_batch(pass_: Optional[int], batch: int = 0) -> None:
+    """Stash the identity of the batch this thread holds (``None``
+    clears it). DeviceFeed sets it beside the current flow, around the
+    consume yield, and only while the consume span is live."""
+    _FLOW_TLS.batch = (
+        _NO_BATCH if pass_ is None else {"pass_": pass_, "batch": batch})
+
+
+def current_batch() -> Dict:
+    """``{"pass_": p, "batch": b}`` of the batch this thread holds, for a
+    span's args (``span("train_step", **current_batch())``); a shared
+    empty dict when none is set."""
+    return getattr(_FLOW_TLS, "batch", _NO_BATCH)
 
 
 def events() -> List[Dict]:

@@ -190,12 +190,9 @@ add five more:
   scores utilization against)
 
 The compiled-step cost attribution layer (obs/xla_cost.py, see
-docs/observability.md "Compiled-step cost attribution") adds three
+docs/observability.md "Compiled-step cost attribution") adds two
 more:
 
-- ``DMLC_TPU_STEP_SAMPLE_N`` — device-step latency sampling stride:
-  every N-th step gets a ``block_until_ready`` and a
-  ``dmlc_step_device_ms`` observation (default 64; 0 = never)
 - ``DMLC_TPU_PEAK_FLOPS`` — override for the roofline peak in FLOP/s
   behind the MFU verdict (default 0 = the device kind's published peak,
   ``obs.xla_cost.DEVICE_PEAKS``; an unknown kind gives no MFU)
@@ -545,15 +542,6 @@ def ici_peak_gbps() -> float:
     return max(0.0, float(get_env("DMLC_TPU_ICI_PEAK_GBPS", 0.0)))
 
 
-def step_sample_n() -> int:
-    """Device-step latency sampling stride (``DMLC_TPU_STEP_SAMPLE_N``,
-    default 64, floor 0 = never sample): every N-th step the fit loop
-    adds one ``block_until_ready`` around the step output and records
-    ``dmlc_step_device_ms`` — the other N−1 steps dispatch async with no
-    added sync. Read once per fit, at FitLoopObs construction."""
-    return max(0, int(get_env("DMLC_TPU_STEP_SAMPLE_N", 64)))
-
-
 def peak_flops() -> float:
     """Override for the roofline peak in FLOP/s (``DMLC_TPU_PEAK_FLOPS``,
     default 0 = the device kind's published peak,
@@ -718,7 +706,6 @@ KNOWN_KNOBS = (
     "DMLC_TPU_PARSE_PEAK_MBPS",
     "DMLC_TPU_STEP_PEAK_MBPS",
     "DMLC_TPU_ICI_PEAK_GBPS",
-    "DMLC_TPU_STEP_SAMPLE_N",
     "DMLC_TPU_PEAK_FLOPS",
     "DMLC_TPU_PEAK_HBM_GBPS",
     # collective / distributed bootstrap

@@ -289,6 +289,107 @@ class TestLearnerEndToEnd:
         )
 
 
+class TestBatchIdentityAndEpochBoundary:
+    """One batch followed through the program's spans by (pass_, batch),
+    and the once-a-pass spans of the epoch boundary, over a two-pass fit."""
+
+    @pytest.fixture
+    def spans(self):
+        from dmlc_tpu.obs import trace as obs_trace
+
+        seen = []
+        obs_trace.add_listener(seen.append)
+        yield seen
+        obs_trace.remove_listener(seen.append)
+
+    @staticmethod
+    def _feed(tmp_path, layout):
+        from dmlc_tpu.data import create_parser
+        from dmlc_tpu.device import BatchSpec, DeviceFeed
+
+        rng = np.random.RandomState(11)
+        nfeat = 12
+        path = tmp_path / "train.svm"
+        with open(path, "w") as fh:
+            for i in range(320):
+                ids = np.sort(rng.choice(nfeat, size=4, replace=False))
+                fh.write("%d %s\n" % (i % 2, " ".join(
+                    "%d:%.4f" % (j, rng.rand()) for j in ids)))
+        return DeviceFeed(
+            create_parser(str(path)),
+            BatchSpec(batch_size=64, layout=layout, num_features=nfeat),
+        )
+
+    @pytest.mark.parametrize("model", ["linear", "fm"])
+    def test_spans_join_by_pass_and_batch(self, tmp_path, spans, model):
+        from dmlc_tpu.models import FMLearner
+
+        if model == "linear":
+            feed = self._feed(tmp_path, "dense")
+            learner = LinearLearner(learning_rate=0.1)
+        else:
+            feed = self._feed(tmp_path, "csr")
+            learner = FMLearner(num_features=12, num_factors=4)
+        history = learner.fit_feed(feed, epochs=2)
+        feed.close()
+        assert len(history) == 2
+
+        def ident(e):
+            return e["args"]["pass_"], e["args"]["batch"]
+
+        by_name = {}
+        for e in spans:
+            if e.get("ph") == "X":
+                by_name.setdefault(e["name"], []).append(e)
+        dispatched = [ident(e) for e in by_name["dispatch"]]
+        # 320 rows in batches of 64, two passes: ids unique in the run
+        assert dispatched == [(p, b) for p in (0, 1) for b in range(5)]
+        for name in ("consume", "train_step"):
+            assert [ident(e) for e in by_name[name]] == dispatched, name
+        # feed_batch also wraps the pull that finds the source exhausted
+        assert set(dispatched) <= {ident(e) for e in by_name["feed_batch"]}
+        # a train_step runs inside the consume span of its own batch
+        for step, held in zip(by_name["train_step"], by_name["consume"]):
+            assert held["ts"] <= step["ts"]
+            assert step["ts"] + step["dur"] <= held["ts"] + held["dur"] + 1
+            assert step["args"]["model"] == model
+        # the epoch boundary: each span once a pass, in this order; the
+        # fit restarts the feed once, between its two passes
+        boundary = [e["name"] for e in spans if e["name"] in
+                    ("loss_readback", "epoch_close", "feed_restart")]
+        assert boundary == ["loss_readback", "epoch_close", "feed_restart",
+                            "loss_readback", "epoch_close"]
+        (restart,) = by_name["feed_restart"]
+        assert restart["args"] == {"pass_": 1}
+        # every span under the read-back's wait is over before it starts
+        first_wait = by_name["loss_readback"][0]
+        assert all(e["ts"] + e["dur"] <= first_wait["ts"] + 1
+                   for e in by_name["consume"][:5])
+
+    def test_python_rebatch_path_carries_ids_on_stage(
+            self, tmp_path, spans, monkeypatch):
+        monkeypatch.setenv("DMLC_TPU_NATIVE", "0")
+        feed = self._feed(tmp_path, "dense")
+        assert not feed._use_native_batches()
+        for _ in feed:
+            pass
+        feed.before_first()
+        for _ in feed:
+            pass
+        feed.close()
+        staged = [(e["args"]["pass_"], e["args"]["batch"])
+                  for e in spans if e["name"] == "stage"]
+        assert staged == [(p, b) for p in (0, 1) for b in range(5)]
+
+    def test_tracing_off_sets_no_batch(self, tmp_path):
+        from dmlc_tpu import obs
+
+        feed = self._feed(tmp_path, "dense")
+        held = [obs.current_batch() for _ in feed]
+        feed.close()
+        assert held == [{}] * 5
+
+
 class TestGraftEntry:
     def test_entry_and_dryrun(self):
         sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))

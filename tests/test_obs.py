@@ -3,10 +3,8 @@ thread safety, disabled-path cost, span tracing, exporters, cross-host
 aggregation, tracker heartbeats, and the Timer satellite fixes.
 """
 
-import gc
 import json
 import os
-import sys
 import threading
 import time
 
@@ -228,30 +226,152 @@ class TestSpans:
         obs.clear_trace()
 
 
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation / StepTraceAnnotation."""
+
+    made = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs, self.entered = name, kwargs, 0
+        _FakeAnnotation.made.append(self)
+
+    def __enter__(self):
+        self.entered += 1
+        return self
+
+    def __exit__(self, *exc):
+        self.entered -= 1
+        return False
+
+
+class TestJaxBridge:
+    @pytest.fixture
+    def bridged(self, monkeypatch):
+        from dmlc_tpu.obs import trace as trace_mod
+
+        monkeypatch.setenv("DMLC_TPU_TRACE_JAX", "1")
+        monkeypatch.delenv("DMLC_TPU_TRACE", raising=False)
+        monkeypatch.setattr(
+            trace_mod, "_bridge", (_FakeAnnotation, _FakeAnnotation))
+        _FakeAnnotation.made = []
+        seen = []
+        trace_mod.add_listener(seen.append)
+        yield seen
+        trace_mod.remove_listener(seen.append)
+
+    def test_span_args_reach_the_annotation(self, bridged):
+        with obs.span("dispatch", pass_=2, batch=5) as live:
+            assert live is not obs.NOOP_SPAN
+            (annot,) = _FakeAnnotation.made
+            assert annot.entered == 1
+        assert annot.entered == 0
+        # the name stays bare; the args travel as the annotation's kwargs
+        assert annot.name == "dispatch"
+        assert annot.kwargs == {"pass_": 2, "batch": 5}
+        assert bridged[0]["args"] == {"pass_": 2, "batch": 5}
+
+    def test_step_span_keeps_step_num_and_args(self, bridged):
+        with obs.step_span(3, "epoch", model="fm"):
+            pass
+        (annot,) = _FakeAnnotation.made
+        assert annot.name == "epoch"
+        assert annot.kwargs == {"step_num": 3, "model": "fm"}
+        assert bridged[0]["args"] == {"model": "fm", "step": 3}
+
+    def test_classes_resolved_once_not_per_span(self, monkeypatch):
+        import jax.profiler
+
+        from dmlc_tpu.obs import trace as trace_mod
+
+        monkeypatch.setenv("DMLC_TPU_TRACE_JAX", "1")
+        monkeypatch.setattr(trace_mod, "_bridge", None)
+        assert trace_mod._jax_annotation_cls() is \
+            jax.profiler.TraceAnnotation
+        resolved = trace_mod._bridge
+        assert trace_mod._jax_annotation_cls(step=True) is \
+            jax.profiler.StepTraceAnnotation
+        assert trace_mod._bridge is resolved  # no second lookup
+        monkeypatch.delenv("DMLC_TPU_TRACE_JAX")
+        assert trace_mod._jax_annotation_cls() is None
+
+    def test_tracing_off_is_the_shared_noop(self, monkeypatch):
+        from dmlc_tpu.obs import trace as trace_mod
+
+        monkeypatch.setenv("DMLC_TPU_TRACE_JAX", "1")
+        monkeypatch.delenv("DMLC_TPU_TRACE", raising=False)
+        monkeypatch.setattr(
+            trace_mod, "_bridge", (_FakeAnnotation, _FakeAnnotation))
+        _FakeAnnotation.made = []
+        seen = []
+        trace_mod.add_listener(seen.append)
+        trace_mod.remove_listener(seen.append)  # disarmed again
+        obs.clear_trace()
+        assert obs.span("consume", pass_=0, batch=1) is obs.NOOP_SPAN
+        assert obs.step_span(0, "epoch") is obs.NOOP_SPAN
+        with obs.span("consume", pass_=0, batch=1):
+            assert obs.current_batch() == {}
+        assert seen == [] and _FakeAnnotation.made == []
+        assert obs.trace_events() == []
+
+    def test_current_batch_is_thread_local(self):
+        obs.set_current_batch(4, 9)
+        try:
+            assert obs.current_batch() == {"pass_": 4, "batch": 9}
+            seen = []
+            t = threading.Thread(
+                target=lambda: seen.append(obs.current_batch()))
+            t.start()
+            t.join()
+            assert seen == [{}]
+        finally:
+            obs.set_current_batch(None)
+        assert obs.current_batch() == {}
+
+
 class TestFlow:
     def test_disabled_is_zero_and_allocation_free(self, monkeypatch):
+        """What the disabled path promises, none of it a count of the
+        whole interpreter's blocks (other threads of the test worker
+        allocate too): flow id 0, no event built or buffered, and no
+        memory left allocated by a line of obs/trace.py."""
+        import tracemalloc
+
+        from dmlc_tpu.obs import trace as trace_mod
+
         monkeypatch.delenv("DMLC_TPU_TRACE", raising=False)
         obs.clear_trace()
+        built = []
+        monkeypatch.setattr(
+            trace_mod, "_flow_event", lambda *a: built.append(a))
         assert obs.new_flow() == 0
 
         def burst(n=2000):
             for _ in range(n):
                 fid = obs.new_flow()
+                assert fid == 0
                 obs.flow_start(fid, "chunk")
                 obs.flow_step(fid, "chunk")
                 obs.flow_end(fid, "chunk")
 
         burst()  # warm caches before measuring
-        # min over trials irons out interpreter noise; a single retained
-        # object per call would show up as ~2000 blocks in every trial
-        deltas = []
-        for _ in range(5):
-            gc.collect()
-            before = sys.getallocatedblocks()
+        started_here = not tracemalloc.is_tracing()
+        if started_here:
+            tracemalloc.start()
+        try:
+            before = tracemalloc.take_snapshot()
             burst()
-            gc.collect()
-            deltas.append(sys.getallocatedblocks() - before)
-        assert min(deltas) <= 0
+            after = tracemalloc.take_snapshot()
+        finally:
+            if started_here:
+                tracemalloc.stop()
+        only = [tracemalloc.Filter(True, trace_mod.__file__)]
+        grown = [
+            str(stat) for stat in after.filter_traces(only).compare_to(
+                before.filter_traces(only), "lineno")
+            if stat.size_diff > 0]
+        assert grown == []
+        assert built == []
+        assert obs.trace_events() == []
 
     def test_enabled_chain_same_id_and_bp(self, monkeypatch, tmp_path):
         monkeypatch.setenv("DMLC_TPU_TRACE", str(tmp_path / "flow.json"))

@@ -305,6 +305,80 @@ def test_pipeline_stats(svm_file):
     parser.close()
 
 
+def test_pipeline_stats_count_thread_cpu(svm_file):
+    """The reader thread and the parse workers count their own CPU time
+    into the two slots appended to ingest_stats."""
+    parser = create_parser(svm_file, 0, 1)
+    list(parser)
+    stats = parser.stats()
+    parser.close()
+    assert stats["reader_cpu_ns"] > 0
+    assert stats["parse_cpu_ns"] > 0
+    # CPU time of the workers cannot pass their wall time by more than the
+    # clocks' granularity (a worker is one thread per parse)
+    assert stats["parse_cpu_ns"] <= stats["parse_ns"] * 1.5 + 5e6
+
+
+def test_ingest_stats_old_length_buffer(svm_file):
+    """A caller that still passes the seven-slot buffer is served as
+    before and nothing is written past its end."""
+    from dmlc_tpu import native
+
+    parser = create_parser(svm_file, 0, 1)
+    assert isinstance(parser, NativePipelineParser)
+    list(parser)
+    pipe = parser._pipe
+    lib, handle = pipe._lib, pipe._handle
+    old = np.full(8, -1.0)
+    lib.ingest_stats(handle, native._ptr(old), 7)
+    new = np.full(10, -1.0)
+    lib.ingest_stats(handle, native._ptr(new), 10)
+    parser.close()
+    assert old[7] == -1.0 and (old[:7] >= 0).all()
+    np.testing.assert_array_equal(new[:7], old[:7])
+    assert new[7] > 0 and new[8] > 0 and new[9] == -1.0
+
+
+def test_parser_teardown_after_its_pipeline_was_finalized(svm_file):
+    """The collector may finalize a parser's native pipeline before the
+    parser itself (both sit in one garbage cycle); the parser's teardown
+    then asks a closed pipeline for its byte count."""
+    parser = create_parser(svm_file, 0, 1)
+    assert isinstance(parser, NativePipelineParser)
+    list(parser)
+    parser._pipe.close()
+    assert parser._pipe.bytes_read == 0
+    parser.close()  # no NULL handle reaches the library
+
+
+def test_device_feed_counts_stage_cpu(svm_file):
+    """dmlc_stage_cpu_ns grows for the feed's producer thread and for
+    the consumer side, one observation a batch."""
+    from dmlc_tpu import obs
+    from dmlc_tpu.device import BatchSpec, DeviceFeed
+
+    def read():
+        flat = obs.registry().flat_values()
+        key = 'dmlc_stage_cpu_ns{stage="%s"}:%s'
+        return {stage: (flat.get(key % (stage, "sum"), 0.0),
+                        flat.get(key % (stage, "count"), 0.0))
+                for stage in ("feed_producer", "consumer")}
+
+    before = read()
+    feed = DeviceFeed(
+        create_parser(svm_file, 0, 1),
+        BatchSpec(batch_size=128, layout="dense", num_features=6),
+        host_prefetch=2,
+    )
+    for _ in feed:
+        np.sum(np.arange(20000, dtype=np.float64))  # the consumer's work
+    feed.close()
+    after = read()
+    for stage in ("feed_producer", "consumer"):
+        assert after[stage][0] > before[stage][0], stage
+        assert after[stage][1] - before[stage][1] >= 8, stage
+
+
 def test_batch_csv_rejected(tmp_path):
     path = tmp_path / "d.csv"
     path.write_text("1,2,3\n4,5,6\n")

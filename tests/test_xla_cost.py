@@ -286,48 +286,6 @@ class TestFlatParsing:
 
 
 # ---------------------------------------------------------------------------
-# sampled device-step latency
-# ---------------------------------------------------------------------------
-
-
-class TestSampledLatency:
-    def test_fires_exactly_one_in_n(self, monkeypatch):
-        monkeypatch.setenv("DMLC_TPU_STEP_SAMPLE_N", "4")
-        reg = Registry()
-        fl = FitLoopObs("m", reg=reg)
-        calls = []
-        monkeypatch.setattr(jax, "block_until_ready",
-                            lambda out: calls.append(out))
-        for i in range(12):
-            fl.sample_latency(i)
-        # steps 4, 8, 12 — never the other N-1
-        assert calls == [3, 7, 11]
-        assert _flat(reg, 'dmlc_step_device_ms{model="m"}:count') == 3.0
-
-    def test_disarmed_without_device_telemetry(self, monkeypatch):
-        monkeypatch.setenv("DMLC_TPU_DEVICE_TELEMETRY", "0")
-        reg = Registry()
-        fl = FitLoopObs("m", reg=reg)
-        monkeypatch.setattr(
-            jax, "block_until_ready",
-            lambda out: pytest.fail("sampled sync ran with telemetry off"))
-        for i in range(16):
-            fl.sample_latency(i)
-        assert "dmlc_step_device_ms" not in str(reg.flat_values())
-
-    def test_disarmed_without_metrics(self, monkeypatch):
-        monkeypatch.setenv("DMLC_TPU_METRICS", "0")
-        fl = FitLoopObs("m", reg=Registry())
-        assert fl._sample_n == 0
-
-    def test_sample_n_zero_disables(self, monkeypatch):
-        monkeypatch.setenv("DMLC_TPU_STEP_SAMPLE_N", "0")
-        fl = FitLoopObs("m", reg=Registry())
-        assert fl._sample_n == 0
-        fl.sample_latency(object())  # no-op, no error
-
-
-# ---------------------------------------------------------------------------
 # goodput MFU / roofline
 # ---------------------------------------------------------------------------
 
