@@ -209,7 +209,7 @@ def ref_dense_sgd(x, y, batch, epochs, lr):
 
 def ref_sparse_sgd(ids, vals, y, dim, batch, lr, factors=None, lr_fm=0.05):
     """One epoch of linear (``factors`` None) or FM csr SGD — models/fm.py
-    ``_fm_forward_grads`` / linear ``_local_grads`` in numpy float64.
+    ``_fm_entry_grads`` / linear ``_local_grads`` in numpy float64.
     ``factors``: the [dim, K] initial factor table for FM."""
     import numpy as np
 

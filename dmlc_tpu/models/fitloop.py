@@ -91,7 +91,9 @@ class FitLoopObs:
     def end_epoch(self, epoch: int, nstep: int, t0_ns: int,
                   loss: Optional[float], feed=None,
                   log_every: int = 0, params=None,
-                  snapshotter=None, snap_state=None) -> Optional[dict]:
+                  snapshotter=None, snap_state=None,
+                  sparse_update_steps: Optional[int] = None
+                  ) -> Optional[dict]:
         """Close one epoch: fit metrics, a goodput-ledger window fed to
         the watchdog, the unified stall/goodput log line (every
         ``log_every``-th epoch), and the registry export. Returns the
@@ -108,10 +110,21 @@ class FitLoopObs:
         (collective/snapshot.py) — capture after the roll so the
         exported audit state describes the *closed* epoch and a resume
         re-arms the chains exactly where an uninterrupted run would
-        be."""
+        be.
+
+        ``sparse_update_steps`` (learners whose step can update only the
+        rows a batch touches: FM) is how many of this epoch's ``nstep``
+        took that path; over ``dmlc_fit_steps_total`` it is the share of
+        steps that engaged it."""
         with obs.span("epoch_close", model=self.model, epoch=epoch):
             self.h_epoch.observe(time.monotonic_ns() - t0_ns)
             self.m_steps.inc(nstep)
+            if sparse_update_steps is not None:
+                self.reg.counter(
+                    "dmlc_fit_sparse_update_steps_total",
+                    "optimizer steps that scatter-added into the touched "
+                    "rows instead of applying a dense gradient",
+                    model=self.model).inc(sparse_update_steps)
             self.m_epochs.inc()
             if loss is not None:
                 self.g_loss.set(loss)

@@ -4,7 +4,9 @@ The libfm format the reference parses (libfm_parser.h) exists to feed this
 model family; the reference ships the parser and leaves the model downstream.
 TPU-first formulation: all per-entry work is gathers + segment_sums (static
 shapes), and the O(nnz·K) factor math is batched so XLA can keep it on the
-vector units; the factor table gradient is one scatter-add.
+vector units. On one device the step scatter-adds each entry's update
+into the rows it names and never passes over the table; on a mesh the
+entries are reduced to a dense gradient for the psum.
 
 score(x) = b + Σ_i w_i x_i + ½ Σ_k [(Σ_i v_ik x_i)² − Σ_i v_ik² x_i²]
 """
@@ -17,7 +19,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dmlc_tpu.collective.device import bucketed_psum
@@ -27,7 +29,7 @@ from dmlc_tpu.models.linear import (
     step_batch,
 )
 from dmlc_tpu.obs.device_telemetry import instrumented_jit
-from dmlc_tpu.ops.spmv import expand_row_ids, spmv, spmv_transpose
+from dmlc_tpu.ops.spmv import expand_row_ids, spmv
 from dmlc_tpu.parallel.partition import match_partition_rules, shard_params
 from dmlc_tpu.params.parameter import Parameter, field
 from dmlc_tpu.utils.logging import check
@@ -60,8 +62,12 @@ def init_fm_params(
 FM_PARTITION_RULES = ((r"^(w|b|v)$", P()),)
 
 
-def _fm_forward_grads(params, batch, objective: str, num_features: int):
-    """Local (unreduced) grads + loss sums for one COO batch shard.
+def _fm_entry_grads(params, batch, objective: str):
+    """Loss sums and the per-entry gradient contributions of one COO
+    batch shard: entry e of row r at feature i adds ``dw[e]`` to w_i's
+    gradient and ``dv[e]`` to v_i's. How they reach the parameters is the
+    caller's: scatter-added into the table (single device) or reduced to
+    dense grads for the psum (mesh).
 
     The ``step.*`` scopes name the step's phases in the compiled
     program's metadata (shared with models/linear.py), so a device
@@ -87,14 +93,66 @@ def _fm_forward_grads(params, batch, objective: str, num_features: int):
     with jax.named_scope("step.backward"):
         wg = weight * gmargin  # [B]
         gb = jnp.sum(wg)
-        # dv[i,k]: per entry x_e * (s[r,k] − x_e v[i,k]), scaled by wg[r]
+        dw = wg[row_ids] * values  # [nnz]
+        # dv[e,k] = x_e * (s[r,k] − x_e v[i,k]), scaled by wg[r]
         s_e = jnp.take(s, row_ids, axis=0)  # [nnz, K]
-        dv_entry = (wg[row_ids] * values)[:, None] * (s_e - xv)
-    with jax.named_scope("step.scatter"):
-        gw = spmv_transpose(values, indices, row_ids, wg, num_features)
-        gv = jax.ops.segment_sum(
-            dv_entry, indices, num_segments=num_features)
-    return gw, gb, gv, loss_sum, jnp.sum(weight)
+        dv = dw[:, None] * (s_e - xv)
+    return dw, gb, dv, loss_sum, jnp.sum(weight)
+
+
+#: factor-table rows one scatter-add of the update loop covers. Timed on
+#: the v5e in the kdd12-fm cell (PERF.md, PR 26): 512 to 2048 read the
+#: same step, 8192 costs 0.6 ms of a 14.2 ms step in slots past the last
+#: distinct id.
+_UPDATE_CHUNK = 2048
+
+
+def _scatter_add_rows(w, v, indices, dw, dv):
+    """``w[i] += Σ dw[e]`` and ``v[i] += Σ dv[e]`` over the entries e that
+    name feature i, into ``w`` and ``v`` themselves (in place when the
+    caller donated them). A row no entry names is not written; a padded
+    entry adds its 0 to feature 0.
+
+    The entries of one id are summed first and reach its row in one
+    add. Ids repeat within a batch (thousands of times for the popular
+    ones under a power law), and entry-by-entry adds into a parameter
+    much larger than the update round at the parameter's magnitude each
+    time: against a float64 step that read 20 times the error of a dense
+    gradient's one subtraction. Summing first keeps that one rounding.
+
+    On the chip a row scatter-add is serial, ~0.1 µs a slot whether the
+    slot's id is in range or dropped, so the distinct ids are sorted to
+    the front and ``v`` takes them ``_UPDATE_CHUNK`` slots at a time until
+    the last slot that holds one. ``w``'s 1-D scatter costs a pass over
+    ``w`` whatever the number of slots, so it is made once."""
+    n = indices.shape[0]
+    order = jnp.argsort(indices)
+    sorted_ids = indices[order]
+    first = jnp.concatenate(
+        [jnp.ones((1,), bool), sorted_ids[1:] != sorted_ids[:-1]])
+    slot_sorted = jnp.cumsum(first.astype(jnp.int32)) - 1
+    # every entry's slot at the entry's own place: dv is not permuted
+    slot = jnp.zeros((n,), jnp.int32).at[order].set(
+        slot_sorted, unique_indices=True)
+    sum_w = jax.ops.segment_sum(dw, slot, num_segments=n)
+    sum_v = jax.ops.segment_sum(dv, slot, num_segments=n)
+    # slot j holds the j-th distinct id; the slots after the last hold ids
+    # past the table, which the scatter drops (distinct, as promised)
+    pad = (-n) % _UPDATE_CHUNK
+    ids = (w.shape[0] + jnp.arange(n + pad, dtype=jnp.int32)).at[
+        slot_sorted].set(sorted_ids)
+    sum_v = jnp.pad(sum_v, ((0, pad), (0, 0)))
+    flags = dict(indices_are_sorted=True, unique_indices=True, mode="drop")
+    w = w.at[ids[:n]].add(sum_w, **flags)
+
+    def add_chunk(i, v):
+        at = i * _UPDATE_CHUNK
+        return v.at[lax.dynamic_slice_in_dim(ids, at, _UPDATE_CHUNK)].add(
+            lax.dynamic_slice_in_dim(sum_v, at, _UPDATE_CHUNK), **flags)
+
+    distinct = slot_sorted[-1] + 1
+    chunks = (distinct + _UPDATE_CHUNK - 1) // _UPDATE_CHUNK
+    return w, lax.fori_loop(0, chunks, add_chunk, v)
 
 
 def make_fm_train_step(
@@ -107,34 +165,49 @@ def make_fm_train_step(
     param_specs=None,
     donate_batch: bool = False,
 ):
-    """Jitted FM SGD step over COO batches; ONE fused (dtype-bucketed)
-    in-graph psum on the mesh — the [F,K] factor grads, [F] linear grads
-    and loss scalars cross ICI as a single contiguous f32 buffer.
+    """Jitted FM SGD step over COO batches.
+
+    Single device (``mesh is None``): the update touches only the rows
+    the batch names. The entries' contributions, scaled by
+    ``-learning_rate / weight_sum``, are scatter-ADDED into ``w`` and
+    ``v`` (:func:`_scatter_add_rows`; ids repeat within a batch); no
+    gradient of the table's shape exists. ``l2 > 0`` adds one scaling
+    pass over the table before the scatter-add:
+    ``v - lr*(g + l2*v) = v*(1 - lr*l2) - lr*g``.
+
+    Mesh: a psum needs one buffer of a fixed shape, so the entries are
+    reduced to dense grads and ONE fused (dtype-bucketed) in-graph psum
+    carries the [F,K] factor grads, [F] linear grads and loss scalars
+    across ICI as a single contiguous f32 buffer, then a dense update.
 
     ``donate_batch=True`` (single-device path) donates params AND the
     batch arrays, the same contract as
     :func:`~dmlc_tpu.models.linear.make_linear_train_step`: XLA reuses
-    the H2D landing buffers and updates the factor table in place —
-    only for streaming callers that rebind params each step and never
-    touch a batch after its step (DeviceFeed loops, FMLearner)."""
+    the H2D landing buffers and scatters into the factor table in place
+    (without it the step copies the table first) — only for streaming
+    callers that rebind params each step and never touch a batch after
+    its step (DeviceFeed loops, FMLearner)."""
     check(num_features > 0, "num_features required")
-
-    @jax.named_scope("step.update")
-    def _apply(params, gw, gb, gv, wsum):
-        denom = jnp.maximum(wsum, 1e-12)
-        return {
-            "w": params["w"] - learning_rate * (gw / denom + l2 * params["w"]),
-            "b": params["b"] - learning_rate * (gb / denom),
-            "v": params["v"] - learning_rate * (gv / denom + l2 * params["v"]),
-        }
 
     if mesh is None:
 
         def step(params, batch):
-            gw, gb, gv, loss_sum, wsum = _fm_forward_grads(
-                params, batch, objective, num_features
-            )
-            params = _apply(params, gw, gb, gv, wsum)
+            dw, gb, dv, loss_sum, wsum = _fm_entry_grads(
+                params, batch, objective)
+            with jax.named_scope("step.update"):
+                denom = jnp.maximum(wsum, 1e-12)
+                scale = -learning_rate / denom
+                w, v = params["w"], params["v"]
+                if l2:
+                    w = w * (1.0 - learning_rate * l2)
+                    v = v * (1.0 - learning_rate * l2)
+                w, v = _scatter_add_rows(
+                    w, v, batch["indices"], scale * dw, scale * dv)
+                params = {
+                    "w": w,
+                    "b": params["b"] - learning_rate * (gb / denom),
+                    "v": v,
+                }
             return params, {"loss_sum": loss_sum, "weight_sum": wsum}
 
         fn = instrumented_jit(
@@ -160,15 +233,23 @@ def make_fm_train_step(
         )
 
     def _sharded(params, batch):
-        gw, gb, gv, loss_sum, wsum = _fm_forward_grads(
-            params, batch, objective, num_features
-        )
+        dw, gb, dv, loss_sum, wsum = _fm_entry_grads(params, batch, objective)
+        with jax.named_scope("step.scatter"):
+            indices = batch["indices"]
+            gw = jax.ops.segment_sum(dw, indices, num_segments=num_features)
+            gv = jax.ops.segment_sum(dv, indices, num_segments=num_features)
         # gradients never round-trip through host numpy: one bucketed
         # in-graph psum carries the whole gradient pytree across ICI
         gw, gb, gv, loss_sum, wsum = bucketed_psum(
             (gw, gb, gv, loss_sum, wsum), axis=axis
         )
-        params = _apply(params, gw, gb, gv, wsum)
+        with jax.named_scope("step.update"):
+            denom = jnp.maximum(wsum, 1e-12)
+            params = {
+                "w": params["w"] - learning_rate * (gw / denom + l2 * params["w"]),
+                "b": params["b"] - learning_rate * (gb / denom),
+                "v": params["v"] - learning_rate * (gv / denom + l2 * params["v"]),
+            }
         return params, {"loss_sum": loss_sum, "weight_sum": wsum}
 
     step = shard_map(
@@ -301,6 +382,9 @@ class FMLearner:
                 snap_state=(None if snapshotter is None else
                             lambda e=epoch: self._snapshot_state(
                                 feed, e, history)),
+                # the step was built for self.mesh (_ensure): without one
+                # every step scatter-adds, on a mesh none does
+                sparse_update_steps=nstep if self.mesh is None else 0,
             )
             if epoch + 1 < epochs:
                 feed.before_first()

@@ -254,6 +254,213 @@ class TestFM:
         )
 
 
+def _fm_step_f64(params, dev, lr, l2):
+    """One FM SGD step in float64 numpy, written from the equations
+    (models/fm.py header) over a padded DeviceCSRBatch; imports nothing
+    from the model. Returns (params, loss_sum, weight_sum)."""
+    w = np.asarray(params["w"], np.float64).copy()
+    v = np.asarray(params["v"], np.float64).copy()
+    b = float(params["b"])
+    gw, gv, gb = np.zeros_like(w), np.zeros_like(v), 0.0
+    loss_sum = 0.0
+    wsum = float(np.sum(dev.weights, dtype=np.float64))
+    for r in range(len(dev.labels)):
+        lo, hi = int(dev.offsets[r]), int(dev.offsets[r + 1])
+        idx = dev.indices[lo:hi]
+        x = dev.values[lo:hi].astype(np.float64)
+        xv = x[:, None] * v[idx]
+        s = xv.sum(axis=0)
+        score = b + x @ w[idx] + 0.5 * float(np.sum(s * s) - np.sum(xv * xv))
+        y, wt = float(dev.labels[r]), float(dev.weights[r])
+        loss_sum += wt * np.logaddexp(0.0, score) - wt * y * score
+        g = wt * (1.0 / (1.0 + np.exp(-score)) - y)
+        gb += g
+        np.add.at(gw, idx, g * x)
+        np.add.at(gv, idx, (g * x)[:, None] * (s[None, :] - xv))
+    denom = max(wsum, 1e-12)
+    return (
+        {"w": w - lr * (gw / denom + l2 * w),
+         "b": b - lr * gb / denom,
+         "v": v - lr * (gv / denom + l2 * v)},
+        loss_sum, wsum,
+    )
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr that holds no jaxpr of its own, through
+    pjit / shard_map / custom-call bodies."""
+    for eqn in jaxpr.eqns:
+        subs = [
+            getattr(sub, "jaxpr", sub)
+            for val in eqn.params.values()
+            for sub in (val if isinstance(val, (list, tuple)) else (val,))
+            if hasattr(getattr(sub, "jaxpr", sub), "eqns")
+        ]
+        # a scatter's update_jaxpr is its combiner, not a body to descend
+        if subs and not eqn.primitive.name.startswith("scatter"):
+            for sub in subs:
+                yield from _walk_eqns(sub)
+        else:
+            yield eqn
+
+
+class TestFMSparseUpdate:
+    """The single-device step scatter-adds into the rows the batch names
+    and builds no gradient of the table's shape."""
+
+    NFEAT, NFACT, ROWS = 37, 4, 16
+
+    def _block(self, nrows):
+        """Ids repeated within a row and across rows; features 0 and
+        23.. never named."""
+        from dmlc_tpu.data.row_block import RowBlockContainer
+
+        rng = np.random.RandomState(7)
+        cont = RowBlockContainer()
+        for i in range(nrows):
+            feats = rng.randint(1, 12, size=5)
+            feats[1] = feats[0]  # the same id twice in one row
+            feats[4] = 20 + (i % 3)  # and one shared by a third of the rows
+            cont.push_row(
+                float(i % 2), feats,
+                value=rng.rand(5).astype(np.float32) + 0.5,
+                weight=float(1 + i % 2))
+        return cont.to_block()
+
+    def _batch(self, padded):
+        """``padded``: 13 of 16 rows and a bucket wider than the entries,
+        so padded entries sit at feature 0 with value 0 and padded rows
+        weigh 0; else the bucket is filled exactly."""
+        from dmlc_tpu.device.csr import pad_to_bucket
+
+        if padded:
+            return pad_to_bucket(self._block(13), self.ROWS, nnz_bucket=128)
+        return pad_to_bucket(
+            self._block(self.ROWS), self.ROWS, nnz_bucket=self.ROWS * 5)
+
+    @staticmethod
+    def _device_batch(dev):
+        return {
+            "label": jnp.asarray(dev.labels),
+            "weight": jnp.asarray(dev.weights),
+            "indices": jnp.asarray(dev.indices),
+            "values": jnp.asarray(dev.values),
+            "offsets": jnp.asarray(dev.offsets),
+        }
+
+    def _params(self):
+        p = init_fm_params(self.NFEAT, self.NFACT, init_scale=0.3, seed=3)
+        rng = np.random.RandomState(9)
+        p["w"] = jnp.asarray(rng.randn(self.NFEAT).astype(np.float32) * 0.2)
+        p["b"] = jnp.asarray(0.1, dtype=jnp.float32)
+        return p
+
+    @pytest.mark.parametrize("padded", [False, True], ids=["filled", "padded"])
+    @pytest.mark.parametrize("donate", [False, True], ids=["kept", "donated"])
+    @pytest.mark.parametrize("l2", [0.0, 0.01])
+    def test_step_matches_float64_and_leaves_other_rows(
+            self, l2, donate, padded):
+        dev = self._batch(padded)
+        params = self._params()
+        before = {k: np.asarray(a).copy() for k, a in params.items()}
+        want, want_loss, want_wsum = _fm_step_f64(before, dev, 0.2, l2)
+
+        step = make_fm_train_step(
+            None, self.NFEAT, learning_rate=0.2, l2=l2, donate_batch=donate)
+        got, metrics = step(params, self._device_batch(dev))
+        if not donate:  # the caller's tree is untouched
+            for k, a in before.items():
+                np.testing.assert_array_equal(np.asarray(params[k]), a)
+
+        np.testing.assert_allclose(
+            float(metrics["loss_sum"]), want_loss, rtol=2e-6)
+        assert float(metrics["weight_sum"]) == want_wsum
+        for k in ("w", "b", "v"):
+            np.testing.assert_allclose(
+                np.asarray(got[k]), want[k], rtol=1e-5, atol=1e-7)
+
+        touched = np.zeros(self.NFEAT, bool)
+        touched[dev.indices[: dev.num_nonzero]] = True
+        assert not touched[0] and 5 < touched.sum() < self.NFEAT - 5
+        moved = np.abs(np.asarray(got["v"]) - before["v"]).max(axis=1) > 0
+        assert moved[touched].all()
+        if l2 == 0.0:
+            # bit for bit, the padded entries' feature 0 included
+            for k in ("w", "v"):
+                np.testing.assert_array_equal(
+                    np.asarray(got[k])[~touched].view(np.uint32),
+                    before[k][~touched].view(np.uint32))
+
+    def test_no_value_of_the_tables_shape_but_the_scatter_adds(self):
+        """A dense gradient, a zero fill or a whole-table update would
+        each be an equation whose result has the table's shape."""
+        step = make_fm_train_step(None, self.NFEAT, learning_rate=0.2)
+        jaxpr = jax.make_jaxpr(step)(
+            self._params(), self._device_batch(self._batch(False))).jaxpr
+        shapes = {(self.NFEAT, self.NFACT), (self.NFEAT,)}
+        makers = [
+            eqn.primitive.name for eqn in _walk_eqns(jaxpr)
+            for out in eqn.outvars if tuple(out.aval.shape) in shapes
+        ]
+        assert sorted(makers) == ["scatter-add", "scatter-add"], makers
+
+    def test_mesh_step_keeps_the_dense_gradient_and_one_psum(self):
+        from dmlc_tpu.device.csr import pad_to_bucket_sharded
+
+        mesh = data_parallel_mesh()
+        step = make_fm_train_step(mesh, self.NFEAT, learning_rate=0.2)
+        sh = pad_to_bucket_sharded(
+            self._block(self.ROWS), self.ROWS, mesh.shape["dp"])
+        jaxpr = jax.make_jaxpr(step)(
+            self._params(), self._device_batch(sh)).jaxpr
+        eqns = list(_walk_eqns(jaxpr))
+        names = [eqn.primitive.name for eqn in eqns]
+        assert sum(n.startswith("psum") for n in names) == 1, names
+        dense = [
+            eqn.primitive.name for eqn in eqns for out in eqn.outvars
+            if tuple(out.aval.shape) == (self.NFEAT, self.NFACT)
+        ]
+        assert "scatter-add" in dense and "sub" in dense, dense
+
+    @pytest.mark.parametrize("on_mesh", [False, True], ids=["single", "mesh"])
+    def test_counter_is_the_share_of_steps_on_the_sparse_path(
+            self, tmp_path, on_mesh):
+        from dmlc_tpu import obs
+        from dmlc_tpu.data import create_parser
+        from dmlc_tpu.device import BatchSpec, DeviceFeed
+        from dmlc_tpu.models import FMLearner
+
+        rng = np.random.RandomState(13)
+        path = tmp_path / "train.svm"
+        with open(path, "w") as fh:
+            for i in range(192):
+                ids = np.sort(rng.choice(self.NFEAT, size=4, replace=False))
+                fh.write("%d %s\n" % (i % 2, " ".join(
+                    "%d:%.4f" % (j, rng.rand()) for j in ids)))
+        mesh = data_parallel_mesh() if on_mesh else None
+        feed = DeviceFeed(
+            create_parser(str(path)),
+            BatchSpec(batch_size=64, layout="csr", num_features=self.NFEAT),
+            mesh=mesh,
+        )
+
+        def read():
+            flat = obs.registry().flat_values()
+            return [flat.get('dmlc_fit_%s_total{model="fm"}' % k, 0.0)
+                    for k in ("steps", "sparse_update_steps")]
+
+        steps0, sparse0 = read()
+        learner = FMLearner(
+            mesh=mesh, num_features=self.NFEAT, num_factors=self.NFACT)
+        learner.fit_feed(feed, epochs=2)
+        feed.close()
+        steps, sparse = read()
+        assert steps - steps0 == 6  # 192 rows in batches of 64, two passes
+        assert sparse - sparse0 == (0 if on_mesh else 6)
+        assert 'dmlc_fit_sparse_update_steps_total{model="fm"}' in (
+            obs.registry().flat_values())
+
+
 class TestLearnerEndToEnd:
     def test_fit_feed_and_checkpoint(self, tmp_path):
         from dmlc_tpu.data import create_parser
