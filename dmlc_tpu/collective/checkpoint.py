@@ -54,7 +54,13 @@ def _to_host(tree: Any) -> Any:
     epoch's donating train steps are already reusing the donated
     buffers — an aliased "copy" would mutate under the writer (or
     outlive a freed buffer). ``np.array(..., copy=True)`` is the
-    donation-safe boundary."""
+    donation-safe boundary.
+
+    An array divided over several devices (a table no single chip
+    holds) is assembled shard by shard into the one logical array:
+    through ``np.array`` it would first be gathered into a copy cached
+    on the device array and then copied again, twice the table's bytes
+    on the host for as long as the device array lives."""
     if isinstance(tree, dict):
         return {k: _to_host(v) for k, v in tree.items()}
     if isinstance(tree, (list, tuple)):
@@ -66,8 +72,25 @@ def _to_host(tree: Any) -> Any:
             return tuple(mapped)
         return mapped
     if hasattr(tree, "__array__") and not isinstance(tree, np.ndarray):
+        if _is_divided(tree):
+            out = np.empty(tree.shape, dtype=tree.dtype)
+            for shard in tree.addressable_shards:
+                if shard.replica_id == 0:  # one copy of each part
+                    out[shard.index] = np.asarray(shard.data)
+            return out
         return np.array(tree, copy=True)
     return tree
+
+
+def _is_divided(arr: Any) -> bool:
+    """A jax array whose devices each hold a PART of it, all of them
+    addressable from this process (a replicated or single-device array,
+    and anything that is not a jax array, is not)."""
+    return (
+        hasattr(arr, "addressable_shards")
+        and not getattr(arr, "is_fully_replicated", True)
+        and getattr(arr, "is_fully_addressable", False)
+    )
 
 
 class CheckpointManager:

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import os
+import time
 from typing import Dict, Iterator, List, Optional
 
 import numpy as np
@@ -907,6 +908,7 @@ class ThreadedParser:
 
     def __init__(self, base: Parser, max_capacity: int = 8):
         self._base = base
+        self._wait_ns = 0  # this pass's waits of next_block on the queue
         self._iter = ThreadedIter(
             self._produce, max_capacity=max_capacity, name="threaded-parser"
         )
@@ -923,7 +925,10 @@ class ThreadedParser:
         return self._base.bytes_read
 
     def next_block(self) -> Optional[RowBlock]:
-        return self._iter.next()
+        t0 = time.monotonic_ns()
+        block = self._iter.next()
+        self._wait_ns += time.monotonic_ns() - t0
+        return block
 
     def __iter__(self) -> Iterator[RowBlock]:
         while True:
@@ -932,9 +937,20 @@ class ThreadedParser:
                 return
             yield block
 
+    def stats(self) -> dict:
+        """The base parser's counters of this pass, where it keeps any,
+        and ``consumer_wait_ns``: how long ``next_block``'s caller waited
+        for the prefetch thread (the native pipeline's name for the same
+        wait). They restart with every pass."""
+        base_stats = getattr(self._base, "stats", None)
+        out = dict(base_stats() or {}) if callable(base_stats) else {}
+        out["consumer_wait_ns"] = int(self._wait_ns)
+        return out
+
     def before_first(self) -> None:
         self._iter.close()
         self._base.before_first()
+        self._wait_ns = 0
         self._iter.before_first()
 
     def close(self) -> None:

@@ -53,6 +53,7 @@ from __future__ import annotations
 import mmap
 import os
 import struct
+import time
 import zlib
 from typing import Dict, Iterator, List, Optional, Tuple
 
@@ -470,6 +471,10 @@ class ShardParser:
 
         self._audit = audit.auditor()
         self.bytes_read = 0
+        # this pass's thread CPU time in window reads and in decodes, under
+        # the native pipeline's names (stats(); restart with every pass)
+        self._reader_cpu_ns = 0
+        self._parse_cpu_ns = 0
         self._order: np.ndarray = np.empty(0, dtype=np.int64)
         self._pos = 0
         self._closed = False
@@ -521,15 +526,20 @@ class ShardParser:
         reader = self._readers[fidx]
         seq = self._seq
         fid = obs.new_flow()
+        cpu0 = time.thread_time_ns()
         with obs.span("io_read", chunk=seq, flow=fid):
             raw = reader.window_bytes(widx)
             obs.flow_start(fid, "chunk")
+        cpu1 = time.thread_time_ns()
+        self._reader_cpu_ns += cpu1 - cpu0
         if self._audit.enabled:
             self._audit.note_chunk(seq - self._epoch_base, raw)
+            cpu1 = time.thread_time_ns()
         with obs.span("parse", chunk=seq, flow=fid):
             obs.flow_step(fid, "chunk")
             faultpoint("shard.read")
             block = reader.read_window(widx, raw)
+        self._parse_cpu_ns += time.thread_time_ns() - cpu1
         if self._audit.enabled:
             self._audit.note_parse(seq - self._epoch_base, block)
         block.flow_id = fid
@@ -550,6 +560,7 @@ class ShardParser:
         next epoch's permutation (construction was epoch 0)."""
         self._epoch += 1
         self._epoch_base = self._seq
+        self._reader_cpu_ns = self._parse_cpu_ns = 0
         self._reorder()
 
     def reset_partition(self, part_index: int, num_parts: int) -> None:
@@ -569,6 +580,8 @@ class ShardParser:
             "epoch": int(self._epoch),
             "shuffle_seed": int(self._seed),
             "shuffle_window": int(self._unit),
+            "reader_cpu_ns": int(self._reader_cpu_ns),
+            "parse_cpu_ns": int(self._parse_cpu_ns),
         }
 
     # ---- job-snapshot state ---------------------------------------------

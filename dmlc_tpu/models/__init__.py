@@ -25,6 +25,7 @@ from dmlc_tpu.models.linear import (
     linear_predict_dense,
 )
 from dmlc_tpu.models.fm import (
+    FM_FACTOR_PARTITION_RULES,
     FM_PARTITION_RULES,
     FMParam,
     FMLearner,
@@ -50,6 +51,7 @@ __all__ = [
     "make_hostsync_train_step",
     "make_linear_train_step",
     "linear_predict_dense",
+    "FM_FACTOR_PARTITION_RULES",
     "FM_PARTITION_RULES",
     "FMParam",
     "FMLearner",

@@ -92,7 +92,9 @@ class FitLoopObs:
                   loss: Optional[float], feed=None,
                   log_every: int = 0, params=None,
                   snapshotter=None, snap_state=None,
-                  sparse_update_steps: Optional[int] = None
+                  sparse_update_steps: Optional[int] = None,
+                  sharded_table_steps: Optional[int] = None,
+                  exchange_bytes: Optional[int] = None
                   ) -> Optional[dict]:
         """Close one epoch: fit metrics, a goodput-ledger window fed to
         the watchdog, the unified stall/goodput log line (every
@@ -115,7 +117,11 @@ class FitLoopObs:
         ``sparse_update_steps`` (learners whose step can update only the
         rows a batch touches: FM) is how many of this epoch's ``nstep``
         took that path; over ``dmlc_fit_steps_total`` it is the share of
-        steps that engaged it."""
+        steps that engaged it. ``sharded_table_steps`` likewise counts the
+        steps taken over a table divided over the mesh's chips, and
+        ``exchange_bytes`` the bytes one chip contributed to those steps'
+        collectives (from the shapes; the gradient psum of a replicated
+        model is not among them, ``dmlc_xla_collective_bytes`` has it)."""
         with obs.span("epoch_close", model=self.model, epoch=epoch):
             self.h_epoch.observe(time.monotonic_ns() - t0_ns)
             self.m_steps.inc(nstep)
@@ -125,6 +131,18 @@ class FitLoopObs:
                     "optimizer steps that scatter-added into the touched "
                     "rows instead of applying a dense gradient",
                     model=self.model).inc(sparse_update_steps)
+            if sharded_table_steps is not None:
+                self.reg.counter(
+                    "dmlc_fit_sharded_table_steps_total",
+                    "optimizer steps over a parameter table sharded over "
+                    "the mesh's chips (no chip holds the whole table)",
+                    model=self.model).inc(sharded_table_steps)
+            if exchange_bytes is not None:
+                self.reg.counter(
+                    "dmlc_fit_exchange_bytes_total",
+                    "bytes one chip contributed to the collectives of "
+                    "sharded-table steps (batch gather + interaction psum)",
+                    model=self.model).inc(exchange_bytes)
             self.m_epochs.inc()
             if loss is not None:
                 self.g_loss.set(loss)
@@ -151,3 +169,64 @@ class FitLoopObs:
             if snapshotter is not None and snap_state is not None:
                 snapshotter.capture(epoch, snap_state)
         return win
+
+
+def fit_uri(learner, uri: str, *, batch_size: int = 4096,
+            epochs: int = 1, layout: str = "dense", num_features: int = 0,
+            part_index: Optional[int] = None,
+            num_parts: Optional[int] = None, drop_remainder: bool = False,
+            log_every: int = 0, snapshot_uri: Optional[str] = None,
+            resume: bool = False, snap_every_epochs: int = 1):
+    """The learners' ``fit_uri``: InputSplit part → parser → DeviceFeed
+    over ``learner.mesh`` → ``learner.fit_feed``. The part defaults to
+    this worker's collective rank/world. With ``snapshot_uri`` the fit
+    runs under a :class:`~dmlc_tpu.collective.Snapshotter`;
+    ``resume=True`` first loads the newest committed snapshot, hands its
+    model to ``learner.restore_snapshot_model`` and continues at the next
+    epoch (see
+    :meth:`LinearLearner.fit_uri` for the contract)."""
+    from dmlc_tpu import collective
+    from dmlc_tpu.data import create_parser
+    from dmlc_tpu.device import BatchSpec, DeviceFeed
+    from dmlc_tpu.utils.logging import check
+
+    check(num_features > 0, "fit_uri requires num_features")
+    if part_index is None:
+        part_index = collective.rank()
+    if num_parts is None:
+        num_parts = collective.world_size()
+    feed = DeviceFeed(
+        create_parser(uri, part_index, num_parts),
+        BatchSpec(batch_size=batch_size, layout=layout,
+                  num_features=num_features, drop_remainder=drop_remainder),
+        mesh=learner.mesh,
+    )
+    if snapshot_uri is None:
+        check(not resume, "resume=True requires snapshot_uri")
+        return learner.fit_feed(feed, epochs=epochs, log_every=log_every)
+    from dmlc_tpu.collective import JobSnapshot, Snapshotter, load_snapshot
+
+    snap = JobSnapshot(snapshot_uri, rank=collective.rank(),
+                       world_size=collective.world_size())
+    start_epoch = 0
+    history = None
+    snapshotter = Snapshotter(snap, every_epochs=snap_every_epochs)
+    try:
+        if resume:
+            version, state, _meta = load_snapshot(snap)
+            if version and state is not None:
+                learner.restore_snapshot_model(state["model"])
+                start_epoch = int(state.get("epoch", -1)) + 1
+                history = list(state.get("history", ()))
+                pst = (state.get("data") or {}).get("parser")
+                parser = getattr(feed, "_parser", None)
+                if pst and hasattr(parser, "restore_state"):
+                    parser.restore_state(pst)
+                snapshotter.mark_restored(start_epoch - 1)
+        return learner.fit_feed(
+            feed, epochs=epochs, log_every=log_every,
+            snapshotter=snapshotter, start_epoch=start_epoch,
+            history=history,
+        )
+    finally:
+        snapshotter.close()

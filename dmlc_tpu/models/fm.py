@@ -5,8 +5,11 @@ model family; the reference ships the parser and leaves the model downstream.
 TPU-first formulation: all per-entry work is gathers + segment_sums (static
 shapes), and the O(nnz·K) factor math is batched so XLA can keep it on the
 vector units. On one device the step scatter-adds each entry's update
-into the rows it names and never passes over the table; on a mesh the
-entries are reduced to a dense gradient for the psum.
+into the rows it names and never passes over the table; on a mesh with
+the table replicated the entries are reduced to a dense gradient for the
+psum; on a mesh with the table's factors sharded
+(``table_sharding="factors"``) every chip scatter-adds into its own
+columns and only the batch and one ``f32[rows]`` psum cross ICI.
 
 score(x) = b + Σ_i w_i x_i + ½ Σ_k [(Σ_i v_ik x_i)² − Σ_i v_ik² x_i²]
 """
@@ -14,6 +17,7 @@ score(x) = b + Σ_i w_i x_i + ½ Σ_k [(Σ_i v_ik x_i)² − Σ_i v_ik² x_i²]
 from __future__ import annotations
 
 import time
+from functools import partial
 from typing import Dict, Optional
 
 import jax
@@ -22,7 +26,7 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dmlc_tpu.collective.device import bucketed_psum
+from dmlc_tpu.collective.device import all_gather, bucketed_psum, psum
 from dmlc_tpu.models.linear import (
     _margin_grad,
     _suppress_donation_warnings,
@@ -30,7 +34,11 @@ from dmlc_tpu.models.linear import (
 )
 from dmlc_tpu.obs.device_telemetry import instrumented_jit
 from dmlc_tpu.ops.spmv import expand_row_ids, spmv
-from dmlc_tpu.parallel.partition import match_partition_rules, shard_params
+from dmlc_tpu.parallel.partition import (
+    match_partition_rules,
+    shard_params,
+    sharding_tree,
+)
 from dmlc_tpu.params.parameter import Parameter, field
 from dmlc_tpu.utils.logging import check
 
@@ -42,6 +50,12 @@ class FMParam(Parameter):
     num_factors = field(int, 8, lower_bound=1)
     num_features = field(int, 0)
     init_scale = field(float, 0.01, lower_bound=0.0)
+    # how a mesh holds the factor table: a whole copy on every chip, or
+    # each chip num_factors / chips of its columns (a table wider than
+    # one chip's memory); without a mesh there is nothing to choose
+    table_sharding = field(
+        str, "replicated",
+        enum={"replicated": "replicated", "factors": "factors"})
 
 
 def init_fm_params(
@@ -61,13 +75,45 @@ def init_fm_params(
 #: by scripts/check_partition_rules.py like LINEAR_PARTITION_RULES.
 FM_PARTITION_RULES = ((r"^(w|b|v)$", P()),)
 
+#: ``table_sharding="factors"``: chip c of the ``dp`` axis holds columns
+#: [c*K/n, (c+1)*K/n) of ``v``; ``w`` and ``b`` stay replicated. The
+#: factors of an FM do not interact, so a chip's columns give its share
+#: of the interaction term and take their update with nothing of the
+#: table's shape crossing ICI.
+FM_FACTOR_PARTITION_RULES = ((r"^(w|b)$", P()), (r"^v$", P(None, "dp")))
 
-def _fm_entry_grads(params, batch, objective: str):
+
+def fm_partition_rules(table_sharding: str = "replicated"):
+    """The rule table of an FM placed as ``table_sharding`` says (over
+    the ``dp`` axis, the only one a learner divides anything over)."""
+    if table_sharding == "replicated":
+        return FM_PARTITION_RULES
+    check(table_sharding == "factors",
+          "table_sharding must be 'replicated' or 'factors', got %r",
+          table_sharding)
+    return FM_FACTOR_PARTITION_RULES
+
+
+def _check_factor_shards(num_factors: int, mesh: Mesh, axis: str) -> None:
+    shards = mesh.shape[axis]
+    check(num_factors % shards == 0,
+          "table_sharding='factors' needs num_factors divisible by the %d "
+          "chips of mesh axis %r, got num_factors=%d",
+          shards, axis, num_factors)
+
+
+def _fm_entry_grads(params, batch, objective: str,
+                    factor_axis: Optional[str] = None):
     """Loss sums and the per-entry gradient contributions of one COO
     batch shard: entry e of row r at feature i adds ``dw[e]`` to w_i's
     gradient and ``dv[e]`` to v_i's. How they reach the parameters is the
-    caller's: scatter-added into the table (single device) or reduced to
-    dense grads for the psum (mesh).
+    caller's: scatter-added into the table (single device, factor-sharded
+    mesh) or reduced to dense grads for the psum (replicated mesh).
+
+    ``factor_axis``: ``params["v"]`` holds this chip's columns only and
+    the batch is the whole step's (``row_ids`` given, global); the
+    columns' share of the interaction term is psummed over that axis
+    between forward and backward, under ``step.exchange``.
 
     The ``step.*`` scopes name the step's phases in the compiled
     program's metadata (shared with models/linear.py), so a device
@@ -80,14 +126,20 @@ def _fm_entry_grads(params, batch, objective: str):
 
     with jax.named_scope("step.gather"):
         # offsets → row ids on device (local per shard under shard_map)
-        row_ids = expand_row_ids(batch["offsets"], values.shape[0])
+        row_ids = batch["row_ids"] if "row_ids" in batch else \
+            expand_row_ids(batch["offsets"], values.shape[0])
         v_e = jnp.take(params["v"], indices, axis=0)  # [nnz, K]
     with jax.named_scope("step.forward"):
         xv = values[:, None] * v_e  # [nnz, K]
         s = jax.ops.segment_sum(xv, row_ids, num_segments=num_rows)  # [B, K]
         q = jax.ops.segment_sum(xv * xv, row_ids, num_segments=num_rows)
         linear = spmv(values, indices, row_ids, params["w"], num_rows)
-        margin = params["b"] + linear + 0.5 * jnp.sum(s * s - q, axis=-1)
+        interaction = 0.5 * jnp.sum(s * s - q, axis=-1)
+    if factor_axis is not None:
+        with jax.named_scope("step.exchange"):
+            interaction = psum(interaction, factor_axis)
+    with jax.named_scope("step.forward"):
+        margin = params["b"] + linear + interaction
         loss, gmargin = _margin_grad(objective, margin, label)
         loss_sum = jnp.sum(weight * loss)
     with jax.named_scope("step.backward"):
@@ -155,6 +207,57 @@ def _scatter_add_rows(w, v, indices, dw, dv):
     return w, lax.fori_loop(0, chunks, add_chunk, v)
 
 
+def _gather_sections(batch, axis: str):
+    """Under ``shard_map``: the feed's row-split sections of one step's
+    batch (``ShardedCSRBatch``: every chip its own rows' entries, with
+    LOCAL offsets) gathered over ``axis`` into the whole COO batch on
+    every chip, row ids made global. What crosses ICI is what crossed
+    H2D: the entries, the offsets (not per-entry row ids), labels and
+    weights."""
+    whole = {k: all_gather(batch[k], axis, tiled=True)
+             for k in ("label", "weight", "indices", "values")}
+    bucket = batch["indices"].shape[0]  # one section's entries
+    rows = batch["label"].shape[0]  # one section's rows
+    offsets = all_gather(batch["offsets"], axis)  # [sections, rows + 1]
+    local = jax.vmap(lambda o: expand_row_ids(o, bucket))(offsets)
+    first_row = rows * jnp.arange(offsets.shape[0], dtype=local.dtype)
+    whole["row_ids"] = (local + first_row[:, None]).reshape(-1)
+    return whole
+
+
+def exchange_bytes(batch, shards: int) -> int:
+    """Bytes one chip contributes to the collectives of one
+    factor-sharded step, from the shapes: its section of the batch to
+    the gather, and its ``f32[rows]`` share of the interaction term to
+    the psum. ``batch``: the arrays the step takes (global shapes)."""
+    gathered = sum(int(a.nbytes) for a in batch.values())
+    return gathered // shards + int(batch["label"].nbytes)
+
+
+def _sparse_update(params, indices, grads, learning_rate: float, l2: float):
+    """The step's update from per-entry contributions ``grads`` =
+    (dw, gb, dv, weight_sum): scaled by ``-learning_rate / weight_sum``
+    and scatter-ADDED into ``w`` and ``v`` (:func:`_scatter_add_rows`;
+    ids repeat within a batch), so only the rows the batch names are
+    written and no gradient of the table's shape exists. ``l2 > 0`` adds
+    one scaling pass over the table before the scatter-add:
+    ``v - lr*(g + l2*v) = v*(1 - lr*l2) - lr*g``."""
+    dw, gb, dv, wsum = grads
+    with jax.named_scope("step.update"):
+        denom = jnp.maximum(wsum, 1e-12)
+        scale = -learning_rate / denom
+        w, v = params["w"], params["v"]
+        if l2:
+            w = w * (1.0 - learning_rate * l2)
+            v = v * (1.0 - learning_rate * l2)
+        w, v = _scatter_add_rows(w, v, indices, scale * dw, scale * dv)
+        return {
+            "w": w,
+            "b": params["b"] - learning_rate * (gb / denom),
+            "v": v,
+        }
+
+
 def make_fm_train_step(
     mesh: Optional[Mesh],
     num_features: int,
@@ -164,21 +267,28 @@ def make_fm_train_step(
     axis: str = "dp",
     param_specs=None,
     donate_batch: bool = False,
+    table_sharding: str = "replicated",
 ):
     """Jitted FM SGD step over COO batches.
 
     Single device (``mesh is None``): the update touches only the rows
-    the batch names. The entries' contributions, scaled by
-    ``-learning_rate / weight_sum``, are scatter-ADDED into ``w`` and
-    ``v`` (:func:`_scatter_add_rows`; ids repeat within a batch); no
-    gradient of the table's shape exists. ``l2 > 0`` adds one scaling
-    pass over the table before the scatter-add:
-    ``v - lr*(g + l2*v) = v*(1 - lr*l2) - lr*g``.
+    the batch names (:func:`_sparse_update`).
 
-    Mesh: a psum needs one buffer of a fixed shape, so the entries are
-    reduced to dense grads and ONE fused (dtype-bucketed) in-graph psum
-    carries the [F,K] factor grads, [F] linear grads and loss scalars
-    across ICI as a single contiguous f32 buffer, then a dense update.
+    Mesh, table replicated: a psum needs one buffer of a fixed shape, so
+    the entries are reduced to dense grads and ONE fused (dtype-bucketed)
+    in-graph psum carries the [F,K] factor grads, [F] linear grads and
+    loss scalars across ICI as a single contiguous f32 buffer, then a
+    dense update.
+
+    Mesh, ``table_sharding="factors"`` (params placed by
+    :data:`FM_FACTOR_PARTITION_RULES`): every chip gathers the step's
+    whole batch (:func:`_gather_sections`), computes the interaction
+    term of its own columns, psums that one ``f32[rows]`` vector, and
+    applies the single-device sparse update to its columns and to its
+    replica of ``w`` and ``b`` (the same arithmetic on the same data on
+    every chip, so the replicas stay bit-equal). Both collectives sit
+    under ``step.exchange``; nothing of the table's shape crosses ICI
+    or exists besides the table.
 
     ``donate_batch=True`` (single-device path) donates params AND the
     batch arrays, the same contract as
@@ -194,20 +304,9 @@ def make_fm_train_step(
         def step(params, batch):
             dw, gb, dv, loss_sum, wsum = _fm_entry_grads(
                 params, batch, objective)
-            with jax.named_scope("step.update"):
-                denom = jnp.maximum(wsum, 1e-12)
-                scale = -learning_rate / denom
-                w, v = params["w"], params["v"]
-                if l2:
-                    w = w * (1.0 - learning_rate * l2)
-                    v = v * (1.0 - learning_rate * l2)
-                w, v = _scatter_add_rows(
-                    w, v, batch["indices"], scale * dw, scale * dv)
-                params = {
-                    "w": w,
-                    "b": params["b"] - learning_rate * (gb / denom),
-                    "v": v,
-                }
+            params = _sparse_update(
+                params, batch["indices"], (dw, gb, dv, wsum),
+                learning_rate, l2)
             return params, {"loss_sum": loss_sum, "weight_sum": wsum}
 
         fn = instrumented_jit(
@@ -228,9 +327,32 @@ def make_fm_train_step(
 
     if param_specs is None:
         param_specs = match_partition_rules(
-            FM_PARTITION_RULES,
+            fm_partition_rules(table_sharding),
             jax.eval_shape(lambda: init_fm_params(max(num_features, 1), 2)),
         )
+
+    if table_sharding == "factors":
+
+        def _factor_sharded(params, batch):
+            with jax.named_scope("step.exchange"):
+                whole = _gather_sections(batch, axis)
+            dw, gb, dv, loss_sum, wsum = _fm_entry_grads(
+                params, whole, objective, factor_axis=axis)
+            params = _sparse_update(
+                params, whole["indices"], (dw, gb, dv, wsum),
+                learning_rate, l2)
+            return params, {"loss_sum": loss_sum, "weight_sum": wsum}
+
+        # every chip computes w, b and the loss sums from the gathered
+        # batch, which shard_map types as varying: the replicas are equal
+        # by construction, not by a collective it could check
+        step = shard_map(
+            _factor_sharded, mesh=mesh,
+            in_specs=(param_specs, batch_specs),
+            out_specs=(param_specs, P()),
+            check_vma=False,
+        )
+        return instrumented_jit(step, "fm.step", donate_argnums=(0,))
 
     def _sharded(params, batch):
         dw, gb, dv, loss_sum, wsum = _fm_entry_grads(params, batch, objective)
@@ -261,7 +383,17 @@ def make_fm_train_step(
 
 
 class FMLearner:
-    """uri → fitted FM params over a DeviceFeed (csr layout)."""
+    """uri → fitted FM params over a DeviceFeed (csr layout).
+
+    On a mesh ``table_sharding`` (an :class:`FMParam` field) says how the
+    factor table is held: ``"replicated"`` (default; a whole copy on every
+    chip, the dense gradient psummed) or ``"factors"`` (each chip
+    ``num_factors / chips`` columns of ``v``, for a table wider than one
+    chip's memory; see :func:`make_fm_train_step`)."""
+
+    #: the mesh axis the batch (and a sharded table) divides over, the
+    #: DeviceFeed's default
+    axis = "dp"
 
     def __init__(self, mesh: Optional[Mesh] = None, **hyper):
         self.param = FMParam()
@@ -276,6 +408,8 @@ class FMLearner:
 
             from dmlc_tpu import collective
 
+            if self.param.table_sharding == "factors":
+                _check_factor_shards(self.param.num_factors, mesh, self.axis)
             ref = weakref.ref(self)
 
             def _membership_cb():
@@ -285,17 +419,40 @@ class FMLearner:
 
             self._unlisten = collective.on_membership_change(_membership_cb)
 
+    @property
+    def table_shards(self) -> int:
+        """Chips one logical factor table is divided over (1: every chip,
+        or the one device, holds all of it)."""
+        if self.mesh is None or self.param.table_sharding != "factors":
+            return 1
+        return int(self.mesh.shape[self.axis])
+
+    def _rules(self):
+        return fm_partition_rules(self.param.table_sharding)
+
+    def param_shardings(self):
+        """NamedSharding tree of the params on this learner's mesh (None
+        without one): what an initialiser's ``out_shardings`` takes so
+        that each chip generates only the part it holds."""
+        if self.mesh is None:
+            return None
+        # the rules go by a leaf's name and rank, not by its size
+        template = jax.eval_shape(
+            lambda: init_fm_params(2, self.param.num_factors))
+        return sharding_tree(
+            self.mesh, match_partition_rules(self._rules(), template))
+
     def _ensure(self, num_features: int):
         if self.params is None:
             nf = self.param.num_features or num_features
-            self.params = init_fm_params(
-                nf, self.param.num_factors, self.param.init_scale
-            )
+            init = partial(init_fm_params, nf, self.param.num_factors,
+                           self.param.init_scale)
+            # on a mesh the initialiser runs as one program placed by the
+            # rules: a chip writes its own part and no whole table exists
+            # on any one of them first
+            self.params = init() if self.mesh is None else jax.jit(
+                init, out_shardings=self.param_shardings())()
             self._nf = nf
-            if self.mesh is not None:
-                self.params = shard_params(
-                    self.params, self.mesh, rules=FM_PARTITION_RULES
-                )
         if self._step is None:
             self._step = make_fm_train_step(
                 self.mesh,
@@ -303,15 +460,21 @@ class FMLearner:
                 objective=self.param.objective,
                 learning_rate=self.param.learning_rate,
                 l2=self.param.l2,
+                axis=self.axis,
                 # the fit loop rebinds params every step and never touches
                 # a batch after its step — the donation contract holds
                 donate_batch=self.mesh is None,
+                table_sharding=self.param.table_sharding,
             )
 
     def reshard(self, mesh: Optional[Mesh] = None) -> None:
         """Elastic re-entry hook (see LinearLearner.reshard): re-place the
         factor table + linear weights on a mesh rebuilt over the current
-        device set and drop the traced step."""
+        device set, by this learner's rules, and drop the traced step.
+        The params pass through one host copy (a factor-sharded table
+        whole: 28 GB at 54.7 M ids x 128), and every chip that held a
+        column slice must still answer: no other chip has those columns,
+        so after losing one the way back is the last snapshot."""
         if self.mesh is None or self.params is None:
             return
         if mesh is None:
@@ -320,11 +483,32 @@ class FMLearner:
                 "pass mesh= to reshard a multi-axis mesh",
             )
             mesh = Mesh(np.asarray(jax.devices()), self.mesh.axis_names)
+        if self.param.table_sharding == "factors":
+            _check_factor_shards(self.param.num_factors, mesh, self.axis)
         self.mesh = mesh
         self.params = shard_params(
-            jax.device_get(self.params), mesh, rules=FM_PARTITION_RULES
+            jax.device_get(self.params), mesh, rules=self._rules()
         )
         self._step = None
+
+    def fit_uri(
+        self,
+        uri: str,
+        batch_size: int = 4096,
+        epochs: int = 1,
+        num_features: int = 0,
+        **kw,
+    ):
+        """One call from data URI to fitted params, as
+        :meth:`LinearLearner.fit_uri` (same arguments, layout csr):
+        InputSplit part → parser → DeviceFeed → :meth:`fit_feed`, with
+        ``snapshot_uri`` / ``resume`` arming job snapshots."""
+        from dmlc_tpu.models.fitloop import fit_uri
+
+        return fit_uri(
+            self, uri, batch_size=batch_size,
+            epochs=epochs, layout="csr",
+            num_features=num_features or self.param.num_features, **kw)
 
     def fit_feed(self, feed, epochs: int = 1, log_every: int = 0,
                  snapshotter=None, start_epoch: int = 0, history=None):
@@ -351,20 +535,32 @@ class FMLearner:
 
         fl = FitLoopObs("fm")
         history = list(history) if history else []
+        shards = self.table_shards
+        # a sharded table's steps: bytes a step exchanges, by nnz bucket
+        # (the shapes the step was compiled for fix them), worked out once
+        bytes_of: Dict[int, int] = {}
         for epoch in range(start_epoch, epochs):
             acc = EpochMetrics()
             nstep = 0
+            steps_of: Dict[int, int] = {}
             preempted = False
             t0 = time.monotonic_ns()
-            with obs.span("epoch", model="fm", epoch=epoch):
+            with obs.span("epoch", model="fm", epoch=epoch,
+                          table_shards=shards):
                 for batch in feed:
                     self._ensure(self.param.num_features)
                     with obs.span("train_step", model="fm", step=nstep,
                                   **obs.current_batch()):
                         obs.flow_step(obs.current_flow(), "chunk")
+                        arrays = step_batch(batch, "csr")
+                        if shards > 1:
+                            bucket = arrays["indices"].shape[0]
+                            steps_of[bucket] = steps_of.get(bucket, 0) + 1
+                            if bucket not in bytes_of:
+                                bytes_of[bucket] = exchange_bytes(
+                                    arrays, shards)
                         self.params, metrics = self._step(
-                            self.params, step_batch(batch, "csr")
-                        )
+                            self.params, arrays)
                     acc.add(metrics)
                     fl.note_step()
                     nstep += 1
@@ -375,6 +571,10 @@ class FMLearner:
                 snapshotter.finalize()
                 raise Preempted(
                     "preempted in epoch %d after %d steps" % (epoch, nstep))
+            # the step was built for self.mesh and the table's sharding
+            # (_ensure): one device and a factor-sharded mesh scatter-add
+            # every step, a mesh of replicas none
+            sparse = self.mesh is None or shards > 1
             fl.finish_epoch(
                 epoch, nstep, t0, acc, history, feed=feed,
                 log_every=log_every, params=self.params,
@@ -382,9 +582,10 @@ class FMLearner:
                 snap_state=(None if snapshotter is None else
                             lambda e=epoch: self._snapshot_state(
                                 feed, e, history)),
-                # the step was built for self.mesh (_ensure): without one
-                # every step scatter-adds, on a mesh none does
-                sparse_update_steps=nstep if self.mesh is None else 0,
+                sparse_update_steps=nstep if sparse else 0,
+                sharded_table_steps=nstep if shards > 1 else 0,
+                exchange_bytes=sum(
+                    n * bytes_of[b] for b, n in steps_of.items()),
             )
             if epoch + 1 < epochs:
                 feed.before_first()
@@ -392,7 +593,11 @@ class FMLearner:
 
     def _snapshot_state(self, feed, epoch: int, history) -> Dict:
         """Job-snapshot state tree at one epoch boundary (see
-        LinearLearner._snapshot_state — FM has no velocity term)."""
+        LinearLearner._snapshot_state — FM has no velocity term). The
+        params go in as the device arrays they are; a table sharded over
+        chips reaches the host as the ONE logical ``[F, K]`` array
+        (``collective.checkpoint._to_host`` assembles it shard by shard),
+        so a snapshot restores under any placement."""
         from dmlc_tpu.obs import audit
 
         state = {
@@ -408,12 +613,21 @@ class FMLearner:
         return state
 
     def restore_snapshot_model(self, model: Dict) -> None:
-        """Re-place a snapshot's host FM params on device (mesh-placed
-        when this learner runs on a mesh)."""
-        self.params = {k: jnp.asarray(v) for k, v in model["params"].items()}
-        if self.mesh is not None:
-            self.params = shard_params(
-                self.params, self.mesh, rules=FM_PARTITION_RULES)
+        """Re-place a snapshot's host FM params on device: straight from
+        the host arrays to this learner's placement (each chip receives
+        only the part its rules give it; the snapshot's own placement
+        does not matter, the table is one logical array)."""
+        params = model["params"]
+        want = (self.param.num_features or params["v"].shape[0],
+                self.param.num_factors)
+        check(tuple(params["v"].shape) == want,
+              "snapshot holds a factor table of shape %s, this learner "
+              "trains %s", tuple(params["v"].shape), want)
+        self._nf = want[0]
+        if self.mesh is None:
+            self.params = {k: jnp.asarray(v) for k, v in params.items()}
+        else:
+            self.params = shard_params(params, self.mesh, rules=self._rules())
 
     def predict_batch(self, batch) -> np.ndarray:
         num_rows = int(batch["label"].shape[0])

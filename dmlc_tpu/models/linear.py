@@ -721,54 +721,18 @@ class LinearLearner:
         permutation, the audit chains re-arm, and training continues at
         the next epoch — bit-identical to a run that was never killed
         (see docs/robustness.md "Preemption & resume")."""
-        from dmlc_tpu import collective
-        from dmlc_tpu.data import create_parser
-        from dmlc_tpu.device import BatchSpec, DeviceFeed
+        from dmlc_tpu.models.fitloop import fit_uri
 
-        nf = num_features or self.param.num_features
-        check(nf > 0, "fit_uri requires num_features")
-        if part_index is None:
-            part_index = collective.rank()
-        if num_parts is None:
-            num_parts = collective.world_size()
-        feed = DeviceFeed(
-            create_parser(uri, part_index, num_parts),
-            BatchSpec(batch_size=batch_size, layout=layout,
-                      num_features=nf, drop_remainder=drop_remainder),
-            mesh=self.mesh,
-        )
-        if snapshot_uri is None:
-            check(not resume, "resume=True requires snapshot_uri")
-            return self.fit_feed(feed, epochs=epochs, log_every=log_every)
-        from dmlc_tpu.collective import JobSnapshot, Snapshotter, \
-            load_snapshot
+        return fit_uri(
+            self, uri, batch_size=batch_size,
+            epochs=epochs, layout=layout,
+            num_features=num_features or self.param.num_features,
+            part_index=part_index, num_parts=num_parts,
+            drop_remainder=drop_remainder, log_every=log_every,
+            snapshot_uri=snapshot_uri, resume=resume,
+            snap_every_epochs=snap_every_epochs)
 
-        snap = JobSnapshot(snapshot_uri, rank=collective.rank(),
-                           world_size=collective.world_size())
-        start_epoch = 0
-        history = None
-        snapshotter = Snapshotter(snap, every_epochs=snap_every_epochs)
-        try:
-            if resume:
-                version, state, _meta = load_snapshot(snap)
-                if version and state is not None:
-                    self._restore_snapshot_model(state["model"])
-                    start_epoch = int(state.get("epoch", -1)) + 1
-                    history = list(state.get("history", ()))
-                    pst = (state.get("data") or {}).get("parser")
-                    parser = getattr(feed, "_parser", None)
-                    if pst and hasattr(parser, "restore_state"):
-                        parser.restore_state(pst)
-                    snapshotter.mark_restored(start_epoch - 1)
-            return self.fit_feed(
-                feed, epochs=epochs, log_every=log_every,
-                snapshotter=snapshotter, start_epoch=start_epoch,
-                history=history,
-            )
-        finally:
-            snapshotter.close()
-
-    def _restore_snapshot_model(self, model: Dict) -> None:
+    def restore_snapshot_model(self, model: Dict) -> None:
         """Re-place a snapshot's host model/optimizer state on device
         (mesh-placed when this learner runs spmd on a mesh)."""
         self.params = {k: jnp.asarray(v) for k, v in model["params"].items()}

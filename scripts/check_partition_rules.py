@@ -37,7 +37,11 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 def build_cases():
     import jax
 
-    from dmlc_tpu.models.fm import FM_PARTITION_RULES, init_fm_params
+    from dmlc_tpu.models.fm import (
+        FM_FACTOR_PARTITION_RULES,
+        FM_PARTITION_RULES,
+        init_fm_params,
+    )
     from dmlc_tpu.models.linear import (
         LINEAR_MP_PARTITION_RULES,
         LINEAR_PARTITION_RULES,
@@ -52,6 +56,7 @@ def build_cases():
         ("LINEAR_PARTITION_RULES", LINEAR_PARTITION_RULES, linear_t),
         ("LINEAR_MP_PARTITION_RULES", LINEAR_MP_PARTITION_RULES, linear_t),
         ("FM_PARTITION_RULES", FM_PARTITION_RULES, fm_t),
+        ("FM_FACTOR_PARTITION_RULES", FM_FACTOR_PARTITION_RULES, fm_t),
     )
 
 

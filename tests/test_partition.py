@@ -124,6 +124,36 @@ class TestShardParams:
             rules=((r".*", P()),), specs={"w": P("dp")})
         assert placed["w"].sharding == NamedSharding(mesh, P("dp"))
 
+    def test_fm_factor_rules_divide_the_tables_columns(self):
+        """FM_FACTOR_PARTITION_RULES: a chip holds a column slice of v
+        and whole replicas of w and b."""
+        from dmlc_tpu.models.fm import (
+            FM_FACTOR_PARTITION_RULES,
+            FM_PARTITION_RULES,
+            fm_partition_rules,
+            init_fm_params,
+        )
+
+        assert fm_partition_rules("factors") is FM_FACTOR_PARTITION_RULES
+        assert fm_partition_rules("replicated") is FM_PARTITION_RULES
+        template = jax.eval_shape(lambda: init_fm_params(16, 8))
+        assert match_partition_rules(
+            FM_FACTOR_PARTITION_RULES, template) == {
+                "w": P(), "b": P(), "v": P(None, "dp")}
+        assert lint_partition_rules(
+            FM_FACTOR_PARTITION_RULES, template) == []
+        with pytest.raises(DMLCError, match="table_sharding"):
+            fm_partition_rules("rows")
+
+        mesh = Mesh(np.asarray(jax.devices()[:4]), ("dp",))
+        placed = shard_params(
+            init_fm_params(16, 8), mesh, rules=FM_FACTOR_PARTITION_RULES)
+        assert placed["v"].sharding == NamedSharding(mesh, P(None, "dp"))
+        assert {s.data.shape for s in placed["v"].addressable_shards} == {
+            (16, 2)}
+        assert placed["w"].sharding == NamedSharding(mesh, P())
+        assert placed["w"].addressable_shards[0].data.shape == (16,)
+
     def test_sharding_tree_maps_specs(self):
         mesh = Mesh(np.asarray(jax.devices()), ("dp",))
         tree = sharding_tree(mesh, {"a": P("dp"), "b": P()})

@@ -194,6 +194,61 @@ class TestRoundTrip:
                 text_digest(svm_file, "libsvm")
 
 
+class TestStageCounters:
+    """The shard reader reports its pass under the native pipeline's
+    names (``parser.stats()``), so whatever reads a text parser's
+    read + parse cost reads a shard parser's too."""
+
+    @pytest.fixture()
+    def shard(self, svm_file, tmp_path):
+        dst = str(tmp_path / "corpus.dtsh")
+        bake_dataset(svm_file, dst, data_format="libsvm", rows_per_window=64)
+        return dst
+
+    @pytest.mark.parametrize("threaded", [True, False])
+    def test_cpu_and_wait_restart_with_every_pass(
+            self, shard, threaded, monkeypatch):
+        import itertools
+
+        from dmlc_tpu.io import shard as shard_mod
+
+        # a thread clock that advances 5 ns a reading: three readings a
+        # window give the read 5 ns and the decode 5 ns
+        ticks = itertools.count(0, 5)
+        monkeypatch.setattr(shard_mod.time, "thread_time_ns",
+                            lambda: next(ticks))
+        parser = create_parser(shard, 0, 1, threaded=threaded)
+        windows = sum(1 for _ in parser)
+        assert windows == -(-ROWS // 64)
+        stats = parser.stats()
+        assert stats["reader_cpu_ns"] == 5 * windows
+        assert stats["parse_cpu_ns"] == 5 * windows
+        if threaded:  # the caller's waits for the prefetch thread
+            assert stats["consumer_wait_ns"] > 0
+        else:  # nothing between the caller and the reader to wait for
+            assert "consumer_wait_ns" not in stats
+        # a second pass reads one pass's worth again, not two
+        parser.before_first()
+        assert sum(1 for _ in parser) == windows
+        again = parser.stats()
+        assert again["reader_cpu_ns"] == 5 * windows
+        assert again["parse_cpu_ns"] == 5 * windows
+        parser.close()
+
+    def test_feed_stats_carry_them(self, shard):
+        from dmlc_tpu.device import BatchSpec, DeviceFeed
+
+        feed = DeviceFeed(create_parser(shard, 0, 1),
+                          BatchSpec(batch_size=64, layout="csr",
+                                    num_features=61))
+        assert sum(int(b["num_rows"]) for b in feed) == ROWS
+        pipe = feed.stats()["pipeline"]
+        assert {"reader_cpu_ns", "parse_cpu_ns", "consumer_wait_ns"} <= set(
+            pipe)
+        assert pipe["consumer_wait_ns"] > 0
+        feed.close()
+
+
 # ---------------------------------------------------------------------------
 # bake CLI + idempotency
 # ---------------------------------------------------------------------------
