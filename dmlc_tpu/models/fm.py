@@ -4,8 +4,13 @@ The libfm format the reference parses (libfm_parser.h) exists to feed this
 model family; the reference ships the parser and leaves the model downstream.
 TPU-first formulation: all per-entry work is gathers + segment_sums (static
 shapes), and the O(nnz·K) factor math is batched so XLA can keep it on the
-vector units. On one device the step scatter-adds each entry's update
-into the rows it names and never passes over the table; on a mesh with
+vector units. Passes over the entries that share an index vector are one
+pass over concatenated columns (on the chip such a pass costs per index,
+not per column): the row sums of the forward pass, a row's terms on their
+way back to its entries, and the sums of an id's entries, for which the
+update sorts the batch's ids once. On one device the step scatter-adds
+each id's summed update into the row it names and never passes over the
+table; on a mesh with
 the table replicated the entries are reduced to a dense gradient for the
 psum; on a mesh with the table's factors sharded
 (``table_sharding="factors"``) every chip scatter-adds into its own
@@ -33,7 +38,7 @@ from dmlc_tpu.models.linear import (
     step_batch,
 )
 from dmlc_tpu.obs.device_telemetry import instrumented_jit
-from dmlc_tpu.ops.spmv import expand_row_ids, spmv
+from dmlc_tpu.ops.spmv import expand_row_ids
 from dmlc_tpu.parallel.partition import (
     match_partition_rules,
     shard_params,
@@ -102,6 +107,25 @@ def _check_factor_shards(num_factors: int, mesh: Mesh, axis: str) -> None:
           shards, axis, num_factors)
 
 
+def _row_sums(params, indices, row_ids, values, num_rows: int):
+    """Per row: ``s`` = Σ x_e v_e, ``q`` = Σ (x_e v_e)² (both ``[B, K]``)
+    and the linear term Σ x_e w_e, in ONE ``segment_sum`` over the
+    concatenated columns; ``xv [nnz, K]`` is handed back for the backward
+    pass. On the chip a pass over the entries costs per index, not per
+    column, while a row of its target fits one 128-lane tile (PERF.md,
+    PR 29), so the three sums share their pass."""
+    k = params["v"].shape[1]
+    with jax.named_scope("step.gather"):
+        v_e = jnp.take(params["v"], indices, axis=0)  # [nnz, K]
+        w_e = jnp.take(params["w"], indices, axis=0)  # [nnz]
+    with jax.named_scope("step.forward"):
+        xv = values[:, None] * v_e  # [nnz, K]
+        sums = jax.ops.segment_sum(
+            jnp.concatenate([xv, xv * xv, (values * w_e)[:, None]], axis=1),
+            row_ids, num_segments=num_rows)  # [B, 2K + 1]
+    return xv, sums[:, :k], sums[:, k:2 * k], sums[:, 2 * k]
+
+
 def _fm_entry_grads(params, batch, objective: str,
                     factor_axis: Optional[str] = None):
     """Loss sums and the per-entry gradient contributions of one COO
@@ -109,6 +133,10 @@ def _fm_entry_grads(params, batch, objective: str,
     gradient and ``dv[e]`` to v_i's. How they reach the parameters is the
     caller's: scatter-added into the table (single device, factor-sharded
     mesh) or reduced to dense grads for the psum (replicated mesh).
+
+    Passes that share an index vector are one pass over concatenated
+    columns: the three row sums of the forward pass (:func:`_row_sums`),
+    and a row's ``s`` and ``wg`` on their way back to its entries.
 
     ``factor_axis``: ``params["v"]`` holds this chip's columns only and
     the batch is the whole step's (``row_ids`` given, global); the
@@ -121,19 +149,15 @@ def _fm_entry_grads(params, batch, objective: str,
     label = batch["label"]
     weight = batch["weight"]
     values = batch["values"]
-    indices = batch["indices"]
     num_rows = label.shape[0]
 
     with jax.named_scope("step.gather"):
         # offsets → row ids on device (local per shard under shard_map)
         row_ids = batch["row_ids"] if "row_ids" in batch else \
             expand_row_ids(batch["offsets"], values.shape[0])
-        v_e = jnp.take(params["v"], indices, axis=0)  # [nnz, K]
+    xv, s, q, linear = _row_sums(
+        params, batch["indices"], row_ids, values, num_rows)
     with jax.named_scope("step.forward"):
-        xv = values[:, None] * v_e  # [nnz, K]
-        s = jax.ops.segment_sum(xv, row_ids, num_segments=num_rows)  # [B, K]
-        q = jax.ops.segment_sum(xv * xv, row_ids, num_segments=num_rows)
-        linear = spmv(values, indices, row_ids, params["w"], num_rows)
         interaction = 0.5 * jnp.sum(s * s - q, axis=-1)
     if factor_axis is not None:
         with jax.named_scope("step.exchange"):
@@ -145,10 +169,12 @@ def _fm_entry_grads(params, batch, objective: str,
     with jax.named_scope("step.backward"):
         wg = weight * gmargin  # [B]
         gb = jnp.sum(wg)
-        dw = wg[row_ids] * values  # [nnz]
+        # a row's s and wg reach its entries in one gather
+        back = jnp.take(
+            jnp.concatenate([s, wg[:, None]], axis=1), row_ids, axis=0)
+        dw = back[:, -1] * values  # [nnz]
         # dv[e,k] = x_e * (s[r,k] − x_e v[i,k]), scaled by wg[r]
-        s_e = jnp.take(s, row_ids, axis=0)  # [nnz, K]
-        dv = dw[:, None] * (s_e - xv)
+        dv = dw[:, None] * (back[:, :-1] - xv)
     return dw, gb, dv, loss_sum, jnp.sum(weight)
 
 
@@ -159,11 +185,31 @@ def _fm_entry_grads(params, batch, objective: str,
 _UPDATE_CHUNK = 2048
 
 
-def _scatter_add_rows(w, v, indices, dw, dv):
-    """``w[i] += Σ dw[e]`` and ``v[i] += Σ dv[e]`` over the entries e that
-    name feature i, into ``w`` and ``v`` themselves (in place when the
-    caller donated them). A row no entry names is not written; a padded
-    entry adds its 0 to feature 0.
+def _in_id_order(indices, dw, dv):
+    """The batch's feature ids sorted, once, and the entries' updates
+    ``[dv | dw]`` (``[nnz, K + 1]``) in that order. The sort carries each
+    entry's place as its payload and the updates follow in one gather
+    (its source is the batch, a few MB: a tenth of a scatter's cost on
+    the chip). Stable: every chip of a factor-sharded mesh sorts the same
+    gathered batch and sums an id's entries in the same order. A padded
+    entry (value 0, feature 0) sorts to the front and still adds 0.
+
+    The gathers from the parameters stay in the feed's order, ahead of
+    this: in id order the entries of a popular id lie side by side and
+    read one row of ``v`` hundreds of times in a row, which the chip
+    serves slower (PERF.md, PR 29: 4.6 ms for 3.4)."""
+    place = jnp.arange(indices.shape[0], dtype=jnp.int32)
+    indices, place = lax.sort((indices, place), num_keys=1)
+    upd = jnp.concatenate([dv, dw[:, None]], axis=1)
+    return indices, jnp.take(upd, place, axis=0)
+
+
+def _scatter_add_rows(w, v, indices, upd):
+    """``v[i] += Σ upd[e, :-1]`` and ``w[i] += Σ upd[e, -1]`` over the
+    entries e that name feature i, into ``w`` and ``v`` themselves (in
+    place when the caller donated them). ``indices`` is SORTED and ``upd``
+    in its order (:func:`_in_id_order`). A row no entry names is not
+    written; a padded entry adds its 0 to feature 0.
 
     The entries of one id are summed first and reach its row in one
     add. Ids repeat within a batch (thousands of times for the popular
@@ -171,38 +217,37 @@ def _scatter_add_rows(w, v, indices, dw, dv):
     much larger than the update round at the parameter's magnitude each
     time: against a float64 step that read 20 times the error of a dense
     gradient's one subtraction. Summing first keeps that one rounding.
+    In id order an id's entries lie side by side, so an entry's slot is
+    the count of distinct ids before it (sorted by construction), and
+    ``v``'s and ``w``'s updates are summed by slot in ONE pass (as
+    :func:`_row_sums` sums by row, and for its reason).
 
     On the chip a row scatter-add is serial, ~0.1 µs a slot whether the
-    slot's id is in range or dropped, so the distinct ids are sorted to
+    slot's id is in range or dropped, so the distinct ids are compacted to
     the front and ``v`` takes them ``_UPDATE_CHUNK`` slots at a time until
     the last slot that holds one. ``w``'s 1-D scatter costs a pass over
     ``w`` whatever the number of slots, so it is made once."""
     n = indices.shape[0]
-    order = jnp.argsort(indices)
-    sorted_ids = indices[order]
     first = jnp.concatenate(
-        [jnp.ones((1,), bool), sorted_ids[1:] != sorted_ids[:-1]])
-    slot_sorted = jnp.cumsum(first.astype(jnp.int32)) - 1
-    # every entry's slot at the entry's own place: dv is not permuted
-    slot = jnp.zeros((n,), jnp.int32).at[order].set(
-        slot_sorted, unique_indices=True)
-    sum_w = jax.ops.segment_sum(dw, slot, num_segments=n)
-    sum_v = jax.ops.segment_sum(dv, slot, num_segments=n)
+        [jnp.ones((1,), bool), indices[1:] != indices[:-1]])
+    slot = jnp.cumsum(first.astype(jnp.int32)) - 1
+    sums = jax.ops.segment_sum(
+        upd, slot, num_segments=n, indices_are_sorted=True)  # [n, K + 1]
     # slot j holds the j-th distinct id; the slots after the last hold ids
     # past the table, which the scatter drops (distinct, as promised)
     pad = (-n) % _UPDATE_CHUNK
     ids = (w.shape[0] + jnp.arange(n + pad, dtype=jnp.int32)).at[
-        slot_sorted].set(sorted_ids)
-    sum_v = jnp.pad(sum_v, ((0, pad), (0, 0)))
+        slot].set(indices)
+    sum_v = jnp.pad(sums[:, :-1], ((0, pad), (0, 0)))
     flags = dict(indices_are_sorted=True, unique_indices=True, mode="drop")
-    w = w.at[ids[:n]].add(sum_w, **flags)
+    w = w.at[ids[:n]].add(sums[:, -1], **flags)
 
     def add_chunk(i, v):
         at = i * _UPDATE_CHUNK
         return v.at[lax.dynamic_slice_in_dim(ids, at, _UPDATE_CHUNK)].add(
             lax.dynamic_slice_in_dim(sum_v, at, _UPDATE_CHUNK), **flags)
 
-    distinct = slot_sorted[-1] + 1
+    distinct = slot[-1] + 1
     chunks = (distinct + _UPDATE_CHUNK - 1) // _UPDATE_CHUNK
     return w, lax.fori_loop(0, chunks, add_chunk, v)
 
@@ -236,13 +281,17 @@ def exchange_bytes(batch, shards: int) -> int:
 
 def _sparse_update(params, indices, grads, learning_rate: float, l2: float):
     """The step's update from per-entry contributions ``grads`` =
-    (dw, gb, dv, weight_sum): scaled by ``-learning_rate / weight_sum``
-    and scatter-ADDED into ``w`` and ``v`` (:func:`_scatter_add_rows`;
-    ids repeat within a batch), so only the rows the batch names are
-    written and no gradient of the table's shape exists. ``l2 > 0`` adds
-    one scaling pass over the table before the scatter-add:
+    (dw, gb, dv, weight_sum): put in feature-id order under ``step.order``
+    (:func:`_in_id_order`, the step's one sort), then under
+    ``step.update`` scaled by ``-learning_rate / weight_sum`` and
+    scatter-ADDED into ``w`` and ``v`` (:func:`_scatter_add_rows`; ids
+    repeat within a batch), so only the rows the batch names are written
+    and no gradient of the table's shape exists. ``l2 > 0`` adds one
+    scaling pass over the table before the scatter-add:
     ``v - lr*(g + l2*v) = v*(1 - lr*l2) - lr*g``."""
     dw, gb, dv, wsum = grads
+    with jax.named_scope("step.order"):
+        indices, upd = _in_id_order(indices, dw, dv)
     with jax.named_scope("step.update"):
         denom = jnp.maximum(wsum, 1e-12)
         scale = -learning_rate / denom
@@ -250,7 +299,7 @@ def _sparse_update(params, indices, grads, learning_rate: float, l2: float):
         if l2:
             w = w * (1.0 - learning_rate * l2)
             v = v * (1.0 - learning_rate * l2)
-        w, v = _scatter_add_rows(w, v, indices, scale * dw, scale * dv)
+        w, v = _scatter_add_rows(w, v, indices, scale * upd)
         return {
             "w": w,
             "b": params["b"] - learning_rate * (gb / denom),
@@ -632,14 +681,8 @@ class FMLearner:
     def predict_batch(self, batch) -> np.ndarray:
         num_rows = int(batch["label"].shape[0])
         row_ids = expand_row_ids(batch["offsets"], batch["values"].shape[0])
-        v_e = jnp.take(self.params["v"], batch["indices"], axis=0)
-        xv = batch["values"][:, None] * v_e
-        s = jax.ops.segment_sum(xv, row_ids, num_segments=num_rows)
-        q = jax.ops.segment_sum(xv * xv, row_ids, num_segments=num_rows)
-        linear = spmv(
-            batch["values"], batch["indices"], row_ids,
-            self.params["w"], num_rows,
-        )
+        _, s, q, linear = _row_sums(
+            self.params, batch["indices"], row_ids, batch["values"], num_rows)
         return np.asarray(
             self.params["b"] + linear + 0.5 * jnp.sum(s * s - q, axis=-1)
         )

@@ -199,14 +199,19 @@ class TestRepeatedIdsAndPadding:
 
     F, K, ROWS, BATCH = 41, 8, 88, 32
 
-    def _file(self, tmp_path):
+    def _file(self, tmp_path, ragged=False):
+        """``ragged``: every fourth row has no entry, the next one entry,
+        the next twelve."""
         rng = np.random.RandomState(11)
         path = tmp_path / "rep.libsvm"
         with open(path, "w") as fh:
             for i in range(self.ROWS):
-                ids = list(rng.randint(1, 14, size=4))
-                ids[1] = ids[0]  # twice in one row
-                ids.append(20 + i % 3)  # shared by a third of the rows
+                size = (0, 1, 12, 4)[i % 4] if ragged else 4
+                ids = list(rng.randint(1, 14, size=size))
+                if size > 1:
+                    ids[1] = ids[0]  # twice in one row
+                if size:
+                    ids.append(20 + i % 3)  # shared by a third of the rows
                 fh.write("%d %s\n" % (i % 2, " ".join(
                     "%d:%.3f" % (j, 0.5 + rng.rand()) for j in ids)))
         return str(path)
@@ -223,9 +228,10 @@ class TestRepeatedIdsAndPadding:
         feed.close()
         return history, _host(model.params)
 
+    @pytest.mark.parametrize("ragged", [False, True], ids=["even", "ragged"])
     @pytest.mark.parametrize("l2", [0.0, 0.01])
-    def test_d_matches_one_device(self, tmp_path, mesh, l2):
-        path = self._file(tmp_path)
+    def test_d_matches_one_device(self, tmp_path, mesh, l2, ragged):
+        path = self._file(tmp_path, ragged)
         start = _host(init_fm_params(self.F, self.K, 0.3, seed=3))
         start["w"] = np.linspace(-0.2, 0.2, self.F).astype(np.float32)
         h1, p1 = self._fit(path, None, start, l2=l2)
@@ -363,8 +369,8 @@ class TestLoweredStep:
             mesh, self.F, table_sharding="factors", learning_rate=0.1)
         params, batch = self._args(mesh)
         text = step.lower(params, batch).as_text(debug_info=True)
-        for scope in ("step.exchange", "step.gather", "step.forward",
-                      "step.backward", "step.update"):
+        for scope in ("step.exchange", "step.order", "step.gather",
+                      "step.forward", "step.backward", "step.update"):
             assert scope in text, scope
         assert "step.scatter" not in text  # no dense gradient is built
 
@@ -383,6 +389,50 @@ class TestLoweredStep:
         assert makers == ["scatter-add", "scatter-add", "while"], makers
         psum = next(e for e in eqns if e.primitive.name.startswith("psum"))
         assert [tuple(v.aval.shape) for v in psum.outvars] == [(self.ROWS,)]
+
+    @pytest.mark.parametrize("sharded", [False, True],
+                             ids=["single-device", "factor-sharded"])
+    def test_g_one_sort_and_eleven_indexed_passes(self, mesh, sharded):
+        """Passes over the entries that share an index vector are one
+        pass over concatenated columns, and the update sorts the ids once.
+        On the chip such a pass costs per index, not per column (PERF.md,
+        PR 29), so what can silently regress is the NUMBER of sorts,
+        gathers and scatters (18 before PR 29): 1 sort + 4 gathers (v, w,
+        a row's s and wg, the updates into id order) + 6 scatters (the
+        offsets' marks, the row sums, the id sums, the ids' compaction,
+        w, v)."""
+        params, batch = self._args(mesh)
+        if sharded:
+            step = make_fm_train_step(
+                mesh, self.F, table_sharding="factors", learning_rate=0.1)
+        else:
+            step = make_fm_train_step(None, self.F, learning_rate=0.1)
+            params = init_fm_params(self.F, self.K)
+            nnz = self.ROWS * 11
+            batch = {
+                "label": jnp.zeros(self.ROWS), "weight": jnp.ones(self.ROWS),
+                "indices": jnp.ones(nnz, jnp.int32),
+                "values": jnp.ones(nnz),
+                "offsets": jnp.arange(self.ROWS + 1, dtype=jnp.int32) * 11}
+        eqns = list(_walk_eqns(jax.make_jaxpr(step)(params, batch).jaxpr))
+        passes = [e.primitive.name for e in eqns if e.primitive.name in (
+            "sort", "gather", "scatter", "scatter-add")]
+        assert passes.count("sort") == 1, passes
+        assert passes.count("gather") <= 4, passes
+        assert len(passes) <= 11, passes
+        # the one sort carries each entry's place with its id, no more
+        (sort,) = [e for e in eqns if e.primitive.name == "sort"]
+        assert len(sort.invars) == 2 and sort.params["num_keys"] == 1
+        assert sort.params["is_stable"]
+        # the parameters are read in the feed's order, ahead of the sort
+        before = passes[:passes.index("sort")]
+        assert before.count("gather") == 3, passes
+        table = {(self.F, self.K), (self.F, self.K // CHIPS), (self.F,)}
+        makers = sorted(
+            e.primitive.name for e in eqns for out in e.outvars
+            if tuple(out.aval.shape) in table
+            and e.primitive.name not in ("shard_map", "pjit", "jit"))
+        assert makers == ["scatter-add", "scatter-add", "while"], makers
 
     def test_g_the_replicated_mesh_step_is_as_it_was(self, mesh):
         step = make_fm_train_step(mesh, self.F, learning_rate=0.1)

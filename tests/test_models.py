@@ -391,6 +391,79 @@ class TestFMSparseUpdate:
                     np.asarray(got[k])[~touched].view(np.uint32),
                     before[k][~touched].view(np.uint32))
 
+    @staticmethod
+    def _shaped_block(shape, nfeat):
+        """``ragged``: rows of 0, 1 and many entries, in turn; ``hot``: one
+        id named four times by every row (2048 entries of 4096) beside
+        ids that repeat less; ``sparse``: 9 of 64 rows and a bucket wider
+        than their entries (the rest is padding)."""
+        from dmlc_tpu.data.row_block import RowBlockContainer
+
+        rng = np.random.RandomState(17)
+        cont = RowBlockContainer()
+        nrows = {"ragged": 64, "hot": 512, "sparse": 9}[shape]
+        for i in range(nrows):
+            if shape == "ragged":
+                feats = rng.randint(1, nfeat - 8, size=(0, 1, 40)[i % 3])
+            elif shape == "hot":
+                feats = np.concatenate(
+                    [np.full(4, 7), rng.randint(1, nfeat - 8, size=4)])
+            else:
+                feats = rng.randint(1, nfeat - 8, size=6)
+            cont.push_row(
+                float(i % 2), feats,
+                value=rng.rand(len(feats)).astype(np.float32) + 0.5,
+                weight=float(1 + i % 2))
+        return cont.to_block()
+
+    @pytest.mark.parametrize("l2", [0.0, 0.01])
+    @pytest.mark.parametrize("nfact", [1, 16, 32])
+    @pytest.mark.parametrize("shape", ["ragged", "hot", "sparse"])
+    def test_merged_passes_match_float64(self, shape, nfact, l2):
+        """The step sums over concatenated columns and updates in
+        feature-id order (one sort): whatever the rows' lengths, however
+        often an id is named and whatever the width of the concatenated
+        passes, it is the float64 step written row by row."""
+        from dmlc_tpu.device.csr import pad_to_bucket
+
+        nfeat = 203
+        block = self._shaped_block(shape, nfeat)
+        rows = max(64, len(block.offset) - 1)  # sparse: 9 rows of 64
+        dev = pad_to_bucket(block, rows, nnz_bucket=rows * 8 + 1024)
+        assert (dev.num_nonzero < len(dev.indices)) and rows * 2 < len(
+            dev.indices)
+        params = init_fm_params(nfeat, nfact, init_scale=0.3, seed=5)
+        rng = np.random.RandomState(19)
+        params["w"] = jnp.asarray(rng.randn(nfeat).astype(np.float32) * 0.2)
+        params["b"] = jnp.asarray(-0.2, dtype=jnp.float32)
+        before = {k: np.asarray(a).copy() for k, a in params.items()}
+        want, want_loss, want_wsum = _fm_step_f64(before, dev, 0.2, l2)
+
+        step = make_fm_train_step(None, nfeat, learning_rate=0.2, l2=l2)
+        got, metrics = step(params, self._device_batch(dev))
+        np.testing.assert_allclose(
+            float(metrics["loss_sum"]), want_loss, rtol=5e-6)
+        assert float(metrics["weight_sum"]) == want_wsum
+        for k in ("w", "b", "v"):
+            np.testing.assert_allclose(
+                np.asarray(got[k]), want[k], rtol=1e-5, atol=2e-7)
+        named = np.zeros(nfeat, bool)
+        named[dev.indices[: dev.num_nonzero]] = True
+        assert not named[0] and not named[nfeat - 8:].any()
+        if shape == "hot":
+            counts = np.bincount(dev.indices[: dev.num_nonzero])
+            assert counts[7] >= 2048
+        if l2 == 0.0:  # bit for bit, the padded entries' feature 0 too
+            for k in ("w", "v"):
+                np.testing.assert_array_equal(
+                    np.asarray(got[k])[~named].view(np.uint32),
+                    before[k][~named].view(np.uint32))
+        # (a row's only entry has no factor gradient, a saturated row no
+        # gradient at all: the reference tells who had to move)
+        must = np.abs(want["w"] - before["w"]) > 1e-5
+        assert must.sum() > 20 and (l2 or not must[~named].any())
+        assert (np.asarray(got["w"]) != before["w"])[must].all()
+
     def test_no_value_of_the_tables_shape_but_the_scatter_adds(self):
         """A dense gradient, a zero fill or a whole-table update would
         each be an equation whose result has the table's shape."""
