@@ -333,7 +333,7 @@ def _timed_sgd_epochs(make_feed, size_mb, step_fn, layout, params, velocity,
     epoch — the per-stage stall breakdown next to its timing."""
     import jax
 
-    from dmlc_tpu.models.linear import step_batch
+    from dmlc_tpu.models.fitloop import step_batch
 
     runs = []
     for trial in range(TRIALS + 1):
@@ -698,10 +698,10 @@ def _bench_device_feed(path: str) -> dict:
 
     from dmlc_tpu.data.parsers import create_parser
     from dmlc_tpu.device.feed import BatchSpec, DeviceFeed
+    from dmlc_tpu.models.fitloop import step_batch
     from dmlc_tpu.models.linear import (
         init_linear_params,
         make_linear_train_step,
-        step_batch,
     )
     import jax.numpy as jnp
 
@@ -816,70 +816,6 @@ def _bench_device_feed(path: str) -> dict:
         lambda: _feed(csr_spec), size_mb, csr_step, "csr", cparams, cvel
     )
 
-    # device-resident fast path A/B (DMLC_TPU_DEVICE_RESIDENT): the
-    # pad-in-place emit rides the python re-batch producer, so both arms
-    # pin the vector parse backend — the spread isolates the staging fuse
-    # (+ donation arena reuse) from the parser choice. The default-path
-    # sgd_e2e_mbps key above stays untouched for A/B history.
-    # h2d_overlap_ratio: the fraction of the resident epoch's wall time
-    # NOT booked to transfer dispatch or waiting on the host producer —
-    # 1.0 means H2D fully hidden behind parse + step (sentry-gated
-    # higher-is-better, BENCH_DIRECTIONS).
-    resident_spec = BatchSpec(batch_size=16384, layout="dense",
-                              num_features=29, prefetch=2)
-    saved_env = {k: os.environ.get(k)
-                 for k in ("DMLC_TPU_DEVICE_RESIDENT",
-                           "DMLC_TPU_PARSE_BACKEND")}
-    resident_stats: list = []
-    try:
-        os.environ["DMLC_TPU_PARSE_BACKEND"] = "vector"
-        os.environ.pop("DMLC_TPU_DEVICE_RESIDENT", None)
-        yparams = init_linear_params(29)
-        yvel = {"w": jnp.zeros_like(yparams["w"]),
-                "b": jnp.zeros_like(yparams["b"])}
-        python_runs = _timed_sgd_epochs(
-            lambda: DeviceFeed(
-                create_parser(path, 0, 1, nthread=max(2, nthread)),
-                resident_spec,
-            ),
-            size_mb, step, "dense", yparams, yvel,
-        )
-        os.environ["DMLC_TPU_DEVICE_RESIDENT"] = "1"
-        rparams = init_linear_params(29)
-        rvel = {"w": jnp.zeros_like(rparams["w"]),
-                "b": jnp.zeros_like(rparams["b"])}
-        resident_runs = _timed_sgd_epochs(
-            lambda: DeviceFeed(
-                create_parser(path, 0, 1, nthread=max(2, nthread)),
-                resident_spec,
-            ),
-            size_mb, step, "dense", rparams, rvel,
-            stats_out=resident_stats,
-        )
-    finally:
-        for k, v in saved_env.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    overlap_samples = []
-    for mbps, stats in zip(resident_runs[1:], resident_stats):
-        wall_s = size_mb / max(mbps, 1e-9)
-        busy_s = (stats.get("dispatch_ns", 0)
-                  + stats.get("host_wait_ns", 0)) / 1e9
-        overlap_samples.append(max(0.0, min(1.0, 1.0 - busy_s / wall_s)))
-    # binding verdict for the resident arm from its own stall ledger:
-    # host_wait = waiting on parse, dispatch = H2D submission, consume =
-    # the jitted step. The fast path's acceptance is that this lands on
-    # parse or device_step, not h2d/host_wait-as-transfer.
-    rstages = _median_stall_stages(resident_stats)
-    rscores = {
-        "parse": rstages.get("host_wait_s", 0.0) + rstages.get("parse_s", 0.0),
-        "h2d": rstages.get("dispatch_s", 0.0),
-        "device_step": rstages.get("consume_s", 0.0),
-    }
-    resident_binding = max(rscores, key=rscores.get)
-
     out = {
         "feed_dense_mbps": round(statistics.median(feed_runs[1:]), 1),
         "feed_dense_trials_mbps": feed_runs[1:],
@@ -896,17 +832,6 @@ def _bench_device_feed(path: str) -> dict:
         "sgd_e2e_cached_trials_mbps": cached_runs[1:],
         "sgd_csr_e2e_mbps": round(statistics.median(csr_runs[1:]), 1),
         "sgd_csr_e2e_trials_mbps": csr_runs[1:],
-        "sgd_e2e_python_mbps": round(statistics.median(python_runs[1:]), 1),
-        "sgd_e2e_python_trials_mbps": python_runs[1:],
-        "sgd_e2e_resident_mbps": round(
-            statistics.median(resident_runs[1:]), 1),
-        "sgd_e2e_resident_trials_mbps": resident_runs[1:],
-        "h2d_overlap_ratio": (
-            round(statistics.median(overlap_samples), 3)
-            if overlap_samples else 0.0
-        ),
-        "resident_stall_stages": rstages,
-        "resident_binding_stage": resident_binding,
     }
     # Sharded sparse H2D accounting (one batch, host-side): per-device
     # entry bytes under the 8-shard partition vs the replicated layout.
@@ -1095,7 +1020,7 @@ def _bench_multijob(path: str) -> dict:
         )
 
         # cold/warm cache pass on a fresh fleet: ONE worker so every part
-        # leased for the warm job is resident where it was parsed. Both
+        # leased for the warm job is cached where it was parsed. Both
         # ledgers are registered up front (a worker whose whole fleet
         # drains retires its stream), then drained one after the other.
         reset_source_cache()
@@ -1190,8 +1115,6 @@ _COMPACT_KEYS = (
     "sgd_e2e_pipelined_mbps", "sgd_e2e_cached_mbps",
     "sgd_csr_e2e_mbps", "recordio_sgd_mbps", "sgd_e2e_shard_mbps",
     "criteo_like_csr_sgd_mbps",
-    "sgd_e2e_resident_mbps", "sgd_e2e_python_mbps", "h2d_overlap_ratio",
-    "resident_binding_stage",
     "gbdt_fit_mrows_s",
     "sgd_e2e_multijob_mbps", "cache_cross_job_hit_ratio",
     "sgd_goodput_ratio", "sgd_mfu", "ckpt_overhead_ratio",
@@ -1212,10 +1135,9 @@ _COMPACT_KEYS = (
 
 # sentry direction registry carried on every record (obs/sentry.py
 # record_directions): extra keys the gate scores that no suffix rule
-# covers — both are 0..1 fractions, higher is better
+# covers
 BENCH_DIRECTIONS = {
     "sgd_goodput_ratio": "higher",
-    "h2d_overlap_ratio": "higher",
     # snapshot tax and restore latency regress upward: gate them down
     "ckpt_overhead_ratio": "lower",
     "resume_restore_s": "lower",

@@ -352,9 +352,9 @@ class RowBlockContainer:
         ``to_block``'s concatenation — field-major over parts, neutral
         defaults filled per part. Concatenation-invariance of the hash
         (parts are hashed back to back within a field) makes this
-        byte-identical to ``self.to_block().audit_arrays()``, which is
-        what lets the device-resident feed digest its pending container
-        while the legacy feed digests the sliced block, and still agree."""
+        byte-identical to ``self.to_block().audit_arrays()``: the parse
+        stage digests a chunk's container (data/pipeline.py) and agrees
+        with any stage that digests the same rows as a block."""
         out = [
             (b"label", list(self._label_parts)),
             (b"counts", list(self._count_parts)),
@@ -376,89 +376,6 @@ class RowBlockContainer:
         if fields_present:
             out.append((b"field", fields_present))
         return out
-
-    def emit_csr_into(
-        self,
-        labels: np.ndarray,
-        weights: np.ndarray,
-        indices: np.ndarray,
-        values: np.ndarray,
-        offsets: np.ndarray,
-    ) -> tuple:
-        """Write the accumulated rows straight into caller-provided CSR
-        staging arrays, skipping ``to_block``'s materialization.
-
-        This is the device-resident fast path's single copy: each pushed
-        part lands sequentially in the (pre-sized, typically pooled)
-        destination arrays — no intermediate concatenate, no second pad
-        copy. ``offsets`` must have room for ``size + 1`` entries and is
-        written rebased to 0; missing per-part weight/value arrays emit
-        the neutral 1.0 defaults ``to_block`` would have filled. Returns
-        ``(nrows, nnz)`` actually written; the caller owns zeroing any
-        pad tail beyond them. qid/field do not ride the device batch and
-        are intentionally not emitted.
-        """
-        check(len(labels) >= self._nrows, "labels staging too small")
-        check(len(offsets) >= self._nrows + 1, "offsets staging too small")
-        check(len(indices) >= self._nnz, "indices staging too small")
-        row = 0
-        ent = 0
-        offsets[0] = 0
-        for i, lbl in enumerate(self._label_parts):
-            n = len(lbl)
-            idx = self._index_parts[i]
-            m = len(idx)
-            labels[row : row + n] = lbl
-            w = self._weight_parts[i]
-            weights[row : row + n] = 1.0 if w is None else w
-            if n:
-                offsets[row + 1 : row + n + 1] = ent + np.cumsum(
-                    self._count_parts[i]
-                )
-            indices[ent : ent + m] = idx
-            v = self._value_parts[i]
-            values[ent : ent + m] = 1.0 if v is None else v
-            row += n
-            ent += m
-        check_eq(row, self._nrows, "emit_csr_into row count drift")
-        check_eq(ent, self._nnz, "emit_csr_into nnz drift")
-        return row, ent
-
-    def emit_dense_into(
-        self,
-        x: np.ndarray,
-        labels: np.ndarray,
-        weights: np.ndarray,
-    ) -> int:
-        """Scatter the accumulated rows straight into a caller-provided
-        (pre-zeroed) dense ``[batch, num_features]`` array — the dense
-        twin of :meth:`emit_csr_into`, fusing ``to_block`` +
-        ``device/csr.block_to_dense`` into one pass over the parts.
-        Out-of-range feature ids are dropped, matching ``block_to_dense``.
-        Returns the row count written; the caller owns the pad tail."""
-        check(len(labels) >= self._nrows, "labels staging too small")
-        check(x.shape[0] >= self._nrows, "dense staging too small")
-        num_features = x.shape[1]
-        row = 0
-        for i, lbl in enumerate(self._label_parts):
-            n = len(lbl)
-            labels[row : row + n] = lbl
-            w = self._weight_parts[i]
-            weights[row : row + n] = 1.0 if w is None else w
-            idx = self._index_parts[i]
-            rows = row + np.repeat(
-                np.arange(n, dtype=np.int64), self._count_parts[i]
-            )
-            v = self._value_parts[i]
-            vals = (
-                np.ones(len(idx), dtype=REAL_DTYPE) if v is None
-                else v
-            )
-            keep = idx < num_features
-            x[rows[keep], idx[keep]] = vals[keep]
-            row += n
-        check_eq(row, self._nrows, "emit_dense_into row count drift")
-        return row
 
     # ---- binary page format (row_block.h:189-215) ----------------------
     def save(self, stream: Stream) -> None:

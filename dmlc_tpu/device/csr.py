@@ -1,4 +1,4 @@
-"""Device-resident CSR batches with static-shape padding/bucketing.
+"""Device CSR batches with static-shape padding/bucketing.
 
 The reference's ``RowBlock`` (data.h:170) is variable-length CSR on the host.
 XLA wants static shapes: a new shape means a new compilation, and a stream of
@@ -59,9 +59,7 @@ class DeviceCSRBatch:
     weights: np.ndarray  # [batch] f32 (0.0 for padded rows)
     indices: np.ndarray  # [nnz_bucket] i32 feature ids
     values: np.ndarray  # [nnz_bucket] f32 (0.0 for padded entries)
-    row_ids: Optional[np.ndarray]  # [nnz_bucket] i32 row of each entry;
-    # None on the device-resident emit path (never shipped — the device
-    # expands offsets itself, so the resident stager skips building it)
+    row_ids: np.ndarray  # [nnz_bucket] i32 row of each entry
     offsets: np.ndarray  # [batch + 1] i32 CSR twin of row_ids (shipped to
     # device instead of row_ids: H2D ∝ rows, not nnz; padded rows repeat
     # the valid nnz)
@@ -130,65 +128,6 @@ def pad_to_bucket(
         indices=indices,
         values=values,
         row_ids=row_ids,
-        offsets=offsets,
-        num_rows=n,
-        num_nonzero=nnz,
-    )
-
-
-def _staging_raw(pool, shape, dtype):
-    """An UNCLEARED staging array (pooled or fresh np.empty) — for the
-    emit path, which overwrites the valid prefix and zeroes only the pad
-    tail instead of paying a full fill before a full overwrite."""
-    if pool is None:
-        return np.empty(shape, dtype=dtype)
-    return pool.acquire(shape, dtype)
-
-
-def emit_to_bucket(
-    container,
-    batch_size: int,
-    nnz_bucket: Optional[int] = None,
-    nnz_floor: int = 256,
-    pool=None,
-) -> DeviceCSRBatch:
-    """Pad-in-place: emit a ``RowBlockContainer``'s rows straight into a
-    static-shape DeviceCSRBatch's (pooled) staging arrays.
-
-    The legacy path is ``container.to_block()`` (a concatenate copy)
-    followed by :func:`pad_to_bucket` (a second copy into staging); this
-    fuses both into ``RowBlockContainer.emit_csr_into`` — the parsed
-    parts' only copy lands directly where ``device_put`` reads. Staging
-    is acquired uncleared and only the pad tails are zeroed (padded
-    entries stay arithmetic no-ops: value 0 at feature 0, zero-weight
-    rows). ``row_ids`` is None: the resident feed never ships it — the
-    device expands ``offsets`` itself (ops/spmv.expand_row_ids).
-    """
-    n = container.size
-    check(n <= batch_size, "container larger than batch_size")
-    nnz = container.num_nonzero
-    bucket = (
-        nnz_bucket if nnz_bucket is not None else round_up_bucket(nnz, nnz_floor)
-    )
-    check(nnz <= bucket, "nnz exceeds bucket")
-
-    labels = _staging_raw(pool, batch_size, np.float32)
-    weights = _staging_raw(pool, batch_size, np.float32)
-    indices = _staging_raw(pool, bucket, np.int32)
-    values = _staging_raw(pool, bucket, np.float32)
-    offsets = _staging_raw(pool, batch_size + 1, np.int32)
-    container.emit_csr_into(labels, weights, indices, values, offsets)
-    labels[n:] = 0.0
-    weights[n:] = 0.0
-    indices[nnz:] = 0
-    values[nnz:] = 0.0
-    offsets[n + 1 :] = nnz
-    return DeviceCSRBatch(
-        labels=labels,
-        weights=weights,
-        indices=indices,
-        values=values,
-        row_ids=None,
         offsets=offsets,
         num_rows=n,
         num_nonzero=nnz,

@@ -40,15 +40,10 @@ from dmlc_tpu.device.csr import (
     DeviceCSRBatch,
     ShardedCSRBatch,
     block_to_dense,
-    emit_to_bucket,
     pad_to_bucket,
     pad_to_bucket_sharded,
 )
-from dmlc_tpu.params.knobs import (
-    default_host_prefetch,
-    default_prefetch,
-    device_resident,
-)
+from dmlc_tpu.params.knobs import default_host_prefetch, default_prefetch
 from dmlc_tpu.utils.logging import check
 from dmlc_tpu.utils.threaded_iter import ThreadedIter
 
@@ -112,18 +107,6 @@ class BatchSpec:
     # classic double-buffer; None resolves
     # through the DMLC_TPU_PREFETCH knob (params/knobs.py).
     prefetch: Optional[int] = None
-
-
-@dataclass
-class _ResidentDense:
-    """A dense batch already scattered into pooled staging by the
-    device-resident producer (``RowBlockContainer.emit_dense_into``) —
-    carries its staging arrays so ``_to_device`` can retire them."""
-
-    x: np.ndarray  # [batch, num_features] f32
-    labels: np.ndarray  # [batch] f32
-    weights: np.ndarray  # [batch] f32 (0.0 for padded rows)
-    num_rows: int
 
 
 class FixedShapePool:
@@ -328,7 +311,7 @@ def stall_breakdown(stats: dict) -> str:
 
 
 class DeviceFeed:
-    """Iterate device-resident batches from a parser or URI.
+    """Iterate device batches from a parser or URI.
 
     With a ``mesh``, batches are sharded over its ``axis`` (default "dp") on
     the leading dimension; each process feeds its local shard (multi-host:
@@ -441,24 +424,12 @@ class DeviceFeed:
         # passes over the source this feed has begun: with a batch's
         # place in its pass, the batch id every feed span carries
         self._pass = 0
-        # device-resident fast path (DMLC_TPU_DEVICE_RESIDENT): parsed
-        # RowBlock parts emit straight into pooled staging (pad-in-place,
-        # device/csr.emit_to_bucket) instead of materialize+pad — python
-        # re-batch paths only (the native pipeline already stages without
-        # container copies; sharded csr keeps its partition path)
-        self._resident = (
-            device_resident()
-            and spec.layout in ("dense", "csr")
-            and self._shards == 1
-        )
         # H2D accounting around _put_tree: None when device telemetry is
         # off, and then the dispatch path has no byte walk and no timer.
         self._h2d = device_telemetry.h2d_meter(feed=fid)
         device_telemetry.maybe_start_hbm_poller()
         # determinism audit: batch-stage digests at pool emit, keyed by
-        # per-epoch batch index (obs/audit.py; the canonical audit_arrays
-        # stream makes the resident container and the legacy sliced block
-        # hash identically for the same rows). The shared no-op child
+        # per-epoch batch index (obs/audit.py). The shared no-op child
         # when DMLC_TPU_AUDIT is off.
         self._audit = audit.auditor()
         self._epoch_base: dict = {}
@@ -512,8 +483,6 @@ class DeviceFeed:
 
         if self._use_native_batches():
             producer = self._host_batches_native()
-        elif self._resident:
-            producer = self._host_batches_resident()
         else:
             producer = self._host_batches_python()
         # sync mode runs this on the consumer's thread, whose own count
@@ -585,92 +554,6 @@ class DeviceFeed:
             # chunks whose rows only ever reached a dropped remainder (or
             # an empty chunk) still count as visited — ack them here or
             # the dispatcher would requeue them forever
-            for sid in seqs:
-                self._ack_seq(sid)
-
-    def _emit_resident(self, pending, flows, seqs, bidx):
-        """Finalize one accumulated container straight into pooled
-        staging — the device-resident single copy (no ``to_block``
-        concatenate, no second pad copy)."""
-        spec = self.spec
-        with obs.span("stage", rows=len(pending), pass_=self._pass,
-                      batch=bidx):
-            for fid in flows:
-                obs.flow_step(fid, "chunk")
-            if spec.layout == "csr":
-                batch = emit_to_bucket(
-                    pending, spec.batch_size, nnz_bucket=spec.nnz_bucket,
-                    pool=self.pool,
-                )
-                batch.staging_bufs = (
-                    batch.labels, batch.weights, batch.indices,
-                    batch.values, batch.offsets,
-                )
-            else:
-                x = self.pool.acquire(
-                    (spec.batch_size, spec.num_features), np.float32)
-                x.fill(0)  # the scatter only writes present entries
-                labels = self.pool.acquire(spec.batch_size, np.float32)
-                weights = self.pool.acquire(spec.batch_size, np.float32)
-                n = pending.emit_dense_into(x, labels, weights)
-                labels[n:] = 0.0
-                weights[n:] = 0.0
-                batch = _ResidentDense(
-                    x=x, labels=labels, weights=weights, num_rows=n)
-        if flows:
-            batch.flow_ids = tuple(flows)
-        if seqs:
-            batch.seq_ids = tuple(seqs)
-        return batch
-
-    def _host_batches_resident(self) -> Iterator:
-        """The device-resident re-batch producer: parser blocks are
-        split at batch boundaries with zero-copy ``slice()`` views into
-        an accumulating container, and each full batch is emitted
-        directly into ``FixedShapePool`` staging via the pad-in-place
-        path (``RowBlockContainer.emit_csr_into`` /
-        ``emit_dense_into``). vs ``_host_batches_python``: the
-        ``to_block()`` concatenate copy and the separate pad copy fuse
-        into ONE write that lands where ``device_put`` reads."""
-        spec = self.spec
-        bs = spec.batch_size
-        if spec.layout == "dense":
-            check(spec.num_features > 0, "dense layout requires num_features")
-        pending = RowBlockContainer()
-        bidx = 0  # per-epoch batch index (audit batch-chain key); the
-        # container digests BEFORE emit consumes it, and hashes the same
-        # bytes as the legacy path's sliced block for the same rows
-        flows = []
-        seqs = []
-        for block in self._parser:
-            fid = getattr(block, "flow_id", 0)
-            if fid:
-                flows.append(fid)
-            sid = getattr(block, "seq_id", None)
-            if sid is not None:
-                seqs.append(sid)
-            start = 0
-            n = len(block)
-            while len(pending) + (n - start) >= bs:
-                take = bs - len(pending)
-                if take:
-                    pending.push_block(block.slice(start, start + take))
-                    start += take
-                self._audit.note_batch(bidx, pending)
-                yield self._emit_resident(pending, flows, seqs, bidx)
-                bidx += 1
-                pending = RowBlockContainer()
-                flows = []
-                seqs = []
-            if start < n:
-                pending.push_block(block.slice(start, n))
-        if len(pending) and not spec.drop_remainder:
-            self._audit.note_batch(bidx, pending)
-            yield self._emit_resident(pending, flows, seqs, bidx)
-            seqs = []
-        if seqs and self._ack is not None:
-            # chunks whose rows only reached a dropped remainder still
-            # count as visited (see _host_batches_python)
             for sid in seqs:
                 self._ack_seq(sid)
 
@@ -835,18 +718,8 @@ class DeviceFeed:
             out["num_rows"] = rows
             return out, ()
         if isinstance(block, (DeviceCSRBatch, ShardedCSRBatch)):
-            # native COO batch (no staging to retire) or the resident
-            # emit path (its pooled staging rides along for retire)
-            return self._put_csr(block), getattr(block, "staging_bufs", ())
-        if isinstance(block, _ResidentDense):
-            out = self._put_tree(
-                {"x": block.x, "label": block.labels,
-                 "weight": block.weights},
-                {"x": P(self._axis), "label": P(self._axis),
-                 "weight": P(self._axis)},
-            )
-            out["num_rows"] = block.num_rows
-            return out, (block.x, block.labels, block.weights)
+            # native COO batch: staged in C++, no pooled staging to retire
+            return self._put_csr(block), ()
         if spec.layout == "dense":
             check(spec.num_features > 0, "dense layout requires num_features")
             with obs.span("stage", rows=len(block), pass_=self._pass,

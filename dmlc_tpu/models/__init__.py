@@ -12,7 +12,18 @@ so this package ships that learner family TPU-natively:
 - ``gbdt``: histogram gradient-boosted trees — the xgboost-over-rabit
   workload the reference backbone was built for, with per-level histogram
   psum standing in for rabit's allreduce
+- ``fitloop``: the one fit loop linear and FM run (``fit_feed``,
+  ``fit_uri``; what a learner supplies to it is ``FeedLearner``), its
+  helpers (``EpochMetrics``, ``step_batch``) and the epoch boundary all
+  three families share (``FitLoopObs``)
 """
+
+from dmlc_tpu.models.fitloop import (
+    EpochMetrics,
+    FeedLearner,
+    FitLoopObs,
+    step_batch,
+)
 
 from dmlc_tpu.models.linear import (
     LINEAR_PARTITION_RULES,
@@ -43,6 +54,10 @@ from dmlc_tpu.models.gbdt import (
 )
 
 __all__ = [
+    "EpochMetrics",
+    "FeedLearner",
+    "FitLoopObs",
+    "step_batch",
     "LINEAR_PARTITION_RULES",
     "LINEAR_MP_PARTITION_RULES",
     "LinearModelParam",

@@ -1,48 +1,146 @@
-"""Shared fit-loop observability: one epoch-boundary helper for every
-learner.
+"""The streaming learners' fit loop, written once.
 
-Before this module each learner hand-rolled the same block — register
-the four ``dmlc_fit_*`` metrics, observe the epoch histogram, log the
-feed's stall breakdown (linear only, behind a function-local import),
-export the registry. :class:`FitLoopObs` is that block once, plus the
-runtime instruments this layer gained: a goodput ledger window per
-epoch (obs/goodput.py) and the SLO watchdog over those windows
-(obs/watchdog.py). linear, FM, and GBDT all funnel through it, so the
-epoch log line and the binding-constraint verdict are uniform across
-models.
+:func:`fit_feed` is the loop over passes of a ``DeviceFeed`` that
+``LinearLearner.fit_feed`` and ``FMLearner.fit_feed`` delegate to, and
+:func:`fit_uri` the path from a data URI to it (parser, feed, snapshots,
+resume). GBDT keeps its own loop (one ``lax.scan``) and shares only the
+epoch boundary, :class:`FitLoopObs`.
 
-Usage::
+What a learner supplies to the loop (:class:`FeedLearner` is the base
+that declares it; linear and FM fill it in):
 
-    fl = FitLoopObs("linear")
-    for epoch in range(epochs):
-        t0 = time.monotonic_ns()
-        for batch in feed:
-            ...
-            fl.note_step()
-        fl.finish_epoch(epoch, nstep, t0, acc, history, feed=feed,
-                        log_every=log_every)
+- ``name``: the ``model`` label of its spans and ``dmlc_fit_*`` metrics;
+- ``mesh``: where its state lives (the feed must have been built over
+  the same one); ``params``: its parameter tree, for the audit's sample;
+  ``param``: its hyper-parameters (``fit_uri`` takes ``num_features``
+  from there when the caller gives none);
+- ``ensure_step(spec)``: make sure parameters and the compiled step
+  exist for batches of ``feed.spec`` (called before every batch: a
+  membership change may have dropped the step mid-pass);
+- ``train_step(arrays)``: run one step on a delivered batch's arrays
+  (the loop has stripped the feed's metadata, :func:`step_batch`), rebind
+  the learner's own state (the step donates it), return the metrics dict
+  (``loss_sum``, ``weight_sum``: device scalars, not read here);
+- ``snapshot_model()``: the ``model`` subtree of a job snapshot, and
+  ``restore_snapshot_model(model)`` its way back;
+- optionally ``epoch_span_args()``: more attributes for the ``epoch``
+  span, and ``epoch_closed(reg, nstep)``: called inside ``epoch_close``
+  for counters only this model has (FM's three).
 
 The loop never waits for the device inside a pass: the one wait is the
 pass's loss read-back in :meth:`FitLoopObs.finish_epoch`, under the
 ``loss_readback`` span; the bookkeeping after it is ``epoch_close``.
 Device time per step comes from the profile (the benchmark's
-``step_device_ms``), not from a host-side sync.
+``step_device_ms``), not from a host-side sync. ``log_every`` counts
+epochs, here and in every learner.
 
-Under ``DMLC_TPU_METRICS=0`` the registry hands back no-op children and
-the ledger/watchdog collapse to the shared no-op child, so the hot path
-stays allocation-free.
+:class:`FitLoopObs` is the epoch boundary every learner shares: the four
+``dmlc_fit_*`` metrics, a goodput-ledger window per epoch
+(obs/goodput.py) fed to the SLO watchdog (obs/watchdog.py), the audit's
+model chain, the stall/goodput log line, the registry export and the
+snapshot capture. Under ``DMLC_TPU_METRICS=0`` the registry hands back
+no-op children and the ledger/watchdog collapse to the shared no-op
+child, so the hot path stays allocation-free.
 """
 
 from __future__ import annotations
 
+import functools
 import time
-from typing import Optional
+import warnings
+import weakref
+from typing import Callable, Dict, Optional
 
-from dmlc_tpu import obs
-from dmlc_tpu.device.feed import stall_breakdown
+import jax
+import numpy as np
+from jax.sharding import Mesh
+
+from dmlc_tpu import collective, obs
+from dmlc_tpu.collective import JobSnapshot, Snapshotter, load_snapshot
+from dmlc_tpu.data import create_parser
+from dmlc_tpu.device.feed import BatchSpec, DeviceFeed, stall_breakdown
 from dmlc_tpu.obs import audit, goodput, xla_cost
 from dmlc_tpu.obs.watchdog import make_watchdog
-from dmlc_tpu.utils.logging import log_info
+from dmlc_tpu.parallel.partition import shard_params
+from dmlc_tpu.resilience import Preempted, preempt
+from dmlc_tpu.utils.logging import check, log_info
+
+_DENSE_KEYS = ("x", "label", "weight")
+_CSR_KEYS = ("label", "weight", "indices", "values", "offsets")
+
+
+def step_batch(batch: Dict, layout: str) -> Dict:
+    """Strip DeviceFeed metadata (num_rows/num_nonzero ints) down to the
+    array fields a jitted train step consumes."""
+    keys = _DENSE_KEYS if layout == "dense" else _CSR_KEYS
+    return {k: batch[k] for k in keys}
+
+
+def suppress_donation_warnings(step):
+    """Batch leaves ([B,F] x, per-entry arrays) can never alias a donating
+    step's outputs (w [F], scalars), so XLA warns "donated buffers were
+    not usable" per compiled shape — the donation is still worth it for
+    the early buffer release. The suppression is scoped to THIS step's
+    call sites via catch_warnings, not installed process-globally: a
+    user's own jitted function emitting the same message may be flagging
+    a real missed donation, and this package must not eat that signal.
+
+    The warnings fire only at trace/compile time (once per argument-shape
+    signature), so the suppression engages only on calls with an unseen
+    signature: steady-state steps call straight through — no per-step
+    catch_warnings, whose filter-version bump would invalidate every
+    module's __warningregistry__ and make unrelated once-per-location
+    warnings re-fire each iteration. (catch_warnings swaps the global
+    filter list for the compile call's duration; the swap is not atomic
+    across threads — the stdlib limitation — but the window is one
+    compile, not every step.)"""
+    seen = set()
+
+    @functools.wraps(step)
+    def wrapped(*args, **kwargs):
+        key = tuple(
+            (getattr(x, "shape", None), str(getattr(x, "dtype", type(x))))
+            for x in jax.tree_util.tree_leaves((args, kwargs))
+        )
+        if key in seen:
+            return step(*args, **kwargs)
+        seen.add(key)
+        with warnings.catch_warnings():
+            for msg in ("Some donated buffers were not usable",
+                        "Donation is not implemented"):
+                warnings.filterwarnings("ignore", message=msg)
+            return step(*args, **kwargs)
+
+    return wrapped
+
+
+class EpochMetrics:
+    """Collect per-step device metric scalars with no per-step dispatch or
+    host sync; reading does one batched device_get (a per-step ``float()``
+    stalls the feed's batch-in-flight overlap; a per-step device add pays
+    dispatch overhead per step)."""
+
+    def __init__(self):
+        self._loss = []
+        self._weight = []
+        self._loss_total = 0.0
+        self._weight_total = 0.0
+
+    def add(self, metrics: Dict) -> None:
+        self._loss.append(metrics["loss_sum"])
+        self._weight.append(metrics["weight_sum"])
+
+    def mean_loss(self) -> float:
+        if self._loss:
+            # drain the pending scalars into the running totals: a repeated
+            # read never re-fetches what was already summed, and the device
+            # scalars are released here, where they were read
+            loss, weight = jax.device_get((self._loss, self._weight))
+            self._loss_total += float(np.sum(loss))
+            self._weight_total += float(np.sum(weight))
+            self._loss.clear()
+            self._weight.clear()
+        return self._loss_total / max(self._weight_total, 1e-12)
 
 
 class FitLoopObs:
@@ -92,10 +190,7 @@ class FitLoopObs:
                   loss: Optional[float], feed=None,
                   log_every: int = 0, params=None,
                   snapshotter=None, snap_state=None,
-                  sparse_update_steps: Optional[int] = None,
-                  sharded_table_steps: Optional[int] = None,
-                  exchange_bytes: Optional[int] = None
-                  ) -> Optional[dict]:
+                  on_close: Optional[Callable] = None) -> Optional[dict]:
         """Close one epoch: fit metrics, a goodput-ledger window fed to
         the watchdog, the unified stall/goodput log line (every
         ``log_every``-th epoch), and the registry export. Returns the
@@ -114,35 +209,14 @@ class FitLoopObs:
         re-arms the chains exactly where an uninterrupted run would
         be.
 
-        ``sparse_update_steps`` (learners whose step can update only the
-        rows a batch touches: FM) is how many of this epoch's ``nstep``
-        took that path; over ``dmlc_fit_steps_total`` it is the share of
-        steps that engaged it. ``sharded_table_steps`` likewise counts the
-        steps taken over a table divided over the mesh's chips, and
-        ``exchange_bytes`` the bytes one chip contributed to those steps'
-        collectives (from the shapes; the gradient psum of a replicated
-        model is not among them, ``dmlc_xla_collective_bytes`` has it)."""
+        ``on_close(reg, nstep)`` runs inside the span, after the step
+        counter: where a learner counts what only it has
+        (:meth:`FeedLearner.epoch_closed`)."""
         with obs.span("epoch_close", model=self.model, epoch=epoch):
             self.h_epoch.observe(time.monotonic_ns() - t0_ns)
             self.m_steps.inc(nstep)
-            if sparse_update_steps is not None:
-                self.reg.counter(
-                    "dmlc_fit_sparse_update_steps_total",
-                    "optimizer steps that scatter-added into the touched "
-                    "rows instead of applying a dense gradient",
-                    model=self.model).inc(sparse_update_steps)
-            if sharded_table_steps is not None:
-                self.reg.counter(
-                    "dmlc_fit_sharded_table_steps_total",
-                    "optimizer steps over a parameter table sharded over "
-                    "the mesh's chips (no chip holds the whole table)",
-                    model=self.model).inc(sharded_table_steps)
-            if exchange_bytes is not None:
-                self.reg.counter(
-                    "dmlc_fit_exchange_bytes_total",
-                    "bytes one chip contributed to the collectives of "
-                    "sharded-table steps (batch gather + interaction psum)",
-                    model=self.model).inc(exchange_bytes)
+            if on_close is not None:
+                on_close(self.reg, nstep)
             self.m_epochs.inc()
             if loss is not None:
                 self.g_loss.set(loss)
@@ -171,25 +245,209 @@ class FitLoopObs:
         return win
 
 
+class FeedLearner:
+    """What :func:`fit_feed` asks of a streaming learner (see the module
+    docstring), with the parts linear and FM would otherwise each write:
+    the mesh-membership listener and :meth:`reshard`.
+
+    A mesh learner registers a ``collective.on_membership_change``
+    listener: elastic re-entry / recovery re-places its state on a mesh
+    rebuilt over the surviving devices."""
+
+    #: the ``model`` label of the learner's spans and ``dmlc_fit_*`` metrics
+    name = ""
+    #: attributes holding the trees :meth:`partition_rules` places
+    state_trees = ("params",)
+
+    def __init__(self, mesh: Optional[Mesh] = None):
+        self.mesh = mesh
+        self.params = None
+        self._step = None
+        self._unlisten = None
+        if mesh is not None:
+            self.check_mesh(mesh)
+            ref = weakref.ref(self)
+
+            def _membership_cb():
+                learner = ref()
+                if learner is not None and learner.params is not None:
+                    learner.reshard()
+
+            self._unlisten = collective.on_membership_change(_membership_cb)
+
+    def partition_rules(self):
+        """The rule table (parallel/partition.py) that places every tree
+        of ``state_trees`` on the mesh."""
+        raise NotImplementedError
+
+    def check_mesh(self, mesh: Mesh) -> None:
+        """Raise if this learner's state cannot be placed on ``mesh``."""
+
+    def reshard(self, mesh: Optional[Mesh] = None) -> None:
+        """Re-place the state trees on ``mesh`` (default: a fresh mesh
+        over the CURRENT device set, same axis names) and drop the traced
+        step — the elastic re-entry hook. Leaves round-trip through host
+        copies because the old placement may reference devices that no
+        longer exist."""
+        if self.mesh is None or self.params is None:
+            return
+        if mesh is None:
+            check(
+                len(self.mesh.axis_names) == 1,
+                "pass mesh= to reshard a multi-axis mesh",
+            )
+            mesh = Mesh(np.asarray(jax.devices()), self.mesh.axis_names)
+        self.check_mesh(mesh)
+        self.mesh = mesh
+        for attr in self.state_trees:
+            tree = getattr(self, attr)
+            if tree is not None:
+                setattr(self, attr, shard_params(
+                    jax.device_get(tree), mesh, rules=self.partition_rules()))
+        self._step = None  # retrace against the new mesh on next batch
+
+    def ensure_step(self, spec) -> None:
+        raise NotImplementedError
+
+    def train_step(self, arrays: Dict) -> Dict:
+        raise NotImplementedError
+
+    def snapshot_model(self) -> Dict:
+        """The ``model`` subtree of a job snapshot: the device arrays as
+        they are (the snapshotter host-copies them before the next
+        epoch's donating steps run)."""
+        raise NotImplementedError
+
+    def restore_snapshot_model(self, model: Dict) -> None:
+        raise NotImplementedError
+
+    def epoch_span_args(self) -> Dict:
+        return {}
+
+    def epoch_closed(self, reg, nstep: int) -> None:
+        """Inside ``epoch_close``: count what only this model has."""
+
+
+def _snapshot_state(learner, feed, epoch: int, history) -> Dict:
+    """The job-snapshot state tree at one epoch boundary (built on the
+    training thread)."""
+    state = {
+        "model": learner.snapshot_model(),
+        "epoch": int(epoch),
+        "history": [float(x) for x in history],
+        "rng": None,  # neither SGD path draws step-time randomness
+        "audit": audit.auditor().export_state(),
+    }
+    parser = getattr(feed, "_parser", None)
+    if hasattr(parser, "snapshot_state"):
+        state["data"] = {"parser": parser.snapshot_state()}
+    return state
+
+
+def fit_feed(learner, feed, epochs: int = 1, log_every: int = 0,
+             snapshotter=None, start_epoch: int = 0, history=None):
+    """Train ``learner`` over a DeviceFeed for N epochs; returns per-epoch
+    losses. Every ``log_every``-th epoch logs the loss and the feed's
+    per-stage stall breakdown (device.feed.stall_breakdown).
+
+    With ``snapshotter`` armed, epoch boundaries hand a state tree to the
+    async snapshot writer and the loop polls for preemption notices
+    between steps (SIGTERM via resilience/preempt.py, or the injectable
+    ``preempt.notice`` faultpoint): a notice stops the partial epoch,
+    finalizes the freshest epoch-boundary snapshot within the grace
+    window, and raises :class:`~dmlc_tpu.resilience.Preempted` so the
+    process exits with the launcher's relaunch code (see
+    docs/robustness.md "Preemption & resume"). ``start_epoch``/``history``
+    continue a resumed run (the returned history covers ALL epochs,
+    restored ones included)."""
+    # mesh csr steps consume the SHARDED entry layout (local row ids);
+    # a feed built without the mesh would deliver replicated entries
+    # whose global row ids silently corrupt every shard's segment-sum
+    check(
+        getattr(feed, "_mesh", None) is learner.mesh,
+        "feed mesh and learner mesh must match (csr entry layouts "
+        "differ between mesh and single-device runs)",
+    )
+    name = learner.name
+    spec = feed.spec
+    layout = spec.layout
+    fl = FitLoopObs(name)
+    history = list(history) if history else []
+    for epoch in range(start_epoch, epochs):
+        acc = EpochMetrics()
+        nstep = 0
+        preempted = False
+        t0 = time.monotonic_ns()
+        with obs.span("epoch", model=name, epoch=epoch,
+                      **learner.epoch_span_args()):
+            for batch in feed:
+                learner.ensure_step(spec)
+                # train_step closes the chunk's arrow chain: the feed
+                # set the thread's current flow around this yield
+                with obs.span("train_step", model=name, step=nstep,
+                              **obs.current_batch()):
+                    obs.flow_step(obs.current_flow(), "chunk")
+                    # the last reference to the previous batch's arrays
+                    # goes here, so they are released inside this span
+                    arrays = step_batch(batch, layout)
+                    metrics = learner.train_step(arrays)
+                acc.add(metrics)
+                fl.note_step()
+                nstep += 1
+                if snapshotter is not None and preempt.poll():
+                    preempted = True
+                    break
+        if preempted:
+            # a partial epoch is never snapshotted (resume replays it
+            # in full — that is what keeps the relaunch bit-identical);
+            # commit the freshest epoch-boundary capture and exit with
+            # the relaunch code
+            snapshotter.finalize()
+            raise Preempted(
+                "preempted in epoch %d after %d steps; last committed "
+                "snapshot epoch %d"
+                % (epoch, nstep, snapshotter.committed_epoch))
+        fl.finish_epoch(
+            epoch, nstep, t0, acc, history, feed=feed,
+            log_every=log_every, params=learner.params,
+            snapshotter=snapshotter,
+            snap_state=(None if snapshotter is None else
+                        lambda e=epoch: _snapshot_state(
+                            learner, feed, e, history)),
+            on_close=learner.epoch_closed,
+        )
+        if epoch + 1 < epochs:
+            feed.before_first()
+    return history
+
+
 def fit_uri(learner, uri: str, *, batch_size: int = 4096,
             epochs: int = 1, layout: str = "dense", num_features: int = 0,
             part_index: Optional[int] = None,
             num_parts: Optional[int] = None, drop_remainder: bool = False,
             log_every: int = 0, snapshot_uri: Optional[str] = None,
             resume: bool = False, snap_every_epochs: int = 1):
-    """The learners' ``fit_uri``: InputSplit part → parser → DeviceFeed
-    over ``learner.mesh`` → ``learner.fit_feed``. The part defaults to
-    this worker's collective rank/world. With ``snapshot_uri`` the fit
-    runs under a :class:`~dmlc_tpu.collective.Snapshotter`;
-    ``resume=True`` first loads the newest committed snapshot, hands its
-    model to ``learner.restore_snapshot_model`` and continues at the next
-    epoch (see
-    :meth:`LinearLearner.fit_uri` for the contract)."""
-    from dmlc_tpu import collective
-    from dmlc_tpu.data import create_parser
-    from dmlc_tpu.device import BatchSpec, DeviceFeed
-    from dmlc_tpu.utils.logging import check
+    """One call from data URI to fitted params, the learners'
+    ``fit_uri``: InputSplit part → parser → DeviceFeed over
+    ``learner.mesh`` → ``learner.fit_feed``. The part defaults to this
+    worker's collective rank/world (each worker reads its own byte range
+    — the reference's ``InputSplit::Create(uri, rank, world)`` contract),
+    so the same line works single-process, on a mesh, or under
+    dmlc-submit with the socket engine. ``num_features`` defaults to the
+    learner's hyper-parameter of that name.
 
+    ``snapshot_uri`` arms preemption-proof job snapshots: every
+    ``snap_every_epochs`` epoch boundary (plus the
+    ``DMLC_TPU_SNAP_EVERY_S`` wall-clock trigger) commits model +
+    optimizer + read-plan + audit state through the async
+    two-phase-commit writer, and a SIGTERM mid-epoch finalizes a
+    just-in-time snapshot and exits with the relaunch code.
+    ``resume=True`` loads the newest committed snapshot first: the model
+    restores (``learner.restore_snapshot_model``), the shuffle re-derives
+    the interrupted epoch permutation, the audit chains re-arm, and
+    training continues at the next epoch — bit-identical to a run that
+    was never killed (see docs/robustness.md "Preemption & resume")."""
+    num_features = num_features or learner.param.num_features
     check(num_features > 0, "fit_uri requires num_features")
     if part_index is None:
         part_index = collective.rank()
@@ -204,8 +462,6 @@ def fit_uri(learner, uri: str, *, batch_size: int = 4096,
     if snapshot_uri is None:
         check(not resume, "resume=True requires snapshot_uri")
         return learner.fit_feed(feed, epochs=epochs, log_every=log_every)
-    from dmlc_tpu.collective import JobSnapshot, Snapshotter, load_snapshot
-
     snap = JobSnapshot(snapshot_uri, rank=collective.rank(),
                        world_size=collective.world_size())
     start_epoch = 0

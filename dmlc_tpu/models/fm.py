@@ -21,7 +21,6 @@ score(x) = b + Σ_i w_i x_i + ½ Σ_k [(Σ_i v_ik x_i)² − Σ_i v_ik² x_i²]
 
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import Dict, Optional
 
@@ -32,11 +31,9 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dmlc_tpu.collective.device import all_gather, bucketed_psum, psum
-from dmlc_tpu.models.linear import (
-    _margin_grad,
-    _suppress_donation_warnings,
-    step_batch,
-)
+from dmlc_tpu.models import fitloop
+from dmlc_tpu.models.fitloop import FeedLearner, suppress_donation_warnings
+from dmlc_tpu.models.linear import margin_grad
 from dmlc_tpu.obs.device_telemetry import instrumented_jit
 from dmlc_tpu.ops.spmv import expand_row_ids
 from dmlc_tpu.parallel.partition import (
@@ -164,7 +161,7 @@ def _fm_entry_grads(params, batch, objective: str,
             interaction = psum(interaction, factor_axis)
     with jax.named_scope("step.forward"):
         margin = params["b"] + linear + interaction
-        loss, gmargin = _margin_grad(objective, margin, label)
+        loss, gmargin = margin_grad(objective, margin, label)
         loss_sum = jnp.sum(weight * loss)
     with jax.named_scope("step.backward"):
         wg = weight * gmargin  # [B]
@@ -362,7 +359,7 @@ def make_fm_train_step(
             step, "fm.step",
             donate_argnums=(0, 1) if donate_batch else (),
         )
-        return _suppress_donation_warnings(fn) if donate_batch else fn
+        return suppress_donation_warnings(fn) if donate_batch else fn
 
     # Entries arrive SHARDED (ShardedCSRBatch: per-shard sections, local
     # row ids) — each device holds only its own nnz; no global mask.
@@ -431,15 +428,21 @@ def make_fm_train_step(
     return instrumented_jit(step, "fm.step", donate_argnums=(0,))
 
 
-class FMLearner:
-    """uri → fitted FM params over a DeviceFeed (csr layout).
+class FMLearner(FeedLearner):
+    """uri → fitted FM params over a DeviceFeed (csr layout); the fit loop
+    is :func:`dmlc_tpu.models.fitloop.fit_feed`.
 
     On a mesh ``table_sharding`` (an :class:`FMParam` field) says how the
     factor table is held: ``"replicated"`` (default; a whole copy on every
     chip, the dense gradient psummed) or ``"factors"`` (each chip
     ``num_factors / chips`` columns of ``v``, for a table wider than one
-    chip's memory; see :func:`make_fm_train_step`)."""
+    chip's memory; see :func:`make_fm_train_step`). ``reshard`` passes
+    the params through one host copy (a factor-sharded table whole: 28 GB
+    at 54.7 M ids x 128), and every chip that held a column slice must
+    still answer: no other chip has those columns, so after losing one
+    the way back is the last snapshot."""
 
+    name = "fm"
     #: the mesh axis the batch (and a sharded table) divides over, the
     #: DeviceFeed's default
     axis = "dp"
@@ -447,26 +450,13 @@ class FMLearner:
     def __init__(self, mesh: Optional[Mesh] = None, **hyper):
         self.param = FMParam()
         self.param.init(hyper)
-        self.mesh = mesh
-        self.params = None
-        self._step = None
         self._nf = None
-        self._unlisten = None
-        if mesh is not None:
-            import weakref
-
-            from dmlc_tpu import collective
-
-            if self.param.table_sharding == "factors":
-                _check_factor_shards(self.param.num_factors, mesh, self.axis)
-            ref = weakref.ref(self)
-
-            def _membership_cb():
-                learner = ref()
-                if learner is not None and learner.params is not None:
-                    learner.reshard()
-
-            self._unlisten = collective.on_membership_change(_membership_cb)
+        # a sharded table's steps since the last epoch boundary, and the
+        # bytes one of them exchanges, both by nnz bucket (the shapes the
+        # step was compiled for fix the bytes)
+        self._steps_of: Dict[int, int] = {}
+        self._bytes_of: Dict[int, int] = {}
+        super().__init__(mesh)
 
     @property
     def table_shards(self) -> int:
@@ -476,8 +466,12 @@ class FMLearner:
             return 1
         return int(self.mesh.shape[self.axis])
 
-    def _rules(self):
+    def partition_rules(self):
         return fm_partition_rules(self.param.table_sharding)
+
+    def check_mesh(self, mesh: Mesh) -> None:
+        if self.param.table_sharding == "factors":
+            _check_factor_shards(self.param.num_factors, mesh, self.axis)
 
     def param_shardings(self):
         """NamedSharding tree of the params on this learner's mesh (None
@@ -489,7 +483,8 @@ class FMLearner:
         template = jax.eval_shape(
             lambda: init_fm_params(2, self.param.num_factors))
         return sharding_tree(
-            self.mesh, match_partition_rules(self._rules(), template))
+            self.mesh,
+            match_partition_rules(self.partition_rules(), template))
 
     def _ensure(self, num_features: int):
         if self.params is None:
@@ -516,150 +511,73 @@ class FMLearner:
                 table_sharding=self.param.table_sharding,
             )
 
-    def reshard(self, mesh: Optional[Mesh] = None) -> None:
-        """Elastic re-entry hook (see LinearLearner.reshard): re-place the
-        factor table + linear weights on a mesh rebuilt over the current
-        device set, by this learner's rules, and drop the traced step.
-        The params pass through one host copy (a factor-sharded table
-        whole: 28 GB at 54.7 M ids x 128), and every chip that held a
-        column slice must still answer: no other chip has those columns,
-        so after losing one the way back is the last snapshot."""
-        if self.mesh is None or self.params is None:
-            return
-        if mesh is None:
-            check(
-                len(self.mesh.axis_names) == 1,
-                "pass mesh= to reshard a multi-axis mesh",
-            )
-            mesh = Mesh(np.asarray(jax.devices()), self.mesh.axis_names)
-        if self.param.table_sharding == "factors":
-            _check_factor_shards(self.param.num_factors, mesh, self.axis)
-        self.mesh = mesh
-        self.params = shard_params(
-            jax.device_get(self.params), mesh, rules=self._rules()
-        )
-        self._step = None
+    def ensure_step(self, spec) -> None:
+        check(spec.layout == "csr", "FM consumes csr batches")
+        self._ensure(self.param.num_features)
 
-    def fit_uri(
-        self,
-        uri: str,
-        batch_size: int = 4096,
-        epochs: int = 1,
-        num_features: int = 0,
-        **kw,
-    ):
-        """One call from data URI to fitted params, as
-        :meth:`LinearLearner.fit_uri` (same arguments, layout csr):
-        InputSplit part → parser → DeviceFeed → :meth:`fit_feed`, with
-        ``snapshot_uri`` / ``resume`` arming job snapshots."""
-        from dmlc_tpu.models.fitloop import fit_uri
-
-        return fit_uri(
-            self, uri, batch_size=batch_size,
-            epochs=epochs, layout="csr",
-            num_features=num_features or self.param.num_features, **kw)
-
-    def fit_feed(self, feed, epochs: int = 1, log_every: int = 0,
-                 snapshotter=None, start_epoch: int = 0, history=None):
-        """Train over a csr DeviceFeed; ``log_every`` (epochs) also logs
-        the feed's per-stage stall breakdown (device.feed.stall_breakdown).
-
-        ``snapshotter``/``start_epoch``/``history`` follow the same
-        preemption-proof contract as LinearLearner.fit_feed: epoch
-        boundaries hand a state tree to the async snapshot writer, a
-        preemption notice finalizes a just-in-time commit and raises
-        ``Preempted`` (see docs/robustness.md "Preemption & resume")."""
-        from dmlc_tpu.models.linear import EpochMetrics
-
-        check(feed.spec.layout == "csr", "FM consumes csr batches")
-        # see LinearLearner.fit_feed: mesh steps need the sharded layout
-        check(
-            getattr(feed, "_mesh", None) is self.mesh,
-            "feed mesh and learner mesh must match (csr entry layouts "
-            "differ between mesh and single-device runs)",
-        )
-        from dmlc_tpu import obs
-        from dmlc_tpu.models.fitloop import FitLoopObs
-        from dmlc_tpu.resilience import Preempted, preempt
-
-        fl = FitLoopObs("fm")
-        history = list(history) if history else []
+    def train_step(self, arrays: Dict) -> Dict:
         shards = self.table_shards
-        # a sharded table's steps: bytes a step exchanges, by nnz bucket
-        # (the shapes the step was compiled for fix them), worked out once
-        bytes_of: Dict[int, int] = {}
-        for epoch in range(start_epoch, epochs):
-            acc = EpochMetrics()
-            nstep = 0
-            steps_of: Dict[int, int] = {}
-            preempted = False
-            t0 = time.monotonic_ns()
-            with obs.span("epoch", model="fm", epoch=epoch,
-                          table_shards=shards):
-                for batch in feed:
-                    self._ensure(self.param.num_features)
-                    with obs.span("train_step", model="fm", step=nstep,
-                                  **obs.current_batch()):
-                        obs.flow_step(obs.current_flow(), "chunk")
-                        arrays = step_batch(batch, "csr")
-                        if shards > 1:
-                            bucket = arrays["indices"].shape[0]
-                            steps_of[bucket] = steps_of.get(bucket, 0) + 1
-                            if bucket not in bytes_of:
-                                bytes_of[bucket] = exchange_bytes(
-                                    arrays, shards)
-                        self.params, metrics = self._step(
-                            self.params, arrays)
-                    acc.add(metrics)
-                    fl.note_step()
-                    nstep += 1
-                    if snapshotter is not None and preempt.poll():
-                        preempted = True
-                        break
-            if preempted:
-                snapshotter.finalize()
-                raise Preempted(
-                    "preempted in epoch %d after %d steps" % (epoch, nstep))
-            # the step was built for self.mesh and the table's sharding
-            # (_ensure): one device and a factor-sharded mesh scatter-add
-            # every step, a mesh of replicas none
-            sparse = self.mesh is None or shards > 1
-            fl.finish_epoch(
-                epoch, nstep, t0, acc, history, feed=feed,
-                log_every=log_every, params=self.params,
-                snapshotter=snapshotter,
-                snap_state=(None if snapshotter is None else
-                            lambda e=epoch: self._snapshot_state(
-                                feed, e, history)),
-                sparse_update_steps=nstep if sparse else 0,
-                sharded_table_steps=nstep if shards > 1 else 0,
-                exchange_bytes=sum(
-                    n * bytes_of[b] for b, n in steps_of.items()),
-            )
-            if epoch + 1 < epochs:
-                feed.before_first()
-        return history
+        if shards > 1:
+            bucket = arrays["indices"].shape[0]
+            self._steps_of[bucket] = self._steps_of.get(bucket, 0) + 1
+            if bucket not in self._bytes_of:
+                self._bytes_of[bucket] = exchange_bytes(arrays, shards)
+        self.params, metrics = self._step(self.params, arrays)
+        return metrics
 
-    def _snapshot_state(self, feed, epoch: int, history) -> Dict:
-        """Job-snapshot state tree at one epoch boundary (see
-        LinearLearner._snapshot_state — FM has no velocity term). The
-        params go in as the device arrays they are; a table sharded over
+    def epoch_span_args(self) -> Dict:
+        return {"table_shards": self.table_shards}
+
+    def epoch_closed(self, reg, nstep: int) -> None:
+        """FM's own counters. The step was built for ``self.mesh`` and the
+        table's sharding (``_ensure``): one device and a factor-sharded
+        mesh scatter-add every step, a mesh of replicas none.
+        ``dmlc_fit_sparse_update_steps_total`` over
+        ``dmlc_fit_steps_total`` is the share of steps that took that
+        path; the exchanged bytes are from the shapes (the gradient psum
+        of a replicated model is not among them,
+        ``dmlc_xla_collective_bytes`` has it)."""
+        shards = self.table_shards
+        sparse = self.mesh is None or shards > 1
+        reg.counter(
+            "dmlc_fit_sparse_update_steps_total",
+            "optimizer steps that scatter-added into the touched "
+            "rows instead of applying a dense gradient",
+            model=self.name).inc(nstep if sparse else 0)
+        reg.counter(
+            "dmlc_fit_sharded_table_steps_total",
+            "optimizer steps over a parameter table sharded over "
+            "the mesh's chips (no chip holds the whole table)",
+            model=self.name).inc(nstep if shards > 1 else 0)
+        reg.counter(
+            "dmlc_fit_exchange_bytes_total",
+            "bytes one chip contributed to the collectives of "
+            "sharded-table steps (batch gather + interaction psum)",
+            model=self.name).inc(
+                sum(n * self._bytes_of[b]
+                    for b, n in self._steps_of.items()))
+        self._steps_of.clear()
+
+    def fit_uri(self, uri: str, **kw):
+        """:func:`dmlc_tpu.models.fitloop.fit_uri` for this learner
+        (layout csr; the arguments and the snapshot / resume contract are
+        listed there)."""
+        return fitloop.fit_uri(self, uri, layout="csr", **kw)
+
+    def fit_feed(self, feed, *args, **kw):
+        """Train over a csr DeviceFeed; returns per-epoch losses:
+        :func:`dmlc_tpu.models.fitloop.fit_feed` for this learner."""
+        # bookkeeping of a pass a preemption cut short, or of other shapes
+        self._steps_of.clear()
+        self._bytes_of.clear()
+        return fitloop.fit_feed(self, feed, *args, **kw)
+
+    def snapshot_model(self) -> Dict:
+        """The params as the device arrays they are; a table sharded over
         chips reaches the host as the ONE logical ``[F, K]`` array
         (``collective.checkpoint._to_host`` assembles it shard by shard),
         so a snapshot restores under any placement."""
-        from dmlc_tpu.obs import audit
-
-        state = {
-            "model": {"params": dict(self.params)},
-            "epoch": int(epoch),
-            "history": [float(x) for x in history],
-            "rng": None,
-            "audit": audit.auditor().export_state(),
-        }
-        parser = getattr(feed, "_parser", None)
-        if hasattr(parser, "snapshot_state"):
-            state["data"] = {"parser": parser.snapshot_state()}
-        return state
+        return {"params": dict(self.params)}
 
     def restore_snapshot_model(self, model: Dict) -> None:
         """Re-place a snapshot's host FM params on device: straight from
@@ -676,7 +594,8 @@ class FMLearner:
         if self.mesh is None:
             self.params = {k: jnp.asarray(v) for k, v in params.items()}
         else:
-            self.params = shard_params(params, self.mesh, rules=self._rules())
+            self.params = shard_params(
+                params, self.mesh, rules=self.partition_rules())
 
     def predict_batch(self, batch) -> np.ndarray:
         num_rows = int(batch["label"].shape[0])

@@ -17,7 +17,6 @@ downstream (rabit-based) consumers run, built TPU-first:
 
 from __future__ import annotations
 
-import time
 from typing import Dict, Optional
 
 import jax
@@ -27,6 +26,8 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dmlc_tpu.collective.device import bucketed_psum
+from dmlc_tpu.models import fitloop
+from dmlc_tpu.models.fitloop import FeedLearner, suppress_donation_warnings
 from dmlc_tpu.obs.device_telemetry import instrumented_jit
 from dmlc_tpu.ops.objectives import margin_loss_grad
 from dmlc_tpu.ops.spmv import expand_row_ids, spmv, spmv_transpose
@@ -47,17 +48,6 @@ class LinearModelParam(Parameter):
     l2 = field(float, 0.0, lower_bound=0.0, description="L2 penalty on w.")
     momentum = field(float, 0.0, lower_bound=0.0, upper_bound=1.0)
     num_features = field(int, 0, description="Feature dim (0 = infer).")
-
-
-_DENSE_KEYS = ("x", "label", "weight")
-_CSR_KEYS = ("label", "weight", "indices", "values", "offsets")
-
-
-def step_batch(batch: Dict, layout: str) -> Dict:
-    """Strip DeviceFeed metadata (num_rows/num_nonzero ints) down to the
-    array fields a jitted train step consumes."""
-    keys = _DENSE_KEYS if layout == "dense" else _CSR_KEYS
-    return {k: batch[k] for k in keys}
 
 
 def init_linear_params(num_features: int, dtype=jnp.float32) -> Dict:
@@ -85,54 +75,13 @@ def linear_predict_dense(params: Dict, x):
     return x @ params["w"] + params["b"]
 
 
-def _margin_grad(objective: str, margin, label):
+def margin_grad(objective: str, margin, label):
     """Per-row (loss, dloss/dmargin) — shared with the Pallas fused kernel
     (ops/objectives.py holds the single definition)."""
     try:
         return margin_loss_grad(objective, margin, label)
     except ValueError as err:
         raise DMLCError(str(err)) from err
-
-
-def _suppress_donation_warnings(step):
-    """Batch leaves ([B,F] x, per-entry arrays) can never alias a donating
-    step's outputs (w [F], scalars), so XLA warns "donated buffers were
-    not usable" per compiled shape — the donation is still worth it for
-    the early buffer release. The suppression is scoped to THIS step's
-    call sites via catch_warnings, not installed process-globally: a
-    user's own jitted function emitting the same message may be flagging
-    a real missed donation, and this package must not eat that signal.
-
-    The warnings fire only at trace/compile time (once per argument-shape
-    signature), so the suppression engages only on calls with an unseen
-    signature: steady-state steps call straight through — no per-step
-    catch_warnings, whose filter-version bump would invalidate every
-    module's __warningregistry__ and make unrelated once-per-location
-    warnings re-fire each iteration. (catch_warnings swaps the global
-    filter list for the compile call's duration; the swap is not atomic
-    across threads — the stdlib limitation — but the window is one
-    compile, not every step.)"""
-    import functools
-    import warnings
-
-    seen = set()
-
-    @functools.wraps(step)
-    def wrapped(*args, **kwargs):
-        key = tuple(
-            (getattr(x, "shape", None), str(getattr(x, "dtype", type(x))))
-            for x in jax.tree_util.tree_leaves((args, kwargs))
-        )
-        if key in seen:
-            return step(*args, **kwargs)
-        seen.add(key)
-        with warnings.catch_warnings():
-            for msg in ("Some donated buffers were not usable",
-                        "Donation is not implemented"):
-                warnings.filterwarnings("ignore", message=msg)
-            return step(*args, **kwargs)
-
-    return wrapped
 
 
 def _resolve_pallas(use_pallas: Optional[bool], layout: str,
@@ -217,7 +166,7 @@ def _build_local_grads(objective: str, layout: str, num_features: int,
                     gb.astype(params["b"].dtype), loss_sum, wsum)
         with jax.named_scope("step.forward"):
             margin, row_ids = _forward(params, batch)
-            loss, gmargin = _margin_grad(objective, margin, label)
+            loss, gmargin = margin_grad(objective, margin, label)
             loss_sum = jnp.sum(weight * loss)
         with jax.named_scope("step.backward"):
             wg = weight * gmargin
@@ -337,7 +286,7 @@ def make_linear_train_step(
             step, "linear.step",
             donate_argnums=(0, 1, 2) if donate_batch else (),
         )
-        return _suppress_donation_warnings(fn) if donate_batch else fn
+        return suppress_donation_warnings(fn) if donate_batch else fn
 
     # Mesh path: one shard_map; batch rows sharded, params replicated. The
     # csr layout ships SHARDED entries (ShardedCSRBatch: per-shard entry
@@ -391,7 +340,7 @@ def make_linear_train_step(
         donate_argnums=(0, 1, 2) if donate_batch else (0, 1),
     )
     if donate_batch:
-        fn = _suppress_donation_warnings(fn)
+        fn = suppress_donation_warnings(fn)
 
     def placed_step(params, velocity, batch):
         # The step returns mesh-placed params; an unplaced tree coming in
@@ -510,7 +459,7 @@ def make_feature_sharded_train_step(
         # local shapes: x [B/dp, F/mp], w [F/mp]
         partial_margin = batch_x @ params["w"]
         margin = jax.lax.psum(partial_margin, mp) + params["b"]
-        loss, dmargin = _margin_grad(objective, margin, batch_y)
+        loss, dmargin = margin_grad(objective, margin, batch_y)
         wg = batch_w * dmargin
         # margin is mp-invariant, so wg is too: gw needs only the dp-psum
         gw = jax.lax.psum(batch_x.T @ wg, dp)
@@ -544,35 +493,7 @@ def make_feature_sharded_train_step(
     return step, in_shardings
 
 
-class EpochMetrics:
-    """Collect per-step device metric scalars with no per-step dispatch or
-    host sync; reading does one batched device_get. Shared by the learners'
-    fit loops (a per-step ``float()`` stalls the feed's batch-in-flight
-    overlap; a per-step device add pays dispatch overhead per step)."""
-
-    def __init__(self):
-        self._loss = []
-        self._weight = []
-        self._loss_total = 0.0
-        self._weight_total = 0.0
-
-    def add(self, metrics: Dict) -> None:
-        self._loss.append(metrics["loss_sum"])
-        self._weight.append(metrics["weight_sum"])
-
-    def mean_loss(self) -> float:
-        if self._loss:
-            # drain pending scalars into the running totals so repeated
-            # reads (log_every) never re-fetch what was already summed
-            loss, weight = jax.device_get((self._loss, self._weight))
-            self._loss_total += float(np.sum(loss))
-            self._weight_total += float(np.sum(weight))
-            self._loss.clear()
-            self._weight.clear()
-        return self._loss_total / max(self._weight_total, 1e-12)
-
-
-class LinearLearner:
+class LinearLearner(FeedLearner):
     """Convenience trainer: uri → fitted params (the rabit-SGD loop).
 
     ``sync`` picks the gradient-reduction flavor:
@@ -585,37 +506,27 @@ class LinearLearner:
       the cross-host fallback when the socket engine spans processes no
       single Mesh can.
 
-    A mesh learner registers a ``collective.on_membership_change``
-    listener: elastic re-entry / recovery re-places its params on a mesh
-    rebuilt over the surviving devices (:meth:`reshard`).
+    The fit loop is :func:`dmlc_tpu.models.fitloop.fit_feed`; a mesh
+    learner re-places params and velocity when the mesh's membership
+    changes (:meth:`~dmlc_tpu.models.fitloop.FeedLearner.reshard`).
     """
+
+    name = "linear"
+    state_trees = ("params", "velocity")
 
     def __init__(self, mesh: Optional[Mesh] = None, sync: str = "spmd",
                  **hyper):
         check(sync in ("spmd", "host"), "sync must be spmd or host")
         self.param = LinearModelParam()
         self.param.init(hyper)
-        self.mesh = mesh
         self.sync = sync
-        self.params = None
         self.velocity = None
-        self._step = None
         self._layout = None
         self._nf = None
-        self._unlisten = None
-        if mesh is not None:
-            import weakref
+        super().__init__(mesh)
 
-            from dmlc_tpu import collective
-
-            ref = weakref.ref(self)
-
-            def _membership_cb():
-                learner = ref()
-                if learner is not None and learner.params is not None:
-                    learner.reshard()
-
-            self._unlisten = collective.on_membership_change(_membership_cb)
+    def partition_rules(self):
+        return LINEAR_PARTITION_RULES
 
     def _ensure(self, num_features: int, layout: str):
         if self.params is None:
@@ -663,74 +574,27 @@ class LinearLearner:
                     donate_batch=True,  # fit_feed consumes batches once
                 )
 
-    def reshard(self, mesh: Optional[Mesh] = None) -> None:
-        """Re-place params/velocity on ``mesh`` (default: a fresh mesh
-        over the CURRENT device set, same axis names) and drop the traced
-        step — the elastic re-entry hook. Leaves round-trip through host
-        copies because the old placement may reference devices that no
-        longer exist."""
-        if self.mesh is None or self.params is None:
-            return
-        if mesh is None:
-            check(
-                len(self.mesh.axis_names) == 1,
-                "pass mesh= to reshard a multi-axis mesh",
-            )
-            mesh = Mesh(np.asarray(jax.devices()), self.mesh.axis_names)
-        self.mesh = mesh
-        self.params = shard_params(
-            jax.device_get(self.params), mesh, rules=LINEAR_PARTITION_RULES
-        )
-        if self.velocity is not None:
-            self.velocity = shard_params(
-                jax.device_get(self.velocity), mesh,
-                rules=LINEAR_PARTITION_RULES,
-            )
-        self._step = None  # retrace against the new mesh on next batch
+    def ensure_step(self, spec) -> None:
+        self._ensure(spec.num_features, spec.layout)
 
-    def fit_uri(
-        self,
-        uri: str,
-        batch_size: int = 4096,
-        epochs: int = 1,
-        layout: str = "dense",
-        num_features: int = 0,
-        part_index: Optional[int] = None,
-        num_parts: Optional[int] = None,
-        drop_remainder: bool = False,
-        log_every: int = 0,
-        snapshot_uri: Optional[str] = None,
-        resume: bool = False,
-        snap_every_epochs: int = 1,
-    ):
-        """One call from data URI to fitted params: InputSplit part →
-        parser → DeviceFeed → fit_feed. The part defaults to this
-        worker's collective rank/world (each worker reads its own byte
-        range — the reference's ``InputSplit::Create(uri, rank, world)``
-        contract), so the same line works single-process, on a mesh, or
-        under dmlc-submit with the socket engine.
+    def train_step(self, arrays: Dict) -> Dict:
+        self.params, self.velocity, metrics = self._step(
+            self.params, self.velocity, arrays)
+        return metrics
 
-        ``snapshot_uri`` arms preemption-proof job snapshots: every
-        ``snap_every_epochs`` epoch boundary (plus the
-        ``DMLC_TPU_SNAP_EVERY_S`` wall-clock trigger) commits model +
-        optimizer + read-plan + audit state through the async
-        two-phase-commit writer, and a SIGTERM mid-epoch finalizes a
-        just-in-time snapshot and exits with the relaunch code.
-        ``resume=True`` loads the newest committed snapshot first: the
-        model restores, the shuffle re-derives the interrupted epoch
-        permutation, the audit chains re-arm, and training continues at
-        the next epoch — bit-identical to a run that was never killed
-        (see docs/robustness.md "Preemption & resume")."""
-        from dmlc_tpu.models.fitloop import fit_uri
+    def fit_uri(self, uri: str, **kw):
+        """:func:`dmlc_tpu.models.fitloop.fit_uri` for this learner (its
+        arguments and the snapshot / resume contract are listed there)."""
+        return fitloop.fit_uri(self, uri, **kw)
 
-        return fit_uri(
-            self, uri, batch_size=batch_size,
-            epochs=epochs, layout=layout,
-            num_features=num_features or self.param.num_features,
-            part_index=part_index, num_parts=num_parts,
-            drop_remainder=drop_remainder, log_every=log_every,
-            snapshot_uri=snapshot_uri, resume=resume,
-            snap_every_epochs=snap_every_epochs)
+    def fit_feed(self, feed, *args, **kw):
+        """Train over a DeviceFeed for N epochs; returns per-epoch losses:
+        :func:`dmlc_tpu.models.fitloop.fit_feed` for this learner."""
+        return fitloop.fit_feed(self, feed, *args, **kw)
+
+    def snapshot_model(self) -> Dict:
+        return {"params": dict(self.params),
+                "velocity": dict(self.velocity or {})}
 
     def restore_snapshot_model(self, model: Dict) -> None:
         """Re-place a snapshot's host model/optimizer state on device
@@ -745,105 +609,6 @@ class LinearLearner:
             if self.velocity is not None:
                 self.velocity = shard_params(
                     self.velocity, self.mesh, rules=LINEAR_PARTITION_RULES)
-
-    def fit_feed(self, feed, epochs: int = 1, log_every: int = 0,
-                 snapshotter=None, start_epoch: int = 0, history=None):
-        """Train over a DeviceFeed for N epochs; returns per-epoch losses.
-
-        With ``snapshotter`` armed the loop polls for preemption notices
-        between steps (SIGTERM via resilience/preempt.py, or the
-        injectable ``preempt.notice`` faultpoint): a notice stops the
-        partial epoch, finalizes the freshest epoch-boundary snapshot
-        within the grace window, and raises
-        :class:`~dmlc_tpu.resilience.Preempted` so the process exits
-        with the launcher's relaunch code. ``start_epoch``/``history``
-        continue a resumed run (the returned history covers ALL epochs,
-        restored ones included)."""
-        from dmlc_tpu.utils.logging import log_info
-
-        layout = feed.spec.layout
-        # mesh csr steps consume the SHARDED entry layout (local row ids);
-        # a feed built without the mesh would deliver replicated entries
-        # whose global row ids silently corrupt every shard's segment-sum
-        check(
-            getattr(feed, "_mesh", None) is self.mesh,
-            "feed mesh and learner mesh must match (csr entry layouts "
-            "differ between mesh and single-device runs)",
-        )
-        from dmlc_tpu import obs
-        from dmlc_tpu.models.fitloop import FitLoopObs
-        from dmlc_tpu.resilience import Preempted, preempt
-
-        fl = FitLoopObs("linear")
-        history = list(history) if history else []
-        for epoch in range(start_epoch, epochs):
-            acc = EpochMetrics()
-            nstep = 0
-            preempted = False
-            t0 = time.monotonic_ns()
-            with obs.span("epoch", model="linear", epoch=epoch):
-                for batch in feed:
-                    self._ensure(feed.spec.num_features, layout)
-                    # train_step closes the chunk's arrow chain: the feed
-                    # set the thread's current flow around this yield
-                    with obs.span("train_step", model="linear", step=nstep,
-                                  **obs.current_batch()):
-                        obs.flow_step(obs.current_flow(), "chunk")
-                        self.params, self.velocity, metrics = self._step(
-                            self.params, self.velocity,
-                            step_batch(batch, layout)
-                        )
-                    acc.add(metrics)
-                    fl.note_step()
-                    nstep += 1
-                    if log_every and nstep % log_every == 0:
-                        log_info(
-                            "epoch %d step %d loss %.6f",
-                            epoch, nstep, acc.mean_loss(),
-                        )
-                    if snapshotter is not None and preempt.poll():
-                        preempted = True
-                        break
-            if preempted:
-                # a partial epoch is never snapshotted (resume replays it
-                # in full — that is what keeps the relaunch bit-identical);
-                # commit the freshest epoch-boundary capture and exit with
-                # the relaunch code
-                snapshotter.finalize()
-                raise Preempted(
-                    "preempted in epoch %d after %d steps; last committed "
-                    "snapshot epoch %d"
-                    % (epoch, nstep, snapshotter.committed_epoch))
-            fl.finish_epoch(
-                epoch, nstep, t0, acc, history, feed=feed,
-                log_every=log_every, params=self.params,
-                snapshotter=snapshotter,
-                snap_state=(None if snapshotter is None else
-                            lambda e=epoch: self._snapshot_state(
-                                feed, e, history)),
-            )
-            if epoch + 1 < epochs:
-                feed.before_first()
-        return history
-
-    def _snapshot_state(self, feed, epoch: int, history) -> Dict:
-        """The job-snapshot state tree at one epoch boundary (built on
-        the training thread; the snapshotter host-copies it before the
-        next epoch's donating steps run)."""
-        from dmlc_tpu.obs import audit
-
-        state = {
-            "model": {"params": dict(self.params),
-                      "velocity": dict(self.velocity or {})},
-            "epoch": int(epoch),
-            "history": [float(x) for x in history],
-            "rng": None,  # SGD path draws no step-time randomness
-            "audit": audit.auditor().export_state(),
-        }
-        parser = getattr(feed, "_parser", None)
-        if hasattr(parser, "snapshot_state"):
-            state["data"] = {"parser": parser.snapshot_state()}
-        return state
 
     def predict(self, x: np.ndarray) -> np.ndarray:
         check(self.params is not None, "model not fitted")

@@ -11,9 +11,6 @@ code changes:
 - ``DMLC_TPU_HOST_PREFETCH`` — parsed-but-undispatched host batches the
   feed's producer thread may buffer (-1 = auto: 0 on a 1-core host,
   else 2 — ``DeviceFeed.host_prefetch``'s own default)
-- ``DMLC_TPU_DEVICE_RESIDENT`` — the device-resident fast path: parsed
-  RowBlocks emit straight into pooled staging (pad-in-place, one copy),
-  one ``device_put`` per batch, donated landing buffers (default off)
 
 Every call site that previously hard-coded a width resolves through
 these helpers, so one env var retunes the whole stack (create_parser,
@@ -479,18 +476,6 @@ def data_hedge_s() -> float:
     return max(0.0, float(get_env("DMLC_TPU_DATA_HEDGE_S", 0.0)))
 
 
-def device_resident() -> bool:
-    """Whether ``DeviceFeed`` uses the device-resident fast path
-    (``DMLC_TPU_DEVICE_RESIDENT``, default off): parsed columnar
-    RowBlocks are emitted straight into pooled staging (one copy,
-    pad-in-place), the whole batch crosses H2D as one ``device_put``,
-    and the jitted steps donate the landing buffers back to XLA. The
-    legacy materialize+pad path stays the default and the fallback
-    (non-CSR layouts, exotic parsers). Read once per feed, at
-    construction."""
-    return get_env("DMLC_TPU_DEVICE_RESIDENT", False)
-
-
 def device_telemetry_enabled() -> bool:
     """Whether the device telemetry layer is live
     (``DMLC_TPU_DEVICE_TELEMETRY``, default on). Read once where each
@@ -656,7 +641,6 @@ KNOWN_KNOBS = (
     "DMLC_TPU_READAHEAD_MB",
     "DMLC_TPU_READAHEAD_CONNS",
     "DMLC_TPU_FEED_PUT",
-    "DMLC_TPU_DEVICE_RESIDENT",
     # vectorized parse path
     "DMLC_TPU_PARSE_BACKEND",
     "DMLC_TPU_PARSE_PROCS",

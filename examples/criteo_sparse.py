@@ -79,11 +79,10 @@ def main() -> int:
 
     from dmlc_tpu.data import create_parser
     from dmlc_tpu.device import BatchSpec, DeviceFeed
+    from dmlc_tpu.models.fitloop import EpochMetrics, step_batch
     from dmlc_tpu.models.linear import (
-        EpochMetrics,
         init_linear_params,
         make_linear_train_step,
-        step_batch,
     )
 
     uri = args.uri

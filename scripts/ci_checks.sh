@@ -197,63 +197,6 @@ else:
     print("ci_checks: parse-parity smoke OK (scalar == vector; no native)")
 EOF
 
-# device-resident fast-path smoke: the same short LibSVM fit run two
-# ways — the legacy python staging path and DMLC_TPU_DEVICE_RESIDENT=1
-# (pad-in-place pool emit + donated batched put) — both pinned to the
-# vector parse backend so only the staging path differs. Loss history
-# and final params must be BIT-identical, and neither arm may recompile
-# past its warmup epoch.
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" DMLC_TPU_PARSE_BACKEND=vector \
-python - <<'EOF'
-import os, sys, tempfile
-
-import numpy as np
-
-from dmlc_tpu.models import LinearLearner
-from dmlc_tpu.obs import device_telemetry as dt
-
-NF, ROWS = 12, 400
-rng = np.random.RandomState(3)
-fd, svm = tempfile.mkstemp(suffix=".svm")
-with os.fdopen(fd, "w") as fh:
-    for i in range(ROWS):
-        ids = np.sort(rng.choice(NF, size=1 + i % 4, replace=False))
-        fh.write("%d %s\n" % (i % 2, " ".join(
-            "%d:%.4f" % (j, rng.rand()) for j in ids)))
-
-def fit(resident):
-    os.environ.pop("DMLC_TPU_DEVICE_RESIDENT", None)
-    if resident:
-        os.environ["DMLC_TPU_DEVICE_RESIDENT"] = "1"
-    dt.reset()
-    learner = LinearLearner(objective="logistic", learning_rate=0.1,
-                            num_features=NF)
-    hist = list(learner.fit_uri(svm, batch_size=64, epochs=1,
-                                num_features=NF))
-    warm = dict(dt.compile_counts())
-    hist += list(learner.fit_uri(svm, batch_size=64, epochs=2,
-                                 num_features=NF))
-    if dict(dt.compile_counts()) != warm:
-        sys.exit("ci_checks: resident smoke recompiled past warmup "
-                 "(resident=%s): %r -> %r"
-                 % (resident, warm, dt.compile_counts()))
-    return {"hist": [float(h).hex() for h in hist],
-            "w": np.asarray(learner.params["w"]).tobytes().hex(),
-            "b": np.asarray(learner.params["b"]).tobytes().hex()}
-
-try:
-    legacy = fit(False)
-    resident = fit(True)
-finally:
-    os.environ.pop("DMLC_TPU_DEVICE_RESIDENT", None)
-    os.unlink(svm)
-if legacy != resident:
-    sys.exit("ci_checks: resident fast path diverged from legacy:\n"
-             "  legacy   %r\n  resident %r" % (legacy, resident))
-print("ci_checks: device-resident smoke OK "
-      "(bit-identical fit, zero post-warmup recompiles)")
-EOF
-
 # Pallas sparse-step parity: the COO segment-sum kernel (interpret mode,
 # passed explicitly) vs XLA's scatter spmv on exactly-representable f32
 # data — sums are integers, so ANY reduction order must produce

@@ -38,8 +38,8 @@ class TestDigests:
     def test_neutral_fills_make_presence_irrelevant(self):
         # a block with NO value array hashes like the same block with the
         # explicit all-ones values the reference defines as its meaning —
-        # the resident/legacy arms materialize presence differently and
-        # must still agree
+        # parser backends materialize presence differently and must
+        # still agree
         b = _block(with_value=False)
         explicit = RowBlock(
             offset=b.offset, label=b.label, index=b.index,

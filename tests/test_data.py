@@ -95,64 +95,6 @@ class TestRowBlock:
     def test_mem_cost(self):
         assert self.make_block().mem_cost_bytes() > 0
 
-    def _multi_part_container(self):
-        """Several pushed parts with mixed weight/value presence — the
-        shape the resident emit path must linearize correctly."""
-        c = RowBlockContainer()
-        c.push_row(1.0, [0, 3], [0.5, 2.0], weight=0.9)
-        c.push_row(0.0, [1], [1.5])
-        c.push_arrays(
-            np.asarray([1.0, 0.0], np.float32),
-            np.asarray([3, 1], np.int64),
-            np.asarray([0, 2, 4, 1], np.int64),
-        )  # no values/weights: neutral defaults
-        return c
-
-    def test_emit_csr_into_matches_to_block(self):
-        c = self._multi_part_container()
-        n, nnz = c.size, c.num_nonzero
-        labels = np.empty(n + 2, np.float32)
-        weights = np.empty(n + 2, np.float32)
-        indices = np.empty(nnz + 5, np.int32)
-        values = np.empty(nnz + 5, np.float32)
-        offsets = np.empty(n + 3, np.int32)
-        rows, ents = c.emit_csr_into(labels, weights, indices, values,
-                                     offsets)
-        assert (rows, ents) == (n, nnz)
-        b = c.to_block()
-        np.testing.assert_array_equal(labels[:n], b.label)
-        np.testing.assert_array_equal(offsets[: n + 1], b.offset)
-        np.testing.assert_array_equal(indices[:nnz], b.index)
-        # absent per-part value/weight arrays emit the neutral defaults
-        np.testing.assert_array_equal(values[:nnz],
-                                      [0.5, 2.0, 1.5, 1, 1, 1, 1])
-        np.testing.assert_array_equal(
-            weights[:n], np.asarray([0.9, 1.0, 1.0, 1.0], np.float32))
-
-    def test_emit_csr_into_rejects_small_staging(self):
-        c = self._multi_part_container()
-        with pytest.raises(Exception, match="staging too small"):
-            c.emit_csr_into(
-                np.empty(1, np.float32), np.empty(4, np.float32),
-                np.empty(16, np.int32), np.empty(16, np.float32),
-                np.empty(8, np.int32),
-            )
-
-    def test_emit_dense_into_matches_block_to_dense(self):
-        from dmlc_tpu.device.csr import block_to_dense
-
-        c = self._multi_part_container()
-        nfeat = 4  # below max_index: the out-of-range filter must engage
-        x = np.zeros((6, nfeat), np.float32)
-        labels = np.empty(6, np.float32)
-        weights = np.empty(6, np.float32)
-        n = c.emit_dense_into(x, labels, weights)
-        assert n == c.size
-        ex, el, ew = block_to_dense(c.to_block(), 6, nfeat)
-        np.testing.assert_array_equal(x, ex)
-        np.testing.assert_array_equal(labels[:n], el[:n])
-        np.testing.assert_array_equal(weights[:n], ew[:n])
-
 
 class TestLibSVMParser:
     def test_basic(self):

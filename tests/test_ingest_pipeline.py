@@ -173,22 +173,20 @@ class TestShutdown:
         piped.close()
 
 
-class TestDeviceFeedParity:
-    def _collect(self, feed):
-        out = []
-        for batch in feed:
-            out.append({k: np.asarray(v).tobytes()
-                        for k, v in batch.items()
-                        if not np.isscalar(v)})
-        return out
+def _collect(feed):
+    """Every array of every batch, as bytes."""
+    return [{k: np.asarray(v).tobytes() for k, v in batch.items()
+             if not np.isscalar(v)} for batch in feed]
 
+
+class TestDeviceFeedParity:
     @pytest.mark.parametrize("layout", ["dense", "csr"])
     def test_pipelined_feed_bit_identical_to_serial(self, svm_path, layout):
         spec_serial = BatchSpec(batch_size=512, layout=layout,
                                 num_features=40, prefetch=1)
         serial = DeviceFeed(_base_parser(svm_path), spec_serial,
                             host_prefetch=0)
-        want = self._collect(serial)
+        want = _collect(serial)
         serial.close()
 
         spec_pipe = BatchSpec(batch_size=512, layout=layout,
@@ -197,7 +195,7 @@ class TestDeviceFeedParity:
             PipelinedParser(_base_parser(svm_path), nthread=4),
             spec_pipe, host_prefetch=2,
         )
-        got = self._collect(piped)
+        got = _collect(piped)
         assert got == want
         stats = piped.stats()
         assert stats["pipeline"]["chunks"] > 1
@@ -206,80 +204,28 @@ class TestDeviceFeedParity:
         piped.close()
 
 
-class TestDeviceResident:
-    """DMLC_TPU_DEVICE_RESIDENT=1: the pad-in-place producer
-    (RowBlockContainer.emit_* → FixedShapePool staging) must be
-    indistinguishable from the legacy materialize+pad path except in
-    copy count."""
-
-    def _collect(self, feed):
-        out = []
-        for batch in feed:
-            out.append({k: np.asarray(v).tobytes()
-                        for k, v in batch.items()
-                        if not np.isscalar(v)})
-        return out
+class TestPythonProducerPuts:
+    """The Python re-batch producer (the parsers with no native batch
+    fetch): batches do not depend on where the parser cut its chunks, and
+    a batch crosses to the device in one put."""
 
     @pytest.mark.parametrize("layout", ["dense", "csr"])
-    def test_resident_bit_identical_to_legacy(self, svm_path, monkeypatch,
-                                              layout):
-        spec = BatchSpec(batch_size=512, layout=layout, num_features=40,
-                         prefetch=1)
-        monkeypatch.delenv("DMLC_TPU_DEVICE_RESIDENT", raising=False)
-        legacy = DeviceFeed(_base_parser(svm_path), spec, host_prefetch=0)
-        assert not legacy._resident
-        want = self._collect(legacy)
-        legacy.close()
-
-        monkeypatch.setenv("DMLC_TPU_DEVICE_RESIDENT", "1")
-        resident = DeviceFeed(_base_parser(svm_path), spec, host_prefetch=0)
-        assert resident._resident
-        got = self._collect(resident)
-        assert got == want  # every array of every batch, byte-exact
-        resident.close()
-
-    def test_resident_one_trace_per_shape_bucket(self, svm_path,
-                                                 monkeypatch):
-        monkeypatch.setenv("DMLC_TPU_DEVICE_RESIDENT", "1")
-        spec = BatchSpec(batch_size=512, layout="csr", num_features=40)
-        feed = DeviceFeed(
-            PipelinedParser(_base_parser(svm_path), nthread=2),
-            spec, host_prefetch=2,
-        )
-        step = jax.jit(
-            lambda b: (b["values"].sum(), b["label"].sum())
-        )
-        shapes_seen = set()
-        nrows = 0
-        for batch in feed:
-            step(batch)
-            nrows += int(batch["num_rows"])
-            shapes_seen.add(tuple(
-                (k, np.shape(v)) for k, v in sorted(batch.items())
-                if not np.isscalar(v)
-            ))
-        assert nrows == ROWS  # row accounting survives the emit path
-        assert step._cache_size() == len(shapes_seen)
-        assert len(shapes_seen) < feed.stats()["batches"]
-        feed.close()
-
-    def test_resident_rebatches_across_chunk_boundaries(self, svm_path,
-                                                        monkeypatch):
+    def test_python_rebatch_spans_chunk_boundaries(self, svm_path, layout):
         """Tiny parser chunks force every batch to span several blocks —
-        the slice/accumulate logic, not the happy one-block path."""
-        monkeypatch.setenv("DMLC_TPU_DEVICE_RESIDENT", "1")
-        spec = BatchSpec(batch_size=256, layout="csr", num_features=40)
-        monkeypatch.delenv("DMLC_TPU_DEVICE_RESIDENT", raising=False)
-        legacy = DeviceFeed(_base_parser(svm_path, chunk=1024), spec,
-                            host_prefetch=0)
-        want = self._collect(legacy)
-        legacy.close()
-        monkeypatch.setenv("DMLC_TPU_DEVICE_RESIDENT", "1")
-        resident = DeviceFeed(_base_parser(svm_path, chunk=1024), spec,
-                              host_prefetch=0)
-        got = self._collect(resident)
-        assert got == want
-        resident.close()
+        the accumulate/slice logic, not the happy one-block path — and
+        must give the batches of the same file read as one chunk."""
+        spec = BatchSpec(batch_size=256, layout=layout, num_features=40)
+        whole = DeviceFeed(_base_parser(svm_path, chunk=1 << 24), spec,
+                           host_prefetch=0)
+        want = _collect(whole)
+        assert whole._parser.bytes_read > 0
+        whole.close()
+        chunked = DeviceFeed(_base_parser(svm_path, chunk=1024), spec,
+                             host_prefetch=0)
+        got = _collect(chunked)
+        chunked.close()
+        assert len(want) == -(-ROWS // 256)
+        assert got == want  # every array of every batch, byte-exact
 
     def test_dispatch_counter_one_per_batch(self, svm_path, monkeypatch):
         """The whole pytree crosses in ONE device_put per batch —

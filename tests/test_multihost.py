@@ -48,8 +48,8 @@ import jax.numpy as jnp
 
 from dmlc_tpu.data import create_parser
 from dmlc_tpu.device import BatchSpec, DeviceFeed
-from dmlc_tpu.models.linear import (
-    init_linear_params, make_linear_train_step, step_batch)
+from dmlc_tpu.models.fitloop import step_batch
+from dmlc_tpu.models.linear import init_linear_params, make_linear_train_step
 from dmlc_tpu.parallel import data_parallel_mesh
 
 uri, LAYOUT = sys.argv[4], sys.argv[5]

@@ -362,9 +362,8 @@ class TestAuditDigestParity:
         block = out.to_block()
         # container ≡ finalized block (concatenation invariance)
         assert audit.rows_digest(out) == audit.rows_digest(block)
-        # ...and ≡ any re-chunking of the same rows (the resident feed
-        # pushes zero-copy slices; the legacy feed slices a concatenated
-        # whole — both must hash like the original)
+        # ...and ≡ any re-chunking of the same rows (a container of
+        # zero-copy slices hashes like the concatenated whole)
         resliced = RowBlockContainer()
         for start in range(0, len(block), 37):
             resliced.push_block(block.slice(start,
