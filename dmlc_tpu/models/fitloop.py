@@ -20,12 +20,14 @@ that declares it; linear and FM fill it in):
 - ``train_step(arrays)``: run one step on a delivered batch's arrays
   (the loop has stripped the feed's metadata, :func:`step_batch`), rebind
   the learner's own state (the step donates it), return the metrics dict
-  (``loss_sum``, ``weight_sum``: device scalars, not read here);
+  (``loss_sum``, ``weight_sum`` and any of the model's own: device
+  scalars, not read here);
 - ``snapshot_model()``: the ``model`` subtree of a job snapshot, and
   ``restore_snapshot_model(model)`` its way back;
 - optionally ``epoch_span_args()``: more attributes for the ``epoch``
-  span, and ``epoch_closed(reg, nstep)``: called inside ``epoch_close``
-  for counters only this model has (FM's three).
+  span, and ``epoch_closed(reg, nstep, sums)``: called inside
+  ``epoch_close`` for counters only this model has (FM's five); ``sums``
+  holds the pass's sum of every scalar its steps returned.
 
 The loop never waits for the device inside a pass: the one wait is the
 pass's loss read-back in :meth:`FitLoopObs.finish_epoch`, under the
@@ -118,29 +120,30 @@ class EpochMetrics:
     """Collect per-step device metric scalars with no per-step dispatch or
     host sync; reading does one batched device_get (a per-step ``float()``
     stalls the feed's batch-in-flight overlap; a per-step device add pays
-    dispatch overhead per step)."""
+    dispatch overhead per step). Every scalar a step returns is kept:
+    ``loss_sum`` and ``weight_sum`` give the mean loss, and a model's own
+    (FM's ``touched_rows``) reach its ``epoch_closed`` through
+    :attr:`sums`."""
 
     def __init__(self):
-        self._loss = []
-        self._weight = []
-        self._loss_total = 0.0
-        self._weight_total = 0.0
+        self._pending: Dict[str, list] = {}
+        #: name -> the sum of that metric over the steps read so far
+        self.sums: Dict[str, float] = {}
 
     def add(self, metrics: Dict) -> None:
-        self._loss.append(metrics["loss_sum"])
-        self._weight.append(metrics["weight_sum"])
+        for name, scalar in metrics.items():
+            self._pending.setdefault(name, []).append(scalar)
 
     def mean_loss(self) -> float:
-        if self._loss:
+        if self._pending:
             # drain the pending scalars into the running totals: a repeated
             # read never re-fetches what was already summed, and the device
             # scalars are released here, where they were read
-            loss, weight = jax.device_get((self._loss, self._weight))
-            self._loss_total += float(np.sum(loss))
-            self._weight_total += float(np.sum(weight))
-            self._loss.clear()
-            self._weight.clear()
-        return self._loss_total / max(self._weight_total, 1e-12)
+            for name, values in jax.device_get(self._pending).items():
+                self.sums[name] = self.sums.get(name, 0) + np.sum(values).item()
+            self._pending.clear()
+        return self.sums.get("loss_sum", 0.0) / max(
+            self.sums.get("weight_sum", 0.0), 1e-12)
 
 
 class FitLoopObs:
@@ -324,8 +327,10 @@ class FeedLearner:
     def epoch_span_args(self) -> Dict:
         return {}
 
-    def epoch_closed(self, reg, nstep: int) -> None:
-        """Inside ``epoch_close``: count what only this model has."""
+    def epoch_closed(self, reg, nstep: int, sums: Dict) -> None:
+        """Inside ``epoch_close``: count what only this model has.
+        ``sums``: the pass's sum of each scalar :meth:`train_step`
+        returned (:attr:`EpochMetrics.sums`)."""
 
 
 def _snapshot_state(learner, feed, epoch: int, history) -> Dict:
@@ -414,7 +419,8 @@ def fit_feed(learner, feed, epochs: int = 1, log_every: int = 0,
             snap_state=(None if snapshotter is None else
                         lambda e=epoch: _snapshot_state(
                             learner, feed, e, history)),
-            on_close=learner.epoch_closed,
+            on_close=functools.partial(
+                learner.epoch_closed, sums=acc.sums),
         )
         if epoch + 1 < epochs:
             feed.before_first()

@@ -4,15 +4,18 @@ The libfm format the reference parses (libfm_parser.h) exists to feed this
 model family; the reference ships the parser and leaves the model downstream.
 TPU-first formulation: all per-entry work is gathers + segment_sums (static
 shapes), and the O(nnz·K) factor math is batched so XLA can keep it on the
-vector units. Passes over the entries that share an index vector are one
-pass over concatenated columns (on the chip such a pass costs per index,
-not per column): the row sums of the forward pass, a row's terms on their
-way back to its entries, and the sums of an id's entries, for which the
-update sorts the batch's ids once. On one device the step scatter-adds
-each id's summed update into the row it names and never passes over the
-table; on a mesh with
-the table replicated the entries are reduced to a dense gradient for the
-psum; on a mesh with the table's factors sharded
+vector units. The step opens with its one sort, of the batch's entries by
+feature id, and everything after it runs in that order: ``v`` and ``w``
+are read at the batch's DISTINCT ids only (ids repeat within a batch,
+about 26 k distinct of 90,112 entries under kdd2012's power law) and the
+entries take their rows from that few-MB buffer; passes over the entries
+that share an index vector are one pass over concatenated columns (on
+the chip such a pass costs per index, not per column): the row sums of
+the forward pass, a row's terms on their way back to its entries, and the
+sums of an id's entries. On one device the step scatter-adds each id's
+summed update into the row it names and never passes over the table; on
+a mesh with the table replicated the entries are reduced to a dense
+gradient for the psum; on a mesh with the table's factors sharded
 (``table_sharding="factors"``) every chip scatter-adds into its own
 columns and only the batch and one ``f32[rows]`` psum cross ICI.
 
@@ -22,7 +25,7 @@ score(x) = b + Σ_i w_i x_i + ½ Σ_k [(Σ_i v_ik x_i)² − Σ_i v_ik² x_i²]
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, Optional
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -104,36 +107,150 @@ def _check_factor_shards(num_factors: int, mesh: Mesh, axis: str) -> None:
           shards, axis, num_factors)
 
 
-def _row_sums(params, indices, row_ids, values, num_rows: int):
+#: parameter rows one pass of the step's two chunk loops covers (the
+#: gather at the distinct ids and ``v``'s scatter-add). Timed on the v5e
+#: in the kdd12-fm cell (PERF.md, PR 26): 512 to 2048 read the same step,
+#: 8192 costs 0.6 ms of a 14.2 ms step in slots past the last distinct
+#: id.
+_UPDATE_CHUNK = 2048
+
+
+class _IdOrder(NamedTuple):
+    """The batch's entries in feature-id order (:func:`_in_id_order`)."""
+
+    #: s32[n] every entry's feature id, ascending
+    entry_ids: jax.Array
+    #: s32[n] every entry's slot: the count of distinct ids before it
+    slot: jax.Array
+    #: s32[n + pad] slot j holds the j-th distinct id; the slots after the
+    #: last hold distinct ids past the table (a gather fills them, a
+    #: scatter drops them); padded to whole chunks of ``_UPDATE_CHUNK``
+    ids: jax.Array
+    #: s32[] how many distinct ids the batch names
+    distinct: jax.Array
+
+    @property
+    def chunks(self):
+        """s32[] passes of ``_UPDATE_CHUNK`` slots up to the last slot that
+        holds a distinct id: what both chunk loops run."""
+        return (self.distinct + _UPDATE_CHUNK - 1) // _UPDATE_CHUNK
+
+
+def _in_id_order(indices, row_ids, values, num_features: int):
+    """The step's one sort: the batch's entries by feature id, ``row_ids``
+    and ``values`` riding as payloads (a 1-D gather of ``s32[nnz]`` by
+    place costs 0.64 ms on the chip, the two payloads 0.05). Returns the
+    :class:`_IdOrder` and the two payloads in that order.
+
+    Stable, so an id's entries keep the feed's order among themselves,
+    and every chip of a factor-sharded mesh, sorting the same gathered
+    batch, sums them in the same order. In id order an id's entries lie
+    side by side, so an entry's slot is the count of distinct ids before
+    it, sorted by construction. A padded entry (value 0, feature 0) sorts
+    to the front, reads row 0 and adds 0 to it."""
+    n = indices.shape[0]
+    indices, row_ids, values = lax.sort(
+        (indices, row_ids, values), num_keys=1)
+    first = jnp.concatenate(
+        [jnp.ones((1,), bool), indices[1:] != indices[:-1]])
+    slot = jnp.cumsum(first.astype(jnp.int32)) - 1
+    # the distinct ids to the front, in order: every other entry becomes
+    # an id past the table, distinct and above them all, and a sort of
+    # that one array does what a scatter by slot would (0.08 ms against
+    # 0.44 on the chip: PERF.md, PR 31)
+    pad = (-n) % _UPDATE_CHUNK
+    past = num_features + jnp.arange(n + pad, dtype=jnp.int32)
+    ids = jnp.concatenate(
+        [lax.sort(jnp.where(first, indices, past[:n])), past[n:]])
+    return _IdOrder(indices, slot, ids, slot[-1] + 1), row_ids, values
+
+
+def _gather_rows(params, order: _IdOrder):
+    """``[v_e | w_e]`` (``[nnz, K + 1]``) for every entry, in id order,
+    with each touched row of the parameters read ONCE: ``rows =
+    [v[ids] | w[ids]]`` at the distinct ids, then one batch-sized gather
+    ``rows[slot]`` whose source is a few MB (a tenth of a gather from the
+    table's cost on the chip).
+
+    A gather from the table costs per index (22 ns a row of 16 columns,
+    37 ns of 32, 16 ns an element of ``w``: PERF.md, PR 31) and nothing
+    for being repeated, so the popular ids of a power law are most of a
+    per-entry gather's cost. The distinct ids are taken ``_UPDATE_CHUNK``
+    slots a pass until the last slot that holds one: the loop adapts to
+    what the batch holds, and a batch with no repeated id gathers what a
+    per-entry gather would."""
+    v, w = params["v"], params["w"]
+    flags = dict(indices_are_sorted=True, unique_indices=True)
+
+    def take_chunk(i, rows):
+        at = i * _UPDATE_CHUNK
+        ids = lax.dynamic_slice_in_dim(order.ids, at, _UPDATE_CHUNK)
+        got = jnp.concatenate(
+            [jnp.take(v, ids, axis=0, **flags),
+             jnp.take(w, ids, axis=0, **flags)[:, None]], axis=1)
+        return lax.dynamic_update_slice_in_dim(rows, got, at, axis=0)
+
+    rows = jnp.zeros((order.ids.shape[0], v.shape[1] + 1), v.dtype)
+    # under a shard_map that checks it, the loop's carry must vary over
+    # the axes the batch varies over from the start
+    varying = tuple(jax.typeof(order.ids).vma)
+    if varying:
+        rows = lax.pcast(rows, varying, to="varying")
+    rows = lax.fori_loop(0, order.chunks, take_chunk, rows)
+    return jnp.take(rows, order.slot, axis=0)
+
+
+def _row_sums(vw, row_ids, values, num_rows: int):
     """Per row: ``s`` = Σ x_e v_e, ``q`` = Σ (x_e v_e)² (both ``[B, K]``)
     and the linear term Σ x_e w_e, in ONE ``segment_sum`` over the
     concatenated columns; ``xv [nnz, K]`` is handed back for the backward
-    pass. On the chip a pass over the entries costs per index, not per
-    column, while a row of its target fits one 128-lane tile (PERF.md,
-    PR 29), so the three sums share their pass."""
-    k = params["v"].shape[1]
-    with jax.named_scope("step.gather"):
-        v_e = jnp.take(params["v"], indices, axis=0)  # [nnz, K]
-        w_e = jnp.take(params["w"], indices, axis=0)  # [nnz]
+    pass. ``vw`` = ``[v_e | w_e]`` per entry (:func:`_gather_rows`), the
+    entries in any order. On the chip a pass over the entries costs per
+    index, not per column, while a row of its target fits one 128-lane
+    tile (PERF.md, PR 29), so the three sums share their pass."""
+    k = vw.shape[1] - 1
     with jax.named_scope("step.forward"):
-        xv = values[:, None] * v_e  # [nnz, K]
+        xv = values[:, None] * vw[:, :k]  # [nnz, K]
         sums = jax.ops.segment_sum(
-            jnp.concatenate([xv, xv * xv, (values * w_e)[:, None]], axis=1),
+            jnp.concatenate(
+                [xv, xv * xv, (values * vw[:, k])[:, None]], axis=1),
             row_ids, num_segments=num_rows)  # [B, 2K + 1]
     return xv, sums[:, :k], sums[:, k:2 * k], sums[:, 2 * k]
+
+
+def _entries_in_id_order(params, batch):
+    """``step.order`` and ``step.gather``, the head of every FM program:
+    the batch's entries sorted by feature id (:func:`_in_id_order`) and
+    ``[v_e | w_e]`` for each (:func:`_gather_rows`). Returns (order, vw,
+    row_ids, values), the last three per entry in id order."""
+    values = batch["values"]
+    with jax.named_scope("step.gather"):
+        # offsets → row ids on device (local per shard under shard_map)
+        row_ids = batch["row_ids"] if "row_ids" in batch else \
+            expand_row_ids(batch["offsets"], values.shape[0])
+    with jax.named_scope("step.order"):
+        order, row_ids, values = _in_id_order(
+            batch["indices"], row_ids, values, params["w"].shape[0])
+    with jax.named_scope("step.gather"):
+        vw = _gather_rows(params, order)
+    return order, vw, row_ids, values
 
 
 def _fm_entry_grads(params, batch, objective: str,
                     factor_axis: Optional[str] = None):
     """Loss sums and the per-entry gradient contributions of one COO
-    batch shard: entry e of row r at feature i adds ``dw[e]`` to w_i's
-    gradient and ``dv[e]`` to v_i's. How they reach the parameters is the
-    caller's: scatter-added into the table (single device, factor-sharded
-    mesh) or reduced to dense grads for the psum (replicated mesh).
+    batch shard, the entries in feature-id order (``order``, an
+    :class:`_IdOrder`): entry e of row r at feature i adds ``dw[e]`` to
+    w_i's gradient and ``dv[e]`` to v_i's. How they reach the parameters
+    is the caller's: scatter-added into the table (single device,
+    factor-sharded mesh) or reduced to dense grads for the psum
+    (replicated mesh). Returns (dw, gb, dv, loss_sum, weight_sum, order).
 
     Passes that share an index vector are one pass over concatenated
     columns: the three row sums of the forward pass (:func:`_row_sums`),
-    and a row's ``s`` and ``wg`` on their way back to its entries.
+    and a row's ``s`` and ``wg`` on their way back to its entries. The
+    row sums add a row's entries in id order, not the feed's: the same
+    float32 terms in another order.
 
     ``factor_axis``: ``params["v"]`` holds this chip's columns only and
     the batch is the whole step's (``row_ids`` given, global); the
@@ -145,15 +262,8 @@ def _fm_entry_grads(params, batch, objective: str,
     profile can be read by phase; they change no operation."""
     label = batch["label"]
     weight = batch["weight"]
-    values = batch["values"]
-    num_rows = label.shape[0]
-
-    with jax.named_scope("step.gather"):
-        # offsets → row ids on device (local per shard under shard_map)
-        row_ids = batch["row_ids"] if "row_ids" in batch else \
-            expand_row_ids(batch["offsets"], values.shape[0])
-    xv, s, q, linear = _row_sums(
-        params, batch["indices"], row_ids, values, num_rows)
+    order, vw, row_ids, values = _entries_in_id_order(params, batch)
+    xv, s, q, linear = _row_sums(vw, row_ids, values, label.shape[0])
     with jax.named_scope("step.forward"):
         interaction = 0.5 * jnp.sum(s * s - q, axis=-1)
     if factor_axis is not None:
@@ -172,41 +282,15 @@ def _fm_entry_grads(params, batch, objective: str,
         dw = back[:, -1] * values  # [nnz]
         # dv[e,k] = x_e * (s[r,k] − x_e v[i,k]), scaled by wg[r]
         dv = dw[:, None] * (back[:, :-1] - xv)
-    return dw, gb, dv, loss_sum, jnp.sum(weight)
+    return dw, gb, dv, loss_sum, jnp.sum(weight), order
 
 
-#: factor-table rows one scatter-add of the update loop covers. Timed on
-#: the v5e in the kdd12-fm cell (PERF.md, PR 26): 512 to 2048 read the
-#: same step, 8192 costs 0.6 ms of a 14.2 ms step in slots past the last
-#: distinct id.
-_UPDATE_CHUNK = 2048
-
-
-def _in_id_order(indices, dw, dv):
-    """The batch's feature ids sorted, once, and the entries' updates
-    ``[dv | dw]`` (``[nnz, K + 1]``) in that order. The sort carries each
-    entry's place as its payload and the updates follow in one gather
-    (its source is the batch, a few MB: a tenth of a scatter's cost on
-    the chip). Stable: every chip of a factor-sharded mesh sorts the same
-    gathered batch and sums an id's entries in the same order. A padded
-    entry (value 0, feature 0) sorts to the front and still adds 0.
-
-    The gathers from the parameters stay in the feed's order, ahead of
-    this: in id order the entries of a popular id lie side by side and
-    read one row of ``v`` hundreds of times in a row, which the chip
-    serves slower (PERF.md, PR 29: 4.6 ms for 3.4)."""
-    place = jnp.arange(indices.shape[0], dtype=jnp.int32)
-    indices, place = lax.sort((indices, place), num_keys=1)
-    upd = jnp.concatenate([dv, dw[:, None]], axis=1)
-    return indices, jnp.take(upd, place, axis=0)
-
-
-def _scatter_add_rows(w, v, indices, upd):
+def _scatter_add_rows(w, v, order: _IdOrder, upd):
     """``v[i] += Σ upd[e, :-1]`` and ``w[i] += Σ upd[e, -1]`` over the
     entries e that name feature i, into ``w`` and ``v`` themselves (in
-    place when the caller donated them). ``indices`` is SORTED and ``upd``
-    in its order (:func:`_in_id_order`). A row no entry names is not
-    written; a padded entry adds its 0 to feature 0.
+    place when the caller donated them). ``upd`` is in ``order``'s order.
+    A row no entry names is not written; a padded entry adds its 0 to
+    feature 0.
 
     The entries of one id are summed first and reach its row in one
     add. Ids repeat within a batch (thousands of times for the popular
@@ -214,28 +298,20 @@ def _scatter_add_rows(w, v, indices, upd):
     much larger than the update round at the parameter's magnitude each
     time: against a float64 step that read 20 times the error of a dense
     gradient's one subtraction. Summing first keeps that one rounding.
-    In id order an id's entries lie side by side, so an entry's slot is
-    the count of distinct ids before it (sorted by construction), and
     ``v``'s and ``w``'s updates are summed by slot in ONE pass (as
     :func:`_row_sums` sums by row, and for its reason).
 
     On the chip a row scatter-add is serial, ~0.1 µs a slot whether the
-    slot's id is in range or dropped, so the distinct ids are compacted to
-    the front and ``v`` takes them ``_UPDATE_CHUNK`` slots at a time until
-    the last slot that holds one. ``w``'s 1-D scatter costs a pass over
-    ``w`` whatever the number of slots, so it is made once."""
-    n = indices.shape[0]
-    first = jnp.concatenate(
-        [jnp.ones((1,), bool), indices[1:] != indices[:-1]])
-    slot = jnp.cumsum(first.astype(jnp.int32)) - 1
+    slot's id is in range or dropped, so ``v`` takes the distinct ids
+    ``_UPDATE_CHUNK`` slots at a time until the last slot that holds one
+    (the loop :func:`_gather_rows` reads them by). ``w``'s 1-D scatter
+    costs a pass over ``w`` whatever the number of slots, so it is made
+    once."""
+    n = order.slot.shape[0]
     sums = jax.ops.segment_sum(
-        upd, slot, num_segments=n, indices_are_sorted=True)  # [n, K + 1]
-    # slot j holds the j-th distinct id; the slots after the last hold ids
-    # past the table, which the scatter drops (distinct, as promised)
-    pad = (-n) % _UPDATE_CHUNK
-    ids = (w.shape[0] + jnp.arange(n + pad, dtype=jnp.int32)).at[
-        slot].set(indices)
-    sum_v = jnp.pad(sums[:, :-1], ((0, pad), (0, 0)))
+        upd, order.slot, num_segments=n, indices_are_sorted=True)  # [n, K + 1]
+    ids = order.ids
+    sum_v = jnp.pad(sums[:, :-1], ((0, ids.shape[0] - n), (0, 0)))
     flags = dict(indices_are_sorted=True, unique_indices=True, mode="drop")
     w = w.at[ids[:n]].add(sums[:, -1], **flags)
 
@@ -244,9 +320,7 @@ def _scatter_add_rows(w, v, indices, upd):
         return v.at[lax.dynamic_slice_in_dim(ids, at, _UPDATE_CHUNK)].add(
             lax.dynamic_slice_in_dim(sum_v, at, _UPDATE_CHUNK), **flags)
 
-    distinct = slot[-1] + 1
-    chunks = (distinct + _UPDATE_CHUNK - 1) // _UPDATE_CHUNK
-    return w, lax.fori_loop(0, chunks, add_chunk, v)
+    return w, lax.fori_loop(0, order.chunks, add_chunk, v)
 
 
 def _gather_sections(batch, axis: str):
@@ -276,27 +350,27 @@ def exchange_bytes(batch, shards: int) -> int:
     return gathered // shards + int(batch["label"].nbytes)
 
 
-def _sparse_update(params, indices, grads, learning_rate: float, l2: float):
+def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
+                   l2: float):
     """The step's update from per-entry contributions ``grads`` =
-    (dw, gb, dv, weight_sum): put in feature-id order under ``step.order``
-    (:func:`_in_id_order`, the step's one sort), then under
+    (dw, gb, dv, weight_sum), the entries in ``order``'s order: under
     ``step.update`` scaled by ``-learning_rate / weight_sum`` and
     scatter-ADDED into ``w`` and ``v`` (:func:`_scatter_add_rows`; ids
     repeat within a batch), so only the rows the batch names are written
-    and no gradient of the table's shape exists. ``l2 > 0`` adds one
-    scaling pass over the table before the scatter-add:
-    ``v - lr*(g + l2*v) = v*(1 - lr*l2) - lr*g``."""
+    and no gradient of the table's shape exists. The sort, the slots and
+    the distinct ids it needs are the step's head's (``step.order``); it
+    computes none. ``l2 > 0`` adds one scaling pass over the table before
+    the scatter-add: ``v - lr*(g + l2*v) = v*(1 - lr*l2) - lr*g``."""
     dw, gb, dv, wsum = grads
-    with jax.named_scope("step.order"):
-        indices, upd = _in_id_order(indices, dw, dv)
     with jax.named_scope("step.update"):
         denom = jnp.maximum(wsum, 1e-12)
-        scale = -learning_rate / denom
+        upd = (-learning_rate / denom) * jnp.concatenate(
+            [dv, dw[:, None]], axis=1)
         w, v = params["w"], params["v"]
         if l2:
             w = w * (1.0 - learning_rate * l2)
             v = v * (1.0 - learning_rate * l2)
-        w, v = _scatter_add_rows(w, v, indices, scale * upd)
+        w, v = _scatter_add_rows(w, v, order, upd)
         return {
             "w": w,
             "b": params["b"] - learning_rate * (gb / denom),
@@ -315,7 +389,12 @@ def make_fm_train_step(
     donate_batch: bool = False,
     table_sharding: str = "replicated",
 ):
-    """Jitted FM SGD step over COO batches.
+    """Jitted FM SGD step over COO batches: ``(params, batch) -> (params,
+    metrics)``, metrics = ``loss_sum``, ``weight_sum`` and
+    ``touched_rows``, the count of parameter rows the step read (the
+    distinct ids of what a chip sorted, summed over the chips where each
+    sorts its own section): device scalars the fit loop reads once a
+    pass. Every program opens with :func:`_entries_in_id_order`.
 
     Single device (``mesh is None``): the update touches only the rows
     the batch names (:func:`_sparse_update`).
@@ -348,12 +427,12 @@ def make_fm_train_step(
     if mesh is None:
 
         def step(params, batch):
-            dw, gb, dv, loss_sum, wsum = _fm_entry_grads(
+            dw, gb, dv, loss_sum, wsum, order = _fm_entry_grads(
                 params, batch, objective)
             params = _sparse_update(
-                params, batch["indices"], (dw, gb, dv, wsum),
-                learning_rate, l2)
-            return params, {"loss_sum": loss_sum, "weight_sum": wsum}
+                params, order, (dw, gb, dv, wsum), learning_rate, l2)
+            return params, {"loss_sum": loss_sum, "weight_sum": wsum,
+                            "touched_rows": order.distinct}
 
         fn = instrumented_jit(
             step, "fm.step",
@@ -382,12 +461,12 @@ def make_fm_train_step(
         def _factor_sharded(params, batch):
             with jax.named_scope("step.exchange"):
                 whole = _gather_sections(batch, axis)
-            dw, gb, dv, loss_sum, wsum = _fm_entry_grads(
+            dw, gb, dv, loss_sum, wsum, order = _fm_entry_grads(
                 params, whole, objective, factor_axis=axis)
             params = _sparse_update(
-                params, whole["indices"], (dw, gb, dv, wsum),
-                learning_rate, l2)
-            return params, {"loss_sum": loss_sum, "weight_sum": wsum}
+                params, order, (dw, gb, dv, wsum), learning_rate, l2)
+            return params, {"loss_sum": loss_sum, "weight_sum": wsum,
+                            "touched_rows": order.distinct}
 
         # every chip computes w, b and the loss sums from the gathered
         # batch, which shard_map types as varying: the replicas are equal
@@ -401,15 +480,22 @@ def make_fm_train_step(
         return instrumented_jit(step, "fm.step", donate_argnums=(0,))
 
     def _sharded(params, batch):
-        dw, gb, dv, loss_sum, wsum = _fm_entry_grads(params, batch, objective)
+        dw, gb, dv, loss_sum, wsum, order = _fm_entry_grads(
+            params, batch, objective)
         with jax.named_scope("step.scatter"):
-            indices = batch["indices"]
-            gw = jax.ops.segment_sum(dw, indices, num_segments=num_features)
-            gv = jax.ops.segment_sum(dv, indices, num_segments=num_features)
+            gw = jax.ops.segment_sum(
+                dw, order.entry_ids, num_segments=num_features,
+                indices_are_sorted=True)
+            gv = jax.ops.segment_sum(
+                dv, order.entry_ids, num_segments=num_features,
+                indices_are_sorted=True)
         # gradients never round-trip through host numpy: one bucketed
-        # in-graph psum carries the whole gradient pytree across ICI
-        gw, gb, gv, loss_sum, wsum = bucketed_psum(
-            (gw, gb, gv, loss_sum, wsum), axis=axis
+        # in-graph psum carries the whole gradient pytree across ICI (the
+        # chips' counts of distinct ids ride it as a float, exact below
+        # 2**24)
+        gw, gb, gv, loss_sum, wsum, touched = bucketed_psum(
+            (gw, gb, gv, loss_sum, wsum,
+             order.distinct.astype(jnp.float32)), axis=axis
         )
         with jax.named_scope("step.update"):
             denom = jnp.maximum(wsum, 1e-12)
@@ -418,7 +504,8 @@ def make_fm_train_step(
                 "b": params["b"] - learning_rate * (gb / denom),
                 "v": params["v"] - learning_rate * (gv / denom + l2 * params["v"]),
             }
-        return params, {"loss_sum": loss_sum, "weight_sum": wsum}
+        return params, {"loss_sum": loss_sum, "weight_sum": wsum,
+                        "touched_rows": touched.astype(jnp.int32)}
 
     step = shard_map(
         _sharded, mesh=mesh,
@@ -451,9 +538,9 @@ class FMLearner(FeedLearner):
         self.param = FMParam()
         self.param.init(hyper)
         self._nf = None
-        # a sharded table's steps since the last epoch boundary, and the
-        # bytes one of them exchanges, both by nnz bucket (the shapes the
-        # step was compiled for fix the bytes)
+        # the steps since the last epoch boundary by nnz bucket, and the
+        # bytes a sharded table's step exchanges at that bucket (the
+        # shapes the step was compiled for fix the bytes)
         self._steps_of: Dict[int, int] = {}
         self._bytes_of: Dict[int, int] = {}
         super().__init__(mesh)
@@ -516,19 +603,18 @@ class FMLearner(FeedLearner):
         self._ensure(self.param.num_features)
 
     def train_step(self, arrays: Dict) -> Dict:
+        bucket = arrays["indices"].shape[0]
+        self._steps_of[bucket] = self._steps_of.get(bucket, 0) + 1
         shards = self.table_shards
-        if shards > 1:
-            bucket = arrays["indices"].shape[0]
-            self._steps_of[bucket] = self._steps_of.get(bucket, 0) + 1
-            if bucket not in self._bytes_of:
-                self._bytes_of[bucket] = exchange_bytes(arrays, shards)
+        if shards > 1 and bucket not in self._bytes_of:
+            self._bytes_of[bucket] = exchange_bytes(arrays, shards)
         self.params, metrics = self._step(self.params, arrays)
         return metrics
 
     def epoch_span_args(self) -> Dict:
         return {"table_shards": self.table_shards}
 
-    def epoch_closed(self, reg, nstep: int) -> None:
+    def epoch_closed(self, reg, nstep: int, sums: Dict) -> None:
         """FM's own counters. The step was built for ``self.mesh`` and the
         table's sharding (``_ensure``): one device and a factor-sharded
         mesh scatter-add every step, a mesh of replicas none.
@@ -536,7 +622,13 @@ class FMLearner(FeedLearner):
         ``dmlc_fit_steps_total`` is the share of steps that took that
         path; the exchanged bytes are from the shapes (the gradient psum
         of a replicated model is not among them,
-        ``dmlc_xla_collective_bytes`` has it)."""
+        ``dmlc_xla_collective_bytes`` has it).
+
+        ``dmlc_fit_touched_rows_total`` over ``dmlc_fit_entries_total`` is
+        the share of parameter reads the steps still made: the distinct
+        ids of each batch (``touched_rows``, counted on the device and
+        read with the pass's losses) over its entries (from the shapes,
+        padding included). 1.0 on data with no repeated id."""
         shards = self.table_shards
         sparse = self.mesh is None or shards > 1
         reg.counter(
@@ -554,8 +646,18 @@ class FMLearner(FeedLearner):
             "bytes one chip contributed to the collectives of "
             "sharded-table steps (batch gather + interaction psum)",
             model=self.name).inc(
-                sum(n * self._bytes_of[b]
+                sum(n * self._bytes_of.get(b, 0)
                     for b, n in self._steps_of.items()))
+        reg.counter(
+            "dmlc_fit_touched_rows_total",
+            "parameter rows the steps read: the distinct feature ids "
+            "of each batch",
+            model=self.name).inc(sums.get("touched_rows", 0))
+        reg.counter(
+            "dmlc_fit_entries_total",
+            "entries of the batches the steps took, padding included",
+            model=self.name).inc(
+                sum(n * b for b, n in self._steps_of.items()))
         self._steps_of.clear()
 
     def fit_uri(self, uri: str, **kw):
@@ -598,10 +700,9 @@ class FMLearner(FeedLearner):
                 params, self.mesh, rules=self.partition_rules())
 
     def predict_batch(self, batch) -> np.ndarray:
-        num_rows = int(batch["label"].shape[0])
-        row_ids = expand_row_ids(batch["offsets"], batch["values"].shape[0])
+        _, vw, row_ids, values = _entries_in_id_order(self.params, batch)
         _, s, q, linear = _row_sums(
-            self.params, batch["indices"], row_ids, batch["values"], num_rows)
+            vw, row_ids, values, int(batch["label"].shape[0]))
         return np.asarray(
             self.params["b"] + linear + 0.5 * jnp.sum(s * s - q, axis=-1)
         )

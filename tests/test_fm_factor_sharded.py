@@ -192,6 +192,68 @@ class TestAgainstTheReference:
         assert 1000 < moved.sum()
 
 
+class TestOneBatchOnEveryPlacement:
+    """(c): one batch through the three steps ``make_fm_train_step``
+    builds. All gather ``v`` and ``w`` at the distinct ids of what a chip
+    sorts: the whole batch on one device and on a factor-sharded mesh (so
+    the replicas of ``w`` stay bit-equal), its own section on a mesh of
+    replicas."""
+
+    F, K, ROWS, PER_ROW = 1003, 16, 256, 11
+
+    def _batches(self, mesh):
+        rng = np.random.RandomState(31)
+        # a few hot ids beside a long tail, as a power law gives
+        ids = np.where(rng.rand(self.ROWS, self.PER_ROW) < 0.5,
+                       rng.randint(1, 9, size=(self.ROWS, self.PER_ROW)),
+                       rng.randint(9, self.F, size=(self.ROWS, self.PER_ROW)))
+        whole = {
+            "label": jnp.asarray((rng.rand(self.ROWS) < 0.4), jnp.float32),
+            "weight": jnp.ones(self.ROWS),
+            "indices": jnp.asarray(ids.ravel(), jnp.int32),
+            "values": jnp.asarray(rng.rand(ids.size) + 0.5, jnp.float32),
+            "offsets": jnp.arange(self.ROWS + 1, dtype=jnp.int32)
+            * self.PER_ROW}
+        # the feed's sections: every chip its rows' entries, local offsets
+        sections = dict(whole, offsets=jnp.tile(
+            whole["offsets"][:self.ROWS // CHIPS + 1], CHIPS))
+        return ids, whole, jax.device_put(
+            sections, NamedSharding(mesh, P("dp")))
+
+    @pytest.mark.parametrize("table_sharding", ["factors", "replicated"])
+    def test_c_matches_the_single_device_step(self, mesh, table_sharding):
+        from dmlc_tpu.models.fm import fm_partition_rules
+        from dmlc_tpu.parallel.partition import shard_params
+
+        ids, whole, sections = self._batches(mesh)
+        start = init_fm_params(self.F, self.K, 0.3, seed=3)
+        start["w"] = jnp.linspace(-0.2, 0.2, self.F, dtype=jnp.float32)
+        want, want_m = make_fm_train_step(
+            None, self.F, learning_rate=0.3)(start, whole)
+        assert int(want_m["touched_rows"]) == len(np.unique(ids))
+
+        step = make_fm_train_step(
+            mesh, self.F, learning_rate=0.3, table_sharding=table_sharding)
+        got, got_m = step(
+            shard_params(_host(start), mesh,
+                         rules=fm_partition_rules(table_sharding)), sections)
+        # every chip reads the distinct ids of what it sorts
+        per_chip = [len(np.unique(part)) for part in np.split(ids, CHIPS)]
+        assert int(got_m["touched_rows"]) == (
+            len(np.unique(ids)) if table_sharding == "factors"
+            else sum(per_chip))
+        np.testing.assert_allclose(
+            float(got_m["loss_sum"]), float(want_m["loss_sum"]), rtol=2e-6)
+        for k in ("w", "b", "v"):
+            np.testing.assert_allclose(
+                np.asarray(got[k]), np.asarray(want[k]), rtol=2e-5, atol=1e-7)
+        first, *rest = _replicas(got["w"])
+        for other in rest:
+            np.testing.assert_array_equal(
+                first.view(np.uint32), other.view(np.uint32))
+        assert np.abs(np.asarray(want["v"]) - np.asarray(start["v"])).max() > 0
+
+
 class TestRepeatedIdsAndPadding:
     """(d): ids repeated within a row and across rows, a last batch of
     fewer rows than the batch (zero-weight rows) whose entries do not fill
@@ -394,13 +456,19 @@ class TestLoweredStep:
                              ids=["single-device", "factor-sharded"])
     def test_g_one_sort_and_eleven_indexed_passes(self, mesh, sharded):
         """Passes over the entries that share an index vector are one
-        pass over concatenated columns, and the update sorts the ids once.
-        On the chip such a pass costs per index, not per column (PERF.md,
-        PR 29), so what can silently regress is the NUMBER of sorts,
-        gathers and scatters (18 before PR 29): 1 sort + 4 gathers (v, w,
-        a row's s and wg, the updates into id order) + 6 scatters (the
-        offsets' marks, the row sums, the id sums, the ids' compaction,
-        w, v)."""
+        pass over concatenated columns, the step sorts its entries once,
+        at its head, and the parameters are read at the distinct ids
+        only. On the chip an indexed pass costs per index, not per column
+        (PERF.md, PRs 29 and 31), so what can silently regress is the
+        NUMBER of sorts, gathers and scatters (18 before PR 29) and the
+        count of indices a gather from the table takes: 2 sorts (the
+        entries by id; one array, to bring the distinct ids to the front)
+        + 4 gathers (v and w at ``_UPDATE_CHUNK`` distinct ids a pass of
+        the loop, every entry's rows from that buffer, a row's s and wg)
+        + 5 scatters (the offsets' marks, the row sums, the id sums, w,
+        v)."""
+        from dmlc_tpu.models.fm import _UPDATE_CHUNK
+
         params, batch = self._args(mesh)
         if sharded:
             step = make_fm_train_step(
@@ -417,21 +485,30 @@ class TestLoweredStep:
         eqns = list(_walk_eqns(jax.make_jaxpr(step)(params, batch).jaxpr))
         passes = [e.primitive.name for e in eqns if e.primitive.name in (
             "sort", "gather", "scatter", "scatter-add")]
-        assert passes.count("sort") == 1, passes
+        assert passes.count("sort") == 2, passes
         assert passes.count("gather") <= 4, passes
         assert len(passes) <= 11, passes
-        # the one sort carries each entry's place with its id, no more
-        (sort,) = [e for e in eqns if e.primitive.name == "sort"]
-        assert len(sort.invars) == 2 and sort.params["num_keys"] == 1
-        assert sort.params["is_stable"]
-        # the parameters are read in the feed's order, ahead of the sort
-        before = passes[:passes.index("sort")]
-        assert before.count("gather") == 3, passes
+        # the entries' sort carries each one's row and value with its id;
+        # the other sorts one array and carries nothing
+        by_id, compact = [e for e in eqns if e.primitive.name == "sort"]
+        assert len(by_id.invars) == 3 and by_id.params["num_keys"] == 1
+        assert by_id.params["is_stable"] and len(compact.invars) == 1
+        # it opens the step: nothing is read from the parameters before
+        # it, and after it only at one chunk of distinct ids a pass
+        assert "gather" not in passes[:passes.index("sort")], passes
         table = {(self.F, self.K), (self.F, self.K // CHIPS), (self.F,)}
+        reads = sorted(
+            tuple(e.outvars[0].aval.shape) for e in eqns
+            if e.primitive.name == "gather"
+            and tuple(e.invars[0].aval.shape) in table)
+        cols = self.K // CHIPS if sharded else self.K
+        assert reads == [(_UPDATE_CHUNK,), (_UPDATE_CHUNK, cols)], reads
         makers = sorted(
             e.primitive.name for e in eqns for out in e.outvars
             if tuple(out.aval.shape) in table
             and e.primitive.name not in ("shard_map", "pjit", "jit"))
+        # w's scatter-add, v's in its chunk loop; the gather's loop
+        # carries the distinct rows' buffer, nothing of the table's shape
         assert makers == ["scatter-add", "scatter-add", "while"], makers
 
     def test_g_the_replicated_mesh_step_is_as_it_was(self, mesh):

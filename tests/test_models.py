@@ -533,6 +533,206 @@ class TestFMSparseUpdate:
         assert 'dmlc_fit_sparse_update_steps_total{model="fm"}' in (
             obs.registry().flat_values())
 
+    @pytest.mark.parametrize(
+        "placement", ["single", "factors", "replicated"])
+    def test_touched_rows_over_entries_and_one_read_a_pass(
+            self, tmp_path, monkeypatch, placement):
+        """``dmlc_fit_touched_rows_total`` is the distinct ids of each
+        batch as the chip that sorts them sees them (padded entries name
+        feature 0), ``dmlc_fit_entries_total`` the batches' shapes, and
+        the count rides the pass's one read of the device."""
+        from jax.sharding import Mesh
+
+        from dmlc_tpu import obs
+        from dmlc_tpu.data import create_parser
+        from dmlc_tpu.device import BatchSpec, DeviceFeed
+        from dmlc_tpu.models import FMLearner
+
+        rng = np.random.RandomState(37)
+        path = tmp_path / "train.svm"
+        with open(path, "w") as fh:
+            for i in range(200):  # three batches of 64 and one of 8
+                ids = rng.randint(1, 12, size=5)
+                fh.write("%d %s\n" % (i % 2, " ".join(
+                    "%d:%.4f" % (j, rng.rand()) for j in ids)))
+        mesh = None if placement == "single" else Mesh(
+            np.asarray(jax.devices()[:4]), ("dp",))
+        hyper = {} if mesh is None else {"table_sharding": placement}
+
+        def feed():
+            return DeviceFeed(
+                create_parser(str(path)),
+                BatchSpec(batch_size=64, layout="csr",
+                          num_features=self.NFEAT), mesh=mesh)
+
+        seen = feed()
+        batches = [np.asarray(b["indices"]) for b in seen]
+        seen.close()
+        assert len(batches) == 4 and (batches[-1] == 0).sum() > 100
+        # a mesh of replicas sorts a chip's section, every other step the
+        # whole batch
+        parts = 4 if placement == "replicated" else 1
+        want_touched = sum(len(np.unique(part)) for b in batches
+                           for part in np.split(b, parts))
+        want_entries = sum(b.size for b in batches)
+
+        def read():
+            flat = obs.registry().flat_values()
+            return [flat.get('dmlc_fit_%s_total{model="fm"}' % k, 0.0)
+                    for k in ("touched_rows", "entries", "steps")]
+
+        reads = []
+        real_get = jax.device_get
+        monkeypatch.setattr(
+            jax, "device_get", lambda tree: reads.append(1) or real_get(tree))
+        before = read()
+        learner = FMLearner(mesh=mesh, num_features=self.NFEAT,
+                            num_factors=self.NFACT, **hyper)
+        train = feed()
+        learner.fit_feed(train, epochs=1)
+        train.close()
+        touched, entries, steps = [a - b for a, b in zip(read(), before)]
+        assert (touched, entries, steps) == (want_touched, want_entries, 4)
+        assert 0 < touched / entries < 0.2
+        assert len(reads) == 1
+
+
+def _plain_fm_step(params, batch, lr, l2, in_id_order):
+    """The FM step with EVERY ENTRY's rows gathered from the parameters
+    (``jnp.take(params[k], indices)``), in the arithmetic of the step
+    before the distinct-row gather: the merged row sums, one stable sort
+    of the ids, an id's entries summed, one add a touched row.
+    ``in_id_order``: the entries are sorted by id first, as the step has
+    them (what it must then reproduce bit for bit); else the forward and
+    backward passes run in the feed's order, as they did before (the row
+    sums then add in another order)."""
+    from dmlc_tpu.models.linear import margin_grad
+    from dmlc_tpu.ops.spmv import expand_row_ids
+
+    indices, values = batch["indices"], batch["values"]
+    label, weight = batch["label"], batch["weight"]
+    n, k = indices.shape[0], params["v"].shape[1]
+    row_ids = expand_row_ids(batch["offsets"], n)
+    order = jnp.argsort(indices, stable=True)
+    if in_id_order:
+        indices, values, row_ids = indices[order], values[order], row_ids[order]
+        order = jnp.arange(n)
+    v_e = jnp.take(params["v"], indices, axis=0)
+    w_e = jnp.take(params["w"], indices, axis=0)
+    xv = values[:, None] * v_e
+    sums = jax.ops.segment_sum(
+        jnp.concatenate([xv, xv * xv, (values * w_e)[:, None]], axis=1),
+        row_ids, num_segments=label.shape[0])
+    s, q, linear = sums[:, :k], sums[:, k:2 * k], sums[:, 2 * k]
+    margin = params["b"] + linear + 0.5 * jnp.sum(s * s - q, axis=-1)
+    loss, gmargin = margin_grad("logistic", margin, label)
+    wg = weight * gmargin
+    back = jnp.take(jnp.concatenate([s, wg[:, None]], axis=1), row_ids, axis=0)
+    dw = back[:, -1] * values
+    dv = dw[:, None] * (back[:, :-1] - xv)
+    denom = jnp.maximum(jnp.sum(weight), 1e-12)
+    upd = (-lr / denom) * jnp.concatenate([dv, dw[:, None]], axis=1)[order]
+    ids, slot = jnp.unique(
+        indices[order], return_inverse=True, size=n,
+        fill_value=params["w"].shape[0])
+    per_id = jax.ops.segment_sum(upd, slot, num_segments=n)
+    w, v = params["w"], params["v"]
+    if l2:
+        w, v = w * (1.0 - lr * l2), v * (1.0 - lr * l2)
+    return {
+        "w": w.at[ids].add(per_id[:, -1], mode="drop"),
+        "b": params["b"] - lr * (jnp.sum(wg) / denom),
+        "v": v.at[ids].add(per_id[:, :-1], mode="drop"),
+    }, jnp.sum(weight * loss)
+
+
+class TestFMDistinctRowGather:
+    """The step sorts its entries by feature id, gathers ``v`` and ``w``
+    at the batch's distinct ids only, ``_UPDATE_CHUNK`` slots a pass, and
+    hands every entry its rows from that buffer: the same float32 values
+    reach the same sums, so the result is the per-entry gather's to the
+    last bit, and within float32 rounding of the step that ran its
+    forward pass in the feed's order."""
+
+    ROWS = 512
+
+    def _case(self, case):
+        """(ids [rows, per_row], how many of the rows are real, nfeat)"""
+        from dmlc_tpu.models.fm import _UPDATE_CHUNK
+
+        rng = np.random.RandomState(23)
+        rows, chunk = self.ROWS, _UPDATE_CHUNK
+        if case in ("thousands", "l2"):  # 4 ids, ~2800 entries each
+            return rng.randint(1, 5, size=(2 * rows, 11)), 2 * rows, 50
+        if case == "distinct":
+            return 1 + rng.permutation(rows * 11).reshape(rows, 11), rows, 6000
+        if case == "equal":  # one distinct id
+            return np.full((rows, 11), 7), rows, 50
+        if case == "padded":  # 300 real rows; feature 0 takes the padding
+            return rng.randint(1, 900, size=(rows, 11)), 300, 1000
+        extra = {"chunk": 0, "chunk+1": 1}[case]  # 2048 or 2049 distinct
+        ids = 1 + np.arange(rows * 8) % (chunk + extra)
+        return rng.permutation(ids).reshape(rows, 8), rows, 3000
+
+    @pytest.mark.parametrize("nfact", [4, 16])
+    @pytest.mark.parametrize("case", [
+        "thousands", "distinct", "equal", "padded", "chunk", "chunk+1", "l2"])
+    def test_equals_the_per_entry_gather(self, case, nfact):
+        from dmlc_tpu.models.fm import _UPDATE_CHUNK
+
+        ids, real, nfeat = self._case(case)
+        rows, per_row = ids.shape
+        rng = np.random.RandomState(29)
+        indices = ids.astype(np.int32).ravel()
+        values = rng.rand(indices.size).astype(np.float32) + 0.5
+        indices[real * per_row:] = 0
+        values[real * per_row:] = 0.0
+        offsets = np.minimum(np.arange(rows + 1), real) * per_row
+        batch = {
+            "label": jnp.asarray((rng.rand(rows) < 0.4).astype(np.float32)),
+            "weight": jnp.asarray(
+                (np.arange(rows) < real) * (1.0 + np.arange(rows) % 2),
+                jnp.float32),
+            "indices": jnp.asarray(indices),
+            "values": jnp.asarray(values),
+            "offsets": jnp.asarray(offsets.astype(np.int32)),
+        }
+        params = init_fm_params(nfeat, nfact, init_scale=0.3, seed=5)
+        params["w"] = jnp.asarray(rng.randn(nfeat).astype(np.float32) * 0.2)
+        params["b"] = jnp.asarray(-0.2, dtype=jnp.float32)
+        l2 = 0.01 if case == "l2" else 0.0
+
+        plain = jax.jit(_plain_fm_step, static_argnums=(2, 3, 4))
+        want, want_loss = plain(params, batch, 0.2, l2, True)
+        near, near_loss = plain(params, batch, 0.2, l2, False)
+        step = make_fm_train_step(None, nfeat, learning_rate=0.2, l2=l2)
+        got, metrics = step(params, batch)
+
+        distinct = len(np.unique(indices))
+        assert int(metrics["touched_rows"]) == distinct
+        assert distinct == {
+            "thousands": 4, "l2": 4, "distinct": rows * 11, "equal": 1,
+            "chunk": _UPDATE_CHUNK, "chunk+1": _UPDATE_CHUNK + 1,
+        }.get(case, distinct)
+        if case == "padded":
+            assert 0 in indices and (np.bincount(indices)[0]
+                                     == (rows - real) * per_row)
+        assert float(metrics["loss_sum"]) == float(want_loss)
+        for k in ("w", "b", "v"):
+            np.testing.assert_array_equal(
+                np.asarray(got[k]).view(np.uint32),
+                np.asarray(want[k]).view(np.uint32), err_msg=k)
+        # the feed's order adds a row's terms in another order
+        np.testing.assert_allclose(
+            float(metrics["loss_sum"]), float(near_loss), rtol=2e-6)
+        for k in ("w", "b", "v"):
+            np.testing.assert_allclose(
+                np.asarray(got[k]), np.asarray(near[k]), rtol=1e-5, atol=1e-7,
+                err_msg=k)
+        moved = np.abs(np.asarray(got["v"]) - np.asarray(params["v"])).max(1)
+        # (an update below a row's last bit moves nothing)
+        assert (moved[np.unique(indices[:real * per_row])] > 0).mean() > 0.9
+
 
 class TestLearnerEndToEnd:
     def test_fit_feed_and_checkpoint(self, tmp_path):
