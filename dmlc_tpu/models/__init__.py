@@ -40,6 +40,7 @@ from dmlc_tpu.models.fm import (
     FM_PARTITION_RULES,
     FMParam,
     FMLearner,
+    FtrlAdagrad,
     init_fm_params,
     make_fm_train_step,
 )
@@ -70,6 +71,7 @@ __all__ = [
     "FM_PARTITION_RULES",
     "FMParam",
     "FMLearner",
+    "FtrlAdagrad",
     "init_fm_params",
     "make_fm_train_step",
     "GBDTLearner",

@@ -19,6 +19,13 @@ gradient for the psum; on a mesh with the table's factors sharded
 (``table_sharding="factors"``) every chip scatter-adds into its own
 columns and only the batch and one ``f32[rows]`` psum cross ICI.
 
+The update rule is ``FMParam.optimizer``'s: ``"sgd"`` adds each id's
+scaled gradient into its row; ``"ftrl_adagrad"`` (difacto's: FTRL-proximal
+on ``w``, per-element AdaGrad on ``v``) keeps state for every parameter
+row, tables ``z``, ``n`` (as ``w``) and ``a`` (as ``v``) beside the
+weights in ``params``, reads a touched row's state once and SETS weights
+and state from the rule (:func:`_stateful_update`).
+
 score(x) = b + Σ_i w_i x_i + ½ Σ_k [(Σ_i v_ik x_i)² − Σ_i v_ik² x_i²]
 """
 
@@ -61,31 +68,72 @@ class FMParam(Parameter):
     table_sharding = field(
         str, "replicated",
         enum={"replicated": "replicated", "factors": "factors"})
+    # the update rule. "sgd": w, v and b by plain SGD at learning_rate
+    # (l2 as weight decay on the whole table). "ftrl_adagrad": w by
+    # FTRL-proximal (alpha = learning_rate, beta = lr_beta, l1, l2), v by
+    # per-element AdaGrad (v_learning_rate, v_lr_beta, v_l2), b by SGD;
+    # the rule and its sources are on _stateful_update
+    optimizer = field(
+        str, "sgd", enum={"sgd": "sgd", "ftrl_adagrad": "ftrl_adagrad"})
+    l1 = field(float, 0.0, lower_bound=0.0)
+    lr_beta = field(float, 1.0, lower_bound=0.0)
+    v_learning_rate = field(float, 0.05, lower_bound=0.0)
+    v_lr_beta = field(float, 1.0, lower_bound=0.0)
+    v_l2 = field(float, 0.0, lower_bound=0.0)
+
+
+class FtrlAdagrad(NamedTuple):
+    """What ``optimizer="ftrl_adagrad"`` needs beside ``learning_rate``
+    (FTRL's alpha) and ``l2`` (FTRL's), named as :class:`FMParam` names
+    them."""
+
+    l1: float
+    lr_beta: float
+    v_learning_rate: float
+    v_lr_beta: float
+    v_l2: float
 
 
 def init_fm_params(
-    num_features: int, num_factors: int, init_scale: float = 0.01, seed: int = 0
+    num_features: int, num_factors: int, init_scale: float = 0.01, seed: int = 0,
+    optimizer: str = "sgd",
 ) -> Dict:
+    """``w``, ``b``, ``v`` and, for a rule that keeps state
+    (``optimizer="ftrl_adagrad"``), its tables at zero: ``z`` and ``n``
+    of ``w``'s shape (FTRL's), ``a`` of ``v``'s (AdaGrad's sum of
+    squared gradients)."""
     key = jax.random.PRNGKey(seed)
-    return {
+    params = {
         "w": jnp.zeros((num_features,), dtype=jnp.float32),
         "b": jnp.zeros((), dtype=jnp.float32),
         "v": init_scale
         * jax.random.normal(key, (num_features, num_factors), dtype=jnp.float32),
     }
+    if optimizer != "sgd":
+        params.update(
+            z=jnp.zeros_like(params["w"]), n=jnp.zeros_like(params["w"]),
+            a=jnp.zeros_like(params["v"]))
+    return params
+
+
+#: the leaves of ``params`` a stateful rule adds
+STATE_TABLES = ("a", "n", "z")
 
 
 #: Data-parallel placement for {"w": [F], "b": scalar, "v": [F, K]}:
 #: everything replicated, the batch shards, grads psum in-graph. Linted
-#: by scripts/check_partition_rules.py like LINEAR_PARTITION_RULES.
-FM_PARTITION_RULES = ((r"^(w|b|v)$", P()),)
+#: by scripts/check_partition_rules.py like LINEAR_PARTITION_RULES. A
+#: stateful rule's tables are placed as their weights are, here and
+#: below: ``z``, ``n`` as ``w``, ``a`` as ``v``.
+FM_PARTITION_RULES = ((r"^(w|b|v|z|n|a)$", P()),)
 
 #: ``table_sharding="factors"``: chip c of the ``dp`` axis holds columns
 #: [c*K/n, (c+1)*K/n) of ``v``; ``w`` and ``b`` stay replicated. The
 #: factors of an FM do not interact, so a chip's columns give its share
 #: of the interaction term and take their update with nothing of the
 #: table's shape crossing ICI.
-FM_FACTOR_PARTITION_RULES = ((r"^(w|b)$", P()), (r"^v$", P(None, "dp")))
+FM_FACTOR_PARTITION_RULES = (
+    (r"^(w|b|z|n)$", P()), (r"^(v|a)$", P(None, "dp")))
 
 
 def fm_partition_rules(table_sharding: str = "replicated"):
@@ -165,39 +213,48 @@ def _in_id_order(indices, row_ids, values, num_features: int):
     return _IdOrder(indices, slot, ids, slot[-1] + 1), row_ids, values
 
 
-def _gather_rows(params, order: _IdOrder):
-    """``[v_e | w_e]`` (``[nnz, K + 1]``) for every entry, in id order,
-    with each touched row of the parameters read ONCE: ``rows =
-    [v[ids] | w[ids]]`` at the distinct ids, then one batch-sized gather
-    ``rows[slot]`` whose source is a few MB (a tenth of a gather from the
-    table's cost on the chip).
-
-    A gather from the table costs per index (22 ns a row of 16 columns,
-    37 ns of 32, 16 ns an element of ``w``: PERF.md, PR 31) and nothing
-    for being repeated, so the popular ids of a power law are most of a
-    per-entry gather's cost. The distinct ids are taken ``_UPDATE_CHUNK``
-    slots a pass until the last slot that holds one: the loop adapts to
-    what the batch holds, and a batch with no repeated id gathers what a
-    per-entry gather would."""
-    v, w = params["v"], params["w"]
+def _take_distinct(tables, order: _IdOrder):
+    """``[t[ids] | ...]`` for the 1-D and 2-D ``tables`` of one height,
+    side by side (``[n + pad, columns]``), at the batch's distinct ids,
+    ``_UPDATE_CHUNK`` slots a pass until the last slot that holds one:
+    the loop adapts to what the batch holds. A slot past the distinct ids
+    reads 0."""
     flags = dict(indices_are_sorted=True, unique_indices=True)
 
     def take_chunk(i, rows):
         at = i * _UPDATE_CHUNK
         ids = lax.dynamic_slice_in_dim(order.ids, at, _UPDATE_CHUNK)
         got = jnp.concatenate(
-            [jnp.take(v, ids, axis=0, **flags),
-             jnp.take(w, ids, axis=0, **flags)[:, None]], axis=1)
+            [jnp.take(t, ids, axis=0, **flags) if t.ndim == 2
+             else jnp.take(t, ids, axis=0, **flags)[:, None]
+             for t in tables], axis=1)
         return lax.dynamic_update_slice_in_dim(rows, got, at, axis=0)
 
-    rows = jnp.zeros((order.ids.shape[0], v.shape[1] + 1), v.dtype)
+    columns = sum(t.shape[1] if t.ndim == 2 else 1 for t in tables)
+    rows = jnp.zeros((order.ids.shape[0], columns), tables[0].dtype)
     # under a shard_map that checks it, the loop's carry must vary over
     # the axes the batch varies over from the start
     varying = tuple(jax.typeof(order.ids).vma)
     if varying:
         rows = lax.pcast(rows, varying, to="varying")
-    rows = lax.fori_loop(0, order.chunks, take_chunk, rows)
-    return jnp.take(rows, order.slot, axis=0)
+    return lax.fori_loop(0, order.chunks, take_chunk, rows)
+
+
+def _gather_rows(params, order: _IdOrder):
+    """``[v_e | w_e]`` (``[nnz, K + 1]``) for every entry, in id order,
+    with each touched row of the parameters read ONCE: ``rows =
+    [v[ids] | w[ids]]`` at the distinct ids (:func:`_take_distinct`),
+    then one batch-sized gather ``rows[slot]`` whose source is a few MB
+    (a tenth of a gather from the table's cost on the chip). Returns
+    (rows, the entries' rows).
+
+    A gather from the table costs per index (22 ns a row of 16 columns,
+    37 ns of 32, 16 ns an element of ``w``: PERF.md, PR 31) and nothing
+    for being repeated, so the popular ids of a power law are most of a
+    per-entry gather's cost; a batch with no repeated id gathers what a
+    per-entry gather would."""
+    rows = _take_distinct((params["v"], params["w"]), order)
+    return rows, jnp.take(rows, order.slot, axis=0)
 
 
 def _row_sums(vw, row_ids, values, num_rows: int):
@@ -221,8 +278,9 @@ def _row_sums(vw, row_ids, values, num_rows: int):
 def _entries_in_id_order(params, batch):
     """``step.order`` and ``step.gather``, the head of every FM program:
     the batch's entries sorted by feature id (:func:`_in_id_order`) and
-    ``[v_e | w_e]`` for each (:func:`_gather_rows`). Returns (order, vw,
-    row_ids, values), the last three per entry in id order."""
+    ``[v_e | w_e]`` for each (:func:`_gather_rows`). Returns (order, rows,
+    vw, row_ids, values): ``rows`` the distinct ids' ``[v | w]``, the last
+    three per entry in id order."""
     values = batch["values"]
     with jax.named_scope("step.gather"):
         # offsets → row ids on device (local per shard under shard_map)
@@ -232,8 +290,8 @@ def _entries_in_id_order(params, batch):
         order, row_ids, values = _in_id_order(
             batch["indices"], row_ids, values, params["w"].shape[0])
     with jax.named_scope("step.gather"):
-        vw = _gather_rows(params, order)
-    return order, vw, row_ids, values
+        rows, vw = _gather_rows(params, order)
+    return order, rows, vw, row_ids, values
 
 
 def _fm_entry_grads(params, batch, objective: str,
@@ -244,7 +302,9 @@ def _fm_entry_grads(params, batch, objective: str,
     w_i's gradient and ``dv[e]`` to v_i's. How they reach the parameters
     is the caller's: scatter-added into the table (single device,
     factor-sharded mesh) or reduced to dense grads for the psum
-    (replicated mesh). Returns (dw, gb, dv, loss_sum, weight_sum, order).
+    (replicated mesh). Returns (dw, gb, dv, loss_sum, weight_sum, order,
+    seen); ``seen`` = (the distinct ids' ``[v | w]``, the entries'
+    values) is what a stateful rule reads besides (:func:`_stateful_update`).
 
     Passes that share an index vector are one pass over concatenated
     columns: the three row sums of the forward pass (:func:`_row_sums`),
@@ -262,7 +322,7 @@ def _fm_entry_grads(params, batch, objective: str,
     profile can be read by phase; they change no operation."""
     label = batch["label"]
     weight = batch["weight"]
-    order, vw, row_ids, values = _entries_in_id_order(params, batch)
+    order, rows, vw, row_ids, values = _entries_in_id_order(params, batch)
     xv, s, q, linear = _row_sums(vw, row_ids, values, label.shape[0])
     with jax.named_scope("step.forward"):
         interaction = 0.5 * jnp.sum(s * s - q, axis=-1)
@@ -282,7 +342,7 @@ def _fm_entry_grads(params, batch, objective: str,
         dw = back[:, -1] * values  # [nnz]
         # dv[e,k] = x_e * (s[r,k] − x_e v[i,k]), scaled by wg[r]
         dv = dw[:, None] * (back[:, :-1] - xv)
-    return dw, gb, dv, loss_sum, jnp.sum(weight), order
+    return dw, gb, dv, loss_sum, jnp.sum(weight), order, (rows, values)
 
 
 def _scatter_add_rows(w, v, order: _IdOrder, upd):
@@ -323,6 +383,15 @@ def _scatter_add_rows(w, v, order: _IdOrder, upd):
     return w, lax.fori_loop(0, order.chunks, add_chunk, v)
 
 
+def _check_rule_placement(stateful: bool, mesh: Optional[Mesh],
+                          table_sharding: str) -> None:
+    check(not stateful or mesh is None or table_sharding == "factors",
+          "optimizer='ftrl_adagrad' keeps state for every parameter row "
+          "and updates the rows a batch names; the replicated mesh step "
+          "applies a dense psummed gradient and has no such path: train "
+          "on one device or with table_sharding='factors'")
+
+
 def _gather_sections(batch, axis: str):
     """Under ``shard_map``: the feed's row-split sections of one step's
     batch (``ShardedCSRBatch``: every chip its own rows' entries, with
@@ -351,7 +420,7 @@ def exchange_bytes(batch, shards: int) -> int:
 
 
 def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
-                   l2: float):
+                   l2: float, seen=None, rule: Optional[FtrlAdagrad] = None):
     """The step's update from per-entry contributions ``grads`` =
     (dw, gb, dv, weight_sum), the entries in ``order``'s order: under
     ``step.update`` scaled by ``-learning_rate / weight_sum`` and
@@ -360,7 +429,13 @@ def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
     and no gradient of the table's shape exists. The sort, the slots and
     the distinct ids it needs are the step's head's (``step.order``); it
     computes none. ``l2 > 0`` adds one scaling pass over the table before
-    the scatter-add: ``v - lr*(g + l2*v) = v*(1 - lr*l2) - lr*g``."""
+    the scatter-add: ``v - lr*(g + l2*v) = v*(1 - lr*l2) - lr*g``.
+
+    With a ``rule`` (``optimizer="ftrl_adagrad"``) the update is
+    :func:`_stateful_update`'s, which sets rows where this one adds."""
+    if rule is not None:
+        return _stateful_update(
+            params, order, grads, seen, learning_rate, l2, rule)
     dw, gb, dv, wsum = grads
     with jax.named_scope("step.update"):
         denom = jnp.maximum(wsum, 1e-12)
@@ -378,6 +453,117 @@ def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
         }
 
 
+def _set_rows(table, order: _IdOrder, new):
+    """``table[ids[j]] = new[j]`` for every slot j that holds a distinct
+    id (``new``: ``[n + pad]`` or ``[n + pad, K]``, by slot), into
+    ``table`` itself when the caller donated it; a row no slot names is
+    not written. Shaped as :func:`_scatter_add_rows` is, and for its
+    reasons: a 2-D table takes the slots ``_UPDATE_CHUNK`` at a time up
+    to the last chunk that holds a distinct id, a 1-D one in one
+    scatter."""
+    ids = order.ids
+    flags = dict(indices_are_sorted=True, unique_indices=True, mode="drop")
+    if table.ndim == 1:
+        n = order.slot.shape[0]
+        return table.at[ids[:n]].set(new[:n], **flags)
+
+    def set_chunk(i, table):
+        at = i * _UPDATE_CHUNK
+        return table.at[lax.dynamic_slice_in_dim(ids, at, _UPDATE_CHUNK)].set(
+            lax.dynamic_slice_in_dim(new, at, _UPDATE_CHUNK), **flags)
+
+    return lax.fori_loop(0, order.chunks, set_chunk, table)
+
+
+def _ftrl_adagrad(old, grad_w, grad_v, alpha: float, l2: float,
+                  rule: FtrlAdagrad):
+    """The rule of :func:`_stateful_update` alone, elementwise over the
+    distinct ids' buffers: ``old`` = (w, z, n ``[slots]``, v, a ``[slots,
+    K]``) and an id's mean gradients give the five new values."""
+    old_w, old_z, old_n, old_v, old_a = old
+    new_n = old_n + grad_w * grad_w
+    root = jnp.sqrt(new_n)
+    new_z = old_z + grad_w - (root - jnp.sqrt(old_n)) / alpha * old_w
+    new_w = jnp.where(
+        jnp.abs(new_z) <= rule.l1, 0.0,
+        -(new_z - jnp.sign(new_z) * rule.l1)
+        / ((rule.lr_beta + root) / alpha + l2))
+    grad_v = grad_v + rule.v_l2 * old_v
+    new_a = old_a + grad_v * grad_v
+    new_v = old_v - rule.v_learning_rate * grad_v / (
+        rule.v_lr_beta + jnp.sqrt(new_a))
+    return new_w, new_z, new_n, new_v, new_a
+
+
+def _stateful_update(params, order: _IdOrder, grads, seen,
+                     learning_rate: float, l2: float, rule: FtrlAdagrad):
+    """The step's update under ``optimizer="ftrl_adagrad"``, difacto's
+    rule (github.com/dmlc/difacto ``src/sgd/sgd_updater.cc``; Li et al.,
+    WSDM 2016), from what :func:`_sparse_update` takes and ``seen`` = (the
+    distinct ids' ``[v | w]`` the head read, the entries' values). Per
+    distinct id i of the batch, with g the batch's mean gradient of w_i
+    and G that of v_i (the sums over the entries that name i, over
+    ``weight_sum``), whole BEFORE the rule runs:
+
+    w, FTRL-proximal (McMahan et al., KDD 2013, algorithm 1; state z, n;
+    alpha = ``learning_rate``, beta = ``lr_beta``)::
+
+        n' = n + g^2;  z' = z + g - (sqrt(n') - sqrt(n)) / alpha * w
+        w' = 0 if |z'| <= l1 else
+             -(z' - sign(z') l1) / ((beta + sqrt(n')) / alpha + l2)
+
+    v, AdaGrad per element (Duchi et al., 2011; state a)::
+
+        G = G + v_l2 * v;  a' = a + G^2
+        v' = v - v_learning_rate * G / (v_lr_beta + sqrt(a'))
+
+    b by SGD at ``learning_rate``. The rule is not a scaled sum of the
+    entries: an id's gradient is summed first (one ``segment_sum`` by
+    sorted slot, as the SGD step's), the id's state is read ONCE
+    (:func:`_take_distinct`), and weights and state are SET at the
+    distinct ids (:func:`_set_rows`): an id under the L1 threshold holds
+    an exact 0, no array of a table's shape exists besides the tables,
+    and a row no entry names is neither read nor written. A slot whose
+    entries all have value 0 (padding names feature 0) keeps its weights
+    and its state to the bit, whatever ``v_l2``.
+
+    ``step.state`` holds what the rule adds to the SGD step: the state
+    rows' read, the rule, the state rows' write. ``w``'s and ``v``'s
+    writes and the id sums stay under ``step.update``."""
+    dw, gb, dv, wsum = grads
+    rows, values = seen
+    n, k = dv.shape
+    with jax.named_scope("step.update"):
+        denom = jnp.maximum(wsum, 1e-12)
+        # the last column counts an id's entries that carry a value
+        sums = jax.ops.segment_sum(
+            jnp.concatenate(
+                [dv, dw[:, None], (values != 0).astype(dv.dtype)[:, None]],
+                axis=1),
+            order.slot, num_segments=n, indices_are_sorted=True)
+        sums = jnp.pad(sums, ((0, order.ids.shape[0] - n), (0, 0)))
+        grad_v, grad_w = sums[:, :k] / denom, sums[:, k] / denom
+        live = sums[:, k + 1] > 0
+        old_v, old_w = rows[:, :k], rows[:, k]
+    with jax.named_scope("step.state"):
+        state = _take_distinct(
+            (params["a"], params["z"], params["n"]), order)
+        old = (old_w, state[:, k], state[:, k + 1], old_v, state[:, :k])
+        new = _ftrl_adagrad(old, grad_w, grad_v, learning_rate, l2, rule)
+        new_w, new_z, new_n, new_v, new_a = (
+            jnp.where(live if o.ndim == 1 else live[:, None], n_, o)
+            for n_, o in zip(new, old))
+        state = {"a": _set_rows(params["a"], order, new_a),
+                 "z": _set_rows(params["z"], order, new_z),
+                 "n": _set_rows(params["n"], order, new_n)}
+    with jax.named_scope("step.update"):
+        return dict(
+            state,
+            w=_set_rows(params["w"], order, new_w),
+            b=params["b"] - learning_rate * (gb / denom),
+            v=_set_rows(params["v"], order, new_v))
+
+
 def make_fm_train_step(
     mesh: Optional[Mesh],
     num_features: int,
@@ -388,8 +574,9 @@ def make_fm_train_step(
     param_specs=None,
     donate_batch: bool = False,
     table_sharding: str = "replicated",
+    rule: Optional[FtrlAdagrad] = None,
 ):
-    """Jitted FM SGD step over COO batches: ``(params, batch) -> (params,
+    """Jitted FM step over COO batches: ``(params, batch) -> (params,
     metrics)``, metrics = ``loss_sum``, ``weight_sum`` and
     ``touched_rows``, the count of parameter rows the step read (the
     distinct ids of what a chip sorted, summed over the chips where each
@@ -415,6 +602,14 @@ def make_fm_train_step(
     under ``step.exchange``; nothing of the table's shape crosses ICI
     or exists besides the table.
 
+    ``rule`` (``FMParam.optimizer="ftrl_adagrad"``; None is plain SGD):
+    ``params`` holds the rule's state tables too (``init_fm_params(...,
+    optimizer=)``) and both sparse paths update through
+    :func:`_stateful_update`, on a factor-sharded mesh every chip its
+    columns of ``a`` as of ``v`` and its replica of ``z`` and ``n``. The
+    replicated mesh step reduces the entries to a dense gradient, which
+    has no per-row state to meet: it refuses a rule.
+
     ``donate_batch=True`` (single-device path) donates params AND the
     batch arrays, the same contract as
     :func:`~dmlc_tpu.models.linear.make_linear_train_step`: XLA reuses
@@ -423,14 +618,16 @@ def make_fm_train_step(
     callers that rebind params each step and never touch a batch after
     its step (DeviceFeed loops, FMLearner)."""
     check(num_features > 0, "num_features required")
+    _check_rule_placement(rule is not None, mesh, table_sharding)
 
     if mesh is None:
 
         def step(params, batch):
-            dw, gb, dv, loss_sum, wsum, order = _fm_entry_grads(
+            dw, gb, dv, loss_sum, wsum, order, seen = _fm_entry_grads(
                 params, batch, objective)
             params = _sparse_update(
-                params, order, (dw, gb, dv, wsum), learning_rate, l2)
+                params, order, (dw, gb, dv, wsum), learning_rate, l2,
+                seen, rule)
             return params, {"loss_sum": loss_sum, "weight_sum": wsum,
                             "touched_rows": order.distinct}
 
@@ -453,7 +650,9 @@ def make_fm_train_step(
     if param_specs is None:
         param_specs = match_partition_rules(
             fm_partition_rules(table_sharding),
-            jax.eval_shape(lambda: init_fm_params(max(num_features, 1), 2)),
+            jax.eval_shape(lambda: init_fm_params(
+                max(num_features, 1), 2,
+                optimizer="sgd" if rule is None else "ftrl_adagrad")),
         )
 
     if table_sharding == "factors":
@@ -461,10 +660,11 @@ def make_fm_train_step(
         def _factor_sharded(params, batch):
             with jax.named_scope("step.exchange"):
                 whole = _gather_sections(batch, axis)
-            dw, gb, dv, loss_sum, wsum, order = _fm_entry_grads(
+            dw, gb, dv, loss_sum, wsum, order, seen = _fm_entry_grads(
                 params, whole, objective, factor_axis=axis)
             params = _sparse_update(
-                params, order, (dw, gb, dv, wsum), learning_rate, l2)
+                params, order, (dw, gb, dv, wsum), learning_rate, l2,
+                seen, rule)
             return params, {"loss_sum": loss_sum, "weight_sum": wsum,
                             "touched_rows": order.distinct}
 
@@ -480,7 +680,7 @@ def make_fm_train_step(
         return instrumented_jit(step, "fm.step", donate_argnums=(0,))
 
     def _sharded(params, batch):
-        dw, gb, dv, loss_sum, wsum, order = _fm_entry_grads(
+        dw, gb, dv, loss_sum, wsum, order, _ = _fm_entry_grads(
             params, batch, objective)
         with jax.named_scope("step.scatter"):
             gw = jax.ops.segment_sum(
@@ -527,7 +727,16 @@ class FMLearner(FeedLearner):
     the params through one host copy (a factor-sharded table whole: 28 GB
     at 54.7 M ids x 128), and every chip that held a column slice must
     still answer: no other chip has those columns, so after losing one
-    the way back is the last snapshot."""
+    the way back is the last snapshot.
+
+    ``optimizer="ftrl_adagrad"`` (and ``l1``, ``lr_beta``,
+    ``v_learning_rate``, ``v_lr_beta``, ``v_l2``; :func:`_stateful_update`
+    has the rule): ``params`` holds the rule's state tables ``z``, ``n``
+    and ``a`` beside ``w``, ``b``, ``v``, placed as their weights are, so
+    a snapshot, a restore under another placement and ``reshard`` carry
+    them with no word of their own. ``predict_batch`` ignores them. One
+    device and a factor-sharded mesh take the rule; a mesh of replicas
+    refuses it."""
 
     name = "fm"
     #: the mesh axis the batch (and a sharded table) divides over, the
@@ -557,8 +766,18 @@ class FMLearner(FeedLearner):
         return fm_partition_rules(self.param.table_sharding)
 
     def check_mesh(self, mesh: Mesh) -> None:
+        _check_rule_placement(
+            self.rule is not None, mesh, self.param.table_sharding)
         if self.param.table_sharding == "factors":
             _check_factor_shards(self.param.num_factors, mesh, self.axis)
+
+    @property
+    def rule(self) -> Optional[FtrlAdagrad]:
+        """The stateful rule's hyperparameters, None under plain SGD."""
+        if self.param.optimizer == "sgd":
+            return None
+        return FtrlAdagrad(**{f: getattr(self.param, f)
+                              for f in FtrlAdagrad._fields})
 
     def param_shardings(self):
         """NamedSharding tree of the params on this learner's mesh (None
@@ -568,7 +787,8 @@ class FMLearner(FeedLearner):
             return None
         # the rules go by a leaf's name and rank, not by its size
         template = jax.eval_shape(
-            lambda: init_fm_params(2, self.param.num_factors))
+            lambda: init_fm_params(2, self.param.num_factors,
+                                   optimizer=self.param.optimizer))
         return sharding_tree(
             self.mesh,
             match_partition_rules(self.partition_rules(), template))
@@ -577,7 +797,8 @@ class FMLearner(FeedLearner):
         if self.params is None:
             nf = self.param.num_features or num_features
             init = partial(init_fm_params, nf, self.param.num_factors,
-                           self.param.init_scale)
+                           self.param.init_scale,
+                           optimizer=self.param.optimizer)
             # on a mesh the initialiser runs as one program placed by the
             # rules: a chip writes its own part and no whole table exists
             # on any one of them first
@@ -596,6 +817,7 @@ class FMLearner(FeedLearner):
                 # a batch after its step — the donation contract holds
                 donate_batch=self.mesh is None,
                 table_sharding=self.param.table_sharding,
+                rule=self.rule,
             )
 
     def ensure_step(self, spec) -> None:
@@ -612,7 +834,19 @@ class FMLearner(FeedLearner):
         return metrics
 
     def epoch_span_args(self) -> Dict:
-        return {"table_shards": self.table_shards}
+        return {"table_shards": self.table_shards,
+                "optimizer": self.param.optimizer}
+
+    def state_bytes(self) -> int:
+        """Bytes of optimizer state one chip holds: its part of every
+        table the rule keeps beside the weights (0 under plain SGD)."""
+        if self.rule is None or self.params is None:
+            return 0
+        # from the shapes: ``a`` is divided as ``v`` is, ``z`` and ``n``
+        # are whole on every chip
+        held = self.params
+        return int(held["a"].nbytes // self.table_shards
+                   + held["z"].nbytes + held["n"].nbytes)
 
     def epoch_closed(self, reg, nstep: int, sums: Dict) -> None:
         """FM's own counters. The step was built for ``self.mesh`` and the
@@ -628,7 +862,12 @@ class FMLearner(FeedLearner):
         the share of parameter reads the steps still made: the distinct
         ids of each batch (``touched_rows``, counted on the device and
         read with the pass's losses) over its entries (from the shapes,
-        padding included). 1.0 on data with no repeated id."""
+        padding included). 1.0 on data with no repeated id.
+
+        ``dmlc_fit_stateful_update_steps_total`` counts the steps that
+        took a rule with per-row state (``optimizer`` names it; none
+        under ``"sgd"``), ``dmlc_fit_optimizer_state_bytes`` what that
+        state holds of one chip's memory."""
         shards = self.table_shards
         sparse = self.mesh is None or shards > 1
         reg.counter(
@@ -658,6 +897,16 @@ class FMLearner(FeedLearner):
             "entries of the batches the steps took, padding included",
             model=self.name).inc(
                 sum(n * b for b, n in self._steps_of.items()))
+        reg.counter(
+            "dmlc_fit_stateful_update_steps_total",
+            "optimizer steps that read and wrote per-row optimizer state "
+            "at the rows the batch named",
+            model=self.name, optimizer=self.param.optimizer).inc(
+                nstep if self.rule is not None else 0)
+        reg.gauge(
+            "dmlc_fit_optimizer_state_bytes",
+            "bytes of optimizer state on one chip, beside the weights",
+            model=self.name).set(self.state_bytes())
         self._steps_of.clear()
 
     def fit_uri(self, uri: str, **kw):
@@ -692,6 +941,11 @@ class FMLearner(FeedLearner):
         check(tuple(params["v"].shape) == want,
               "snapshot holds a factor table of shape %s, this learner "
               "trains %s", tuple(params["v"].shape), want)
+        held = sorted(k for k in STATE_TABLES if k in params)
+        need = sorted(STATE_TABLES) if self.rule is not None else []
+        check(held == need,
+              "snapshot holds the optimizer state %s, optimizer=%r keeps %s",
+              held, self.param.optimizer, need)
         self._nf = want[0]
         if self.mesh is None:
             self.params = {k: jnp.asarray(v) for k, v in params.items()}
@@ -700,7 +954,7 @@ class FMLearner(FeedLearner):
                 params, self.mesh, rules=self.partition_rules())
 
     def predict_batch(self, batch) -> np.ndarray:
-        _, vw, row_ids, values = _entries_in_id_order(self.params, batch)
+        _, _, vw, row_ids, values = _entries_in_id_order(self.params, batch)
         _, s, q, linear = _row_sums(
             vw, row_ids, values, int(batch["label"].shape[0]))
         return np.asarray(

@@ -52,11 +52,16 @@ def build_cases():
     # never device buffers
     linear_t = jax.eval_shape(lambda: init_linear_params(8))
     fm_t = jax.eval_shape(lambda: init_fm_params(8, 4))
+    # with the state tables of a stateful optimizer beside the weights
+    fm_state_t = jax.eval_shape(
+        lambda: init_fm_params(8, 4, optimizer="ftrl_adagrad"))
     return (
         ("LINEAR_PARTITION_RULES", LINEAR_PARTITION_RULES, linear_t),
         ("LINEAR_MP_PARTITION_RULES", LINEAR_MP_PARTITION_RULES, linear_t),
         ("FM_PARTITION_RULES", FM_PARTITION_RULES, fm_t),
         ("FM_FACTOR_PARTITION_RULES", FM_FACTOR_PARTITION_RULES, fm_t),
+        ("FM_PARTITION_RULES", FM_PARTITION_RULES, fm_state_t),
+        ("FM_FACTOR_PARTITION_RULES", FM_FACTOR_PARTITION_RULES, fm_state_t),
     )
 
 
