@@ -953,6 +953,18 @@ class ThreadedParser:
         self._wait_ns = 0
         self._iter.before_first()
 
+    # ---- job-snapshot state: the base's read plan, where it has one ----
+    def snapshot_state(self) -> Optional[dict]:
+        snap = getattr(self._base, "snapshot_state", None)
+        return snap() if callable(snap) else None
+
+    def restore_state(self, st: dict) -> None:
+        # the prefetch thread is reading the plan the base was built with
+        self._iter.close()
+        self._base.restore_state(st)
+        self._wait_ns = 0
+        self._iter.before_first()
+
     def close(self) -> None:
         self._iter.close()
         self._base.close()

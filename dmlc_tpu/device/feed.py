@@ -9,7 +9,11 @@ and keeps ``spec.prefetch`` batches in flight (default 1 — the classic
 double-buffer; deeper windows pin more HBM but hide per-batch dispatch/DMA
 latency) so H2D DMA overlaps both host parsing and the previous step's
 compute. ``host_prefetch`` separately bounds the host-side ThreadedIter
-queue of parsed-but-undispatched blocks.
+queue of parsed-but-undispatched blocks. At the end of a pass the producer
+thread rewinds the parser itself and stages the next pass's first batches
+while the device drains this one's last steps, so ``before_first`` after a
+whole pass has only its bookkeeping left (docs/pipeline.md, "Restarting a
+pass").
 
 Batch layouts:
 - "dense": [batch, num_features] f32 + labels/weights — the MXU-friendly
@@ -23,6 +27,7 @@ from __future__ import annotations
 
 import itertools
 import os
+import threading
 import time
 from collections import deque
 from dataclasses import dataclass
@@ -78,6 +83,11 @@ class _SyncIter:
 
     def next(self):
         return next(self._gen, None)
+
+    def advance(self) -> bool:
+        # no thread to have wound ahead: always the caller's rewind
+        self.close()
+        return False
 
     def before_first(self) -> None:
         # close the old generator first (ThreadedIter.before_first fully
@@ -310,6 +320,214 @@ def stall_breakdown(stats: dict) -> str:
     return " | ".join(parts)
 
 
+class _HostStage:
+    """The feed's host side: the parser's blocks re-batched into fixed-size
+    host batches, on the producer thread (inline with ``host_prefetch=0``),
+    and the parser's rewind at the end of a pass.
+
+    Held by the feed and by the producer thread, and holds no reference to
+    the feed: a feed that is dropped is collected, and its ThreadedIter
+    stops the thread."""
+
+    # what a pass ended with, kept when the producer winds past it
+    _FACTS = ("pipeline", "bytes_read", "plan", "host_batch_ns")
+
+    def __init__(self, parser, spec: BatchSpec, shards: int, host_batch_ns,
+                 cpu):
+        self.parser = parser
+        self.spec = spec
+        self.shards = shards
+        self.host_batch_ns = host_batch_ns
+        # the producer thread's CPU histogram; None in sync mode, where
+        # this runs on the consumer's thread and its count covers it
+        self.cpu = cpu
+        # determinism audit: batch-stage digests at pool emit, keyed by
+        # per-epoch batch index (obs/audit.py). The shared no-op child
+        # when DMLC_TPU_AUDIT is off.
+        self.audit = audit.auditor()
+        # exactly-once ack emission (dispatcher-mode RemoteBlockParser):
+        # switch the parser to explicit acks BEFORE the producer thread
+        # can issue its first fetch, so prefetched chunks are acked only
+        # when their rows are consumed (or dropped) by this feed
+        self.ack = getattr(parser, "ack", None)
+        set_explicit = getattr(parser, "set_explicit_ack", None)
+        if callable(self.ack) and callable(set_explicit):
+            set_explicit()
+        else:
+            self.ack = None
+        # orders the producer's rewind of the parser against the
+        # consumer's reads of it (fact)
+        self.lock = threading.Lock()
+        # _FACTS of each pass the producer has wound past and the
+        # consumer has not left (DeviceFeed.before_first), oldest first
+        self.ended: deque = deque()
+
+    @property
+    def rewinds_itself(self) -> bool:
+        """Whether the end of a pass is this stage's to rewind at. Not
+        with explicit acks (a rewind asks the service for the next epoch's
+        chunks and moves the exactly-once frontier) and not under an
+        armed audit (its batch chain is keyed per epoch and closes at the
+        boundary the consumer draws)."""
+        return self.ack is None and not self.audit.enabled
+
+    def use_native(self) -> bool:
+        """Native C++ re-batch + densify/COO-pad (pipeline.cc StageBatch):
+        no RowBlockContainer copies, no numpy scatter — the feed-side answer
+        to the parse-vs-feed throughput cliff (BASELINE.md)."""
+        return (
+            getattr(self.parser, "supports_batch_fetch", False)
+            and self.spec.layout in ("dense", "csr")
+        )
+
+    # ---- the end of a pass ----------------------------------------------
+    def _live(self, fact):
+        if fact == "host_batch_ns":
+            return self.host_batch_ns.sum
+        if fact == "bytes_read":
+            return self.parser.bytes_read
+        read = getattr(
+            self.parser,
+            {"pipeline": "stats", "plan": "snapshot_state"}[fact], None)
+        return read() if callable(read) else None
+
+    def fact(self, name: str):
+        """One of ``_FACTS`` for the pass the consumer is in: as that pass
+        ended where the producer has wound past it (the parser's counters
+        and read plan are the next pass's by then), else live."""
+        with self.lock:
+            return self.ended[0][name] if self.ended else self._live(name)
+
+    def rewind(self) -> bool:
+        """On the producer thread, the pass's last batch staged: keep
+        what the pass ended with and rewind the parser, so that the next
+        pass's first batches are staged while the consumer launches the
+        last steps of this one and the device drains them. False where the
+        parser does not rewind (a one-pass stream): the producer stops,
+        and ``before_first`` raises it where it is asked."""
+        with self.lock:
+            facts = {name: self._live(name) for name in self._FACTS}
+            try:
+                self.parser.before_first()
+            except Exception:  # noqa: BLE001 — see docstring
+                return False
+            self.ended.append(facts)
+        return True
+
+    # ---- re-batch parser blocks into fixed-size slices ------------------
+    def _host_batches(self) -> Iterator:
+        from dmlc_tpu.resilience import faultpoint
+
+        if self.use_native():
+            producer = self._host_batches_native()
+        else:
+            producer = self._host_batches_python()
+        cpu = self.cpu
+        cpu_at = time.thread_time_ns()
+        while True:
+            faultpoint("device.feed")
+            t0 = time.monotonic_ns()
+            try:
+                item = next(producer)
+            except StopIteration:
+                return
+            finally:
+                self.host_batch_ns.observe(time.monotonic_ns() - t0)
+                if cpu is not None:
+                    now = time.thread_time_ns()
+                    cpu.observe(now - cpu_at)
+                    cpu_at = now
+            yield item
+
+    def _host_batches_python(self) -> Iterator:
+        bs = self.spec.batch_size
+        bidx = 0  # per-epoch batch index (audit batch-chain key)
+        pending = RowBlockContainer()
+        # flow ids (and dispatcher chunk seq ids) of parser chunks not yet
+        # represented in an emitted batch; rebatching is N:M, so each
+        # chunk's ids ride the first slice it contributes rows to
+        flows = []
+        seqs = []
+        for block in self.parser:
+            fid = getattr(block, "flow_id", 0)
+            if fid:
+                flows.append(fid)
+            sid = getattr(block, "seq_id", None)
+            if sid is not None:
+                seqs.append(sid)
+            pending.push_block(block)
+            if len(pending) < bs:
+                continue
+            # Finalize once, emit every full slice, keep only the tail.
+            whole = pending.to_block()
+            nfull = len(whole) // bs
+            for k in range(nfull):
+                piece = whole.slice(k * bs, (k + 1) * bs)
+                if flows:
+                    piece.flow_ids = tuple(flows)
+                    flows = []
+                if seqs:
+                    piece.seq_ids = tuple(seqs)
+                    seqs = []
+                self.audit.note_batch(bidx, piece)
+                bidx += 1
+                yield piece
+            pending = RowBlockContainer()
+            if len(whole) > nfull * bs:
+                pending.push_block(whole.slice(nfull * bs, len(whole)))
+        if len(pending) and not self.spec.drop_remainder:
+            tail = pending.to_block()
+            if flows:
+                tail.flow_ids = tuple(flows)
+            if seqs:
+                tail.seq_ids = tuple(seqs)
+                seqs = []
+            self.audit.note_batch(bidx, tail)
+            yield tail
+        if seqs and self.ack is not None:
+            # chunks whose rows only ever reached a dropped remainder (or
+            # an empty chunk) still count as visited — ack them here or
+            # the dispatcher would requeue them forever
+            for sid in seqs:
+                self.ack_seq(sid)
+
+    def _host_batches_native(self) -> Iterator:
+        spec = self.spec
+        bs = spec.batch_size
+        shards = self.shards
+        while True:
+            if spec.layout == "dense":
+                check(spec.num_features > 0,
+                      "dense layout requires num_features")
+                out = self.parser.read_batch_dense(bs, spec.num_features)
+            elif shards > 1:
+                # mesh csr: entries partitioned per shard on the host so
+                # each device receives only its own nnz
+                out = self.parser.read_batch_coo_sharded(
+                    bs, shards, nnz_bucket=spec.nnz_bucket
+                )
+            else:
+                out = self.parser.read_batch_coo(
+                    bs, nnz_bucket=spec.nnz_bucket
+                )
+            if out is None:
+                return
+            rows = out[3] if spec.layout == "dense" else out.num_rows
+            if rows < bs and spec.drop_remainder:
+                return
+            yield out
+
+    def ack_seq(self, sid) -> None:
+        """Report one dispatcher chunk consumed; best-effort — a dead
+        dispatcher must not kill the training loop (the lease deadline
+        covers a lost ack; the duplicate-ack path makes a retried one
+        harmless)."""
+        try:
+            self.ack(sid)
+        except Exception:  # noqa: BLE001 — see docstring
+            pass
+
+
 class DeviceFeed:
     """Iterate device batches from a parser or URI.
 
@@ -428,33 +646,35 @@ class DeviceFeed:
         # off, and then the dispatch path has no byte walk and no timer.
         self._h2d = device_telemetry.h2d_meter(feed=fid)
         device_telemetry.maybe_start_hbm_poller()
-        # determinism audit: batch-stage digests at pool emit, keyed by
-        # per-epoch batch index (obs/audit.py). The shared no-op child
-        # when DMLC_TPU_AUDIT is off.
-        self._audit = audit.auditor()
         self._epoch_base: dict = {}
-        # exactly-once ack emission (dispatcher-mode RemoteBlockParser):
-        # switch the parser to explicit acks BEFORE the producer thread
-        # can issue its first fetch, so prefetched chunks are acked only
-        # when their rows are consumed (or dropped) by this feed
-        self._ack = getattr(self._parser, "ack", None)
-        set_explicit = getattr(self._parser, "set_explicit_ack", None)
-        if callable(self._ack) and callable(set_explicit):
-            set_explicit()
-        else:
-            self._ack = None
+        # restarts, and those of them that found the next pass staged:
+        # the producer had reached the end of its pass and rewound
+        self._m_restarts = reg.counter(
+            "dmlc_feed_restarts_total",
+            "before_first() calls: passes begun after the first", feed=fid)
+        self._m_prewound = reg.counter(
+            "dmlc_feed_prewound_restarts_total",
+            "restarts that found the producer rewound and the next pass's "
+            "first batches staged", feed=fid)
         self._sync_host = host_prefetch <= 0
+        self._host = host = _HostStage(
+            self._parser, spec, self._shards, self._stage["host_batch_ns"],
+            None if self._sync_host else self._m_cpu["feed_producer"])
         if self._sync_host:
             # synchronous host stage: on a 1-core host the prefetch
             # thread cannot overlap anything and only adds context
             # switches (~5% of the recordio->SGD epoch); a real TPU host
             # (many cores) keeps the thread and the overlap
-            self._host_iter = _SyncIter(self._host_batches)
+            self._host_iter = _SyncIter(host._host_batches)
         else:
             self._host_iter = ThreadedIter(
-                self._host_batches, max_capacity=host_prefetch,
-                name="device-feed"
+                host._host_batches, max_capacity=host_prefetch,
+                name="device-feed",
+                rewind=host.rewind if host.rewinds_itself else None,
             )
+
+    def _use_native_batches(self) -> bool:
+        return self._host.use_native()
 
     def _axis_shards(self) -> int:
         """How many shard sections THIS process builds along the batch
@@ -467,121 +687,6 @@ class DeviceFeed:
         from dmlc_tpu.parallel import local_axis_shards
 
         return local_axis_shards(self._mesh, self._axis)
-
-    # ---- host side: re-batch parser blocks into fixed-size slices ------
-    def _use_native_batches(self) -> bool:
-        """Native C++ re-batch + densify/COO-pad (pipeline.cc StageBatch):
-        no RowBlockContainer copies, no numpy scatter — the feed-side answer
-        to the parse-vs-feed throughput cliff (BASELINE.md)."""
-        return (
-            getattr(self._parser, "supports_batch_fetch", False)
-            and self.spec.layout in ("dense", "csr")
-        )
-
-    def _host_batches(self) -> Iterator:
-        from dmlc_tpu.resilience import faultpoint
-
-        if self._use_native_batches():
-            producer = self._host_batches_native()
-        else:
-            producer = self._host_batches_python()
-        # sync mode runs this on the consumer's thread, whose own count
-        # already covers it
-        cpu = None if self._sync_host else self._m_cpu["feed_producer"]
-        cpu_at = time.thread_time_ns()
-        while True:
-            faultpoint("device.feed")
-            t0 = time.monotonic_ns()
-            try:
-                item = next(producer)
-            except StopIteration:
-                return
-            finally:
-                self._stage["host_batch_ns"].observe(
-                    time.monotonic_ns() - t0)
-                if cpu is not None:
-                    now = time.thread_time_ns()
-                    cpu.observe(now - cpu_at)
-                    cpu_at = now
-            yield item
-
-    def _host_batches_python(self) -> Iterator:
-        bs = self.spec.batch_size
-        bidx = 0  # per-epoch batch index (audit batch-chain key)
-        pending = RowBlockContainer()
-        # flow ids (and dispatcher chunk seq ids) of parser chunks not yet
-        # represented in an emitted batch; rebatching is N:M, so each
-        # chunk's ids ride the first slice it contributes rows to
-        flows = []
-        seqs = []
-        for block in self._parser:
-            fid = getattr(block, "flow_id", 0)
-            if fid:
-                flows.append(fid)
-            sid = getattr(block, "seq_id", None)
-            if sid is not None:
-                seqs.append(sid)
-            pending.push_block(block)
-            if len(pending) < bs:
-                continue
-            # Finalize once, emit every full slice, keep only the tail.
-            whole = pending.to_block()
-            nfull = len(whole) // bs
-            for k in range(nfull):
-                piece = whole.slice(k * bs, (k + 1) * bs)
-                if flows:
-                    piece.flow_ids = tuple(flows)
-                    flows = []
-                if seqs:
-                    piece.seq_ids = tuple(seqs)
-                    seqs = []
-                self._audit.note_batch(bidx, piece)
-                bidx += 1
-                yield piece
-            pending = RowBlockContainer()
-            if len(whole) > nfull * bs:
-                pending.push_block(whole.slice(nfull * bs, len(whole)))
-        if len(pending) and not self.spec.drop_remainder:
-            tail = pending.to_block()
-            if flows:
-                tail.flow_ids = tuple(flows)
-            if seqs:
-                tail.seq_ids = tuple(seqs)
-                seqs = []
-            self._audit.note_batch(bidx, tail)
-            yield tail
-        if seqs and self._ack is not None:
-            # chunks whose rows only ever reached a dropped remainder (or
-            # an empty chunk) still count as visited — ack them here or
-            # the dispatcher would requeue them forever
-            for sid in seqs:
-                self._ack_seq(sid)
-
-    def _host_batches_native(self) -> Iterator:
-        spec = self.spec
-        bs = spec.batch_size
-        shards = self._shards
-        while True:
-            if spec.layout == "dense":
-                check(spec.num_features > 0,
-                      "dense layout requires num_features")
-                out = self._parser.read_batch_dense(bs, spec.num_features)
-            elif shards > 1:
-                # mesh csr: entries partitioned per shard on the host so
-                # each device receives only its own nnz
-                out = self._parser.read_batch_coo_sharded(
-                    bs, shards, nnz_bucket=spec.nnz_bucket
-                )
-            else:
-                out = self._parser.read_batch_coo(
-                    bs, nnz_bucket=spec.nnz_bucket
-                )
-            if out is None:
-                return
-            rows = out[3] if spec.layout == "dense" else out.num_rows
-            if rows < bs and spec.drop_remainder:
-                return
-            yield out
 
     # ---- device side ---------------------------------------------------
     def _sharding(self, spec: P) -> Optional[NamedSharding]:
@@ -788,16 +893,6 @@ class DeviceFeed:
         out["num_nonzero"] = batch.num_nonzero
         return out
 
-    def _ack_seq(self, sid) -> None:
-        """Report one dispatcher chunk consumed; best-effort — a dead
-        dispatcher must not kill the training loop (the lease deadline
-        covers a lost ack; the duplicate-ack path makes a retried one
-        harmless)."""
-        try:
-            self._ack(sid)
-        except Exception:  # noqa: BLE001 — see docstring
-            pass
-
     def _deliver(self, entry):
         """Retire a pending batch's staging buffers — guarded by its own
         device arrays, asked NOW, before a donating consumer deletes
@@ -852,12 +947,12 @@ class DeviceFeed:
                     for fid in flows:
                         obs.flow_end(fid, "chunk")
             self._stage["consume_ns"].observe(time.monotonic_ns() - t2)
-            if self._ack is not None:
+            if self._host.ack is not None:
                 # the consumer released the batch: every chunk whose rows
                 # first appeared in it is now consumed — advance the
                 # exactly-once ack frontier
                 for sid in entry[3]:
-                    self._ack_seq(sid)
+                    self._host.ack_seq(sid)
             # this thread's CPU time since the last delivery: dispatches,
             # the consumer's loop body, the step's launch
             now = time.thread_time_ns()
@@ -921,18 +1016,33 @@ class DeviceFeed:
             "pool": self.pool.stats(),
         }
         for key, hist in self._stage.items():
-            out[key] = int(hist.sum - base.get(key, 0))
-        parser_stats = getattr(self._parser, "stats", None)
-        if callable(parser_stats):
-            pipeline = parser_stats()
-            if pipeline:
-                out["pipeline"] = pipeline
+            # the producer's side of a pass that has ended reads as it
+            # ended until before_first(), though it is staging the next
+            total = (self._host.fact(key) if key == "host_batch_ns"
+                     else hist.sum)
+            out[key] = int(total - base.get(key, 0))
+        pipeline = self._host.fact("pipeline")
+        if pipeline:
+            out["pipeline"] = pipeline
         return out
 
     def before_first(self) -> None:
         with obs.span("feed_restart", pass_=self._pass + 1):
-            self._host_iter.close()
-            self._parser.before_first()
+            self._m_restarts.inc()
+            host = self._host
+            prewound = self._host_iter.advance()
+            if prewound:
+                # the producer reached the end of the pass, rewound the
+                # parser on its own thread and has this pass's first
+                # batches staged: only the bookkeeping is left
+                self._m_prewound.inc()
+                with host.lock:
+                    host_batch_base = host.ended.popleft()["host_batch_ns"]
+            else:
+                # mid-pass, or a rewind that is not the producer's to
+                # make: the producer is stopped; rewind and start it again
+                self._parser.before_first()
+                host_batch_base = host.host_batch_ns.sum
             # registry metrics are monotonic (Prometheus semantics);
             # stats() windows them against this baseline so it always
             # describes the current epoch, aligned with the native
@@ -940,15 +1050,37 @@ class DeviceFeed:
             self._epoch_base = {
                 key: hist.sum for key, hist in self._stage.items()
             }
+            self._epoch_base["host_batch_ns"] = host_batch_base
             self._epoch_base["batches"] = self._m_batches.value
-            # the producer is stopped: the next pass's spans, its
-            # thread's included, read the new number
+            # the consumer's spans read the new number from here on; a
+            # producer that staged ahead emits none that carry it
             self._pass += 1
-            self._host_iter.before_first()
+            if not prewound:
+                self._host_iter.before_first()
 
     @property
     def bytes_read(self) -> int:
-        return self._parser.bytes_read
+        return self._host.fact("bytes_read")
+
+    # ---- job-snapshot state ---------------------------------------------
+    def snapshot_state(self) -> Optional[dict]:
+        """The parser's resumable read plan (None where it has none) at
+        the boundary of the pass the consumer is in: where the producer
+        has wound the parser past it, the plan it kept at that pass's
+        end."""
+        return self._host.fact("plan")
+
+    def restore_state(self, plan: dict) -> None:
+        """Put the parser at the boundary a job snapshot captured. The
+        producer has been staging the plan the feed was built with: it is
+        stopped first and started again over the restored one."""
+        restore = getattr(self._parser, "restore_state", None)
+        if not callable(restore):
+            return
+        self._host_iter.close()
+        self._host.ended.clear()
+        restore(plan)
+        self._host_iter.before_first()
 
     def close(self) -> None:
         self._host_iter.close()

@@ -47,6 +47,7 @@ child, so the hot path stays allocation-free.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 import warnings
@@ -343,9 +344,11 @@ def _snapshot_state(learner, feed, epoch: int, history) -> Dict:
         "rng": None,  # neither SGD path draws step-time randomness
         "audit": audit.auditor().export_state(),
     }
-    parser = getattr(feed, "_parser", None)
-    if hasattr(parser, "snapshot_state"):
-        state["data"] = {"parser": parser.snapshot_state()}
+    # through the feed: its producer may have wound the parser to the
+    # next epoch already, and the feed keeps the boundary's plan
+    plan = feed.snapshot_state()
+    if plan is not None:
+        state["data"] = {"parser": plan}
     return state
 
 
@@ -465,30 +468,32 @@ def fit_uri(learner, uri: str, *, batch_size: int = 4096,
                   num_features=num_features, drop_remainder=drop_remainder),
         mesh=learner.mesh,
     )
-    if snapshot_uri is None:
-        check(not resume, "resume=True requires snapshot_uri")
-        return learner.fit_feed(feed, epochs=epochs, log_every=log_every)
-    snap = JobSnapshot(snapshot_uri, rank=collective.rank(),
-                       world_size=collective.world_size())
-    start_epoch = 0
-    history = None
-    snapshotter = Snapshotter(snap, every_epochs=snap_every_epochs)
-    try:
-        if resume:
-            version, state, _meta = load_snapshot(snap)
-            if version and state is not None:
-                learner.restore_snapshot_model(state["model"])
-                start_epoch = int(state.get("epoch", -1)) + 1
-                history = list(state.get("history", ()))
-                pst = (state.get("data") or {}).get("parser")
-                parser = getattr(feed, "_parser", None)
-                if pst and hasattr(parser, "restore_state"):
-                    parser.restore_state(pst)
-                snapshotter.mark_restored(start_epoch - 1)
-        return learner.fit_feed(
-            feed, epochs=epochs, log_every=log_every,
-            snapshotter=snapshotter, start_epoch=start_epoch,
-            history=history,
-        )
-    finally:
-        snapshotter.close()
+    # closed on the way out: the feed's producer has by then staged the
+    # start of a pass nobody will ask for
+    with contextlib.closing(feed):
+        if snapshot_uri is None:
+            check(not resume, "resume=True requires snapshot_uri")
+            return learner.fit_feed(feed, epochs=epochs, log_every=log_every)
+        snap = JobSnapshot(snapshot_uri, rank=collective.rank(),
+                           world_size=collective.world_size())
+        start_epoch = 0
+        history = None
+        snapshotter = Snapshotter(snap, every_epochs=snap_every_epochs)
+        try:
+            if resume:
+                version, state, _meta = load_snapshot(snap)
+                if version and state is not None:
+                    learner.restore_snapshot_model(state["model"])
+                    start_epoch = int(state.get("epoch", -1)) + 1
+                    history = list(state.get("history", ()))
+                    pst = (state.get("data") or {}).get("parser")
+                    if pst:
+                        feed.restore_state(pst)
+                    snapshotter.mark_restored(start_epoch - 1)
+            return learner.fit_feed(
+                feed, epochs=epochs, log_every=log_every,
+                snapshotter=snapshotter, start_epoch=start_epoch,
+                history=history,
+            )
+        finally:
+            snapshotter.close()

@@ -98,6 +98,44 @@ class TestOneLoopForEveryLearner:
             np.testing.assert_array_equal(
                 np.asarray(clean.params[key]), np.asarray(resumed.params[key]))
 
+    @pytest.mark.parametrize("source", ["text", "dtsh-shuffled"])
+    @pytest.mark.parametrize("polls", [2 * STEPS, 2 * STEPS + 1])
+    def test_kill_at_an_epoch_boundary_resumes_bit_identical(
+            self, train_file, tmp_path, model, source, polls):
+        """The notice falls on the last step of epoch 1 or the first of
+        epoch 2: the feed's producer had by then wound the parser on to
+        the next epoch, and the snapshot of the boundary must still hold
+        the boundary's read plan (the shuffle's epoch), not the parser's
+        of the moment."""
+        uri = train_file
+        if source == "dtsh-shuffled":
+            from dmlc_tpu.tools.bake import bake_dataset
+
+            baked = str(tmp_path / "fit.dtsh")
+            bake_dataset(train_file, baked, data_format="libsvm",
+                         rows_per_window=BATCH)
+            uri = baked + "?shuffle_chunks=7"
+        clean, clean_history = _fit(model, uri, EPOCHS)
+        snap_uri = str(tmp_path / "snap")
+        resilience.configure("preempt.notice:nth=%d" % polls)
+        try:
+            with pytest.raises(Preempted):
+                _fit(model, uri, EPOCHS, snapshot_uri=snap_uri)
+        finally:
+            resilience.reset()
+            preempt.reset()
+        _version, state, meta = JobSnapshot(snap_uri).restore()
+        # a pass cut at its last step is a partial pass: never committed
+        assert meta["epoch"] == (0 if polls == 2 * STEPS else 1)
+        if source == "dtsh-shuffled":
+            assert state["data"]["parser"]["epoch"] == meta["epoch"]
+        resumed, history = _fit(model, uri, EPOCHS,
+                                snapshot_uri=snap_uri, resume=True)
+        assert history == clean_history
+        for key in clean.params:
+            np.testing.assert_array_equal(
+                np.asarray(clean.params[key]), np.asarray(resumed.params[key]))
+
     def test_spans_and_counters_of_two_epochs(self, train_file, model):
         def counters():
             flat = obs.registry().flat_values()

@@ -6,7 +6,9 @@ directly) so the contracts hold even where the native C++ pipeline would
 normally win the create_parser routing.
 """
 
+import gc
 import threading
+import time
 
 import jax
 import numpy as np
@@ -22,6 +24,7 @@ from dmlc_tpu.device.feed import (
 )
 from dmlc_tpu.io.input_split import create_input_split
 from dmlc_tpu.io.readahead import OrderedWindow
+from dmlc_tpu.obs import trace as obs_trace
 from dmlc_tpu.params.knobs import (
     default_host_prefetch,
     default_nthread,
@@ -409,6 +412,251 @@ class TestFixedShapePool:
                         if r["kind"] == "pool.leak"]
         finally:
             flight.reset()
+
+
+def _ids_of(seen, names=("feed_batch", "dispatch", "consume", "stage")):
+    """{span name: [(pass_, batch), ...]} in the order the spans closed."""
+    out = {}
+    for e in seen:
+        if e.get("ph") == "X" and e["name"] in names:
+            args = e["args"]
+            out.setdefault(e["name"], []).append(
+                (args["pass_"], args["batch"]))
+    return out
+
+
+def _restart_counts(feed):
+    """(restarts, pre-wound restarts) this feed has counted."""
+    return int(feed._m_restarts.value), int(feed._m_prewound.value)
+
+
+def _feed_threads():
+    return [t for t in threading.enumerate() if t.name == "device-feed"]
+
+
+class _Wrapped:
+    """A parser seen through a wrapper a test can hang facts on."""
+
+    def __init__(self, base):
+        self._base = base
+        self.rewound_on = []  # thread idents, one a before_first()
+
+    def __iter__(self):
+        return iter(self._base)
+
+    def before_first(self):
+        self.rewound_on.append(threading.get_ident())
+        self._base.before_first()
+
+    @property
+    def bytes_read(self):
+        return self._base.bytes_read
+
+    def close(self):
+        self._base.close()
+
+
+class _Acked(_Wrapped):
+    """The dispatcher parser's surface: explicit acks."""
+
+    def set_explicit_ack(self):
+        pass
+
+    def ack(self, seq):
+        pass
+
+
+class _OnePass(_Wrapped):
+    """A stream that cannot rewind (RemoteBlockParser's answer)."""
+
+    def before_first(self):
+        self.rewound_on.append(threading.get_ident())
+        raise DMLCError("a one-pass stream")
+
+
+@pytest.fixture(params=["text", "dtsh", "mesh"])
+def source(request, tmp_path):
+    """make_feed(host_prefetch) over the same rows from native text (the
+    native stager), a baked ``.dtsh`` shard (the Python re-batch path) or
+    text staged per chip for a mesh (``read_batch_coo_sharded``)."""
+    from dmlc_tpu import native
+    from dmlc_tpu.data import create_parser
+    from dmlc_tpu.parallel import data_parallel_mesh
+    from dmlc_tpu.tools.bake import bake_dataset
+
+    kind = request.param
+    if kind != "dtsh" and not native.available():
+        pytest.skip("native library not built")
+    uri = _write_svm(tmp_path / "rewind.svm")
+    mesh = None
+    if kind == "dtsh":
+        uri = str(tmp_path / "rewind.dtsh")
+        bake_dataset(str(tmp_path / "rewind.svm"), uri, data_format="libsvm",
+                     rows_per_window=100)
+    elif kind == "mesh":
+        mesh = data_parallel_mesh(jax.devices()[:4])
+    spec = BatchSpec(batch_size=64, layout="csr", num_features=40,
+                     nnz_bucket=512)
+
+    def make_feed(host_prefetch=2, parser=None):
+        return DeviceFeed(parser or create_parser(uri, 0, 1), spec,
+                          mesh=mesh, host_prefetch=host_prefetch)
+
+    make_feed.uri = uri
+    return make_feed
+
+
+class TestProducerRewindsItself:
+    """A feed whose producer has reached the end of a pass rewinds the
+    parser on its own thread and stages the next pass; ``before_first``
+    then has only its bookkeeping to do. ``host_prefetch=0`` has no thread
+    to work ahead, so it is the feed restarted the old way."""
+
+    BATCHES = -(-ROWS // 64)
+
+    def _passes(self, feed, n):
+        seen, passes = [], []
+        obs_trace.add_listener(seen.append)
+        try:
+            for k in range(n):
+                if k:
+                    feed.before_first()
+                passes.append(_collect(feed))
+        finally:
+            obs_trace.remove_listener(seen.append)
+        return passes, _ids_of(seen)
+
+    @pytest.mark.parametrize("passes", [2, 3])
+    def test_passes_equal_those_of_a_feed_restarted_the_old_way(
+            self, source, passes):
+        old = source(host_prefetch=0)
+        want, want_ids = self._passes(old, passes)
+        assert _restart_counts(old) == (passes - 1, 0)
+        old.close()
+        feed = source()
+        got, got_ids = self._passes(feed, passes)
+        # the end mark follows the rewind, so every restart after a whole
+        # pass found the next one staged
+        assert _restart_counts(feed) == (passes - 1, passes - 1)
+        feed.close()
+        assert len(got[0]) == self.BATCHES and got == want
+        assert got_ids == want_ids
+        assert got_ids["consume"] == [
+            (p, b) for p in range(passes) for b in range(self.BATCHES)]
+
+    def test_restart_in_mid_pass_takes_the_old_path(self, source):
+        old = source(host_prefetch=0)
+        want = _collect(old)
+        old.close()
+        feed = source()
+        it = iter(feed)
+        head = [next(it) for _ in range(3)]
+        it.close()
+        assert len(head) == 3
+        feed.before_first()
+        assert _restart_counts(feed) == (1, 0)
+        assert _collect(feed) == want
+        feed.before_first()  # this one after a whole pass
+        assert _restart_counts(feed) == (2, 1)
+        assert _collect(feed) == want
+        feed.close()
+
+    def test_stats_are_the_ended_passes_until_before_first(self, source):
+        old = source(host_prefetch=0)
+        _collect(old)
+        want_bytes = old.bytes_read
+        want_pipe = old.stats().get("pipeline") or {}
+        old.close()
+        feed = source()
+        _collect(feed)
+        deadline = time.monotonic() + 30
+        while not feed._host_iter._queue.full() and \
+                time.monotonic() < deadline:
+            time.sleep(0.001)
+        # the producer has read on into the next pass; the feed says what
+        # the pass that ended read
+        assert feed._host_iter._queue.full()
+        assert feed._parser.bytes_read > want_bytes
+        assert feed.bytes_read == want_bytes
+        pipe = feed.stats().get("pipeline") or {}
+        counts = [k for k, v in want_pipe.items()
+                  if isinstance(v, int) and not k.endswith("_ns")]
+        assert counts and {k: pipe[k] for k in counts} == {
+            k: want_pipe[k] for k in counts}
+        assert feed.stats()["batches"] == self.BATCHES
+        ended_host_ns = feed.stats()["host_batch_ns"]
+        time.sleep(0.01)
+        assert feed.stats()["host_batch_ns"] == ended_host_ns
+        feed.before_first()
+        assert feed.stats()["batches"] == 0
+        assert feed.bytes_read > want_bytes
+        _collect(feed)
+        assert feed.bytes_read == 2 * want_bytes
+        feed.close()
+
+    def test_close_after_a_staged_pass_leaves_nothing(self, source):
+        feed = source()
+        # on the cpu backend the pool only counts shapes; recycle as an
+        # accelerator's does (each batch is copied while it is held)
+        feed.pool = FixedShapePool(recycle=True)
+        assert len(_collect(feed)) == self.BATCHES
+        thread = feed._host_iter._thread
+        assert thread.is_alive()  # parked on the pass it staged
+        feed.close()
+        assert not thread.is_alive() and feed._host_iter._thread is None
+        assert feed.pool.outstanding == 0
+
+    def test_a_dropped_feed_is_collected_and_stops_its_thread(self, source):
+        before = set(_feed_threads())
+        feed = source()
+        _collect(feed)
+        mine = [t for t in _feed_threads() if t not in before]
+        assert len(mine) == 1 and mine[0].is_alive()
+        del feed
+        gc.collect()
+        mine[0].join(timeout=30)
+        assert not mine[0].is_alive()
+
+    @pytest.mark.parametrize("why", ["ack", "audit", "sync"])
+    def test_never_rewinds_where_it_is_not_the_feeds_to_decide(
+            self, source, why, monkeypatch):
+        from dmlc_tpu.data import create_parser
+        from dmlc_tpu.obs import audit
+
+        if why == "audit":
+            monkeypatch.setenv("DMLC_TPU_AUDIT", "1")
+            audit.reset_auditor()
+        try:
+            parser = None
+            if why == "ack":
+                parser = _Acked(create_parser(source.uri, 0, 1))
+            feed = source(host_prefetch=0 if why == "sync" else 2,
+                          parser=parser)
+            first = _collect(feed)
+            feed.before_first()
+            assert _collect(feed) == first
+            assert _restart_counts(feed) == (1, 0)
+            if parser is not None:  # rewound once, by the consumer
+                assert parser.rewound_on == [threading.get_ident()]
+            feed.close()
+        finally:
+            if why == "audit":
+                monkeypatch.delenv("DMLC_TPU_AUDIT")
+                audit.reset_auditor()
+
+    def test_a_one_pass_stream_raises_where_it_is_asked(self, source):
+        from dmlc_tpu.data import create_parser
+
+        parser = _OnePass(create_parser(source.uri, 0, 1))
+        feed = source(parser=parser)
+        assert len(_collect(feed)) == self.BATCHES
+        with pytest.raises(DMLCError, match="one-pass"):
+            feed.before_first()
+        # asked once by the producer, which stopped, and once here
+        assert len(parser.rewound_on) == 2
+        assert parser.rewound_on[1] == threading.get_ident()
+        assert _restart_counts(feed) == (1, 0)
+        feed.close()
 
 
 class TestKnobs:
