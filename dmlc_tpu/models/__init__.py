@@ -9,6 +9,9 @@ so this package ships that learner family TPU-natively:
   batches, data-parallel psum gradient sync over a mesh axis
 - ``fm``: factorization machines (the libfm format's model family), embedding
   table sharded or replicated, same segment-sum sparse kernels
+- ``ffm``: field-aware factorization machines (libffm's model and
+  AdaGrad), an entry's field taken from its id's range; the FM's step
+  head, chunk loops and stateful-update skeleton at another width
 - ``gbdt``: histogram gradient-boosted trees — the xgboost-over-rabit
   workload the reference backbone was built for, with per-level histogram
   psum standing in for rabit's allreduce
@@ -44,6 +47,13 @@ from dmlc_tpu.models.fm import (
     init_fm_params,
     make_fm_train_step,
 )
+from dmlc_tpu.models.ffm import (
+    FFM_FACTOR_PARTITION_RULES,
+    FFMParam,
+    FFMLearner,
+    init_ffm_params,
+    make_ffm_train_step,
+)
 from dmlc_tpu.models.gbdt import (
     GBDTLearner,
     GBDTParam,
@@ -74,6 +84,11 @@ __all__ = [
     "FtrlAdagrad",
     "init_fm_params",
     "make_fm_train_step",
+    "FFM_FACTOR_PARTITION_RULES",
+    "FFMParam",
+    "FFMLearner",
+    "init_ffm_params",
+    "make_ffm_train_step",
     "GBDTLearner",
     "GBDTParam",
     "apply_bins",

@@ -1,11 +1,17 @@
 """Factorization machines over COO device batches.
 
-The libfm format the reference parses (libfm_parser.h) exists to feed this
-model family; the reference ships the parser and leaves the model downstream.
-TPU-first formulation: all per-entry work is gathers + segment_sums (static
-shapes), and the O(nnz·K) factor math is batched so XLA can keep it on the
-vector units. The step opens with its one sort, of the batch's entries by
-feature id, and everything after it runs in that order: ``v`` and ``w``
+The reference ships a libfm parser (libfm_parser.h, ``label
+field:idx:val``) and leaves the models downstream. This model reads no
+field: it trains from any CSR batch (LIBSVM, libfm with the field column
+ignored, shards). The field-aware model that format exists for is
+models/ffm.py, which shares this module's step head, chunk loops and
+stateful-update skeleton and takes an entry's field from its id's range
+(``FFMParam.field_sizes``): the feed carries no field column to the
+device. TPU-first formulation: all per-entry work is gathers +
+segment_sums (static shapes), and the O(nnz·K) factor math is batched so
+XLA can keep it on the vector units. The step opens with its one sort,
+of the batch's entries by feature id, and everything after it runs in
+that order: ``v`` and ``w``
 are read at the batch's DISTINCT ids only (ids repeat within a batch,
 about 26 k distinct of 90,112 entries under kdd2012's power law) and the
 entries take their rows from that few-MB buffer; passes over the entries
@@ -240,20 +246,20 @@ def _take_distinct(tables, order: _IdOrder):
     return lax.fori_loop(0, order.chunks, take_chunk, rows)
 
 
-def _gather_rows(params, order: _IdOrder):
-    """``[v_e | w_e]`` (``[nnz, K + 1]``) for every entry, in id order,
-    with each touched row of the parameters read ONCE: ``rows =
-    [v[ids] | w[ids]]`` at the distinct ids (:func:`_take_distinct`),
-    then one batch-sized gather ``rows[slot]`` whose source is a few MB
-    (a tenth of a gather from the table's cost on the chip). Returns
-    (rows, the entries' rows).
+def _gather_rows(tables, order: _IdOrder):
+    """The entries' rows of ``tables`` side by side (the FM's ``[v_e |
+    w_e]``, ``[nnz, K + 1]``), in id order, with each touched row of the
+    parameters read ONCE: ``rows = [v[ids] | w[ids]]`` at the distinct
+    ids (:func:`_take_distinct`), then one batch-sized gather
+    ``rows[slot]`` whose source is a few MB (a tenth of a gather from the
+    table's cost on the chip). Returns (rows, the entries' rows).
 
     A gather from the table costs per index (22 ns a row of 16 columns,
     37 ns of 32, 16 ns an element of ``w``: PERF.md, PR 31) and nothing
     for being repeated, so the popular ids of a power law are most of a
     per-entry gather's cost; a batch with no repeated id gathers what a
     per-entry gather would."""
-    rows = _take_distinct((params["v"], params["w"]), order)
+    rows = _take_distinct(tables, order)
     return rows, jnp.take(rows, order.slot, axis=0)
 
 
@@ -275,12 +281,13 @@ def _row_sums(vw, row_ids, values, num_rows: int):
     return xv, sums[:, :k], sums[:, k:2 * k], sums[:, 2 * k]
 
 
-def _entries_in_id_order(params, batch):
-    """``step.order`` and ``step.gather``, the head of every FM program:
-    the batch's entries sorted by feature id (:func:`_in_id_order`) and
-    ``[v_e | w_e]`` for each (:func:`_gather_rows`). Returns (order, rows,
-    vw, row_ids, values): ``rows`` the distinct ids' ``[v | w]``, the last
-    three per entry in id order."""
+def _entries_in_id_order(tables, batch):
+    """``step.order`` and ``step.gather``, the head of every FM and FFM
+    program: the batch's entries sorted by feature id
+    (:func:`_in_id_order`) and the rows of ``tables`` (the FM's ``(v,
+    w)``) side by side for each (:func:`_gather_rows`). Returns (order,
+    rows, vw, row_ids, values): ``rows`` the distinct ids' ``[v | w]``,
+    the last three per entry in id order."""
     values = batch["values"]
     with jax.named_scope("step.gather"):
         # offsets → row ids on device (local per shard under shard_map)
@@ -288,9 +295,9 @@ def _entries_in_id_order(params, batch):
             expand_row_ids(batch["offsets"], values.shape[0])
     with jax.named_scope("step.order"):
         order, row_ids, values = _in_id_order(
-            batch["indices"], row_ids, values, params["w"].shape[0])
+            batch["indices"], row_ids, values, tables[0].shape[0])
     with jax.named_scope("step.gather"):
-        rows, vw = _gather_rows(params, order)
+        rows, vw = _gather_rows(tables, order)
     return order, rows, vw, row_ids, values
 
 
@@ -322,7 +329,8 @@ def _fm_entry_grads(params, batch, objective: str,
     profile can be read by phase; they change no operation."""
     label = batch["label"]
     weight = batch["weight"]
-    order, rows, vw, row_ids, values = _entries_in_id_order(params, batch)
+    order, rows, vw, row_ids, values = _entries_in_id_order(
+        (params["v"], params["w"]), batch)
     xv, s, q, linear = _row_sums(vw, row_ids, values, label.shape[0])
     with jax.named_scope("step.forward"):
         interaction = 0.5 * jnp.sum(s * s - q, axis=-1)
@@ -383,13 +391,14 @@ def _scatter_add_rows(w, v, order: _IdOrder, upd):
     return w, lax.fori_loop(0, order.chunks, add_chunk, v)
 
 
-def _check_rule_placement(stateful: bool, mesh: Optional[Mesh],
+def _check_rule_placement(optimizer: str, mesh: Optional[Mesh],
                           table_sharding: str) -> None:
-    check(not stateful or mesh is None or table_sharding == "factors",
-          "optimizer='ftrl_adagrad' keeps state for every parameter row "
+    """Every rule but ``"sgd"`` keeps state for every parameter row."""
+    check(optimizer == "sgd" or mesh is None or table_sharding == "factors",
+          "optimizer=%r keeps state for every parameter row "
           "and updates the rows a batch names; the replicated mesh step "
           "applies a dense psummed gradient and has no such path: train "
-          "on one device or with table_sharding='factors'")
+          "on one device or with table_sharding='factors'", optimizer)
 
 
 def _gather_sections(batch, axis: str):
@@ -475,24 +484,95 @@ def _set_rows(table, order: _IdOrder, new):
     return lax.fori_loop(0, order.chunks, set_chunk, table)
 
 
-def _ftrl_adagrad(old, grad_w, grad_v, alpha: float, l2: float,
-                  rule: FtrlAdagrad):
+def _split_columns(buffer, like):
+    """{name: its columns of ``buffer``} for the tables of ``like`` (name
+    -> an array of the table's rank) side by side in that order: a 1-D
+    table is one column and comes back 1-D."""
+    out, at = {}, 0
+    for name, table in like.items():
+        if table.ndim == 1:
+            out[name] = buffer[:, at]
+            at += 1
+        else:
+            out[name] = buffer[:, at:at + table.shape[1]]
+            at += table.shape[1]
+    return out
+
+
+def _update_at_distinct(params, order: _IdOrder, grads, seen, wsum, state,
+                        rule):
+    """The skeleton of an update by a rule that keeps state for every
+    parameter row, written once for the rules of this module and of
+    models/ffm.py. ``grads``: {weight table: the entries' contributions
+    to its gradient, ``[n]`` or ``[n, C]`` in ``order``'s order}, the
+    tables in the column order of the head's read; ``seen`` = (the
+    distinct ids' weights as the head read them, the entries' values);
+    ``state``: the names of the tables of ``params`` the rule keeps beside
+    the weights; ``rule(old, grad) -> new``: dicts by table name over the
+    distinct ids' buffers (``grad`` the mean gradients of the weight
+    tables, ``new`` every table's rows, weights and state). Returns (the
+    new tables, ``max(wsum, 1e-12)``).
+
+    The rule is not a scaled sum of the entries: an id's gradient is
+    summed first (one ``segment_sum`` by sorted slot, as the SGD step's),
+    whole BEFORE the rule runs; the id's state is read ONCE
+    (:func:`_take_distinct`); weights and state are SET at the distinct
+    ids (:func:`_set_rows`), so no array of a table's shape exists
+    besides the tables and a row no entry names is neither read nor
+    written. A slot whose entries all have value 0 (padding names feature
+    0) keeps its weights and its state to the bit, whatever the rule.
+
+    ``step.state`` holds what the rule adds to the SGD step: the state
+    rows' read, the rule, the state rows' write. The weights' writes and
+    the id sums stay under ``step.update``."""
+    rows, values = seen
+    n = values.shape[0]
+    with jax.named_scope("step.update"):
+        denom = jnp.maximum(wsum, 1e-12)
+        # the last column counts an id's entries that carry a value
+        sums = jax.ops.segment_sum(
+            jnp.concatenate(
+                [g if g.ndim == 2 else g[:, None] for g in grads.values()]
+                + [(values != 0).astype(rows.dtype)[:, None]], axis=1),
+            order.slot, num_segments=n, indices_are_sorted=True)
+        sums = jnp.pad(sums, ((0, order.ids.shape[0] - n), (0, 0)))
+        grad = {name: g / denom
+                for name, g in _split_columns(sums, grads).items()}
+        live = sums[:, -1] > 0
+        old = _split_columns(rows, grads)
+    with jax.named_scope("step.state"):
+        tables = {name: params[name] for name in state}
+        old.update(_split_columns(
+            _take_distinct(tuple(tables.values()), order), tables))
+        new = {name: jnp.where(live if rows_.ndim == 1 else live[:, None],
+                               rows_, old[name])
+               for name, rows_ in rule(old, grad).items()}
+        out = {name: _set_rows(params[name], order, new[name])
+               for name in state}
+    with jax.named_scope("step.update"):
+        out.update({name: _set_rows(params[name], order, new[name])
+                    for name in grads})
+    return out, denom
+
+
+def _ftrl_adagrad(old, grad, alpha: float, l2: float, rule: FtrlAdagrad):
     """The rule of :func:`_stateful_update` alone, elementwise over the
-    distinct ids' buffers: ``old`` = (w, z, n ``[slots]``, v, a ``[slots,
-    K]``) and an id's mean gradients give the five new values."""
-    old_w, old_z, old_n, old_v, old_a = old
-    new_n = old_n + grad_w * grad_w
+    distinct ids' buffers: ``old`` = {w, z, n ``[slots]``, v, a ``[slots,
+    K]``} and an id's mean gradients ``grad`` = {w, v} give the five new
+    values."""
+    new_n = old["n"] + grad["w"] * grad["w"]
     root = jnp.sqrt(new_n)
-    new_z = old_z + grad_w - (root - jnp.sqrt(old_n)) / alpha * old_w
+    new_z = old["z"] + grad["w"] - (
+        root - jnp.sqrt(old["n"])) / alpha * old["w"]
     new_w = jnp.where(
         jnp.abs(new_z) <= rule.l1, 0.0,
         -(new_z - jnp.sign(new_z) * rule.l1)
         / ((rule.lr_beta + root) / alpha + l2))
-    grad_v = grad_v + rule.v_l2 * old_v
-    new_a = old_a + grad_v * grad_v
-    new_v = old_v - rule.v_learning_rate * grad_v / (
+    grad_v = grad["v"] + rule.v_l2 * old["v"]
+    new_a = old["a"] + grad_v * grad_v
+    new_v = old["v"] - rule.v_learning_rate * grad_v / (
         rule.v_lr_beta + jnp.sqrt(new_a))
-    return new_w, new_z, new_n, new_v, new_a
+    return {"w": new_w, "z": new_z, "n": new_n, "v": new_v, "a": new_a}
 
 
 def _stateful_update(params, order: _IdOrder, grads, seen,
@@ -503,7 +583,7 @@ def _stateful_update(params, order: _IdOrder, grads, seen,
     distinct ids' ``[v | w]`` the head read, the entries' values). Per
     distinct id i of the batch, with g the batch's mean gradient of w_i
     and G that of v_i (the sums over the entries that name i, over
-    ``weight_sum``), whole BEFORE the rule runs:
+    ``weight_sum``):
 
     w, FTRL-proximal (McMahan et al., KDD 2013, algorithm 1; state z, n;
     alpha = ``learning_rate``, beta = ``lr_beta``)::
@@ -517,51 +597,64 @@ def _stateful_update(params, order: _IdOrder, grads, seen,
         G = G + v_l2 * v;  a' = a + G^2
         v' = v - v_learning_rate * G / (v_lr_beta + sqrt(a'))
 
-    b by SGD at ``learning_rate``. The rule is not a scaled sum of the
-    entries: an id's gradient is summed first (one ``segment_sum`` by
-    sorted slot, as the SGD step's), the id's state is read ONCE
-    (:func:`_take_distinct`), and weights and state are SET at the
-    distinct ids (:func:`_set_rows`): an id under the L1 threshold holds
-    an exact 0, no array of a table's shape exists besides the tables,
-    and a row no entry names is neither read nor written. A slot whose
-    entries all have value 0 (padding names feature 0) keeps its weights
-    and its state to the bit, whatever ``v_l2``.
-
-    ``step.state`` holds what the rule adds to the SGD step: the state
-    rows' read, the rule, the state rows' write. ``w``'s and ``v``'s
-    writes and the id sums stay under ``step.update``."""
+    b by SGD at ``learning_rate``. How the sums, the state's read and the
+    SETs are laid out is :func:`_update_at_distinct`'s: an id under the
+    L1 threshold holds an exact 0, and a slot whose entries all have
+    value 0 keeps weights and state to the bit, whatever ``v_l2``."""
     dw, gb, dv, wsum = grads
-    rows, values = seen
-    n, k = dv.shape
+    new, denom = _update_at_distinct(
+        params, order, {"v": dv, "w": dw}, seen, wsum, ("a", "z", "n"),
+        partial(_ftrl_adagrad, alpha=learning_rate, l2=l2, rule=rule))
     with jax.named_scope("step.update"):
-        denom = jnp.maximum(wsum, 1e-12)
-        # the last column counts an id's entries that carry a value
-        sums = jax.ops.segment_sum(
-            jnp.concatenate(
-                [dv, dw[:, None], (values != 0).astype(dv.dtype)[:, None]],
-                axis=1),
-            order.slot, num_segments=n, indices_are_sorted=True)
-        sums = jnp.pad(sums, ((0, order.ids.shape[0] - n), (0, 0)))
-        grad_v, grad_w = sums[:, :k] / denom, sums[:, k] / denom
-        live = sums[:, k + 1] > 0
-        old_v, old_w = rows[:, :k], rows[:, k]
-    with jax.named_scope("step.state"):
-        state = _take_distinct(
-            (params["a"], params["z"], params["n"]), order)
-        old = (old_w, state[:, k], state[:, k + 1], old_v, state[:, :k])
-        new = _ftrl_adagrad(old, grad_w, grad_v, learning_rate, l2, rule)
-        new_w, new_z, new_n, new_v, new_a = (
-            jnp.where(live if o.ndim == 1 else live[:, None], n_, o)
-            for n_, o in zip(new, old))
-        state = {"a": _set_rows(params["a"], order, new_a),
-                 "z": _set_rows(params["z"], order, new_z),
-                 "n": _set_rows(params["n"], order, new_n)}
-    with jax.named_scope("step.update"):
-        return dict(
-            state,
-            w=_set_rows(params["w"], order, new_w),
-            b=params["b"] - learning_rate * (gb / denom),
-            v=_set_rows(params["v"], order, new_v))
+        return dict(new, b=params["b"] - learning_rate * (gb / denom))
+
+
+def _batch_specs(axis: str):
+    """How a mesh step takes the feed's batch: entries arrive SHARDED
+    (ShardedCSRBatch: per-shard sections, local row ids), each device
+    holds only its own nnz; no global mask."""
+    return {k: P(axis)
+            for k in ("label", "weight", "indices", "values", "offsets")}
+
+
+def _make_sparse_step(local, name: str, mesh: Optional[Mesh], axis: str,
+                      param_specs, donate_batch: bool):
+    """The two programs whose update touches only the rows a batch names,
+    for a step ``local(params, batch, factor_axis) -> (params, metrics)``
+    (the FM's, models/ffm.py's), jitted as ``name``.
+
+    ``mesh is None``: ``local`` on the one device; ``donate_batch``
+    donates params AND the batch arrays. On a mesh (the table's factors
+    sharded by ``param_specs``): every chip gathers the step's whole
+    batch (:func:`_gather_sections`, under ``step.exchange``) and runs
+    ``local`` on its own columns with ``factor_axis=axis``."""
+    if mesh is None:
+
+        def step(params, batch):
+            return local(params, batch, None)
+
+        fn = instrumented_jit(
+            step, name,
+            donate_argnums=(0, 1) if donate_batch else (),
+        )
+        return suppress_donation_warnings(fn) if donate_batch else fn
+
+    def _factor_sharded(params, batch):
+        with jax.named_scope("step.exchange"):
+            whole = _gather_sections(batch, axis)
+        return local(params, whole, axis)
+
+    # every chip computes its replicas (the FM's w and b) and the loss
+    # sums from the gathered batch, which shard_map types as varying: the
+    # replicas are equal by construction, not by a collective it could
+    # check
+    step = shard_map(
+        _factor_sharded, mesh=mesh,
+        in_specs=(param_specs, _batch_specs(axis)),
+        out_specs=(param_specs, P()),
+        check_vma=False,
+    )
+    return instrumented_jit(step, name, donate_argnums=(0,))
 
 
 def make_fm_train_step(
@@ -618,66 +711,27 @@ def make_fm_train_step(
     callers that rebind params each step and never touch a batch after
     its step (DeviceFeed loops, FMLearner)."""
     check(num_features > 0, "num_features required")
-    _check_rule_placement(rule is not None, mesh, table_sharding)
+    optimizer = "sgd" if rule is None else "ftrl_adagrad"
+    _check_rule_placement(optimizer, mesh, table_sharding)
 
-    if mesh is None:
+    def local(params, batch, factor_axis):
+        dw, gb, dv, loss_sum, wsum, order, seen = _fm_entry_grads(
+            params, batch, objective, factor_axis=factor_axis)
+        params = _sparse_update(
+            params, order, (dw, gb, dv, wsum), learning_rate, l2,
+            seen, rule)
+        return params, {"loss_sum": loss_sum, "weight_sum": wsum,
+                        "touched_rows": order.distinct}
 
-        def step(params, batch):
-            dw, gb, dv, loss_sum, wsum, order, seen = _fm_entry_grads(
-                params, batch, objective)
-            params = _sparse_update(
-                params, order, (dw, gb, dv, wsum), learning_rate, l2,
-                seen, rule)
-            return params, {"loss_sum": loss_sum, "weight_sum": wsum,
-                            "touched_rows": order.distinct}
-
-        fn = instrumented_jit(
-            step, "fm.step",
-            donate_argnums=(0, 1) if donate_batch else (),
-        )
-        return suppress_donation_warnings(fn) if donate_batch else fn
-
-    # Entries arrive SHARDED (ShardedCSRBatch: per-shard sections, local
-    # row ids) — each device holds only its own nnz; no global mask.
-    batch_specs = {
-        "label": P(axis),
-        "weight": P(axis),
-        "indices": P(axis),
-        "values": P(axis),
-        "offsets": P(axis),
-    }
-
-    if param_specs is None:
+    if mesh is not None and param_specs is None:
         param_specs = match_partition_rules(
             fm_partition_rules(table_sharding),
             jax.eval_shape(lambda: init_fm_params(
-                max(num_features, 1), 2,
-                optimizer="sgd" if rule is None else "ftrl_adagrad")),
+                max(num_features, 1), 2, optimizer=optimizer)),
         )
-
-    if table_sharding == "factors":
-
-        def _factor_sharded(params, batch):
-            with jax.named_scope("step.exchange"):
-                whole = _gather_sections(batch, axis)
-            dw, gb, dv, loss_sum, wsum, order, seen = _fm_entry_grads(
-                params, whole, objective, factor_axis=axis)
-            params = _sparse_update(
-                params, order, (dw, gb, dv, wsum), learning_rate, l2,
-                seen, rule)
-            return params, {"loss_sum": loss_sum, "weight_sum": wsum,
-                            "touched_rows": order.distinct}
-
-        # every chip computes w, b and the loss sums from the gathered
-        # batch, which shard_map types as varying: the replicas are equal
-        # by construction, not by a collective it could check
-        step = shard_map(
-            _factor_sharded, mesh=mesh,
-            in_specs=(param_specs, batch_specs),
-            out_specs=(param_specs, P()),
-            check_vma=False,
-        )
-        return instrumented_jit(step, "fm.step", donate_argnums=(0,))
+    if mesh is None or table_sharding == "factors":
+        return _make_sparse_step(
+            local, "fm.step", mesh, axis, param_specs, donate_batch)
 
     def _sharded(params, batch):
         dw, gb, dv, loss_sum, wsum, order, _ = _fm_entry_grads(
@@ -709,7 +763,7 @@ def make_fm_train_step(
 
     step = shard_map(
         _sharded, mesh=mesh,
-        in_specs=(param_specs, batch_specs),
+        in_specs=(param_specs, _batch_specs(axis)),
         out_specs=(param_specs, P()),
     )
     return instrumented_jit(step, "fm.step", donate_argnums=(0,))
@@ -739,12 +793,14 @@ class FMLearner(FeedLearner):
     refuses it."""
 
     name = "fm"
+    #: the learner's hyper-parameters' class
+    param_class = FMParam
     #: the mesh axis the batch (and a sharded table) divides over, the
     #: DeviceFeed's default
     axis = "dp"
 
     def __init__(self, mesh: Optional[Mesh] = None, **hyper):
-        self.param = FMParam()
+        self.param = self.param_class()
         self.param.init(hyper)
         self._nf = None
         # the steps since the last epoch boundary by nnz bucket, and the
@@ -762,12 +818,30 @@ class FMLearner(FeedLearner):
             return 1
         return int(self.mesh.shape[self.axis])
 
+    @property
+    def optimizer(self) -> str:
+        """The update rule's name, the ``optimizer`` label of the
+        learner's span and counters."""
+        return self.param.optimizer
+
+    @property
+    def state_tables(self):
+        """The tables of ``params`` the rule keeps beside the weights
+        (none under plain SGD)."""
+        return () if self.param.optimizer == "sgd" else STATE_TABLES
+
+    @property
+    def columns(self) -> int:
+        """The width of ``v`` (and ``a``), what a factor-sharded mesh
+        divides."""
+        return self.param.num_factors
+
     def partition_rules(self):
         return fm_partition_rules(self.param.table_sharding)
 
     def check_mesh(self, mesh: Mesh) -> None:
         _check_rule_placement(
-            self.rule is not None, mesh, self.param.table_sharding)
+            self.optimizer, mesh, self.param.table_sharding)
         if self.param.table_sharding == "factors":
             _check_factor_shards(self.param.num_factors, mesh, self.axis)
 
@@ -779,6 +853,26 @@ class FMLearner(FeedLearner):
         return FtrlAdagrad(**{f: getattr(self.param, f)
                               for f in FtrlAdagrad._fields})
 
+    def _initialiser(self, num_features: int):
+        """``seed -> params`` of this learner's shapes over
+        ``num_features`` ids."""
+        return partial(init_fm_params, num_features, self.param.num_factors,
+                       self.param.init_scale, optimizer=self.param.optimizer)
+
+    def _make_step(self, num_features: int):
+        return make_fm_train_step(
+            self.mesh, num_features,
+            objective=self.param.objective,
+            learning_rate=self.param.learning_rate,
+            l2=self.param.l2,
+            axis=self.axis,
+            # the fit loop rebinds params every step and never touches
+            # a batch after its step — the donation contract holds
+            donate_batch=self.mesh is None,
+            table_sharding=self.param.table_sharding,
+            rule=self.rule,
+        )
+
     def param_shardings(self):
         """NamedSharding tree of the params on this learner's mesh (None
         without one): what an initialiser's ``out_shardings`` takes so
@@ -786,9 +880,7 @@ class FMLearner(FeedLearner):
         if self.mesh is None:
             return None
         # the rules go by a leaf's name and rank, not by its size
-        template = jax.eval_shape(
-            lambda: init_fm_params(2, self.param.num_factors,
-                                   optimizer=self.param.optimizer))
+        template = jax.eval_shape(self._initialiser(2))
         return sharding_tree(
             self.mesh,
             match_partition_rules(self.partition_rules(), template))
@@ -796,9 +888,7 @@ class FMLearner(FeedLearner):
     def _ensure(self, num_features: int):
         if self.params is None:
             nf = self.param.num_features or num_features
-            init = partial(init_fm_params, nf, self.param.num_factors,
-                           self.param.init_scale,
-                           optimizer=self.param.optimizer)
+            init = self._initialiser(nf)
             # on a mesh the initialiser runs as one program placed by the
             # rules: a chip writes its own part and no whole table exists
             # on any one of them first
@@ -806,19 +896,8 @@ class FMLearner(FeedLearner):
                 init, out_shardings=self.param_shardings())()
             self._nf = nf
         if self._step is None:
-            self._step = make_fm_train_step(
-                self.mesh,
-                self._nf or self.param.num_features or num_features,
-                objective=self.param.objective,
-                learning_rate=self.param.learning_rate,
-                l2=self.param.l2,
-                axis=self.axis,
-                # the fit loop rebinds params every step and never touches
-                # a batch after its step — the donation contract holds
-                donate_batch=self.mesh is None,
-                table_sharding=self.param.table_sharding,
-                rule=self.rule,
-            )
+            self._step = self._make_step(
+                self._nf or self.param.num_features or num_features)
 
     def ensure_step(self, spec) -> None:
         check(spec.layout == "csr", "FM consumes csr batches")
@@ -835,18 +914,19 @@ class FMLearner(FeedLearner):
 
     def epoch_span_args(self) -> Dict:
         return {"table_shards": self.table_shards,
-                "optimizer": self.param.optimizer}
+                "optimizer": self.optimizer}
 
     def state_bytes(self) -> int:
         """Bytes of optimizer state one chip holds: its part of every
         table the rule keeps beside the weights (0 under plain SGD)."""
-        if self.rule is None or self.params is None:
+        if self.params is None:
             return 0
         # from the shapes: ``a`` is divided as ``v`` is, ``z`` and ``n``
         # are whole on every chip
-        held = self.params
-        return int(held["a"].nbytes // self.table_shards
-                   + held["z"].nbytes + held["n"].nbytes)
+        return sum(
+            int(self.params[k].nbytes)
+            // (self.table_shards if self.params[k].ndim == 2 else 1)
+            for k in self.state_tables)
 
     def epoch_closed(self, reg, nstep: int, sums: Dict) -> None:
         """FM's own counters. The step was built for ``self.mesh`` and the
@@ -901,8 +981,8 @@ class FMLearner(FeedLearner):
             "dmlc_fit_stateful_update_steps_total",
             "optimizer steps that read and wrote per-row optimizer state "
             "at the rows the batch named",
-            model=self.name, optimizer=self.param.optimizer).inc(
-                nstep if self.rule is not None else 0)
+            model=self.name, optimizer=self.optimizer).inc(
+                nstep if self.state_tables else 0)
         reg.gauge(
             "dmlc_fit_optimizer_state_bytes",
             "bytes of optimizer state on one chip, beside the weights",
@@ -937,15 +1017,15 @@ class FMLearner(FeedLearner):
         does not matter, the table is one logical array)."""
         params = model["params"]
         want = (self.param.num_features or params["v"].shape[0],
-                self.param.num_factors)
+                self.columns)
         check(tuple(params["v"].shape) == want,
               "snapshot holds a factor table of shape %s, this learner "
               "trains %s", tuple(params["v"].shape), want)
         held = sorted(k for k in STATE_TABLES if k in params)
-        need = sorted(STATE_TABLES) if self.rule is not None else []
+        need = sorted(self.state_tables)
         check(held == need,
               "snapshot holds the optimizer state %s, optimizer=%r keeps %s",
-              held, self.param.optimizer, need)
+              held, self.optimizer, need)
         self._nf = want[0]
         if self.mesh is None:
             self.params = {k: jnp.asarray(v) for k, v in params.items()}
@@ -954,7 +1034,8 @@ class FMLearner(FeedLearner):
                 params, self.mesh, rules=self.partition_rules())
 
     def predict_batch(self, batch) -> np.ndarray:
-        _, _, vw, row_ids, values = _entries_in_id_order(self.params, batch)
+        _, _, vw, row_ids, values = _entries_in_id_order(
+            (self.params["v"], self.params["w"]), batch)
         _, s, q, linear = _row_sums(
             vw, row_ids, values, int(batch["label"].shape[0]))
         return np.asarray(

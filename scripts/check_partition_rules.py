@@ -37,6 +37,10 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 def build_cases():
     import jax
 
+    from dmlc_tpu.models.ffm import (
+        FFM_FACTOR_PARTITION_RULES,
+        init_ffm_params,
+    )
     from dmlc_tpu.models.fm import (
         FM_FACTOR_PARTITION_RULES,
         FM_PARTITION_RULES,
@@ -55,7 +59,10 @@ def build_cases():
     # with the state tables of a stateful optimizer beside the weights
     fm_state_t = jax.eval_shape(
         lambda: init_fm_params(8, 4, optimizer="ftrl_adagrad"))
+    # the field-aware FM: v and its accumulator, 2-D, nothing else
+    ffm_t = jax.eval_shape(lambda: init_ffm_params(8, 2, 3))
     return (
+        ("FFM_FACTOR_PARTITION_RULES", FFM_FACTOR_PARTITION_RULES, ffm_t),
         ("LINEAR_PARTITION_RULES", LINEAR_PARTITION_RULES, linear_t),
         ("LINEAR_MP_PARTITION_RULES", LINEAR_MP_PARTITION_RULES, linear_t),
         ("FM_PARTITION_RULES", FM_PARTITION_RULES, fm_t),
