@@ -51,20 +51,22 @@ def learner(cfg, mesh):
 
 
 def init_params(cfg, seed, model, mesh):
-    """The program's own initialiser, in one jitted call with the seed as
+    """The learner builds its own storage from the seed
+    (``harness/tables.py``); for one that keeps ``params`` as named tables,
+    from the program's own initialiser: one jitted call with the seed as
     an argument (one program for every seed), straight on the device:
     kdd12-fm's weights, the rule's state at zero."""
-    from functools import partial
-
-    import jax
-    import jax.numpy as jnp
-
     from dmlc_tpu.models.fm import init_fm_params
 
-    init = jax.jit(partial(init_fm_params, int(cfg["num_features"]),
-                           int(cfg["num_factors"]), float(cfg["init_scale"]),
-                           optimizer=cfg["optimizer"]))
-    model.params = init(jnp.uint32(seed % (1 << 32)))
+    from harness import tables
+
+    def params(key):
+        return {"params": init_fm_params(
+            int(cfg["num_features"]), int(cfg["num_factors"]),
+            float(cfg["init_scale"]), key,
+            optimizer=cfg["optimizer"])}
+
+    tables.of(model, params).init_tables(seed)
 
 
 def reference_steps(cfg, params, batches):

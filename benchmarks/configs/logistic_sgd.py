@@ -23,23 +23,24 @@ def learner(cfg, mesh):
 
 
 def init_params(cfg, seed, model, mesh):
-    """Zeros, as the learner starts (no seed to draw from), placed where
-    the learner would place them; set here so that the check can read the
-    parameters before the first step."""
+    """The learner builds its own storage (``harness/tables.py``); for
+    one that keeps ``params`` and ``velocity`` as named tables: zeros, as
+    the learner starts (no seed to draw from), placed where the learner
+    places them, so that the check can read the parameters before the
+    first step."""
+    import jax
     import jax.numpy as jnp
 
-    from dmlc_tpu.models.linear import (
-        LINEAR_PARTITION_RULES,
-        init_linear_params,
-    )
-    from dmlc_tpu.parallel.partition import shard_params
+    from dmlc_tpu.models.linear import init_linear_params
 
-    params = init_linear_params(int(cfg["num_features"]))
-    velocity = {k: jnp.zeros_like(v) for k, v in params.items()}
-    if mesh is not None:
-        params = shard_params(params, mesh, rules=LINEAR_PARTITION_RULES)
-        velocity = shard_params(velocity, mesh, rules=LINEAR_PARTITION_RULES)
-    model.params, model.velocity = params, velocity
+    from harness import tables
+
+    def zeros(seed):
+        params = init_linear_params(int(cfg["num_features"]))
+        return {"params": params,
+                "velocity": jax.tree_util.tree_map(jnp.zeros_like, params)}
+
+    tables.of(model, zeros).init_tables(seed)
 
 
 def reference_steps(cfg, params, batches):

@@ -6,18 +6,21 @@ configuration's float64 reference, from the same parameters. The reference
 reads the rows from the arrays the file was printed from, not from the
 system's batches, so the parser and the batch assembly are under test too.
 
-Compared: the loss of each step; the updated values of every parameter row
-the batches touch; and that no other row of a table changed (a 32-bit
-fingerprint of every row before and after, so no second copy of a table is
-held).
+Compared: the loss of each step; the updated values of every row the batches
+touch, in every logical table (weights and optimizer state); and that no
+other row of a table changed (a 32-bit fingerprint of every row before and
+after, so no second copy of a table is held).
+
+The tables are read through the learner (``tables.py``: by a table's name
+and ids), never from its storage, so their layout is the learner's own.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from harness import tables
 from harness.window import FeedProxy
 
 
@@ -30,18 +33,6 @@ class _OneBatch(FeedProxy):
 
     def __iter__(self):
         yield next(self._batches)
-
-
-@jax.jit
-def _fingerprint(table):
-    """[rows] uint32: the wrapping sum of each row's bit patterns."""
-    bits = jax.lax.bitcast_convert_type(table, jnp.uint32)
-    return bits if bits.ndim == 1 else jnp.sum(bits, axis=1, dtype=jnp.uint32)
-
-
-@jax.jit
-def _rows_of(table, at):
-    return jnp.take(table, at, axis=0)
 
 
 def run(cell, model, feed, data, steps):
@@ -69,38 +60,47 @@ def run(cell, model, feed, data, steps):
     padded[: len(touched)] = touched
     at = jnp.asarray(padded)
     n = len(touched)
-    tables = {k: v for k, v in model.params.items() if v.ndim >= 1}
-    before = {k: np.asarray(_rows_of(t, at), dtype=np.float64)[:n]
-              for k, t in tables.items()}
-    scalars = {k: np.float64(v) for k, v in model.params.items()
-               if v.ndim == 0}
-    prints = {k: _fingerprint(t) for k, t in tables.items()}
-    del tables  # the learner's step donates them
+    learner = tables.of(model)
+
+    def touched_rows(name):
+        return np.asarray(learner.table_rows(name, at), dtype=np.float64)[:n]
+
+    def scalars():
+        return {k: np.float64(v) for k, v in learner.scalars().items()}
+
+    names = learner.table_names()
+    before = {k: touched_rows(k) for k in names}
+    before.update(scalars())
+    prints = {k: learner.table_fingerprints(k) for k in names}
 
     one = _OneBatch(feed, iter(feed))
     losses = [float(model.fit_feed(one, epochs=1)[0]) for _ in range(steps)]
 
-    ref_losses, ref = cell.config.reference_steps(
-        cfg, dict(before, **scalars), batches)
+    ref_losses, ref = cell.config.reference_steps(cfg, before, batches)
 
     touched_mask = jnp.zeros((int(cfg["num_features"]),), bool).at[at].set(True)
-    after = {k: np.asarray(_rows_of(model.params[k], at),
-                           dtype=np.float64)[:n] for k in before}
-    after.update({k: np.float64(model.params[k]) for k in scalars})
-    before.update(scalars)
+    after = {k: touched_rows(k) for k in names}
+    after.update(scalars())
     # the system's distance from the reference, in units of the update
-    update_rel = max(
-        float(np.max(np.abs(after[k] - ref[k]))
-              / max(np.max(np.abs(ref[k] - before[k])), 1e-30))
-        for k in before)
+    update_rel_of = {
+        k: float(np.max(np.abs(after[k] - ref[k]))
+                 / max(np.max(np.abs(ref[k] - before[k])), 1e-30))
+        for k in before}
+    update_rel = max(update_rel_of.values())
     untouched_changed = sum(
-        int(jnp.sum((_fingerprint(model.params[k]) != prints[k])
-                    & ~touched_mask)) for k in prints)
+        int(jnp.sum((learner.table_fingerprints(k) != prints[k])
+                    & ~touched_mask)) for k in names)
     loss_rel = max(abs(a - b) / abs(b) for a, b in zip(losses, ref_losses))
-    ok = (loss_rel <= tol["loss_rel_tol"]
-          and update_rel <= tol["update_rel_tol"]
-          and untouched_changed == 0
-          and all(np.isfinite(losses)))
+    # every number the verdict rests on, beside its limit
+    compared = {
+        "loss_rel": [loss_rel, tol["loss_rel_tol"]],
+        "update_rel": [update_rel, tol["update_rel_tol"]],
+        "untouched_changed": [untouched_changed, 0],
+        "losses_not_finite": [int(np.sum(~np.isfinite(losses))), 0],
+    }
+    ok = all(value <= limit for value, limit in compared.values())
     return {"ok": bool(ok), "steps": steps, "loss_rel": loss_rel,
-            "update_rel": update_rel, "untouched_changed": untouched_changed,
-            "touched_rows": int(len(touched)), "losses": losses}
+            "update_rel": update_rel, "update_rel_of": update_rel_of,
+            "untouched_changed": untouched_changed,
+            "touched_rows": int(len(touched)), "losses": losses,
+            "compared": compared}

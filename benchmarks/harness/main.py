@@ -26,6 +26,9 @@ from harness import spec
 RUN_DIR = os.path.join(spec.ROOT, ".bench_run")  # gitignored scratch
 TRACE_START_SHARE = 0.3  # the profiler starts this far into the window
 TRACE_SECONDS = 3.0  # and runs this long (at most 40% of the window)
+PRINTED_COUNTERS = (  # on the detail line, as the window's deltas
+    "dmlc_feed_restarts_total", "dmlc_feed_prewound_restarts_total",
+    "dmlc_fit_touched_rows_total", "dmlc_fit_entries_total")
 
 
 def _process_age_s() -> float:
@@ -348,6 +351,9 @@ def run(argv=None) -> int:
         "compile_cache": {"dir": cache_dir, "hits": compiles.hits,
                           "misses": compiles.misses},
         "memory": peak_parts, "pipeline": raw["pipeline"], "notes": notes,
+        # the window's deltas of program counters no metric reads
+        "counters": {name: window.family_sum(raw["counters"], name)
+                     for name in PRINTED_COUNTERS},
     }
     # every full pass must have delivered the whole file: a shortfall is
     # rows lost to parse errors or dropped batches
@@ -356,8 +362,11 @@ def run(argv=None) -> int:
     if run_facts["compiles_in_window"]:
         _say("%d programs compiled inside the window"
              % run_facts["compiles_in_window"])
-    correct = bool(facts["ok"]) and failed == 0 and \
-        not run_facts["compiles_in_window"]
+    # every number `correct` rests on, beside its limit
+    compared = dict(
+        facts["compared"], rows_failed=[failed, 0],
+        compiles_in_window=[run_facts["compiles_in_window"], 0])
+    correct = all(value <= limit for value, limit in compared.values())
     result = {"correct": correct, "attempted": raw["rows"] + failed,
               "failed": failed, "metrics": metrics, "device": device}
     if run_facts["trace"] is not None:
@@ -372,5 +381,8 @@ def run(argv=None) -> int:
     if args.rehearse:  # no metric value leaves a run off the chip
         result = dict(result, rehearsal=True, metric_names=sorted(metrics))
         del result["metrics"]
+    result["compared"] = compared  # last on the line
     print(json.dumps(result), flush=True)
+    for name, (value, limit) in compared.items():
+        _say("compared %s %r limit %r" % (name, value, limit))
     return 0 if correct else 1

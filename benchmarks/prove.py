@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Prove a cell on the chip as the benchmark's contract asks: a first run
 (which builds and compiles), sets of runs with one seed per run and the
-same seeds in every set, then a traced run; each run its own process, one
+same seeds in every set, then the traced runs; each run its own process, one
 after the other, so that one process holds the chip at a time.
 
     chiprun -- python3 benchmarks/prove.py --workload <name> [--sets 2 --runs 6]
@@ -65,7 +65,10 @@ def main():
     p.add_argument("--seconds", type=float, default=None)
     p.add_argument("--sets", type=int, default=2)
     p.add_argument("--runs", type=int, default=6)
-    p.add_argument("--trace", type=int, default=1)
+    p.add_argument("--trace", type=int, default=1,
+                   help="traced runs after the sets, each with its own seed")
+    p.add_argument("--seed0", type=int, default=SEED0,
+                   help="every run's seed is this plus a fixed offset")
     p.add_argument("--no-first", action="store_true")
     p.add_argument("--keep-trace", action="store_true",
                    help="copy the traced run's .xplane.pb to chiprun_out/")
@@ -77,12 +80,12 @@ def main():
     out = os.path.join(ROOT, "chiprun_out", args.workload + ".jsonl")
 
     if not args.no_first:
-        one_run(args.workload, SEED0, seconds, 0, "first", out)
+        one_run(args.workload, args.seed0, seconds, 0, "first", out)
     per_set = []
     for s in range(args.sets):
         got = {}
         for r in range(args.runs):
-            _, values = one_run(args.workload, SEED0 + 7919 * (r + 1),
+            _, values = one_run(args.workload, args.seed0 + 7919 * (r + 1),
                                 seconds, 0, "set%d.run%d" % (s, r), out)
             for k, v in values.items():
                 got.setdefault(k, []).append(v)
@@ -92,20 +95,21 @@ def main():
                 print("SET %d %s median=%.6g spread=%.4f min=%.6g max=%.6g n=%d"
                       % (s, k, statistics.median(vals), spread(vals),
                          min(vals), max(vals), len(vals)), flush=True)
-    if args.trace:
-        record, _ = one_run(args.workload, SEED0 + 1, seconds, 1, "traced", out)
+    for t in range(args.trace):
+        record, _ = one_run(args.workload, args.seed0 + 1 + t, seconds, 1,
+                            "traced%d" % t, out)
         if record["result"]:
             print("TRACED " + json.dumps(record["result"]), flush=True)
-        if args.keep_trace:
-            import glob
-            import shutil
+    if args.trace and args.keep_trace:
+        import glob
+        import shutil
 
-            found = sorted(glob.glob(os.path.join(
-                ROOT, ".bench_run", args.workload, "trace", "plugins",
-                "profile", "*", "*.xplane.pb")))
-            if found:
-                shutil.copy(found[-1], os.path.join(
-                    ROOT, "chiprun_out", args.workload + ".xplane.pb"))
+        found = sorted(glob.glob(os.path.join(
+            ROOT, ".bench_run", args.workload, "trace", "plugins",
+            "profile", "*", "*.xplane.pb")))
+        if found:
+            shutil.copy(found[-1], os.path.join(
+                ROOT, "chiprun_out", args.workload + ".xplane.pb"))
     for k in (per_set[0] if per_set else {}):
         spreads = [spread(g[k]) for g in per_set if len(g.get(k, [])) >= 2]
         if not spreads:
