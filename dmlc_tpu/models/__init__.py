@@ -44,6 +44,7 @@ from dmlc_tpu.models.fm import (
     FMParam,
     FMLearner,
     FtrlAdagrad,
+    PackedTables,
     init_fm_params,
     make_fm_train_step,
 )
@@ -82,6 +83,7 @@ __all__ = [
     "FMParam",
     "FMLearner",
     "FtrlAdagrad",
+    "PackedTables",
     "init_fm_params",
     "make_fm_train_step",
     "FFM_FACTOR_PARTITION_RULES",

@@ -29,7 +29,11 @@ The tables are 2-D, ``v``, ``a`` ``f32[F, k * fields]``, the columns
 FACTOR-MAJOR: column ``c * fields + b`` holds factor c for partners of
 field b, so that a contiguous split of the columns over a mesh
 (``table_sharding="factors"``) gives each chip whole factors of every
-field. The pair term is a sum over the factor index, so a chip's columns
+field. One device keeps them as ONE packed array ``[v | a]`` (models/fm.py
+``PackedTables``): an id's factors and their accumulators are one row,
+read once and set once a step (9.54 ms a step where the two tables cost
+11.69 in the ``kdd12-ffm.libsvm`` cell: PERF.md, PR 36); a mesh keeps the
+two arrays, each chip its columns of both. The pair term is a sum over the factor index, so a chip's columns
 give its share of ``phi``, one psum of ``f32[rows]`` completes it, and
 every update is local (field-major columns would split by partner
 field, over which the pair term does not decompose).
@@ -71,7 +75,10 @@ from dmlc_tpu.models.fm import (
     FMLearner,
     _check_rule_placement,
     _entries_in_id_order,
+    _head_tables,
     _make_sparse_step,
+    _regroup,
+    init_packed,
     _update_at_distinct,
 )
 from dmlc_tpu.models.linear import margin_grad
@@ -81,6 +88,10 @@ from dmlc_tpu.utils.logging import check
 
 #: the one rule this model trains by, as its span and counters name it
 OPTIMIZER = "adagrad"
+
+#: the per-id tables in the order the step's head reads them (the order of
+#: a packed row's columns): the factors, then AdaGrad's accumulator
+FFM_TABLES = ("v", "a")
 
 
 def _field_sizes(value) -> Tuple[int, ...]:
@@ -121,6 +132,11 @@ class FFMParam(Parameter):
         enum={"replicated": "replicated", "factors": "factors"})
 
 
+def _ffm_draw(key, shape, init_scale: float):
+    """``v``'s start: uniform draws in [0, ``init_scale``)."""
+    return init_scale * jax.random.uniform(key, shape, dtype=jnp.float32)
+
+
 def init_ffm_params(num_features: int, num_factors: int, fields: int,
                     init_scale: float = 0.5, a_init: float = 1.0,
                     seed: int = 0) -> Dict:
@@ -129,8 +145,7 @@ def init_ffm_params(num_features: int, num_factors: int, fields: int,
     fields]``, columns factor-major."""
     shape = (num_features, num_factors * fields)
     return {
-        "v": init_scale * jax.random.uniform(
-            jax.random.PRNGKey(seed), shape, dtype=jnp.float32),
+        "v": _ffm_draw(jax.random.PRNGKey(seed), shape, init_scale),
         "a": jnp.full(shape, a_init, dtype=jnp.float32),
     }
 
@@ -216,7 +231,7 @@ def _ffm_entry_grads(params, batch, lows, objective: str,
     on every chip."""
     label, weight = batch["label"], batch["weight"]
     order, rows, ve, row_ids, values = _entries_in_id_order(
-        (params["v"],), batch)
+        *_head_tables(params, ("v",)), batch)
     share, norm, back, mine, seg = _field_sums(
         ve, order, row_ids, values, lows, label.shape[0])
     if factor_axis is not None:
@@ -260,15 +275,18 @@ def make_ffm_train_step(
     table_sharding: str = "replicated",
 ):
     """Jitted FFM step over COO batches, ``(params, batch) -> (params,
-    metrics)`` with ``params`` = {``v``, ``a``} and the metrics of
+    metrics)`` with ``params`` = {``v``, ``a``} or the packed ``[v | a]``
+    (a :class:`~dmlc_tpu.models.fm.PackedTables`; the step takes the
+    grouping from the tree it is given) and the metrics of
     :func:`~dmlc_tpu.models.fm.make_fm_train_step`. One device, or a mesh
     with ``table_sharding="factors"`` (params placed by
     :data:`FFM_FACTOR_PARTITION_RULES`): the two programs of
     ``_make_sparse_step``. The update sets rows from their state
-    (``_update_at_distinct``: ``a``'s read, the rule and ``a``'s write
-    under ``step.state``, ``v``'s write and the id sums under
-    ``step.update``), so a mesh of replicas, whose step applies a dense
-    psummed gradient, is refused."""
+    (``_update_at_distinct``: over tables apart ``a``'s read, the rule and
+    ``a``'s write under ``step.state``, ``v``'s write and the id sums
+    under ``step.update``; over a packed row the rule alone under
+    ``step.state``, the one row write under ``step.update``), so a mesh of
+    replicas, whose step applies a dense psummed gradient, is refused."""
     check(num_features > 0, "num_features required")
     _check_rule_placement(OPTIMIZER, mesh, table_sharding)
     lows = field_lows(field_sizes, num_features)
@@ -277,8 +295,9 @@ def make_ffm_train_step(
     def local(params, batch, factor_axis):
         dv, loss_sum, wsum, order, seen = _ffm_entry_grads(
             params, batch, lows, objective, factor_axis)
-        params, _ = _update_at_distinct(
+        arrays, _ = _update_at_distinct(
             params, order, {"v": dv}, seen, wsum, ("a",), rule)
+        params = _regroup(params, FFM_TABLES, arrays, {})
         return params, {"loss_sum": loss_sum, "weight_sum": wsum,
                         "touched_rows": order.distinct}
 
@@ -294,11 +313,13 @@ class FFMLearner(FMLearner):
     """uri → fitted FFM params over a DeviceFeed (csr layout), through the
     fit loop, the counters, the snapshots and the placement code of
     :class:`~dmlc_tpu.models.fm.FMLearner`, whose factors this model
-    makes field-aware. ``params`` = {``v``, ``a``}, both ``f32[F,
-    num_factors * fields]`` (the module's docstring has the layout, the
-    equations and the UNITS of ``a_init`` and ``l2``); AdaGrad's
-    accumulator is placed as ``v`` is, so a snapshot, a restore under
-    another placement and ``reshard`` carry it with no word of their own.
+    makes field-aware. The logical tables are ``v`` and ``a``, both
+    ``f32[F, num_factors * fields]`` (the module's docstring has the
+    layout, the equations and the UNITS of ``a_init`` and ``l2``): one
+    packed array ``[v | a]`` on one device, two arrays on a mesh, where
+    AdaGrad's accumulator is placed as ``v`` is; a snapshot holds the two
+    logical tables either way, so a restore under another placement and
+    ``reshard`` carry ``a`` with no word of their own.
     One device and a factor-sharded mesh (``num_factors`` divisible by
     its chips) train it; a mesh of replicas is refused, and so is a
     learner given no ``field_sizes``."""
@@ -326,9 +347,18 @@ class FFMLearner(FMLearner):
     def partition_rules(self):
         return FFM_FACTOR_PARTITION_RULES
 
+    def table_layout(self):
+        return tuple((name, self.columns) for name in FFM_TABLES)
+
     def _initialiser(self, num_features: int):
-        return partial(init_ffm_params, num_features, self.param.num_factors,
-                       self.fields, self.param.init_scale, self.param.a_init)
+        if not self.packs:
+            return partial(
+                init_ffm_params, num_features, self.param.num_factors,
+                self.fields, self.param.init_scale, self.param.a_init)
+        return partial(
+            init_packed, num_features, self.table_layout(),
+            partial(_ffm_draw, init_scale=self.param.init_scale),
+            {"a": self.param.a_init}, {})
 
     def _make_step(self, num_features: int):
         return make_ffm_train_step(
@@ -355,9 +385,9 @@ class FFMLearner(FMLearner):
 
     def predict_batch(self, batch) -> np.ndarray:
         """``phi`` of every row of one device batch (one device)."""
-        lows = field_lows(self.param.field_sizes, self.params["v"].shape[0])
+        lows = field_lows(self.param.field_sizes, self._nf)
         order, _, ve, row_ids, values = _entries_in_id_order(
-            (self.params["v"],), batch)
+            *_head_tables(self.params, ("v",)), batch)
         share, norm, _, _, _ = _field_sums(
             ve, order, row_ids, values, lows, int(batch["label"].shape[0]))
         return np.asarray(norm * share)

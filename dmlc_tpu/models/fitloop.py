@@ -24,7 +24,9 @@ that declares it; linear and FM fill it in):
   scalars, not read here);
 - ``snapshot_model()``: the ``model`` subtree of a job snapshot, and
   ``restore_snapshot_model(model)`` its way back;
-- optionally ``epoch_span_args()``: more attributes for the ``epoch``
+- optionally ``audit_params()``: the arrays the audit samples at an
+  epoch's end where ``params`` itself is not that tree (FM's packed row);
+  ``epoch_span_args()``: more attributes for the ``epoch``
   span, and ``epoch_closed(reg, nstep, sums)``: called inside
   ``epoch_close`` for counters only this model has (FM's five); ``sums``
   holds the pass's sum of every scalar its steps returned.
@@ -328,6 +330,10 @@ class FeedLearner:
     def epoch_span_args(self) -> Dict:
         return {}
 
+    def audit_params(self):
+        """The arrays the audit samples at an epoch's end."""
+        return self.params
+
     def epoch_closed(self, reg, nstep: int, sums: Dict) -> None:
         """Inside ``epoch_close``: count what only this model has.
         ``sums``: the pass's sum of each scalar :meth:`train_step`
@@ -417,7 +423,7 @@ def fit_feed(learner, feed, epochs: int = 1, log_every: int = 0,
                 % (epoch, nstep, snapshotter.committed_epoch))
         fl.finish_epoch(
             epoch, nstep, t0, acc, history, feed=feed,
-            log_every=log_every, params=learner.params,
+            log_every=log_every, params=learner.audit_params(),
             snapshotter=snapshotter,
             snap_state=(None if snapshotter is None else
                         lambda e=epoch: _snapshot_state(

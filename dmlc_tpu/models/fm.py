@@ -25,25 +25,42 @@ gradient for the psum; on a mesh with the table's factors sharded
 (``table_sharding="factors"``) every chip scatter-adds into its own
 columns and only the batch and one ``f32[rows]`` psum cross ICI.
 
+How the logical per-id tables (``v``, ``w``; a stateful rule's ``a``,
+``z``, ``n``) are grouped into physical arrays is the one parameter of
+the step's reads and writes (:func:`_groups`). Where one device holds
+whole rows of every table they are ONE array, :class:`PackedTables`: an
+id's weights and state side by side in one row, ``[v | w]`` or ``[v | w
+| a | z | n]``, so the step makes one chunk loop of reads at the batch's
+distinct ids and one of row writes, where five tables apart cost a read
+and a write each (on the chip an indexed pass over a table costs per
+index and hardly per column while a row fits one 128-lane tile: PERF.md,
+PRs 29, 32 and 36). Tables divided or replicated over a mesh stay one
+array a table, each placed by its own rule. The grouping follows from the
+tree a step is given and, for a learner, from its placement
+(``FMLearner.packs``); no hyper-parameter names it.
+
 The update rule is ``FMParam.optimizer``'s: ``"sgd"`` adds each id's
 scaled gradient into its row; ``"ftrl_adagrad"`` (difacto's: FTRL-proximal
 on ``w``, per-element AdaGrad on ``v``) keeps state for every parameter
 row, tables ``z``, ``n`` (as ``w``) and ``a`` (as ``v``) beside the
-weights in ``params``, reads a touched row's state once and SETS weights
-and state from the rule (:func:`_stateful_update`).
+weights in ``params`` (in the same packed row on one device), reads a
+touched row's state once and SETS weights and state from the rule
+(:func:`_stateful_update`).
 
 score(x) = b + Σ_i w_i x_i + ½ Σ_k [(Σ_i v_ik x_i)² − Σ_i v_ik² x_i²]
 """
 
 from __future__ import annotations
 
+from collections.abc import Mapping
 from functools import partial
-from typing import Dict, NamedTuple, Optional
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax, shard_map
+from jax.extend import random as jex_random
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dmlc_tpu.collective.device import all_gather, bucketed_psum, psum
@@ -100,6 +117,11 @@ class FtrlAdagrad(NamedTuple):
     v_l2: float
 
 
+def _fm_draw(key, shape, init_scale: float):
+    """``v``'s start: normal draws at ``init_scale``."""
+    return init_scale * jax.random.normal(key, shape, dtype=jnp.float32)
+
+
 def init_fm_params(
     num_features: int, num_factors: int, init_scale: float = 0.01, seed: int = 0,
     optimizer: str = "sgd",
@@ -112,8 +134,7 @@ def init_fm_params(
     params = {
         "w": jnp.zeros((num_features,), dtype=jnp.float32),
         "b": jnp.zeros((), dtype=jnp.float32),
-        "v": init_scale
-        * jax.random.normal(key, (num_features, num_factors), dtype=jnp.float32),
+        "v": _fm_draw(key, (num_features, num_factors), init_scale),
     }
     if optimizer != "sgd":
         params.update(
@@ -124,6 +145,201 @@ def init_fm_params(
 
 #: the leaves of ``params`` a stateful rule adds
 STATE_TABLES = ("a", "n", "z")
+
+#: the FM's per-id tables in the order the step's head reads them (the
+#: order of a packed row's columns): under plain SGD, and under
+#: ``optimizer="ftrl_adagrad"``, whose state follows the weights
+SGD_TABLES = ("v", "w")
+FTRL_TABLES = ("v", "w", "a", "z", "n")
+
+
+def _columns(widths) -> int:
+    """The columns tables of these ``widths`` take side by side (a 1-D
+    table, width 0, takes one)."""
+    return sum(max(width, 1) for width in widths)
+
+
+def _span(buffer, span: Tuple[int, int]):
+    """The columns ``span`` = (first, width) of ``buffer [n, C]``; width
+    0 names a 1-D table, one column, and comes back 1-D."""
+    first, width = span
+    return buffer[:, first:first + width] if width else buffer[:, first]
+
+
+@jax.tree_util.register_pytree_node_class
+class PackedTables(Mapping):
+    """The per-id tables of one learner side by side in ONE array: ``rows
+    f32[F, C]`` holds, for every id, its row of each logical table in the
+    order of ``layout`` = ((name, width), ...), width 0 a 1-D table (one
+    column); ``scalars`` = {name: f32[]} (the FM's ``b``). What a device
+    that holds whole rows of every table keeps, so that a step reads each
+    touched row once and writes it once. A pytree: ``rows`` and the
+    scalars are its leaves, the layout its static part, so a step jitted
+    over either tree takes the grouping from the tree it is given.
+
+    As a mapping it reads like the tree of tables it stands for:
+    ``params["v"]`` is the logical table, a COPY of its columns (for a
+    look at a fitted model or a test; the step, the check's five calls
+    and the snapshot never make one), and ``dict(params)`` is the tree
+    with one array a table."""
+
+    def __init__(self, rows, scalars: Dict, layout):
+        self.rows = rows
+        self.scalars = scalars
+        self.layout = tuple(layout)
+
+    def tree_flatten(self):
+        return (self.rows, self.scalars), self.layout
+
+    @classmethod
+    def tree_unflatten(cls, layout, children):
+        return cls(*children, layout)
+
+    @classmethod
+    def pack(cls, parts, layout):
+        """The logical tree ``parts`` (one array a table, numpy or jax)
+        as one packed tree; every leaf ``layout`` does not name is a
+        scalar."""
+        lib = np if isinstance(parts[layout[0][0]], np.ndarray) else jnp
+        names = [name for name, _ in layout]
+        rows = lib.concatenate(
+            [parts[name] if width else parts[name][:, None]
+             for name, width in layout], axis=1)
+        return cls(rows, {k: v for k, v in parts.items() if k not in names},
+                   layout)
+
+    def span(self, name: str) -> Tuple[int, int]:
+        """(first column, width) of logical table ``name``."""
+        first = 0
+        for table, width in self.layout:
+            if table == name:
+                return first, width
+            first += max(width, 1)
+        raise KeyError(name)
+
+    def __getitem__(self, name):
+        if name in self.scalars:
+            return self.scalars[name]
+        return _span(self.rows, self.span(name))
+
+    def __iter__(self):
+        yield from (name for name, _ in self.layout)
+        yield from self.scalars
+
+    def __len__(self):
+        return len(self.layout) + len(self.scalars)
+
+
+def _no_such_key_op(*_):
+    raise NotImplementedError(
+        "a key of draws at an offset only draws (init_packed)")
+
+
+def _bits_at_offset(key, bit_width: int, shape):
+    """``jax.random``'s threefry bits (``jax_threefry_partitionable``, the
+    default: element i of a shape is drawn from the counter i) for the
+    counters ``offset + i``: ``key`` = uint32[3] (k1, k2, offset)."""
+    check(bit_width == 32, "draws at an offset are 32 bits wide")
+    counts = key[2] + lax.iota(jnp.uint32, int(np.prod(shape))).reshape(shape)
+    bits1, bits2 = jex_random.threefry2x32_p.bind(
+        key[0], key[1], jnp.zeros_like(counts), counts)
+    return bits1 ^ bits2
+
+
+#: threefry keys that draw a BLOCK of a larger array's draws: what
+#: ``jax.random.normal(key, (F, K))[at:at + R]`` holds, from a key that
+#: carries the offset ``at * K``, without the larger array
+_OFFSET_DRAWS = jex_random.define_prng_impl(
+    key_shape=(3,), seed=_no_such_key_op, split=_no_such_key_op,
+    random_bits=_bits_at_offset, fold_in=_no_such_key_op,
+    name="threefry2x32_at_offset", tag="fryo")
+
+#: rows one pass of a packed row's initialiser draws (64 MB of 16 columns)
+_INIT_BLOCK = 1 << 20
+
+
+def init_packed(num_features: int, layout, draw, fill: Dict, scalars: Dict,
+                seed) -> PackedTables:
+    """A learner's tables at their start as ONE packed array, written in
+    place: every column at its table's ``fill`` value (0 where it has
+    none), then the leading table's columns (``v``: the one table that
+    starts random) drawn ``_INIT_BLOCK`` rows a pass into the array
+    itself, so that no table-sized array exists beside it (the compiler
+    does not fuse a table of draws into the array that pads it: drawn
+    whole it lies beside the packed array, 3.9 GB of 16 columns).
+
+    ``draw(key, shape)``: the draws of the logical initialiser
+    (``init_fm_params``: ``init_scale * normal``). A block's key carries
+    its offset (:data:`_OFFSET_DRAWS`), so the array holds the draws of
+    ``draw(PRNGKey(seed), (num_features, width))`` to the bit."""
+    name, width = layout[0]
+    check(num_features * width < 1 << 32,
+          "a packed row's initialiser counts draws in 32 bits: %d x %d",
+          num_features, width)
+    start = np.concatenate(
+        [np.full(max(w, 1), fill.get(table, 0.0), np.float32)
+         for table, w in layout])  # one row
+    rows = jnp.broadcast_to(start, (num_features, start.size))
+    block = min(num_features, _INIT_BLOCK)
+    k1, k2 = jax.random.key_data(jax.random.PRNGKey(seed))
+
+    def draw_block(i, rows):
+        # the last block starts early and draws some rows again
+        at = jnp.minimum(i * block, num_features - block)
+        key = jax.random.wrap_key_data(
+            jnp.stack([k1, k2, (at * width).astype(jnp.uint32)]),
+            impl=_OFFSET_DRAWS)
+        return lax.dynamic_update_slice(
+            rows, draw(key, (block, width)), (at, 0))
+
+    rows = lax.fori_loop(0, -(-num_features // block), draw_block, rows)
+    return PackedTables(rows, scalars, layout)
+
+
+class _Group(NamedTuple):
+    """A physical array of the parameters and the logical tables whose
+    columns it holds: how the step's reads and writes are divided."""
+
+    array: jax.Array
+    #: {logical table: its columns, 0 for a 1-D table}, in column order
+    widths: Dict[str, int]
+
+
+def _groups(params, names) -> List[_Group]:
+    """The physical arrays that hold the logical tables ``names``: one
+    group a table for a tree with one array a table, ONE group for a
+    :class:`PackedTables` (whose layout must be ``names`` in that order:
+    the order the step's head reads them in)."""
+    if isinstance(params, PackedTables):
+        held = tuple(name for name, _ in params.layout)
+        check(held == tuple(names),
+              "the packed row holds %s, the step reads %s", held, names)
+        return [_Group(params.rows, dict(params.layout))]
+    return [_Group(params[name], {
+        name: params[name].shape[1] if params[name].ndim == 2 else 0})
+        for name in names]
+
+
+def _regroup(params, names, arrays, scalars: Dict):
+    """The tree ``params`` came as, over the groups' new ``arrays`` (as
+    :func:`_groups` listed them) and the new ``scalars``."""
+    if isinstance(params, PackedTables):
+        (rows,) = arrays
+        return PackedTables(rows, scalars, params.layout)
+    return dict(zip(names, arrays), **scalars)
+
+
+def _head_tables(params, names):
+    """What the step's head reads for the logical tables ``names``: (the
+    physical arrays, how many leading columns of their rows side by side
+    are ``names``' own; None when all are)."""
+    if isinstance(params, PackedTables):
+        head = params.layout[:len(names)]
+        check(tuple(name for name, _ in head) == tuple(names),
+              "the packed row starts with %s, the step's head reads %s",
+              [name for name, _ in head], names)
+        return (params.rows,), _columns(width for _, width in head)
+    return tuple(params[name] for name in names), None
 
 
 #: Data-parallel placement for {"w": [F], "b": scalar, "v": [F, K]}:
@@ -246,7 +462,7 @@ def _take_distinct(tables, order: _IdOrder):
     return lax.fori_loop(0, order.chunks, take_chunk, rows)
 
 
-def _gather_rows(tables, order: _IdOrder):
+def _gather_rows(tables, order: _IdOrder, head: Optional[int] = None):
     """The entries' rows of ``tables`` side by side (the FM's ``[v_e |
     w_e]``, ``[nnz, K + 1]``), in id order, with each touched row of the
     parameters read ONCE: ``rows = [v[ids] | w[ids]]`` at the distinct
@@ -254,13 +470,18 @@ def _gather_rows(tables, order: _IdOrder):
     ``rows[slot]`` whose source is a few MB (a tenth of a gather from the
     table's cost on the chip). Returns (rows, the entries' rows).
 
+    ``head``: the entries take the first ``head`` columns only (a packed
+    row holds the optimizer's state after the weights: the distinct ids'
+    buffer has it, the per-entry buffer does not grow by it).
+
     A gather from the table costs per index (22 ns a row of 16 columns,
     37 ns of 32, 16 ns an element of ``w``: PERF.md, PR 31) and nothing
     for being repeated, so the popular ids of a power law are most of a
     per-entry gather's cost; a batch with no repeated id gathers what a
     per-entry gather would."""
     rows = _take_distinct(tables, order)
-    return rows, jnp.take(rows, order.slot, axis=0)
+    weights = rows if head in (None, rows.shape[1]) else rows[:, :head]
+    return rows, jnp.take(weights, order.slot, axis=0)
 
 
 def _row_sums(vw, row_ids, values, num_rows: int):
@@ -281,13 +502,15 @@ def _row_sums(vw, row_ids, values, num_rows: int):
     return xv, sums[:, :k], sums[:, k:2 * k], sums[:, 2 * k]
 
 
-def _entries_in_id_order(tables, batch):
+def _entries_in_id_order(tables, head: Optional[int], batch):
     """``step.order`` and ``step.gather``, the head of every FM and FFM
     program: the batch's entries sorted by feature id
     (:func:`_in_id_order`) and the rows of ``tables`` (the FM's ``(v,
-    w)``) side by side for each (:func:`_gather_rows`). Returns (order,
-    rows, vw, row_ids, values): ``rows`` the distinct ids' ``[v | w]``,
-    the last three per entry in id order."""
+    w)``, or the one packed array; :func:`_head_tables` gives both
+    arguments) side by side for each (:func:`_gather_rows`). Returns
+    (order, rows, vw, row_ids, values): ``rows`` the distinct ids' rows
+    of ``tables``, every column; the last three per entry in id order,
+    ``vw`` the first ``head`` columns."""
     values = batch["values"]
     with jax.named_scope("step.gather"):
         # offsets → row ids on device (local per shard under shard_map)
@@ -297,7 +520,7 @@ def _entries_in_id_order(tables, batch):
         order, row_ids, values = _in_id_order(
             batch["indices"], row_ids, values, tables[0].shape[0])
     with jax.named_scope("step.gather"):
-        rows, vw = _gather_rows(tables, order)
+        rows, vw = _gather_rows(tables, order, head)
     return order, rows, vw, row_ids, values
 
 
@@ -310,7 +533,8 @@ def _fm_entry_grads(params, batch, objective: str,
     is the caller's: scatter-added into the table (single device,
     factor-sharded mesh) or reduced to dense grads for the psum
     (replicated mesh). Returns (dw, gb, dv, loss_sum, weight_sum, order,
-    seen); ``seen`` = (the distinct ids' ``[v | w]``, the entries'
+    seen); ``seen`` = (the distinct ids' rows as the head read them:
+    ``[v | w]``, or the whole packed row, state and all; the entries'
     values) is what a stateful rule reads besides (:func:`_stateful_update`).
 
     Passes that share an index vector are one pass over concatenated
@@ -330,7 +554,7 @@ def _fm_entry_grads(params, batch, objective: str,
     label = batch["label"]
     weight = batch["weight"]
     order, rows, vw, row_ids, values = _entries_in_id_order(
-        (params["v"], params["w"]), batch)
+        *_head_tables(params, ("v", "w")), batch)
     xv, s, q, linear = _row_sums(vw, row_ids, values, label.shape[0])
     with jax.named_scope("step.forward"):
         interaction = 0.5 * jnp.sum(s * s - q, axis=-1)
@@ -353,12 +577,14 @@ def _fm_entry_grads(params, batch, objective: str,
     return dw, gb, dv, loss_sum, jnp.sum(weight), order, (rows, values)
 
 
-def _scatter_add_rows(w, v, order: _IdOrder, upd):
-    """``v[i] += Σ upd[e, :-1]`` and ``w[i] += Σ upd[e, -1]`` over the
-    entries e that name feature i, into ``w`` and ``v`` themselves (in
-    place when the caller donated them). ``upd`` is in ``order``'s order.
-    A row no entry names is not written; a padded entry adds its 0 to
-    feature 0.
+def _scatter_add_rows(groups, order: _IdOrder, upd):
+    """``t[i] += Σ upd[e, t's columns]`` over the entries e that name
+    feature i, for every logical table t of ``groups``
+    (:func:`_groups`; the FM's ``v`` and ``w``, or the one packed ``[v |
+    w]``), into the groups' arrays themselves (in place when the caller
+    donated them). ``upd`` ``[n, columns]`` is in ``order``'s order, its
+    columns in the groups'. Returns the new arrays, one a group. A row no
+    entry names is not written; a padded entry adds its 0 to feature 0.
 
     The entries of one id are summed first and reach its row in one
     add. Ids repeat within a batch (thousands of times for the popular
@@ -366,29 +592,43 @@ def _scatter_add_rows(w, v, order: _IdOrder, upd):
     much larger than the update round at the parameter's magnitude each
     time: against a float64 step that read 20 times the error of a dense
     gradient's one subtraction. Summing first keeps that one rounding.
-    ``v``'s and ``w``'s updates are summed by slot in ONE pass (as
+    Every table's updates are summed by slot in ONE pass (as
     :func:`_row_sums` sums by row, and for its reason).
 
     On the chip a row scatter-add is serial, ~0.1 µs a slot whether the
-    slot's id is in range or dropped, so ``v`` takes the distinct ids
-    ``_UPDATE_CHUNK`` slots at a time until the last slot that holds one
-    (the loop :func:`_gather_rows` reads them by). ``w``'s 1-D scatter
-    costs a pass over ``w`` whatever the number of slots, so it is made
-    once."""
+    slot's id is in range or dropped, so a 2-D array takes the distinct
+    ids ``_UPDATE_CHUNK`` slots at a time until the last slot that holds
+    one (the loop :func:`_gather_rows` reads them by), whatever the
+    number of tables in its row. A 1-D array's scatter costs a pass over
+    it whatever the number of slots, so it is made once (1.39 ms for
+    ``w`` beside a ``v`` of 16 columns; as the 17th column of a packed
+    row it is nearly free: PERF.md, PR 36)."""
     n = order.slot.shape[0]
     sums = jax.ops.segment_sum(
         upd, order.slot, num_segments=n, indices_are_sorted=True)  # [n, K + 1]
     ids = order.ids
-    sum_v = jnp.pad(sums[:, :-1], ((0, ids.shape[0] - n), (0, 0)))
     flags = dict(indices_are_sorted=True, unique_indices=True, mode="drop")
-    w = w.at[ids[:n]].add(sums[:, -1], **flags)
 
-    def add_chunk(i, v):
-        at = i * _UPDATE_CHUNK
-        return v.at[lax.dynamic_slice_in_dim(ids, at, _UPDATE_CHUNK)].add(
-            lax.dynamic_slice_in_dim(sum_v, at, _UPDATE_CHUNK), **flags)
+    def add_chunks(array, cols):
+        cols = jnp.pad(cols, ((0, ids.shape[0] - n), (0, 0)))
 
-    return w, lax.fori_loop(0, order.chunks, add_chunk, v)
+        def add_chunk(i, array):
+            at = i * _UPDATE_CHUNK
+            return array.at[
+                lax.dynamic_slice_in_dim(ids, at, _UPDATE_CHUNK)].add(
+                    lax.dynamic_slice_in_dim(cols, at, _UPDATE_CHUNK),
+                    **flags)
+
+        return lax.fori_loop(0, order.chunks, add_chunk, array)
+
+    out, first = [], 0
+    for array, widths in groups:
+        columns = _columns(widths.values())
+        cols = sums[:, first:first + columns]
+        out.append(array.at[ids[:n]].add(cols[:, 0], **flags)
+                   if array.ndim == 1 else add_chunks(array, cols))
+        first += columns
+    return out
 
 
 def _check_rule_placement(optimizer: str, mesh: Optional[Mesh],
@@ -433,12 +673,13 @@ def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
     """The step's update from per-entry contributions ``grads`` =
     (dw, gb, dv, weight_sum), the entries in ``order``'s order: under
     ``step.update`` scaled by ``-learning_rate / weight_sum`` and
-    scatter-ADDED into ``w`` and ``v`` (:func:`_scatter_add_rows`; ids
-    repeat within a batch), so only the rows the batch names are written
-    and no gradient of the table's shape exists. The sort, the slots and
-    the distinct ids it needs are the step's head's (``step.order``); it
-    computes none. ``l2 > 0`` adds one scaling pass over the table before
-    the scatter-add: ``v - lr*(g + l2*v) = v*(1 - lr*l2) - lr*g``.
+    scatter-ADDED into ``v`` and ``w``, or into the one packed ``[v |
+    w]``, as ``params`` holds them (:func:`_scatter_add_rows`; ids repeat
+    within a batch), so only the rows the batch names are written and no
+    gradient of the table's shape exists. The sort, the slots and the
+    distinct ids it needs are the step's head's (``step.order``); it
+    computes none. ``l2 > 0`` adds one scaling pass over each array
+    before the scatter-add: ``v - lr*(g + l2*v) = v*(1 - lr*l2) - lr*g``.
 
     With a ``rule`` (``optimizer="ftrl_adagrad"``) the update is
     :func:`_stateful_update`'s, which sets rows where this one adds."""
@@ -450,16 +691,14 @@ def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
         denom = jnp.maximum(wsum, 1e-12)
         upd = (-learning_rate / denom) * jnp.concatenate(
             [dv, dw[:, None]], axis=1)
-        w, v = params["w"], params["v"]
+        groups = _groups(params, SGD_TABLES)
         if l2:
-            w = w * (1.0 - learning_rate * l2)
-            v = v * (1.0 - learning_rate * l2)
-        w, v = _scatter_add_rows(w, v, order, upd)
-        return {
-            "w": w,
-            "b": params["b"] - learning_rate * (gb / denom),
-            "v": v,
-        }
+            groups = [group._replace(
+                array=group.array * (1.0 - learning_rate * l2))
+                for group in groups]
+        return _regroup(
+            params, SGD_TABLES, _scatter_add_rows(groups, order, upd),
+            {"b": params["b"] - learning_rate * (gb / denom)})
 
 
 def _set_rows(table, order: _IdOrder, new):
@@ -484,19 +723,26 @@ def _set_rows(table, order: _IdOrder, new):
     return lax.fori_loop(0, order.chunks, set_chunk, table)
 
 
-def _split_columns(buffer, like):
-    """{name: its columns of ``buffer``} for the tables of ``like`` (name
-    -> an array of the table's rank) side by side in that order: a 1-D
-    table is one column and comes back 1-D."""
-    out, at = {}, 0
-    for name, table in like.items():
-        if table.ndim == 1:
-            out[name] = buffer[:, at]
-            at += 1
-        else:
-            out[name] = buffer[:, at:at + table.shape[1]]
-            at += table.shape[1]
+def _split_columns(buffer, widths):
+    """{name: its columns of ``buffer``} for the tables of ``widths``
+    (name -> its columns, 0 for a 1-D table) side by side in that order:
+    a 1-D table is one column and comes back 1-D."""
+    out, first = {}, 0
+    for name, width in widths.items():
+        out[name] = _span(buffer, (first, width))
+        first += max(width, 1)
     return out
+
+
+def _join_columns(tables, widths):
+    """:func:`_split_columns` back: the tables of ``widths`` side by
+    side; one table alone is handed back as it is."""
+    if len(widths) == 1:
+        (name,) = widths
+        return tables[name]
+    return jnp.concatenate(
+        [tables[name] if width else tables[name][:, None]
+         for name, width in widths.items()], axis=1)
 
 
 def _update_at_distinct(params, order: _IdOrder, grads, seen, wsum, state,
@@ -506,27 +752,50 @@ def _update_at_distinct(params, order: _IdOrder, grads, seen, wsum, state,
     models/ffm.py. ``grads``: {weight table: the entries' contributions
     to its gradient, ``[n]`` or ``[n, C]`` in ``order``'s order}, the
     tables in the column order of the head's read; ``seen`` = (the
-    distinct ids' weights as the head read them, the entries' values);
+    distinct ids' rows as the head read them, the entries' values);
     ``state``: the names of the tables of ``params`` the rule keeps beside
     the weights; ``rule(old, grad) -> new``: dicts by table name over the
     distinct ids' buffers (``grad`` the mean gradients of the weight
     tables, ``new`` every table's rows, weights and state). Returns (the
-    new tables, ``max(wsum, 1e-12)``).
+    new arrays, one for each of ``_groups(params, weights + state)``,
+    ``max(wsum, 1e-12)``).
 
     The rule is not a scaled sum of the entries: an id's gradient is
     summed first (one ``segment_sum`` by sorted slot, as the SGD step's),
-    whole BEFORE the rule runs; the id's state is read ONCE
-    (:func:`_take_distinct`); weights and state are SET at the distinct
-    ids (:func:`_set_rows`), so no array of a table's shape exists
-    besides the tables and a row no entry names is neither read nor
-    written. A slot whose entries all have value 0 (padding names feature
-    0) keeps its weights and its state to the bit, whatever the rule.
+    whole BEFORE the rule runs; the id's state is read ONCE; weights and
+    state are SET at the distinct ids (:func:`_set_rows`), so no array of
+    a table's shape exists besides the tables and a row no entry names is
+    neither read nor written. A slot whose entries all have value 0
+    (padding names feature 0) keeps its weights and its state to the bit,
+    whatever the rule.
 
-    ``step.state`` holds what the rule adds to the SGD step: the state
-    rows' read, the rule, the state rows' write. The weights' writes and
-    the id sums stay under ``step.update``."""
+    The reads and the writes go by physical array (:func:`_groups`). A
+    packed row came whole in the head's read, state and all, and goes
+    back in ONE set of all its columns. Tables that lie apart: the head
+    read the weights, the state's arrays are read here
+    (:func:`_take_distinct`), and every array is set on its own.
+
+    ``step.state`` holds what the rule adds to the SGD step: the read of
+    the arrays that hold state only, the rule and the keep-or-take
+    selects, those arrays' write. The write of an array that holds
+    weights and the id sums stay under ``step.update``."""
     rows, values = seen
     n = values.shape[0]
+    groups = _groups(params, tuple(grads) + tuple(state))
+    # the head read every array that holds a weight table
+    def in_head(group):
+        return any(name in grads for name in group.widths)
+
+    head = [g for g in groups if in_head(g)]
+    rest = [g for g in groups if not in_head(g)]
+
+    def widths_of(some):
+        return {k: v for g in some for k, v in g.widths.items()}
+
+    def set_rows_of(some, new):
+        return [_set_rows(g.array, order, _join_columns(new, g.widths))
+                for g in some]
+
     with jax.named_scope("step.update"):
         denom = jnp.maximum(wsum, 1e-12)
         # the last column counts an id's entries that carry a value
@@ -536,23 +805,24 @@ def _update_at_distinct(params, order: _IdOrder, grads, seen, wsum, state,
                 + [(values != 0).astype(rows.dtype)[:, None]], axis=1),
             order.slot, num_segments=n, indices_are_sorted=True)
         sums = jnp.pad(sums, ((0, order.ids.shape[0] - n), (0, 0)))
+        widths = {name: 0 if g.ndim == 1 else g.shape[1]
+                  for name, g in grads.items()}
         grad = {name: g / denom
-                for name, g in _split_columns(sums, grads).items()}
+                for name, g in _split_columns(sums, widths).items()}
         live = sums[:, -1] > 0
-        old = _split_columns(rows, grads)
+        old = _split_columns(rows, widths_of(head))
     with jax.named_scope("step.state"):
-        tables = {name: params[name] for name in state}
-        old.update(_split_columns(
-            _take_distinct(tuple(tables.values()), order), tables))
+        if rest:
+            old.update(_split_columns(
+                _take_distinct(tuple(g.array for g in rest), order),
+                widths_of(rest)))
         new = {name: jnp.where(live if rows_.ndim == 1 else live[:, None],
                                rows_, old[name])
                for name, rows_ in rule(old, grad).items()}
-        out = {name: _set_rows(params[name], order, new[name])
-               for name in state}
+        state_arrays = set_rows_of(rest, new)
     with jax.named_scope("step.update"):
-        out.update({name: _set_rows(params[name], order, new[name])
-                    for name in grads})
-    return out, denom
+        # the arrays in ``groups``' order: the weights' lead it
+        return set_rows_of(head, new) + state_arrays, denom
 
 
 def _ftrl_adagrad(old, grad, alpha: float, l2: float, rule: FtrlAdagrad):
@@ -602,11 +872,13 @@ def _stateful_update(params, order: _IdOrder, grads, seen,
     L1 threshold holds an exact 0, and a slot whose entries all have
     value 0 keeps weights and state to the bit, whatever ``v_l2``."""
     dw, gb, dv, wsum = grads
-    new, denom = _update_at_distinct(
+    arrays, denom = _update_at_distinct(
         params, order, {"v": dv, "w": dw}, seen, wsum, ("a", "z", "n"),
         partial(_ftrl_adagrad, alpha=learning_rate, l2=l2, rule=rule))
     with jax.named_scope("step.update"):
-        return dict(new, b=params["b"] - learning_rate * (gb / denom))
+        return _regroup(
+            params, FTRL_TABLES, arrays,
+            {"b": params["b"] - learning_rate * (gb / denom)})
 
 
 def _batch_specs(axis: str):
@@ -677,7 +949,13 @@ def make_fm_train_step(
     pass. Every program opens with :func:`_entries_in_id_order`.
 
     Single device (``mesh is None``): the update touches only the rows
-    the batch names (:func:`_sparse_update`).
+    the batch names (:func:`_sparse_update`). ``params`` is the dict with
+    one array a table (``init_fm_params``) or a :class:`PackedTables`
+    with the tables of :data:`SGD_TABLES` / :data:`FTRL_TABLES` as its
+    row, and comes back as it came: the step takes the grouping of its
+    reads and writes from the tree it is given (one read and one write
+    of the packed row; one of each for every table that lies apart), and
+    the two agree to the bit.
 
     Mesh, table replicated: a psum needs one buffer of a fixed shape, so
     the entries are reduced to dense grads and ONE fused (dtype-bucketed)
@@ -769,6 +1047,26 @@ def make_fm_train_step(
     return instrumented_jit(step, "fm.step", donate_argnums=(0,))
 
 
+@partial(jax.jit, static_argnames=("span",))
+def _rows_at(array, ids, span=None):
+    """A logical table's rows at ``ids``: gathered from the array that
+    holds them, the table's columns (``span``; None: the array is the
+    table) cut from the RESULT."""
+    rows = jnp.take(array, ids, axis=0)
+    return rows if span is None else _span(rows, span)
+
+
+@partial(jax.jit, static_argnames=("span",))
+def _row_fingerprints(array, span=None):
+    """``uint32[rows]``: the wrapping sum of the bit patterns of each
+    row of a logical table, read where its columns lie (``span`` as
+    :func:`_rows_at` takes it): a slice inside a reduction, no copy."""
+    bits = lax.bitcast_convert_type(array, jnp.uint32)
+    if span is not None:
+        bits = _span(bits, span)
+    return bits if bits.ndim == 1 else jnp.sum(bits, axis=1, dtype=jnp.uint32)
+
+
 class FMLearner(FeedLearner):
     """uri → fitted FM params over a DeviceFeed (csr layout); the fit loop
     is :func:`dmlc_tpu.models.fitloop.fit_feed`.
@@ -790,7 +1088,18 @@ class FMLearner(FeedLearner):
     a snapshot, a restore under another placement and ``reshard`` carry
     them with no word of their own. ``predict_batch`` ignores them. One
     device and a factor-sharded mesh take the rule; a mesh of replicas
-    refuses it."""
+    refuses it.
+
+    ``params`` on ONE device is a :class:`PackedTables` (:attr:`packs`):
+    every per-id table of :meth:`table_layout` in one array, which reads
+    as the mapping of logical tables it stands for (``params["v"]`` is a
+    copy of ``v``'s columns). On a mesh it is the dict with one array a
+    table, placed by :meth:`partition_rules`. A snapshot holds the logical
+    tables under either, and restores under either. The benchmark's check
+    reads the tables through :meth:`init_tables`, :meth:`table_names`,
+    :meth:`scalars`, :meth:`table_rows` and :meth:`table_fingerprints`
+    (``benchmarks/harness/tables.py``), which read them wherever they
+    lie and never make an array of a table's shape."""
 
     name = "fm"
     #: the learner's hyper-parameters' class
@@ -808,6 +1117,8 @@ class FMLearner(FeedLearner):
         # shapes the step was compiled for fix the bytes)
         self._steps_of: Dict[int, int] = {}
         self._bytes_of: Dict[int, int] = {}
+        # of those steps, the ones that took a packed tree
+        self._packed_steps = 0
         super().__init__(mesh)
 
     @property
@@ -853,11 +1164,36 @@ class FMLearner(FeedLearner):
         return FtrlAdagrad(**{f: getattr(self.param, f)
                               for f in FtrlAdagrad._fields})
 
+    @property
+    def packs(self) -> bool:
+        """Whether this learner keeps its per-id tables as one packed
+        array (:class:`PackedTables`). It follows from the placement:
+        where one device holds whole rows of every table, an id's
+        weights and state are one row; tables divided or replicated over
+        a mesh lie apart, each placed by its own rule."""
+        return self.mesh is None
+
+    def table_layout(self) -> Tuple[Tuple[str, int], ...]:
+        """((logical table, its columns; 0 for a 1-D table), ...) in the
+        order the step's head reads them, a packed row's column order."""
+        k = self.param.num_factors
+        width = {"v": k, "a": k}
+        names = SGD_TABLES if self.param.optimizer == "sgd" else FTRL_TABLES
+        return tuple((name, width.get(name, 0)) for name in names)
+
     def _initialiser(self, num_features: int):
         """``seed -> params`` of this learner's shapes over
-        ``num_features`` ids."""
-        return partial(init_fm_params, num_features, self.param.num_factors,
-                       self.param.init_scale, optimizer=self.param.optimizer)
+        ``num_features`` ids, in the grouping it keeps them in: the
+        tables of :func:`init_fm_params`, or the same values as one
+        packed array (:func:`init_packed`)."""
+        if not self.packs:
+            return partial(
+                init_fm_params, num_features, self.param.num_factors,
+                self.param.init_scale, optimizer=self.param.optimizer)
+        return partial(
+            init_packed, num_features, self.table_layout(),
+            partial(_fm_draw, init_scale=self.param.init_scale), {},
+            {"b": jnp.zeros((), jnp.float32)})
 
     def _make_step(self, num_features: int):
         return make_fm_train_step(
@@ -880,21 +1216,28 @@ class FMLearner(FeedLearner):
         if self.mesh is None:
             return None
         # the rules go by a leaf's name and rank, not by its size
-        template = jax.eval_shape(self._initialiser(2))
+        template = jax.eval_shape(self._initialiser(2), 0)
         return sharding_tree(
             self.mesh,
             match_partition_rules(self.partition_rules(), template))
 
+    def init_tables(self, seed, num_features: int = 0) -> None:
+        """The learner's own storage from ``seed``: ONE jitted program
+        with the seed as an argument (one program for every seed), its
+        output placed by the learner's own shardings, so that on a mesh a
+        chip writes its own part and no whole table exists on any one of
+        them first, and a packed row is written as one array (the draws
+        into ``v``'s columns, the rest beside them) with the draws of
+        :func:`init_fm_params`."""
+        nf = self.param.num_features or num_features
+        self.params = jax.jit(
+            self._initialiser(nf), out_shardings=self.param_shardings())(
+                jnp.uint32(int(seed) % (1 << 32)))
+        self._nf = nf
+
     def _ensure(self, num_features: int):
         if self.params is None:
-            nf = self.param.num_features or num_features
-            init = self._initialiser(nf)
-            # on a mesh the initialiser runs as one program placed by the
-            # rules: a chip writes its own part and no whole table exists
-            # on any one of them first
-            self.params = init() if self.mesh is None else jax.jit(
-                init, out_shardings=self.param_shardings())()
-            self._nf = nf
+            self.init_tables(0, num_features)
         if self._step is None:
             self._step = self._make_step(
                 self._nf or self.param.num_features or num_features)
@@ -909,24 +1252,43 @@ class FMLearner(FeedLearner):
         shards = self.table_shards
         if shards > 1 and bucket not in self._bytes_of:
             self._bytes_of[bucket] = exchange_bytes(arrays, shards)
+        self._packed_steps += isinstance(self.params, PackedTables)
         self.params, metrics = self._step(self.params, arrays)
         return metrics
 
+    @property
+    def row_columns(self) -> int:
+        """The columns of the one row that holds an id's weights and
+        state; 0 when the tables lie apart."""
+        packed = self.packs if self.params is None else isinstance(
+            self.params, PackedTables)
+        return _columns(w for _, w in self.table_layout()) if packed else 0
+
     def epoch_span_args(self) -> Dict:
         return {"table_shards": self.table_shards,
-                "optimizer": self.optimizer}
+                "optimizer": self.optimizer,
+                "row_columns": self.row_columns}
+
+    def audit_params(self):
+        """The arrays as they lie (a packed row as ``rows``): a sample
+        of a logical table would copy its columns."""
+        if isinstance(self.params, PackedTables):
+            return dict(self.params.scalars, rows=self.params.rows)
+        return self.params
 
     def state_bytes(self) -> int:
         """Bytes of optimizer state one chip holds: its part of every
-        table the rule keeps beside the weights (0 under plain SGD)."""
+        table the rule keeps beside the weights (0 under plain SGD),
+        their logical columns wherever they lie."""
         if self.params is None:
             return 0
         # from the shapes: ``a`` is divided as ``v`` is, ``z`` and ``n``
         # are whole on every chip
         return sum(
-            int(self.params[k].nbytes)
-            // (self.table_shards if self.params[k].ndim == 2 else 1)
-            for k in self.state_tables)
+            4 * self._nf * max(width, 1)
+            // (self.table_shards if width else 1)
+            for name, width in self.table_layout()
+            if name in self.state_tables)
 
     def epoch_closed(self, reg, nstep: int, sums: Dict) -> None:
         """FM's own counters. The step was built for ``self.mesh`` and the
@@ -947,7 +1309,12 @@ class FMLearner(FeedLearner):
         ``dmlc_fit_stateful_update_steps_total`` counts the steps that
         took a rule with per-row state (``optimizer`` names it; none
         under ``"sgd"``), ``dmlc_fit_optimizer_state_bytes`` what that
-        state holds of one chip's memory."""
+        state holds of one chip's memory.
+
+        ``dmlc_fit_packed_row_steps_total`` counts the steps whose
+        program read and wrote ONE packed row for each touched id (the
+        tree the step took was a :class:`PackedTables`): every step on
+        one device, none on a mesh."""
         shards = self.table_shards
         sparse = self.mesh is None or shards > 1
         reg.counter(
@@ -987,7 +1354,13 @@ class FMLearner(FeedLearner):
             "dmlc_fit_optimizer_state_bytes",
             "bytes of optimizer state on one chip, beside the weights",
             model=self.name).set(self.state_bytes())
+        reg.counter(
+            "dmlc_fit_packed_row_steps_total",
+            "optimizer steps that read and wrote one packed row (weights "
+            "and optimizer state side by side) for each touched id",
+            model=self.name).inc(self._packed_steps)
         self._steps_of.clear()
+        self._packed_steps = 0
 
     def fit_uri(self, uri: str, **kw):
         """:func:`dmlc_tpu.models.fitloop.fit_uri` for this learner
@@ -1001,20 +1374,31 @@ class FMLearner(FeedLearner):
         # bookkeeping of a pass a preemption cut short, or of other shapes
         self._steps_of.clear()
         self._bytes_of.clear()
+        self._packed_steps = 0
         return fitloop.fit_feed(self, feed, *args, **kw)
 
     def snapshot_model(self) -> Dict:
-        """The params as the device arrays they are; a table sharded over
-        chips reaches the host as the ONE logical ``[F, K]`` array
-        (``collective.checkpoint._to_host`` assembles it shard by shard),
-        so a snapshot restores under any placement."""
-        return {"params": dict(self.params)}
+        """The params by LOGICAL table, whatever array holds them: a
+        table sharded over chips reaches the host as the ONE logical
+        ``[F, K]`` array (``collective.checkpoint._to_host`` assembles
+        the device arrays handed over here shard by shard), a packed row
+        is copied to the host once and cut there into its tables. A
+        snapshot has the same keys under every placement and restores
+        under any."""
+        params = self.params
+        if not isinstance(params, PackedTables):
+            return {"params": dict(params)}
+        host = np.array(params.rows, copy=True)
+        tables = {name: np.ascontiguousarray(_span(host, params.span(name)))
+                  for name, _ in params.layout}
+        return {"params": dict(tables, **params.scalars)}
 
     def restore_snapshot_model(self, model: Dict) -> None:
         """Re-place a snapshot's host FM params on device: straight from
         the host arrays to this learner's placement (each chip receives
-        only the part its rules give it; the snapshot's own placement
-        does not matter, the table is one logical array)."""
+        only the part its rules give it; one device receives the tables
+        joined on the host into its packed row; the snapshot's own
+        placement does not matter, a table is one logical array)."""
         params = model["params"]
         want = (self.param.num_features or params["v"].shape[0],
                 self.columns)
@@ -1027,17 +1411,53 @@ class FMLearner(FeedLearner):
               "snapshot holds the optimizer state %s, optimizer=%r keeps %s",
               held, self.optimizer, need)
         self._nf = want[0]
-        if self.mesh is None:
-            self.params = {k: jnp.asarray(v) for k, v in params.items()}
+        if self.packs:
+            self.params = jax.tree_util.tree_map(
+                jnp.asarray, PackedTables.pack(
+                    {k: np.asarray(v) for k, v in params.items()},
+                    self.table_layout()))
         else:
             self.params = shard_params(
                 params, self.mesh, rules=self.partition_rules())
 
     def predict_batch(self, batch) -> np.ndarray:
         _, _, vw, row_ids, values = _entries_in_id_order(
-            (self.params["v"], self.params["w"]), batch)
+            *_head_tables(self.params, SGD_TABLES), batch)
         _, s, q, linear = _row_sums(
             vw, row_ids, values, int(batch["label"].shape[0]))
         return np.asarray(
             self.params["b"] + linear + 0.5 * jnp.sum(s * s - q, axis=-1)
         )
+
+    # what the benchmark's check reads a learner's tables through
+    # (benchmarks/harness/tables.py), with :meth:`init_tables`
+
+    def table_names(self) -> Tuple[str, ...]:
+        """The logical per-id tables, weights and state, by the names the
+        model's equations use."""
+        return tuple(name for name, _ in self.table_layout())
+
+    def scalars(self) -> Dict[str, float]:
+        names = self.table_names()
+        return {k: float(self.params[k]) for k in self.params
+                if k not in names}
+
+    def _held(self, name: str):
+        """(the array that holds logical table ``name``, its columns
+        there; None where the array is the table)."""
+        if isinstance(self.params, PackedTables):
+            return self.params.rows, self.params.span(name)
+        return self.params[name], None
+
+    def table_rows(self, name: str, ids):
+        """Logical table ``name`` at ``ids`` (``[n]`` or ``[n, K]``, a
+        device array): one jitted gather from the array that holds it."""
+        array, span = self._held(name)
+        return _rows_at(array, ids, span=span)
+
+    def table_fingerprints(self, name: str):
+        """``uint32[F]``: the wrapping sum of the bit patterns of each
+        row of logical table ``name``, inside one jit, with no copy of a
+        table."""
+        array, span = self._held(name)
+        return _row_fingerprints(array, span=span)
