@@ -361,6 +361,10 @@ class _HostStage:
         # _FACTS of each pass the producer has wound past and the
         # consumer has not left (DeviceFeed.before_first), oldest first
         self.ended: deque = deque()
+        # the pass the batches being produced belong to, for the
+        # ``produce`` span: the producer's own rewind moves it on, and the
+        # feed sets it where it restarts the producer itself
+        self.pass_ = 0
 
     @property
     def rewinds_itself(self) -> bool:
@@ -412,6 +416,7 @@ class _HostStage:
             except Exception:  # noqa: BLE001 — see docstring
                 return False
             self.ended.append(facts)
+            self.pass_ += 1
         return True
 
     # ---- re-batch parser blocks into fixed-size slices ------------------
@@ -424,19 +429,24 @@ class _HostStage:
             producer = self._host_batches_python()
         cpu = self.cpu
         cpu_at = time.thread_time_ns()
-        while True:
+        npass = self.pass_
+        for nbatch in itertools.count():
             faultpoint("device.feed")
-            t0 = time.monotonic_ns()
-            try:
-                item = next(producer)
-            except StopIteration:
-                return
-            finally:
-                self.host_batch_ns.observe(time.monotonic_ns() - t0)
-                if cpu is not None:
-                    now = time.thread_time_ns()
-                    cpu.observe(now - cpu_at)
-                    cpu_at = now
+            # the batch's life begins here, under the identifier every
+            # later span of it carries; the pass is the one the batch
+            # BELONGS to (the producer stages pass n+1 while the consumer
+            # is still in pass n)
+            with obs.span("produce", hist=self.host_batch_ns, pass_=npass,
+                          batch=nbatch):
+                try:
+                    item = next(producer)
+                except StopIteration:
+                    return
+                finally:
+                    if cpu is not None:
+                        now = time.thread_time_ns()
+                        cpu.observe(now - cpu_at)
+                        cpu_at = now
             yield item
 
     def _host_batches_python(self) -> Iterator:
@@ -612,6 +622,19 @@ class DeviceFeed:
                 "dmlc_feed_consume_ns",
                 "per-batch time the consumer held the batch", feed=fid),
         }
+        # the parts of a batch on the consumer thread that stats() does
+        # not window: each is the ``hist=`` of the span of its name
+        self._h_stage = reg.histogram(
+            "dmlc_feed_stage_ns",
+            "per-batch padding / densifying on the consumer thread (the "
+            "Python re-batch producer's batches only)", feed=fid)
+        self._h_put = reg.histogram(
+            "dmlc_feed_put_ns",
+            "per-batch device_put submission alone", feed=fid)
+        self._m_unlanded = reg.counter(
+            "dmlc_feed_unlanded_deliveries_total",
+            "batches handed to the consumer before their host-to-device "
+            "copy had landed", feed=fid)
         self._m_batches = reg.counter(
             "dmlc_feed_batches_total", "device batches delivered", feed=fid)
         # rows delivered — the goodput ledger's examples/s numerator
@@ -694,22 +717,23 @@ class DeviceFeed:
             return None
         return NamedSharding(self._mesh, spec)
 
-    def _put_tree(self, arrays: dict, specs: dict) -> dict:
+    def _put_tree(self, arrays: dict, specs: dict, nbatch: int = 0) -> dict:
         """One batched transfer for all of a batch's arrays: per-array
         device_put pays the dispatch overhead N times; a pytree
-        device_put batches them.
-        With device telemetry on, the put is metered: payload bytes →
-        ``dmlc_feed_h2d_bytes_total``, submission MB/s →
-        ``dmlc_feed_h2d_mbps``."""
+        device_put batches them. The ``put`` span covers the submission
+        alone (``dmlc_feed_put_ns``).
+        With device telemetry on, the put is metered from the span's own
+        clock reads: payload bytes → ``dmlc_feed_h2d_bytes_total``, bytes
+        over the submission's time → ``dmlc_feed_h2d_mbps``."""
+        with obs.span("put", hist=self._h_put, pass_=self._pass,
+                      batch=nbatch) as span:
+            out = self._put_tree_raw(arrays, specs)
         meter = self._h2d
-        if meter is None:
-            return self._put_tree_raw(arrays, specs)
-        nbytes = 0
-        for v in arrays.values():
-            nbytes += getattr(v, "nbytes", 0)
-        t0 = time.monotonic_ns()
-        out = self._put_tree_raw(arrays, specs)
-        meter.note(nbytes, time.monotonic_ns() - t0)
+        if meter is not None:
+            nbytes = 0
+            for v in arrays.values():
+                nbytes += getattr(v, "nbytes", 0)
+            meter.note(nbytes, span.dur_ns)
         return out
 
     def _put_tree_raw(self, arrays: dict, specs: dict) -> dict:
@@ -819,16 +843,17 @@ class DeviceFeed:
                 {"x": x, "label": labels, "weight": weights},
                 {"x": P(self._axis), "label": P(self._axis),
                  "weight": P(self._axis)},
+                nbatch,
             )
             out["num_rows"] = rows
             return out, ()
         if isinstance(block, (DeviceCSRBatch, ShardedCSRBatch)):
             # native COO batch: staged in C++, no pooled staging to retire
-            return self._put_csr(block), ()
+            return self._put_csr(block, nbatch), ()
         if spec.layout == "dense":
             check(spec.num_features > 0, "dense layout requires num_features")
-            with obs.span("stage", rows=len(block), pass_=self._pass,
-                          batch=nbatch):
+            with obs.span("stage", hist=self._h_stage, rows=len(block),
+                          pass_=self._pass, batch=nbatch):
                 for fid in flows:
                     obs.flow_step(fid, "chunk")
                 x, labels, weights = block_to_dense(
@@ -838,13 +863,14 @@ class DeviceFeed:
                 {"x": x, "label": labels, "weight": weights},
                 {"x": P(self._axis), "label": P(self._axis),
                  "weight": P(self._axis)},
+                nbatch,
             )
             out["num_rows"] = len(block)
             return out, (x, labels, weights)
         if spec.layout == "csr":
             shards = self._shards
-            with obs.span("stage", rows=len(block), pass_=self._pass,
-                          batch=nbatch):
+            with obs.span("stage", hist=self._h_stage, rows=len(block),
+                          pass_=self._pass, batch=nbatch):
                 for fid in flows:
                     obs.flow_step(fid, "chunk")
                 if shards > 1:
@@ -860,10 +886,10 @@ class DeviceFeed:
                     )
                     bufs = (batch.labels, batch.weights, batch.indices,
                             batch.values, batch.row_ids, batch.offsets)
-            return self._put_csr(batch), bufs
+            return self._put_csr(batch, nbatch), bufs
         raise ValueError(f"unknown layout {spec.layout!r}")
 
-    def _put_csr(self, batch):
+    def _put_csr(self, batch, nbatch: int = 0):
         # ShardedCSRBatch: per-shard entry sections — P(axis) on the flat
         # entry arrays ships each device only its own nnz (H2D ∝
         # global_nnz / world). DeviceCSRBatch (no mesh / single shard):
@@ -888,21 +914,28 @@ class DeviceFeed:
                 "values": entry_spec,
                 "offsets": entry_spec,
             },
+            nbatch,
         )
         out["num_rows"] = batch.num_rows
         out["num_nonzero"] = batch.num_nonzero
         return out
 
     def _deliver(self, entry):
-        """Retire a pending batch's staging buffers — guarded by its own
-        device arrays, asked NOW, before a donating consumer deletes
-        them: they are reused only if the async H2D copy has landed —
-        and hand the batch to the consumer."""
+        """Ask whether the batch's async H2D copy has landed — of its own
+        device arrays, NOW, before a donating consumer deletes them —
+        count the batch where it has not
+        (``dmlc_feed_unlanded_deliveries_total``: its step's launch will
+        wait for its input), retire its staging buffers, which are reused
+        only if it has, and hand the batch to the consumer."""
         batch, bufs = entry[0], entry[1]
+        guards = [v for v in batch.values() if hasattr(v, "is_ready")]
+        landed = all(g.is_ready() for g in guards)
+        if not landed:
+            self._m_unlanded.inc()
         if bufs:
-            self.pool.retire(
-                bufs, [v for v in batch.values() if isinstance(v, jax.Array)]
-            )
+            # asked once where the copy has landed; a pool handed guards
+            # asks them itself
+            self.pool.retire(bufs, () if landed else guards)
         return batch
 
     def __iter__(self):
@@ -919,20 +952,27 @@ class DeviceFeed:
         cpu = self._m_cpu["consumer"]
         cpu_at = time.thread_time_ns()
 
+        stage = self._stage
+        # sync mode has no producer thread to wait on: the time inside
+        # next() IS host production and already accrues to the host_batch
+        # stage — also counting it as a wait would double-book the stage
+        # breakdown
+        wait_hist = None if self._sync_host else stage["host_wait_ns"]
+
         def _consume(entry):
             nonlocal cpu_at
-            batch = self._deliver(entry)
+            with obs.span("deliver", pass_=npass, batch=entry[4]):
+                batch = self._deliver(entry)
             flows = entry[2]
-            t2 = time.monotonic_ns()
             # the consume span covers the yield: its duration IS the time
             # the consumer held the batch (generator suspended). The
             # thread-local current flow and batch id are set for that same
             # window so fit-loop spans (train_step, collective ops) can
             # mark the in-flight chunk and batch; flow_end fires inside
             # the span, closing the arrow chain on the consume slice.
-            span = obs.span("consume", pass_=npass, batch=entry[4])
-            live = span is not obs.NOOP_SPAN
-            with span:
+            with obs.span("consume", hist=stage["consume_ns"], pass_=npass,
+                          batch=entry[4]) as span:
+                live = span.live
                 if flows:
                     obs.set_current_flow(flows[0])
                 if live:
@@ -946,7 +986,6 @@ class DeviceFeed:
                         obs.set_current_batch(None)
                     for fid in flows:
                         obs.flow_end(fid, "chunk")
-            self._stage["consume_ns"].observe(time.monotonic_ns() - t2)
             if self._host.ack is not None:
                 # the consumer released the batch: every chunk whose rows
                 # first appeared in it is now consumed — advance the
@@ -961,23 +1000,17 @@ class DeviceFeed:
 
         while True:
             with obs.span("feed_batch", pass_=npass, batch=nbatch):
-                t0 = time.monotonic_ns()
                 try:
-                    block = next(it)
+                    # the wait for the producer's queue
+                    with obs.span("take", hist=wait_hist, pass_=npass,
+                                  batch=nbatch):
+                        block = next(it)
                 except StopIteration:
                     break
-                finally:
-                    # sync mode has no producer thread to wait on: the time
-                    # inside next() IS host production and already accrues
-                    # to the host_batch stage — also counting it here would
-                    # double-book the stage breakdown
-                    if not self._sync_host:
-                        self._stage["host_wait_ns"].observe(
-                            time.monotonic_ns() - t0)
-                t1 = time.monotonic_ns()
                 flows = getattr(block, "flow_ids", ())
                 seqs = getattr(block, "seq_ids", ())
-                with obs.span("dispatch", pass_=npass, batch=nbatch):
+                with obs.span("dispatch", hist=stage["dispatch_ns"],
+                              pass_=npass, batch=nbatch):
                     for fid in flows:
                         obs.flow_step(fid, "chunk")
                     batch_bufs = self._to_device(block, flows, nbatch)
@@ -985,7 +1018,6 @@ class DeviceFeed:
                     # _consume can close flows and ack seqs on delivery,
                     # and the batch's number for its consume span
                     pending.append(batch_bufs + (flows, seqs, nbatch))
-                self._stage["dispatch_ns"].observe(time.monotonic_ns() - t1)
                 self._m_batches.inc()
                 # row accounting across block shapes: native dense tuple
                 # carries its count at [3], padded batches as num_rows,
@@ -1056,6 +1088,7 @@ class DeviceFeed:
             # producer that staged ahead emits none that carry it
             self._pass += 1
             if not prewound:
+                host.pass_ = self._pass
                 self._host_iter.before_first()
 
     @property
@@ -1079,6 +1112,7 @@ class DeviceFeed:
             return
         self._host_iter.close()
         self._host.ended.clear()
+        self._host.pass_ = self._pass
         restore(plan)
         self._host_iter.before_first()
 

@@ -33,10 +33,15 @@ that declares it; linear and FM fill it in):
 
 The loop never waits for the device inside a pass: the one wait is the
 pass's loss read-back in :meth:`FitLoopObs.finish_epoch`, under the
-``loss_readback`` span; the bookkeeping after it is ``epoch_close``.
+``loss_readback`` span, which holds the wait for the last step
+(``drain_wait``) apart from the fetch of the pass's scalars
+(``loss_fetch``); the bookkeeping after it is ``epoch_close``.
 Device time per step comes from the profile (the benchmark's
-``step_device_ms``), not from a host-side sync. ``log_every`` counts
-epochs, here and in every learner.
+``step_device_ms``), not from a host-side sync. What the loop does ask
+the device, once a step and without waiting, is which of the steps it has
+launched are done (:meth:`EpochMetrics.inflight`): the host's lead over
+the chip, ``dmlc_fit_inflight_steps``. ``log_every`` counts epochs, here
+and in every learner.
 
 :class:`FitLoopObs` is the epoch boundary every learner shares: the four
 ``dmlc_fit_*`` metrics, a goodput-ledger window per epoch
@@ -70,6 +75,8 @@ from dmlc_tpu.parallel.partition import shard_params
 from dmlc_tpu.resilience import Preempted, preempt
 from dmlc_tpu.utils.logging import check, log_info
 
+# steps in flight: the runtime's queue caps the lead at about 31
+_INFLIGHT_BUCKETS = (0, 1, 2, 4, 8, 16, 24, 32, 48, 64, 128)
 _DENSE_KEYS = ("x", "label", "weight")
 _CSR_KEYS = ("label", "weight", "indices", "values", "offsets")
 
@@ -128,23 +135,86 @@ class EpochMetrics:
     (FM's ``touched_rows``) reach its ``epoch_closed`` through
     :attr:`sums`."""
 
+    #: scalars asked ``is_ready()`` at most, one :meth:`inflight` call
+    MAX_POLLS = 4
+
     def __init__(self):
         self._pending: Dict[str, list] = {}
         #: name -> the sum of that metric over the steps read so far
         self.sums: Dict[str, float] = {}
+        # how many of the steps' scalars have been seen ready
+        self._ready = 0
 
     def add(self, metrics: Dict) -> None:
         for name, scalar in metrics.items():
             self._pending.setdefault(name, []).append(scalar)
+
+    def inflight(self, max_polls: Optional[int] = MAX_POLLS) -> int:
+        """Steps launched and not yet done on the device: the host's lead
+        over the chip, without a wait. A pointer into the first metric's
+        pending scalars, one a step in launch order (every scalar of a
+        step is an output of one run of one program, so any of them tells
+        when the step is done), moves on while ``scalar.is_ready()``, at
+        most ``max_polls`` questions a call (``None``: as many as there
+        are): a call once a step asks about two where host and chip keep
+        pace, and the reading lags by a step or two after the chip has
+        caught up many at once."""
+        watch = next(iter(self._pending.values()), ())
+        n = len(watch)
+        ready = self._ready
+        polls = n if max_polls is None else max_polls
+        while ready < n and polls > 0:
+            is_ready = getattr(watch[ready], "is_ready", None)
+            if is_ready is not None and not is_ready():
+                break
+            ready += 1
+            polls -= 1
+        self._ready = ready
+        return n - ready
+
+    @property
+    def pending_scalars(self) -> int:
+        """Device scalars the next :meth:`mean_loss` will fetch."""
+        return sum(len(values) for values in self._pending.values())
+
+    def start_fetch(self) -> None:
+        """Queue the device-to-host copy of every pending scalar, without
+        waiting: what ``jax.device_get`` does first, for the whole tree.
+        Queued while the device still runs the pass's last steps, each
+        copy rides behind the step that makes its scalar; a scalar read
+        with no copy queued is fetched there and then, one after another
+        on an idle chip (measured on a v5e: 384 scalars, 30-40 ms a
+        pass)."""
+        for values in self._pending.values():
+            for scalar in values:
+                start = getattr(scalar, "copy_to_host_async", None)
+                if start is not None:
+                    start()
+
+    def _read(self, pending: Dict[str, list]) -> None:
+        """Sum ``pending`` into :attr:`sums`, scalar by scalar, as
+        ``jax.device_get`` reads a tree once it has queued the copies."""
+        for name, values in pending.items():
+            got = [np.asarray(scalar) for scalar in values]
+            self.sums[name] = self.sums.get(name, 0) + np.sum(got).item()
+
+    def drain(self) -> None:
+        """Read the first metric's scalars, one of every step in launch
+        order: the read returns when the last step's has arrived, so it
+        is the wait for the device to drain (where one ``jax.device_get``
+        of the whole tree waits, which reads this list first)."""
+        for name in list(self._pending)[:1]:
+            self._read({name: self._pending.pop(name)})
+        self._ready = 0
 
     def mean_loss(self) -> float:
         if self._pending:
             # drain the pending scalars into the running totals: a repeated
             # read never re-fetches what was already summed, and the device
             # scalars are released here, where they were read
-            for name, values in jax.device_get(self._pending).items():
-                self.sums[name] = self.sums.get(name, 0) + np.sum(values).item()
+            self._read(self._pending)
             self._pending.clear()
+            self._ready = 0
         return self.sums.get("loss_sum", 0.0) / max(
             self.sums.get("weight_sum", 0.0), 1e-12)
 
@@ -164,6 +234,14 @@ class FitLoopObs:
             "dmlc_fit_loss_value", "last epoch mean loss", model=model)
         self.h_epoch = self.reg.histogram(
             "dmlc_fit_epoch_ns", "wall time per epoch", model=model)
+        # the host's lead over the chip, observed once a step before the
+        # launch; (sum, count) at the last epoch boundary give the pass's
+        # mean for the log line
+        self.h_inflight = self.reg.histogram(
+            "dmlc_fit_inflight_steps",
+            "steps launched and not yet done on the device, read before "
+            "each launch", buckets=_INFLIGHT_BUCKETS, model=model)
+        self._lead_at = (0.0, 0)
         # the fit owns a device, so its roofline reads that device's
         # published peaks (knob overrides win; an unknown kind gives no
         # MFU at all)
@@ -179,15 +257,39 @@ class FitLoopObs:
         ``DMLC_TPU_METRICS=0``)."""
         self.ledger.note_step(n)
 
+    def pass_lead(self) -> Optional[float]:
+        """Mean of ``dmlc_fit_inflight_steps`` since the last call: the
+        pass's mean lead at an epoch boundary. None where no step was
+        observed (GBDT's one-scan fit; metrics off)."""
+        hist = self.h_inflight
+        total, count = hist.sum, hist.count
+        base_total, base_count = self._lead_at
+        self._lead_at = (total, count)
+        if count <= base_count:
+            return None
+        return (total - base_total) / (count - base_count)
+
     def finish_epoch(self, epoch: int, nstep: int, t0_ns: int, acc,
                      history: list, **end_epoch_kw) -> float:
         """The epoch boundary of the streaming learners (linear, FM):
         read the pass's mean loss back from ``acc`` (an
         ``EpochMetrics``) — the one point where the loop waits for the
         device to drain, under the ``loss_readback`` span — append it to
-        ``history`` and close the epoch (:meth:`end_epoch`)."""
+        ``history`` and close the epoch (:meth:`end_epoch`). Inside the
+        span, apart: ``drain_wait``, the read of the first metric's
+        scalars, which ends with the last step's (the chip is busy until
+        then), and ``loss_fetch``, the read of the other metrics' and the
+        sums, once the chip has drained (the chip is idle). Every copy is
+        queued before the wait, as one ``jax.device_get`` of the whole
+        tree queues them before it reads the first: each rides behind the
+        step that makes its scalar, none waits for an idle chip. Neither
+        span has a counter: nothing would read one."""
         with obs.span("loss_readback", model=self.model, epoch=epoch):
-            loss = acc.mean_loss()
+            acc.start_fetch()
+            with obs.span("drain_wait", steps=acc.inflight(None)):
+                acc.drain()
+            with obs.span("loss_fetch", scalars=acc.pending_scalars):
+                loss = acc.mean_loss()
         history.append(loss)
         self.end_epoch(epoch, nstep, t0_ns, loss, **end_epoch_kw)
         return loss
@@ -231,12 +333,18 @@ class FitLoopObs:
             if win is not None:
                 win["nonfinite"] = nonfinite
                 self.watchdog.observe(win)
+            lead = self.pass_lead()
             if log_every and (epoch + 1) % log_every == 0:
                 parts = ["%s epoch %d" % (self.model, epoch)]
                 if loss is not None:
                     parts.append("loss %.6f" % loss)
                 if feed is not None:
                     parts.append(stall_breakdown(feed.stats()))
+                if lead is not None:
+                    # steps launched ahead of the chip, the pass's mean:
+                    # near the runtime queue's cap the host has room,
+                    # near 1 the job is host-bound
+                    parts.append("lead %.1f" % lead)
                 if win is not None:
                     parts.append("goodput %.2f binding=%s" % (
                         win["goodput"]["ratio"], win["binding"]))
@@ -396,10 +504,13 @@ def fit_feed(learner, feed, epochs: int = 1, log_every: int = 0,
                       **learner.epoch_span_args()):
             for batch in feed:
                 learner.ensure_step(spec)
+                # the host's lead over the chip before this launch
+                lead = acc.inflight()
+                fl.h_inflight.observe(lead)
                 # train_step closes the chunk's arrow chain: the feed
                 # set the thread's current flow around this yield
                 with obs.span("train_step", model=name, step=nstep,
-                              **obs.current_batch()):
+                              inflight=lead, **obs.current_batch()):
                     obs.flow_step(obs.current_flow(), "chunk")
                     # the last reference to the previous batch's arrays
                     # goes here, so they are released inside this span

@@ -7,8 +7,9 @@ bottleneck diagnosis and auto-tuning):
 - :func:`registry` — the process-wide label-aware Counter/Gauge/Histogram
   store every stage counter lives in (``DMLC_TPU_METRICS=0`` disables;
   see obs/metrics.py)
-- :func:`span` / :func:`step_span` — Chrome-trace span context managers
-  gated by ``DMLC_TPU_TRACE=<path>`` (see obs/trace.py)
+- :func:`span` — the Chrome-trace span context manager gated by
+  ``DMLC_TPU_TRACE=<path>``; with ``hist=`` it also observes its duration
+  into a registry histogram, tracing on or off (see obs/trace.py)
 - :func:`new_flow` / :func:`flow_start` / :func:`flow_step` /
   :func:`flow_end` — causal dataflow arrows (Chrome-trace flow events)
   connecting a chunk's io→parse→stage→dispatch→consume journey across
@@ -83,7 +84,6 @@ from dmlc_tpu.obs.trace import (
     set_current_batch,
     set_current_flow,
     span,
-    step_span,
 )
 
 __all__ = [
@@ -93,7 +93,6 @@ __all__ = [
     "Registry",
     "registry",
     "span",
-    "step_span",
     "NOOP_SPAN",
     "new_flow",
     "flow_start",

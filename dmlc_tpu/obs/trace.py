@@ -20,8 +20,13 @@ job trace, obs/plane.py) recover it as ``anchor_unix_ns + ts·1000``.
 The emitted JSON stays Perfetto-compatible — extra top-level keys next
 to ``traceEvents`` are part of the Chrome trace object format.
 
-Span durations are measured by :class:`dmlc_tpu.utils.timer.Timer` (the
-repo's one stopwatch — obs reuses it rather than growing a second one).
+A span reads the clock twice: ``time.monotonic_ns()`` at entry and at
+exit give its ``ts``, its ``dur`` and — where the caller passes a registry
+histogram, ``span(name, hist=h, ...)`` — the one value the histogram
+observes, so a span and the counter of the same name time the same
+interval. ``hist`` fills with tracing on or off: with tracing off the span
+is a two-read timer that records no event (and the shared no-op when the
+histogram is the registry's no-op child, ``DMLC_TPU_METRICS=0``).
 
 Listeners: :func:`add_listener` registers a callback invoked with each
 completed span event. While any listener is registered, spans are
@@ -31,17 +36,18 @@ way), but the in-process buffer only grows when a trace *file* is
 configured, so a listener alone cannot leak memory.
 
 Optional jax bridging: with ``DMLC_TPU_TRACE_JAX=1`` each span also enters
-a ``jax.profiler.TraceAnnotation`` (and ``step_span`` a
-``StepTraceAnnotation``) when the running jax exposes them, so the same
-span names show up inside an XLA profiler capture next to the device
-timeline. The span's args go with it (``TraceAnnotation(name, **args)``)
-and land as the event's stats in the ``.xplane.pb``; the event's name
-stays bare. The two classes are looked up once, by the first bridged
-span. Absent jax or the API, the bridge silently stays off.
+a ``jax.profiler.TraceAnnotation`` when the running jax exposes it, so the
+same span names show up inside an XLA profiler capture next to the device
+timeline, on the device trace's clock. The span's args go with it
+(``TraceAnnotation(name, **args)``) and land as the event's stats in the
+``.xplane.pb``; the event's name stays bare. The class is looked up once,
+by the first bridged span. Absent jax or the API, the bridge silently
+stays off.
 
 Batch identity: ``DeviceFeed`` numbers a batch ``(pass_, batch)`` — its
-pass over the source and the batch's place in that pass — on ``stage``,
-``feed_batch``, ``dispatch`` and ``consume``, and leaves the pair in a
+pass over the source and the batch's place in that pass — on ``produce``
+(the producer's thread), ``feed_batch``, ``take``, ``dispatch``, ``stage``,
+``put``, ``deliver`` and ``consume``, and leaves the pair in a
 thread-local around the consume yield (:func:`set_current_batch`), which
 the fit loop's ``train_step`` span reads back (:func:`current_batch`).
 
@@ -67,7 +73,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
-from dmlc_tpu.utils.timer import Timer
+from dmlc_tpu.obs.metrics import NOOP as _NOOP_METRIC
 
 _lock = threading.Lock()
 _events: List[Dict] = []
@@ -91,12 +97,12 @@ def anchor_unix_ns() -> int:
     return _ANCHOR_UNIX_NS
 
 
-# (TraceAnnotation, StepTraceAnnotation) of the running jax, looked up by
-# the first bridged span; (None, None) when jax or the API is absent
+# (TraceAnnotation,) of the running jax, looked up by the first bridged
+# span; (None,) when jax or the API is absent
 _bridge: Optional[Tuple] = None
 
 
-def _jax_annotation_cls(step: bool = False):
+def _jax_annotation_cls():
     if os.environ.get("DMLC_TPU_TRACE_JAX") != "1":
         return None
     global _bridge
@@ -104,17 +110,20 @@ def _jax_annotation_cls(step: bool = False):
         try:
             import jax.profiler as _jp
         except Exception:
-            _bridge = (None, None)
+            _bridge = (None,)
         else:
-            _bridge = (getattr(_jp, "TraceAnnotation", None),
-                       getattr(_jp, "StepTraceAnnotation", None))
-    return _bridge[1 if step else 0]
+            _bridge = (getattr(_jp, "TraceAnnotation", None),)
+    return _bridge[0]
 
 
 class _NoopSpan:
     """Shared disabled span: stateless, safe to reuse concurrently."""
 
     __slots__ = ()
+    #: whether the span records an event (what ``set_current_batch`` and
+    #: other per-event work is worth doing for)
+    live = False
+    dur_ns = 0
 
     def __enter__(self):
         return self
@@ -126,32 +135,59 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
-class _Span:
-    __slots__ = ("name", "args", "_timer", "_ts", "_annot")
+class _TimedSpan:
+    """``span(name, hist=h)`` with tracing off: the span's two clock reads
+    into its histogram, no event, no args kept."""
 
-    def __init__(self, name: str, args: Dict, annot=None):
+    __slots__ = ("_hist", "_t0", "dur_ns")
+    live = False
+
+    def __init__(self, hist):
+        self._hist = hist
+        self._t0 = 0
+        self.dur_ns = 0
+
+    def __enter__(self):
+        self._t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.dur_ns = time.monotonic_ns() - self._t0
+        self._hist.observe(self.dur_ns)
+        return False
+
+
+class _Span:
+    __slots__ = ("name", "args", "_hist", "_t0", "_annot", "dur_ns")
+    live = True
+
+    def __init__(self, name: str, args: Dict, annot=None, hist=None):
         self.name = name
         self.args = args
-        self._timer = Timer()
-        self._ts = 0.0
+        self._hist = hist
+        self._t0 = 0
         self._annot = annot
+        #: the span's duration, from exit on (what ``hist`` observed)
+        self.dur_ns = 0
 
     def __enter__(self):
         if self._annot is not None:
             self._annot.__enter__()
-        self._ts = _now_us()
-        self._timer.__enter__()
+        self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, *exc):
-        self._timer.__exit__(*exc)
+        # the one pair of reads: ts, dur and the histogram's value
+        dur_ns = self.dur_ns = time.monotonic_ns() - self._t0
         if self._annot is not None:
             self._annot.__exit__(*exc)
+        if self._hist is not None:
+            self._hist.observe(dur_ns)
         event = {
             "name": self.name,
             "ph": "X",
-            "ts": self._ts,
-            "dur": self._timer.elapsed * 1e6,
+            "ts": (self._t0 - _EPOCH_MONO_NS) / 1e3,
+            "dur": dur_ns / 1e3,
             "pid": _PID,
             "tid": threading.get_ident(),
         }
@@ -201,30 +237,28 @@ def remove_listener(fn: Callable[[Dict], None]) -> None:
             pass
 
 
-def span(name: str, **args):
+def span(name: str, hist=None, **args):
     """Context manager timing one pipeline stage as a named trace span.
 
-    No-op (a shared inert object) unless ``DMLC_TPU_TRACE`` names an
-    output file or a listener is registered. Keyword args become the
-    event's ``args`` payload — keep them small and JSON-serializable
-    (chunk/batch indices)."""
+    Keyword args become the event's ``args`` payload — keep them small
+    and JSON-serializable (chunk/batch indices). ``hist``: a registry
+    histogram that observes the span's duration in ns at exit, from the
+    same two clock reads that give the event's ``ts`` and ``dur``, with
+    tracing on or off.
+
+    Tracing is off unless ``DMLC_TPU_TRACE`` names an output file or a
+    listener is registered. The span is then the shared inert
+    ``NOOP_SPAN`` where there is nothing to observe into (no ``hist``, or
+    the registry's no-op child under ``DMLC_TPU_METRICS=0``), else a
+    two-read timer that records no event."""
     if _active_path() is None and not _listeners:
-        return NOOP_SPAN
+        if hist is None or hist is _NOOP_METRIC:
+            return NOOP_SPAN
+        return _TimedSpan(hist)
     _ensure_atexit()
     cls = _jax_annotation_cls()
     annot = cls(name, **args) if cls is not None else None
-    return _Span(name, args, annot)
-
-
-def step_span(step_num: int, name: str = "step", **args):
-    """Like :func:`span` but bridges to ``jax.profiler.StepTraceAnnotation``
-    (the profiler's step marker) when available — for fit-loop epochs."""
-    if _active_path() is None and not _listeners:
-        return NOOP_SPAN
-    _ensure_atexit()
-    cls = _jax_annotation_cls(step=True)
-    annot = cls(name, step_num=step_num, **args) if cls is not None else None
-    return _Span(name, dict(args, step=step_num), annot)
+    return _Span(name, args, annot, hist)
 
 
 # ---- Flow events (causal dataflow arrows) -------------------------------

@@ -28,7 +28,10 @@ UNITS = {"total", "ns", "bytes", "rows", "value", "count", "rank", "version",
          # compiled-step cost attribution (obs/xla_cost.py + goodput MFU):
          # per-call FLOPs, "bytes accessed" (XLA cost_analysis's own key,
          # kept verbatim), a 0..1 utilization ratio, sampled milliseconds
-         "flops", "accessed", "ratio", "ms"}
+         "flops", "accessed", "ratio", "ms",
+         # the host's lead over the chip (models/fitloop.py): a count of
+         # launched steps, observed a step at a time into a histogram
+         "steps"}
 
 # ".counter(" / ".gauge(" / ".histogram(" followed by a string literal —
 # matches across the line break of a wrapped call
@@ -40,7 +43,7 @@ CALL_RE = re.compile(
 DOC_NAME_RE = re.compile(
     r"`(dmlc_[a-z0-9_]+_"
     r"(?:total|ns|bytes|rows|value|count|rank|version|mbps"
-    r"|flops|accessed|ratio|ms))"
+    r"|flops|accessed|ratio|ms|steps))"
 )
 
 

@@ -1,6 +1,8 @@
 """The benchmark's trace reduction on recorded v5e traces (CPU only, a few
-seconds): benchmarks/testdata/check.py for harness/xplane.py, and
-check_timeline.py for harness/timeline.py and every reader built on it.
+seconds): benchmarks/testdata/check.py for harness/xplane.py,
+check_timeline.py for harness/timeline.py and every reader built on it,
+and check_pass_boundary.py for the readers of the pass boundary (a third
+trace, from a program that has their spans).
 Also: each per-layer metric BENCHMARK.json declares has its two files."""
 
 import json
@@ -14,7 +16,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmarks")
 
 
-@pytest.mark.parametrize("script", ["check.py", "check_timeline.py"])
+@pytest.mark.parametrize(
+    "script", ["check.py", "check_timeline.py", "check_pass_boundary.py"])
 def test_recorded_trace_reduces_to_expected(script):
     proc = subprocess.run(
         [sys.executable, os.path.join(BENCH, "testdata", script)],

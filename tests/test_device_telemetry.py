@@ -105,8 +105,12 @@ class TestDisabledPath:
         # like the flow-id discipline in test_obs.py.
         from dmlc_tpu.device.feed import DeviceFeed
 
+        from dmlc_tpu.obs.metrics import NOOP
+
         class _Feed:
             _h2d = None
+            _h_put = NOOP  # the put span's counter under DMLC_TPU_METRICS=0
+            _pass = 0
 
             def _put_tree_raw(self, arrays, specs):
                 return arrays

@@ -178,7 +178,8 @@ class TestOneLoopForEveryLearner:
     def test_log_every_counts_epochs_and_never_syncs_in_a_pass(
             self, train_file, monkeypatch, model):
         """``log_every=1``: one line an epoch, and the host reads the
-        device once an epoch, after the pass's last step."""
+        device once an epoch, after the pass's last step (the read-back's
+        ``drain_wait``; a ``jax.device_get`` anywhere would show too)."""
         events = []
         real_get = jax.device_get
 
@@ -192,7 +193,8 @@ class TestOneLoopForEveryLearner:
             lambda msg, *args: events.append("log: " + (msg % args)))
 
         def on_span(e):
-            if e.get("ph") == "X" and e["name"] in ("train_step", "epoch"):
+            if e.get("ph") == "X" and e["name"] in (
+                    "train_step", "epoch", "drain_wait"):
                 events.append(e["name"])
 
         obs_trace.add_listener(on_span)
@@ -205,6 +207,249 @@ class TestOneLoopForEveryLearner:
         for epoch, (line, loss) in enumerate(zip(logs, history)):
             assert line.startswith(
                 "log: %s epoch %d loss %.6f" % (model, epoch, loss)), line
+            # the pass's mean lead over the chip, beside the stall breakdown
+            lead = float(line.split(" lead ")[1].split()[0])
+            assert 0.0 <= lead <= STEPS, line
         shape = [e if not e.startswith("log: ") else "log" for e in events]
-        one_pass = ["train_step"] * STEPS + ["epoch", "device_get", "log"]
+        one_pass = ["train_step"] * STEPS + ["epoch", "drain_wait", "log"]
         assert shape == one_pass + one_pass
+
+
+class _ParentEpochMetrics:
+    """``EpochMetrics`` as it was before the pass boundary was split: one
+    ``device_get`` of everything pending, then the sums."""
+
+    def __init__(self):
+        self._pending = {}
+        self.sums = {}
+
+    def add(self, metrics):
+        for name, scalar in metrics.items():
+            self._pending.setdefault(name, []).append(scalar)
+
+    def inflight(self, max_polls=None):
+        return 0
+
+    pending_scalars = 0
+
+    def start_fetch(self):
+        pass
+
+    def drain(self):
+        pass  # the one device_get below waits, as it used to
+
+    def mean_loss(self):
+        if self._pending:
+            for name, values in jax.device_get(self._pending).items():
+                self.sums[name] = self.sums.get(name, 0) + np.sum(
+                    values).item()
+            self._pending.clear()
+        return self.sums.get("loss_sum", 0.0) / max(
+            self.sums.get("weight_sum", 0.0), 1e-12)
+
+
+@pytest.mark.parametrize("model", ["linear", "fm"])
+class TestThePassBoundaryAndTheLead:
+    def _hist(self, name, model):
+        flat = obs.registry().flat_values()
+        key = 'dmlc_fit_%s{model="%s"}' % (name, model)
+        return flat.get(key + ":sum", 0.0), flat.get(key + ":count", 0.0)
+
+    def test_readback_holds_the_drain_then_the_fetch(
+            self, train_file, monkeypatch, model):
+        """``drain_wait`` and ``loss_fetch`` inside ``loss_readback``, in
+        that order, once a pass; what is fetched, and so every loss, is
+        the parent's to the bit."""
+        names = ("loss_readback", "drain_wait", "loss_fetch")
+        seen = []
+        obs_trace.add_listener(seen.append)
+        try:
+            _, history = _fit(model, train_file, 3)
+        finally:
+            obs_trace.remove_listener(seen.append)
+        closed = [e for e in seen if e.get("ph") == "X"
+                  and e["name"] in names]
+        assert [e["name"] for e in closed] == 3 * [
+            "drain_wait", "loss_fetch", "loss_readback"]
+        scalars = {"linear": 2, "fm": 3}[model]  # a step's metrics
+        for k in range(3):
+            drain, fetch, outer = closed[3 * k: 3 * k + 3]
+            assert outer["args"] == {"model": model, "epoch": k}
+            for inner in (drain, fetch):
+                assert outer["ts"] <= inner["ts"]
+                assert (inner["ts"] + inner["dur"]
+                        <= outer["ts"] + outer["dur"] + 1e-3)
+            assert drain["ts"] + drain["dur"] <= fetch["ts"] + 1e-3
+            assert 0 <= drain["args"]["steps"] <= STEPS
+            assert fetch["args"] == {"scalars": (scalars - 1) * STEPS}
+        monkeypatch.setattr(fitloop, "EpochMetrics", _ParentEpochMetrics)
+        _, parent = _fit(model, train_file, 3)
+        assert history == parent  # floats, to the bit
+
+    def test_every_launch_carries_the_lead(self, train_file, model):
+        lead_before = self._hist("inflight_steps", model)
+        seen = []
+        obs_trace.add_listener(seen.append)
+        try:
+            _fit(model, train_file, 2)
+        finally:
+            obs_trace.remove_listener(seen.append)
+        steps = [e for e in seen if e.get("ph") == "X"
+                 and e["name"] == "train_step"]
+        assert len(steps) == 2 * STEPS
+        leads = [e["args"]["inflight"] for e in steps]
+        # read before the launch: nothing is in flight at a pass's head,
+        # and never more than the steps launched so far
+        for e, lead in zip(steps, leads):
+            assert 0 <= lead <= e["args"]["step"]
+        total, count = self._hist("inflight_steps", model)
+        assert count - lead_before[1] == 2 * STEPS
+        assert total - lead_before[0] == sum(leads)
+
+    def test_tracing_off_the_lead_fills_and_the_spans_are_inert(
+            self, train_file, monkeypatch, model):
+        """No listener, no trace file: the lead is counted all the same,
+        and the fit loop's spans, which have no counter, are the shared
+        inert object: nothing is timed and nothing recorded."""
+        assert not obs_trace._listeners
+        obs.clear_trace()
+        inert, real = {}, obs.span
+
+        def span(name, hist=None, **args):
+            made = real(name, hist=hist, **args)
+            inert.setdefault(name, set()).add(made is obs.NOOP_SPAN)
+            return made
+
+        monkeypatch.setattr(obs, "span", span)
+        before = self._hist("inflight_steps", model)[1]
+        _fit(model, train_file, 2)
+        assert self._hist("inflight_steps", model)[1] - before == 2 * STEPS
+        for name in ("epoch", "train_step", "loss_readback", "drain_wait",
+                     "loss_fetch", "epoch_close", "deliver"):
+            assert inert[name] == {True}, name
+        assert obs.trace_events() == []
+
+
+class _Scalar:
+    """A step's device scalar that turns ready on command."""
+
+    def __init__(self):
+        self.ready = False
+        self.asked = 0
+
+    def is_ready(self):
+        self.asked += 1
+        return self.ready
+
+
+class TestInflight:
+    def _launch(self, acc, n):
+        made = [_Scalar() for _ in range(n)]
+        for s in made:
+            # a step's scalars are outputs of one run: ready together
+            acc.add({"loss_sum": s, "weight_sum": s})
+        return made
+
+    def test_the_lead_rises_with_launches_and_falls_as_steps_finish(self):
+        acc = fitloop.EpochMetrics()
+        assert acc.inflight() == 0
+        steps = self._launch(acc, 5)
+        assert acc.inflight() == 5
+        for s in steps[:2]:
+            s.ready = True
+        assert acc.inflight() == 3
+        steps += self._launch(acc, 2)
+        assert acc.inflight() == 5
+        for s in steps:
+            s.ready = True
+        assert acc.inflight(None) == 0
+        # what turned ready is not asked again
+        asked = [s.asked for s in steps]
+        assert acc.inflight() == 0
+        assert [s.asked for s in steps] == asked
+
+    def test_steps_finish_in_order_so_the_first_unready_ends_the_poll(self):
+        acc = fitloop.EpochMetrics()
+        steps = self._launch(acc, 4)
+        steps[2].ready = True  # cannot be: a later step before an earlier
+        assert acc.inflight() == 4
+        assert [s.asked for s in steps] == [1, 0, 0, 0]
+
+    def test_a_call_asks_a_bounded_number_of_questions(self):
+        acc = fitloop.EpochMetrics()
+        steps = self._launch(acc, 20)
+        for s in steps:
+            s.ready = True
+        bound = fitloop.EpochMetrics.MAX_POLLS
+        assert acc.inflight() == 20 - bound
+        assert sum(s.asked for s in steps) == bound
+        assert acc.inflight() == 20 - 2 * bound
+        assert acc.inflight(None) == 0
+
+    def test_host_values_are_done_and_a_read_starts_over(self):
+        acc = fitloop.EpochMetrics()
+        for k in range(3):
+            acc.add({"loss_sum": np.float32(k), "weight_sum": np.float32(1)})
+        assert acc.pending_scalars == 6
+        assert acc.inflight() == 0
+        acc.drain()  # reads the first metric's, leaves the other's
+        assert acc.pending_scalars == 3 and acc.sums == {"loss_sum": 3.0}
+        assert acc.mean_loss() == 1.0
+        assert acc.pending_scalars == 0 and acc.inflight() == 0
+        late = self._launch(acc, 2)
+        assert acc.inflight() == 2
+        late[0].ready = True
+        assert acc.inflight() == 1
+
+    class _Copied(_Scalar):
+        """A device scalar that tells what is asked of it."""
+
+        def __init__(self, calls):
+            super().__init__()
+            self.calls = calls
+
+        def copy_to_host_async(self):
+            self.calls.append("copy")
+
+        def __array__(self, dtype=None, copy=None):
+            self.calls.append("read")
+            return np.asarray(1.0, dtype=np.float32)
+
+    def _copied(self, acc, calls, n=3):
+        for _ in range(n):
+            acc.add({"loss_sum": self._Copied(calls),
+                     "weight_sum": self._Copied(calls)})
+
+    def test_the_copies_are_queued_before_the_wait(self):
+        """As ``jax.device_get`` queues a tree's before it reads the first:
+        each rides behind the step that makes its scalar while the chip
+        drains. Queued after the wait they run on an idle chip."""
+        calls = []
+        acc = fitloop.EpochMetrics()
+        self._copied(acc, calls)
+        seen = []
+        obs_trace.add_listener(seen.append)
+        try:
+            history = []
+            fitloop.FitLoopObs("linear").finish_epoch(
+                0, 3, 0, acc, history)
+        finally:
+            obs_trace.remove_listener(seen.append)
+        # every copy first, asked for once; the wait then reads the first
+        # metric's scalars and the fetch the other's
+        assert calls == ["copy"] * 6 + ["read"] * 6
+        assert history == [1.0]
+        assert [e["name"] for e in seen if e.get("ph") == "X"][:3] == [
+            "drain_wait", "loss_fetch", "loss_readback"]
+
+    def test_a_read_alone_reads_every_scalar_once(self):
+        """``mean_loss`` with no ``start_fetch`` before it is right, if
+        slower on a device (each scalar fetched as it is read); a second
+        read finds nothing pending."""
+        calls = []
+        acc = fitloop.EpochMetrics()
+        self._copied(acc, calls)
+        assert acc.mean_loss() == 1.0
+        assert calls == ["read"] * 6
+        assert acc.pending_scalars == 0
+        assert acc.mean_loss() == 1.0 and len(calls) == 6

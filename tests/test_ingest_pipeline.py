@@ -659,6 +659,220 @@ class TestProducerRewindsItself:
         feed.close()
 
 
+# the consumer thread's spans of one batch, and where each lies
+_BATCH_TREE = {
+    "take": "feed_batch", "dispatch": "feed_batch", "stage": "dispatch",
+    "put": "dispatch", "deliver": None, "consume": None,
+}
+
+
+def _inside(inner, outer):
+    return (outer["ts"] <= inner["ts"] and inner["ts"] + inner["dur"]
+            <= outer["ts"] + outer["dur"] + 1e-3)
+
+
+class TestBatchSpans:
+    """One batch under one ``(pass_, batch)`` from the producer's thread to
+    the consumer's hand, every part of it under a leaf span (device/feed.py:
+    with the counter of its name where something reads one), on both
+    producers' paths."""
+
+    BATCHES = -(-ROWS // 64)
+
+    def _traced(self, make_feed, passes=1, **kw):
+        """(feed, spans): the listener is there before the feed is, whose
+        producer starts to stage as soon as it is built."""
+        seen = []
+        obs_trace.add_listener(seen.append)
+        try:
+            feed = make_feed(**kw)
+            for k in range(passes):
+                if k:
+                    feed.before_first()
+                for _ in feed:
+                    pass
+        finally:
+            obs_trace.remove_listener(seen.append)
+        return feed, [e for e in seen if e.get("ph") == "X"]
+
+    @pytest.mark.parametrize("host_prefetch", [2, 0])
+    def test_the_span_tree_of_a_batch(self, source, host_prefetch):
+        feed, spans = self._traced(source, host_prefetch=host_prefetch)
+        python_path = not feed._use_native_batches()
+        feed.close()
+        by_name = {}
+        for e in spans:
+            by_name.setdefault(e["name"], []).append(e)
+        want = set(_BATCH_TREE) | {"produce", "feed_batch"}
+        if not python_path:
+            want.discard("stage")  # the native stager pads in C++
+        assert want <= set(by_name)
+        assert ("stage" in by_name) == python_path
+        consumer = {e["tid"] for e in by_name["consume"]}
+        assert len(consumer) == 1
+        producer = {e["tid"] for e in by_name["produce"]}
+        # the producer has its own thread, or none (inline under take)
+        assert (producer == consumer) == (host_prefetch == 0)
+        ids = lambda e: (e["args"]["pass_"], e["args"]["batch"])  # noqa: E731
+        batches = [(0, b) for b in range(self.BATCHES)]
+        for name, parent in _BATCH_TREE.items():
+            if name not in by_name:
+                continue
+            found = {ids(e): e for e in by_name[name]}
+            # take also has the wait that found the pass's end
+            assert sorted(found)[:len(batches)] == batches, name
+            assert {e["tid"] for e in by_name[name]} == consumer, name
+            for ident in batches:
+                e = found[ident]
+                outer = [o for o in spans if o is not e and _inside(e, o)
+                         and o["tid"] == e["tid"]
+                         and o["name"] in _BATCH_TREE.values()]
+                innermost = min(outer, key=lambda o: o["dur"], default=None)
+                assert (innermost and innermost["name"]) == parent, (name, e)
+                if parent:
+                    assert ids(innermost) == ident, (name, e)
+        # (a pass's last produce span found its end and made no batch)
+        produced = {ids(e): e for e in by_name["produce"]
+                    if e["args"]["batch"] < self.BATCHES}
+        # a producer with a thread has staged the head of the next pass
+        ahead = [i for i in produced if i[0] == 1]
+        # (its queue, and the one it holds while the queue is full)
+        assert len(ahead) <= (host_prefetch + 1 if host_prefetch else 0), ahead
+        for ident in ahead:
+            del produced[ident]
+        assert sorted(produced) == batches
+        if host_prefetch == 0:
+            takes = {ids(e): e for e in by_name["take"]}
+            for ident, e in produced.items():
+                assert _inside(e, takes[ident])
+        for ident in batches:  # made before it is taken, put, handed over
+            order = [produced[ident]] + [
+                next(e for e in by_name[n] if ids(e) == ident)
+                for n in ("take", "put", "deliver", "consume")]
+            ends = [e["ts"] + e["dur"] for e in order]
+            assert ends == sorted(ends), ident
+
+    def test_produce_carries_the_pass_its_batch_belongs_to(self, source):
+        """Across pre-wound restarts: the producer stages pass n+1 while
+        the consumer is in pass n, and numbers it n+1."""
+        feed, spans = self._traced(source, passes=3)
+        assert _restart_counts(feed) == (2, 2)
+        feed.close()
+        made = [(e["args"]["pass_"], e["args"]["batch"]) for e in spans
+                if e["name"] == "produce"
+                and e["args"]["batch"] < self.BATCHES]
+        # the producer may have staged the head of a fourth pass
+        want = [(p, b) for p in range(3) for b in range(self.BATCHES)]
+        assert made[:len(want)] == want
+        assert all(p == 3 for p, _ in made[len(want):])
+        held = [(e["args"]["pass_"], e["args"]["batch"]) for e in spans
+                if e["name"] == "consume"]
+        assert held == want
+
+    def test_produce_follows_a_restart_made_in_mid_pass(self, source):
+        seen = []
+        obs_trace.add_listener(seen.append)
+        try:
+            feed = source()
+            it = iter(feed)
+            for _ in range(3):
+                next(it)
+            it.close()
+            feed.before_first()  # stops the producer and starts it again
+            for _ in feed:
+                pass
+        finally:
+            obs_trace.remove_listener(seen.append)
+        feed.close()
+        made = [(e["args"]["pass_"], e["args"]["batch"]) for e in seen
+                if e.get("ph") == "X" and e["name"] == "produce"
+                and e["args"]["batch"] < self.BATCHES]
+        second = [m for m in made if m[0] == 1]
+        assert second[:self.BATCHES] == [
+            (1, b) for b in range(self.BATCHES)]
+        assert {p for p, _ in made} <= {0, 1, 2}
+
+    def test_counters_fill_with_tracing_off(self, source, monkeypatch):
+        """No listener, no trace file: every span with a counter is a
+        two-read timer, a span without one (``deliver``) the shared inert
+        object, and nothing is recorded."""
+        from dmlc_tpu import obs
+
+        assert not obs_trace._listeners
+        obs.clear_trace()
+        inert, real = {}, obs.span
+
+        def span(name, hist=None, **args):
+            made = real(name, hist=hist, **args)
+            inert.setdefault(name, set()).add(made is obs.NOOP_SPAN)
+            return made
+
+        monkeypatch.setattr(obs, "span", span)
+        feed = source()
+        python_path = not feed._use_native_batches()
+        n = sum(1 for _ in feed)
+        assert n == self.BATCHES
+        timed = {"produce", "take", "dispatch", "put", "consume"}
+        if python_path:
+            timed.add("stage")
+        assert {k for k, v in inert.items() if v == {False}} == timed
+        # (the Python parser's own spans have no counter either)
+        assert {"feed_batch", "deliver"} <= {
+            k for k, v in inert.items() if v == {True}}
+        counts = {
+            "host_wait": feed._stage["host_wait_ns"].count,
+            "dispatch": feed._stage["dispatch_ns"].count,
+            "consume": feed._stage["consume_ns"].count,
+            "put": feed._h_put.count, "stage": feed._h_stage.count,
+        }
+        # one wait more than batches: the one that found the pass's end
+        assert counts == {
+            "host_wait": n + 1, "dispatch": n, "consume": n, "put": n,
+            "stage": n if python_path else 0}
+        assert feed._stage["host_batch_ns"].count >= n
+        assert feed._h_put.sum <= feed._stage["dispatch_ns"].sum
+        assert feed._m_unlanded.value == 0
+        feed.close()
+        assert obs.trace_events() == []
+
+    def test_sync_host_books_production_once(self, source):
+        """``host_prefetch=0``: the time inside next() is production
+        (``produce``, host_batch_ns) and no wait (``take`` has no
+        counter)."""
+        feed = source(host_prefetch=0)
+        n = sum(1 for _ in feed)
+        assert feed._stage["host_wait_ns"].count == 0
+        assert feed._stage["host_batch_ns"].count == n + 1
+        feed.close()
+
+    def test_a_delivery_before_the_copy_landed_is_counted(self, source):
+        class Guard:
+            def __init__(self, ready):
+                self.ready = ready
+
+            def is_ready(self):
+                return self.ready
+
+        feed = source()
+        feed.pool.recycle = True  # as on an accelerator
+        landed = {"label": Guard(True), "values": Guard(True), "num_rows": 4}
+        flying = {"label": Guard(True), "values": Guard(False), "num_rows": 4}
+        a = feed.pool.acquire(8, np.float32)
+        b = feed.pool.acquire(8, np.float32)
+        assert feed._deliver((landed, (a,), (), (), 0)) is landed
+        assert feed._m_unlanded.value == 0
+        assert feed._deliver((flying, (b,), (), (), 1)) is flying
+        assert feed._m_unlanded.value == 1
+        # no staging buffers to retire (the native stager's batches): the
+        # question is asked all the same
+        assert feed._deliver((flying, (), (), (), 2)) is flying
+        assert feed._m_unlanded.value == 2
+        # and the pool reuses only the buffer whose copy had landed
+        stats = feed.pool.stats()
+        assert (stats["retired"], stats["dropped"]) == (1, 1)
+        feed.close()
+
+
 class TestKnobs:
     def test_nthread_knob(self, monkeypatch, svm_path):
         monkeypatch.setenv("DMLC_TPU_NTHREAD", "3")

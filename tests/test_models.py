@@ -540,7 +540,8 @@ class TestFMSparseUpdate:
         """``dmlc_fit_touched_rows_total`` is the distinct ids of each
         batch as the chip that sorts them sees them (padded entries name
         feature 0), ``dmlc_fit_entries_total`` the batches' shapes, and
-        the count rides the pass's one read of the device."""
+        the count rides the pass's one read of the device (the read-back's
+        ``loss_fetch``; a ``jax.device_get`` beside it would show)."""
         from jax.sharding import Mesh
 
         from dmlc_tpu import obs
@@ -589,12 +590,21 @@ class TestFMSparseUpdate:
         learner = FMLearner(mesh=mesh, num_features=self.NFEAT,
                             num_factors=self.NFACT, **hyper)
         train = feed()
-        learner.fit_feed(train, epochs=1)
+        spans = []
+        obs.trace.add_listener(spans.append)
+        try:
+            learner.fit_feed(train, epochs=1)
+        finally:
+            obs.trace.remove_listener(spans.append)
         train.close()
         touched, entries, steps = [a - b for a, b in zip(read(), before)]
         assert (touched, entries, steps) == (want_touched, want_entries, 4)
         assert 0 < touched / entries < 0.2
-        assert len(reads) == 1
+        # weight_sum's and touched_rows' of the four steps, after
+        # loss_sum's, which the wait for the device read
+        assert [e["args"]["scalars"] for e in spans
+                if e.get("ph") == "X" and e["name"] == "loss_fetch"] == [8]
+        assert reads == []
 
 
 def _plain_fm_step(params, batch, lr, l2, in_id_order):

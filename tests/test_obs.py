@@ -141,26 +141,144 @@ class TestDisabledPath:
         assert reg.snapshot() == {} and reg.flat_values() == {}
 
     def test_disabled_overhead_under_2x_noop_call(self, monkeypatch):
+        """The disabled path's contract, by what it does and not by a
+        stopwatch (the machine's load is not the program's): every
+        disabled handle IS the shared no-op, a disabled span records
+        nothing and keeps nothing, and 10,000 disabled calls leave no
+        memory allocated by a line of obs/trace.py or obs/metrics.py."""
+        import tracemalloc
+
+        from dmlc_tpu.obs import metrics as metrics_mod
+        from dmlc_tpu.obs import trace as trace_mod
+
+        monkeypatch.delenv("DMLC_TPU_TRACE", raising=False)
+        assert not trace_mod._listeners
+        obs.clear_trace()
+        live = Registry().histogram("dmlc_t_cost_live_ns")
         monkeypatch.setenv("DMLC_TPU_METRICS", "0")
-        inc = Registry().counter("dmlc_t_cost_total").inc
-
-        def baseline():
+        reg = Registry()
+        counter = reg.counter("dmlc_t_cost_total")
+        hist = reg.histogram("dmlc_t_cost_ns", who="x")
+        # identity, not speed: one shared child, whose mutators are the
+        # class's empty functions
+        assert counter is NOOP and hist is NOOP
+        assert counter.inc.__func__ is type(NOOP).inc
+        assert hist.observe.__func__ is type(NOOP).observe
+        # a span with nothing to observe into is the shared inert object,
+        # args or not; with a live histogram it is a timer that holds no
+        # name and no args, and records no event
+        assert obs.span("stage") is obs.NOOP_SPAN
+        assert obs.span("stage", pass_=1, batch=2) is obs.NOOP_SPAN
+        assert obs.span("stage", hist=hist, pass_=1) is obs.NOOP_SPAN
+        timed = obs.span("stage", hist=live, pass_=1, batch=2)
+        assert timed is not obs.NOOP_SPAN and not timed.live
+        assert not hasattr(timed, "args") and not hasattr(timed, "name")
+        assert not hasattr(timed, "__dict__")
+        with timed:
             pass
+        assert live.count == 1 and live.sum == timed.dur_ns
+        assert obs.trace_events() == []
 
-        n = 200_000
+        def burst(n=10_000):
+            for i in range(n):
+                counter.inc()
+                hist.observe(i)
+                with obs.span("stage", pass_=0, batch=i):
+                    pass
+                with obs.span("stage", hist=hist, pass_=0, batch=i):
+                    pass
 
-        def timed(fn):
-            best = float("inf")
-            for _ in range(5):
-                t0 = time.perf_counter()
-                for _ in range(n):
-                    fn()
-                best = min(best, time.perf_counter() - t0)
-            return best
+        burst(200)  # warm caches before measuring
+        started_here = not tracemalloc.is_tracing()
+        if started_here:
+            tracemalloc.start()
+        try:
+            before = tracemalloc.take_snapshot()
+            burst()
+            after = tracemalloc.take_snapshot()
+        finally:
+            if started_here:
+                tracemalloc.stop()
+        only = [tracemalloc.Filter(True, trace_mod.__file__),
+                tracemalloc.Filter(True, metrics_mod.__file__)]
+        grown = [
+            str(stat) for stat in after.filter_traces(only).compare_to(
+                before.filter_traces(only), "lineno")
+            if stat.size_diff > 0]
+        assert grown == []
+        assert obs.trace_events() == []
+        assert reg.snapshot() == {}
 
-        timed(baseline)  # warm up both paths
-        timed(inc)
-        assert timed(inc) < 2.0 * timed(baseline) + 1e-3
+
+class TestSpanHistogram:
+    """``obs.span(name, hist=h)``: the span observes its own duration, from
+    the two clock reads that give its ``ts`` and ``dur``."""
+
+    def test_dur_and_observed_value_are_one_number(self):
+        from dmlc_tpu.obs import trace as trace_mod
+
+        hist = Registry().histogram("dmlc_t_span_ns")
+        seen = []
+        trace_mod.add_listener(seen.append)
+        try:
+            for k in range(3):
+                with obs.span("put", hist=hist, batch=k) as live:
+                    time.sleep(0.001)
+                assert live.live and live.dur_ns > 0
+        finally:
+            trace_mod.remove_listener(seen.append)
+        assert [e["args"] for e in seen] == [{"batch": k} for k in range(3)]
+        assert hist.count == 3
+        # the event's dur is the histogram's ns in us: the same reads
+        assert hist.sum == sum(round(e["dur"] * 1e3) for e in seen)
+        assert live.dur_ns == round(seen[-1]["dur"] * 1e3)
+        for e in seen:
+            assert e["ts"] > 0 and e["dur"] >= 1000.0
+
+    def test_tracing_off_fills_the_histogram_and_records_no_event(
+            self, monkeypatch):
+        monkeypatch.delenv("DMLC_TPU_TRACE", raising=False)
+        obs.clear_trace()
+        hist = Registry().histogram("dmlc_t_span_off_ns")
+        with obs.span("put", hist=hist, batch=0) as timed:
+            time.sleep(0.001)
+        assert timed is not obs.NOOP_SPAN and not timed.live
+        assert (hist.count, hist.sum) == (1, timed.dur_ns)
+        assert timed.dur_ns >= 1_000_000
+        assert obs.trace_events() == []
+
+    def test_an_exception_still_observes(self):
+        hist = Registry().histogram("dmlc_t_span_exc_ns")
+        with pytest.raises(StopIteration):
+            with obs.span("take", hist=hist):
+                raise StopIteration
+        assert hist.count == 1
+
+    @pytest.mark.parametrize("tracing", [False, True])
+    def test_an_inner_span_observes_no_more_than_its_outer(self, tracing):
+        """``put`` inside ``dispatch``: four clock reads in order, so the
+        two counters nest as the spans do, tracing on or off."""
+        from dmlc_tpu.obs import trace as trace_mod
+
+        reg = Registry()
+        outer_h = reg.histogram("dmlc_t_outer_ns")
+        inner_h = reg.histogram("dmlc_t_inner_ns")
+        seen = []
+        if tracing:
+            trace_mod.add_listener(seen.append)
+        try:
+            for k in range(5):
+                with obs.span("dispatch", hist=outer_h, batch=k) as outer:
+                    with obs.span("put", hist=inner_h, batch=k) as inner:
+                        pass
+                assert outer.live == inner.live == tracing
+                assert 0 <= inner.dur_ns <= outer.dur_ns
+        finally:
+            if tracing:
+                trace_mod.remove_listener(seen.append)
+        assert outer_h.count == inner_h.count == 5
+        assert inner_h.sum <= outer_h.sum
+        assert [e["name"] for e in seen] == 5 * tracing * ["put", "dispatch"]
 
 
 class TestSpans:
@@ -220,14 +338,15 @@ class TestSpans:
             np.asarray(batch["label"])
         feed.close()
         names = {e["name"] for e in obs.trace_events()}
-        assert {"feed_batch", "dispatch", "consume"} <= names
+        assert {"produce", "feed_batch", "take", "dispatch", "stage", "put",
+                "deliver", "consume"} <= names
         obs.flush_trace()
         json.loads(out.read_text())  # loadable Chrome trace
         obs.clear_trace()
 
 
 class _FakeAnnotation:
-    """Stands in for jax.profiler.TraceAnnotation / StepTraceAnnotation."""
+    """Stands in for jax.profiler.TraceAnnotation."""
 
     made = []
 
@@ -252,7 +371,7 @@ class TestJaxBridge:
         monkeypatch.setenv("DMLC_TPU_TRACE_JAX", "1")
         monkeypatch.delenv("DMLC_TPU_TRACE", raising=False)
         monkeypatch.setattr(
-            trace_mod, "_bridge", (_FakeAnnotation, _FakeAnnotation))
+            trace_mod, "_bridge", (_FakeAnnotation,))
         _FakeAnnotation.made = []
         seen = []
         trace_mod.add_listener(seen.append)
@@ -270,14 +389,6 @@ class TestJaxBridge:
         assert annot.kwargs == {"pass_": 2, "batch": 5}
         assert bridged[0]["args"] == {"pass_": 2, "batch": 5}
 
-    def test_step_span_keeps_step_num_and_args(self, bridged):
-        with obs.step_span(3, "epoch", model="fm"):
-            pass
-        (annot,) = _FakeAnnotation.made
-        assert annot.name == "epoch"
-        assert annot.kwargs == {"step_num": 3, "model": "fm"}
-        assert bridged[0]["args"] == {"model": "fm", "step": 3}
-
     def test_classes_resolved_once_not_per_span(self, monkeypatch):
         import jax.profiler
 
@@ -288,8 +399,8 @@ class TestJaxBridge:
         assert trace_mod._jax_annotation_cls() is \
             jax.profiler.TraceAnnotation
         resolved = trace_mod._bridge
-        assert trace_mod._jax_annotation_cls(step=True) is \
-            jax.profiler.StepTraceAnnotation
+        assert trace_mod._jax_annotation_cls() is \
+            jax.profiler.TraceAnnotation
         assert trace_mod._bridge is resolved  # no second lookup
         monkeypatch.delenv("DMLC_TPU_TRACE_JAX")
         assert trace_mod._jax_annotation_cls() is None
@@ -300,14 +411,13 @@ class TestJaxBridge:
         monkeypatch.setenv("DMLC_TPU_TRACE_JAX", "1")
         monkeypatch.delenv("DMLC_TPU_TRACE", raising=False)
         monkeypatch.setattr(
-            trace_mod, "_bridge", (_FakeAnnotation, _FakeAnnotation))
+            trace_mod, "_bridge", (_FakeAnnotation,))
         _FakeAnnotation.made = []
         seen = []
         trace_mod.add_listener(seen.append)
         trace_mod.remove_listener(seen.append)  # disarmed again
         obs.clear_trace()
         assert obs.span("consume", pass_=0, batch=1) is obs.NOOP_SPAN
-        assert obs.step_span(0, "epoch") is obs.NOOP_SPAN
         with obs.span("consume", pass_=0, batch=1):
             assert obs.current_batch() == {}
         assert seen == [] and _FakeAnnotation.made == []
