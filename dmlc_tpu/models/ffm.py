@@ -31,9 +31,11 @@ field b, so that a contiguous split of the columns over a mesh
 (``table_sharding="factors"``) gives each chip whole factors of every
 field. One device keeps them as ONE packed array ``[v | a]`` (models/fm.py
 ``PackedTables``): an id's factors and their accumulators are one row,
-read once and set once a step (9.54 ms a step where the two tables cost
-11.69 in the ``kdd12-ffm.libsvm`` cell: PERF.md, PR 36); a mesh keeps the
-two arrays, each chip its columns of both. The pair term is a sum over the factor index, so a chip's columns
+read once and set once a step, since PR 38 as lanes of a row-major
+lane row (5 ids of 44 columns to 256 lanes; 7.04 ms a step where the
+two tables apart cost 11.69 in the ``kdd12-ffm.libsvm`` cell: PERF.md,
+PRs 36 and 38); a mesh keeps the two arrays, each chip its columns of
+both. The pair term is a sum over the factor index, so a chip's columns
 give its share of ``phi``, one psum of ``f32[rows]`` completes it, and
 every update is local (field-major columns would split by partner
 field, over which the pair term does not decompose).

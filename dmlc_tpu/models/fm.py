@@ -34,10 +34,16 @@ id's weights and state side by side in one row, ``[v | w]`` or ``[v | w
 distinct ids and one of row writes, where five tables apart cost a read
 and a write each (on the chip an indexed pass over a table costs per
 index and hardly per column while a row fits one 128-lane tile: PERF.md,
-PRs 29, 32 and 36). Tables divided or replicated over a mesh stay one
-array a table, each placed by its own rule. The grouping follows from the
-tree a step is given and, for a learner, from its placement
-(``FMLearner.packs``); no hyper-parameter names it.
+PRs 29, 32 and 36). That array is laid ROW-MAJOR, several ids to a lane
+row (:func:`lane_geometry`): the chip lays a narrow ``f32[F, C]`` out
+column-major in tiles of 8 columns x 128 ids, an id's words 512 bytes
+apart over C / 8 tiles it shares with 127 other ids, while a row of
+``f32[R, 128]`` is 512 contiguous bytes of one tile, so a touched id is
+read and written in one piece (PERF.md, PR 38). Tables divided or
+replicated over a mesh stay one array a table, each placed by its own
+rule. The grouping follows from the tree a step is given and, for a
+learner, from its placement (``FMLearner.packs``); no hyper-parameter
+names it.
 
 The update rule is ``FMParam.optimizer``'s: ``"sgd"`` adds each id's
 scaled gradient into its row; ``"ftrl_adagrad"`` (difacto's: FTRL-proximal
@@ -166,16 +172,73 @@ def _span(buffer, span: Tuple[int, int]):
     return buffer[:, first:first + width] if width else buffer[:, first]
 
 
+#: lanes of one row of the chip's tiles; a lane row is a multiple of them
+_LANES = 128
+
+
+def lane_geometry(columns: int) -> Tuple[int, int]:
+    """(``L``, ``p``): the lanes of one lane row of a packed array whose
+    ids keep ``columns`` words each, and the ids that lie side by side in
+    it. ``L`` is the smallest multiple of 128 whose lanes no id uses (``L
+    - p * columns``) are under a fifth of the row: 17 columns 128 and 7,
+    35 columns 128 and 3, 44 columns 256 and 5 (two ids of 44 in 128
+    lanes would leave 40 unused, a third more memory)."""
+    lanes = _LANES
+    while True:
+        per_row = lanes // columns
+        if 5 * (lanes - per_row * columns) < lanes:
+            return lanes, per_row
+        lanes += _LANES
+
+
+def _to_lane_rows(flat):
+    """``[F, C]`` (numpy or jax; id i's words in row i) as lane rows
+    ``[R, L]``: id i in row ``i // p`` at lanes ``[(i % p) * C, (i % p +
+    1) * C)``; the lanes past ``p * C`` and the places past the last id
+    hold 0."""
+    lib = np if isinstance(flat, np.ndarray) else jnp
+    ids, columns = flat.shape
+    lanes, per_row = lane_geometry(columns)
+    rows = -(-ids // per_row)
+    flat = lib.pad(flat, ((0, rows * per_row - ids), (0, 0)))
+    return lib.pad(flat.reshape(rows, per_row * columns),
+                   ((0, 0), (0, lanes - per_row * columns)))
+
+
+def _from_lane_rows(rows, columns: int, num_ids: int):
+    """:func:`_to_lane_rows` back: ``[num_ids, columns]``."""
+    per_row = rows.shape[1] // columns
+    return rows[:, :per_row * columns].reshape(-1, columns)[:num_ids]
+
+
+def _cut_lanes(lanes, place, columns: int):
+    """``[n, columns]``: of each lane row of ``lanes [n, L]`` the words
+    of the id at ``place [n]`` (a select over the row's static slices)."""
+    out = lanes[..., :columns]
+    for at in range(1, lanes.shape[-1] // columns):
+        out = jnp.where((place == at)[..., None],
+                        lanes[..., at * columns:(at + 1) * columns], out)
+    return out
+
+
 @jax.tree_util.register_pytree_node_class
 class PackedTables(Mapping):
-    """The per-id tables of one learner side by side in ONE array: ``rows
-    f32[F, C]`` holds, for every id, its row of each logical table in the
-    order of ``layout`` = ((name, width), ...), width 0 a 1-D table (one
-    column); ``scalars`` = {name: f32[]} (the FM's ``b``). What a device
-    that holds whole rows of every table keeps, so that a step reads each
-    touched row once and writes it once. A pytree: ``rows`` and the
-    scalars are its leaves, the layout its static part, so a step jitted
-    over either tree takes the grouping from the tree it is given.
+    """The per-id tables of one learner side by side in ONE array. An id
+    keeps ``C`` words, its row of each logical table in the order of
+    ``layout`` = ((name, width), ...), width 0 a 1-D table (one word);
+    ``rows f32[R, L]`` holds ``p`` ids to a lane row (:func:`lane_geometry`
+    of ``C``), id i in row ``i // p`` at lanes ``[(i % p) * C, (i % p + 1)
+    * C)``, ``R = ceil(num_ids / p)``::
+
+        lane   0        C        2C            p*C      L
+        row r  | id r*p | id r*p+1 | ... | id r*p+p-1 | 0 |
+
+    ``scalars`` = {name: f32[]} (the FM's ``b``). What a device that
+    holds whole rows of every table keeps, so that a step reads each
+    touched id's words once and writes them once, in one piece of one
+    tile. A pytree: ``rows`` and the scalars are its leaves, the layout
+    and ``num_ids`` its static part, so a step jitted over either tree
+    takes the grouping from the tree it is given.
 
     As a mapping it reads like the tree of tables it stands for:
     ``params["v"]`` is the logical table, a COPY of its columns (for a
@@ -183,17 +246,18 @@ class PackedTables(Mapping):
     and the snapshot never make one), and ``dict(params)`` is the tree
     with one array a table."""
 
-    def __init__(self, rows, scalars: Dict, layout):
+    def __init__(self, rows, scalars: Dict, layout, num_ids: int):
         self.rows = rows
         self.scalars = scalars
         self.layout = tuple(layout)
+        self.num_ids = int(num_ids)
 
     def tree_flatten(self):
-        return (self.rows, self.scalars), self.layout
+        return (self.rows, self.scalars), (self.layout, self.num_ids)
 
     @classmethod
-    def tree_unflatten(cls, layout, children):
-        return cls(*children, layout)
+    def tree_unflatten(cls, static, children):
+        return cls(*children, *static)
 
     @classmethod
     def pack(cls, parts, layout):
@@ -202,14 +266,26 @@ class PackedTables(Mapping):
         scalar."""
         lib = np if isinstance(parts[layout[0][0]], np.ndarray) else jnp
         names = [name for name, _ in layout]
-        rows = lib.concatenate(
+        flat = lib.concatenate(
             [parts[name] if width else parts[name][:, None]
              for name, width in layout], axis=1)
-        return cls(rows, {k: v for k, v in parts.items() if k not in names},
-                   layout)
+        return cls(_to_lane_rows(flat),
+                   {k: v for k, v in parts.items() if k not in names},
+                   layout, flat.shape[0])
+
+    @property
+    def columns(self) -> int:
+        """``C``: the words an id keeps."""
+        return _columns(width for _, width in self.layout)
+
+    @property
+    def per_row(self) -> int:
+        """``p``: the ids of one lane row."""
+        return self.rows.shape[1] // self.columns
 
     def span(self, name: str) -> Tuple[int, int]:
-        """(first column, width) of logical table ``name``."""
+        """(first column, width) of logical table ``name`` among an id's
+        words."""
         first = 0
         for table, width in self.layout:
             if table == name:
@@ -217,10 +293,20 @@ class PackedTables(Mapping):
             first += max(width, 1)
         raise KeyError(name)
 
+    def lane_rows_of(self, ids):
+        """(lane row, place in it) of every id of ``ids``; an id past the
+        table names a lane row past the array (a gather fills it, a
+        scatter drops it), ascending as the id does."""
+        past = ids >= self.num_ids
+        return (jnp.where(past, self.rows.shape[0] + (ids - self.num_ids),
+                          ids // self.per_row),
+                ids % self.per_row)
+
     def __getitem__(self, name):
         if name in self.scalars:
             return self.scalars[name]
-        return _span(self.rows, self.span(name))
+        return _span(_from_lane_rows(self.rows, self.columns, self.num_ids),
+                     self.span(name))
 
     def __iter__(self):
         yield from (name for name, _ in self.layout)
@@ -254,46 +340,71 @@ _OFFSET_DRAWS = jex_random.define_prng_impl(
     random_bits=_bits_at_offset, fold_in=_no_such_key_op,
     name="threefry2x32_at_offset", tag="fryo")
 
-#: rows one pass of a packed row's initialiser draws (64 MB of 16 columns)
-_INIT_BLOCK = 1 << 20
+#: ids one pass of a packed row's initialiser draws (16 MB of 16 columns:
+#: what a pass holds beside the array is a few times that)
+_INIT_BLOCK = 1 << 18
 
 
 def init_packed(num_features: int, layout, draw, fill: Dict, scalars: Dict,
                 seed) -> PackedTables:
-    """A learner's tables at their start as ONE packed array, written in
-    place: every column at its table's ``fill`` value (0 where it has
-    none), then the leading table's columns (``v``: the one table that
-    starts random) drawn ``_INIT_BLOCK`` rows a pass into the array
-    itself, so that no table-sized array exists beside it (the compiler
-    does not fuse a table of draws into the array that pads it: drawn
-    whole it lies beside the packed array, 3.9 GB of 16 columns).
+    """A learner's tables at their start as ONE packed array of lane rows
+    (:class:`PackedTables`), written in place a block of whole lane rows
+    a pass: every id's words at their tables' ``fill`` values (0 where a
+    table has none) but the leading table's (``v``: the one table that
+    starts random), which are drawn about ``_INIT_BLOCK`` ids a pass, so
+    that no table-sized array exists beside the one being written (the
+    compiler does not fuse a table of draws into the array that pads it:
+    drawn whole it lies beside the packed array, 3.9 GB of 16 columns).
 
     ``draw(key, shape)``: the draws of the logical initialiser
     (``init_fm_params``: ``init_scale * normal``). A block's key carries
-    its offset (:data:`_OFFSET_DRAWS`), so the array holds the draws of
-    ``draw(PRNGKey(seed), (num_features, width))`` to the bit."""
+    its offset (:data:`_OFFSET_DRAWS`), so id i holds row i of
+    ``draw(PRNGKey(seed), (num_features, width))`` to the bit; the last
+    lane row's places past the last id hold 0, as the unused lanes do."""
     name, width = layout[0]
-    check(num_features * width < 1 << 32,
+    columns = _columns(w for _, w in layout)
+    lanes, per_row = lane_geometry(columns)
+    rows = -(-num_features // per_row)
+    check(rows * per_row * width < 1 << 32,
           "a packed row's initialiser counts draws in 32 bits: %d x %d",
           num_features, width)
     start = np.concatenate(
         [np.full(max(w, 1), fill.get(table, 0.0), np.float32)
-         for table, w in layout])  # one row
-    rows = jnp.broadcast_to(start, (num_features, start.size))
-    block = min(num_features, _INIT_BLOCK)
+         for table, w in layout])  # an id's words, but for the draws
+    # lane by lane: the place it belongs to and its column there, whether
+    # it holds a draw (then which of a lane row's draws), else its value
+    place, column = np.divmod(np.arange(lanes), columns)
+    drawn = (place < per_row) & (column < width)
+    source = np.where(drawn, place * width + column, 0)
+    filled = np.where(place < per_row, start[column], np.float32(0))
+    block = min(rows, max(_INIT_BLOCK // per_row, 1))  # lane rows a pass
     k1, k2 = jax.random.key_data(jax.random.PRNGKey(seed))
 
-    def draw_block(i, rows):
+    def draw_block(i, array):
         # the last block starts early and draws some rows again
-        at = jnp.minimum(i * block, num_features - block)
+        at = jnp.minimum(i * block, rows - block)
         key = jax.random.wrap_key_data(
-            jnp.stack([k1, k2, (at * width).astype(jnp.uint32)]),
+            jnp.stack([k1, k2, (at * (per_row * width)).astype(jnp.uint32)]),
             impl=_OFFSET_DRAWS)
+        # a lane row's draws side by side. The chip keeps such a narrow
+        # array with its LONG side along the lanes, so the lanes of the
+        # lane rows are put together as ROWS of that array (each a row of
+        # draws or a constant) and turned once, whole tiles at a time
+        # (cut into an id's 16 columns first, each piece is padded to 128
+        # lanes and turned on its own: 140 ms of 200 at 35 columns)
+        draws = draw(key, (block, per_row * width)).T
+        lanes_first = jnp.where(drawn[:, None],
+                                jnp.take(draws, source, axis=0),
+                                filled[:, None])
+        ids = (at + jnp.arange(block)) * per_row + place[:, None]
         return lax.dynamic_update_slice(
-            rows, draw(key, (block, width)), (at, 0))
+            array, jnp.where(ids < num_features, lanes_first, 0.0).T,
+            (at, 0))
 
-    rows = lax.fori_loop(0, -(-num_features // block), draw_block, rows)
-    return PackedTables(rows, scalars, layout)
+    array = lax.fori_loop(
+        0, -(-rows // block), draw_block,
+        jnp.zeros((rows, lanes), jnp.float32))
+    return PackedTables(array, scalars, layout, num_features)
 
 
 class _Group(NamedTuple):
@@ -325,20 +436,20 @@ def _regroup(params, names, arrays, scalars: Dict):
     :func:`_groups` listed them) and the new ``scalars``."""
     if isinstance(params, PackedTables):
         (rows,) = arrays
-        return PackedTables(rows, scalars, params.layout)
+        return PackedTables(rows, scalars, params.layout, params.num_ids)
     return dict(zip(names, arrays), **scalars)
 
 
 def _head_tables(params, names):
     """What the step's head reads for the logical tables ``names``: (the
-    physical arrays, how many leading columns of their rows side by side
-    are ``names``' own; None when all are)."""
+    physical arrays, or the packed tree itself; how many leading words of
+    their rows side by side are ``names``' own, None when all are)."""
     if isinstance(params, PackedTables):
         head = params.layout[:len(names)]
         check(tuple(name for name, _ in head) == tuple(names),
               "the packed row starts with %s, the step's head reads %s",
               [name for name, _ in head], names)
-        return (params.rows,), _columns(width for _, width in head)
+        return params, _columns(width for _, width in head)
     return tuple(params[name] for name in names), None
 
 
@@ -462,13 +573,115 @@ def _take_distinct(tables, order: _IdOrder):
     return lax.fori_loop(0, order.chunks, take_chunk, rows)
 
 
+class _Read(NamedTuple):
+    """What the step's head read at the batch's distinct ids."""
+
+    #: [n + pad, C] slot j holds the j-th distinct id's row of every table
+    #: the head read, side by side
+    words: jax.Array
+    #: [n + pad, L] the lane rows those words were cut from, as read (a
+    #: packed tree; None when the tables lie apart): what the write puts
+    #: back around them
+    lanes: Optional[jax.Array] = None
+
+
+def _take_lane_rows(packed: PackedTables, order: _IdOrder) -> _Read:
+    """:func:`_take_distinct` over a packed tree: each distinct id's lane
+    row (one indexed read of 512 or 1024 contiguous bytes; a lane row
+    that holds several of the batch's ids is read once for each) and the
+    id's own words cut out of it, ``_UPDATE_CHUNK`` slots a pass until
+    the last slot that holds a distinct id."""
+    lane_rows, places = packed.lane_rows_of(order.ids)
+    columns = packed.columns
+
+    def take_chunk(i, read):
+        at = i * _UPDATE_CHUNK
+        got = jnp.take(
+            packed.rows, lax.dynamic_slice_in_dim(lane_rows, at, _UPDATE_CHUNK),
+            axis=0, indices_are_sorted=True)
+        words = _cut_lanes(
+            got, lax.dynamic_slice_in_dim(places, at, _UPDATE_CHUNK), columns)
+        return _Read(
+            lax.dynamic_update_slice_in_dim(read.words, words, at, axis=0),
+            lax.dynamic_update_slice_in_dim(read.lanes, got, at, axis=0))
+
+    slots = order.ids.shape[0]
+    dtype = packed.rows.dtype
+    return lax.fori_loop(0, order.chunks, take_chunk, _Read(
+        jnp.zeros((slots, columns), dtype),
+        jnp.zeros((slots, packed.rows.shape[1]), dtype)))
+
+
+def _put_lane_rows(packed: PackedTables, order: _IdOrder, lanes, new):
+    """``new [n + pad, C]``, the distinct ids' new words by slot, put
+    back into ``packed.rows`` (in place when the caller donated it): each
+    distinct LANE row written once, whole, with no read of anybody else's
+    data. ``lanes``: the lane rows as :func:`_take_lane_rows` read them.
+
+    Ids that share a lane row are neighbours in id order, at most ``p``
+    slots in a run. The run's first slot takes the lane row as read and
+    lays over it the new words of every slot of the run, each at its
+    place, so that the lanes of ids the batch does not name go back as
+    they were read; the run's other slots write nothing (they name a row
+    past the array, as do the slots past the distinct ids and the slots
+    of ids past the table)."""
+    lane_rows, places = packed.lane_rows_of(order.ids)
+    columns, per_row = packed.columns, packed.per_row
+    height, width = packed.rows.shape
+    slots = order.ids.shape[0]
+    # the place each lane belongs to (the unused lanes to none)
+    place_of = np.minimum(np.arange(width) // columns, per_row)
+    first = jnp.concatenate(
+        [jnp.ones((1,), bool), lane_rows[1:] != lane_rows[:-1]])
+    # a slot that writes nothing names the row ``height`` + the slot's
+    # own number, whatever row past the array its id named: the targets
+    # stay distinct, as the scatter is promised
+    target = jnp.where(
+        first & (lane_rows < height), lane_rows,
+        height + jnp.arange(slots, dtype=lane_rows.dtype))
+    # a chunk's last slots look at the slots after it
+    reach = per_row - 1
+    lane_rows = jnp.pad(lane_rows, (0, reach), constant_values=-1)
+    places = jnp.pad(places, (0, reach))
+    new = jnp.pad(new, ((0, reach), (0, 0)))
+
+    def put_chunk(i, array):
+        at = i * _UPDATE_CHUNK
+
+        def chunk_of(x, ahead=0):
+            return lax.dynamic_slice_in_dim(x, at + ahead, _UPDATE_CHUNK)
+
+        merged = chunk_of(lanes)
+        here = chunk_of(lane_rows)
+        for ahead in range(per_row):
+            words = jnp.pad(
+                jnp.tile(chunk_of(new, ahead), (1, per_row)),
+                ((0, 0), (0, width - per_row * columns)))
+            mine = (chunk_of(lane_rows, ahead) == here)[:, None] & (
+                chunk_of(places, ahead)[:, None] == place_of[None, :])
+            merged = jnp.where(mine, words, merged)
+        return _write_rows(array, chunk_of(target), merged)
+
+    return lax.fori_loop(0, order.chunks, put_chunk, packed.rows)
+
+
+def _write_rows(array, target, new):
+    """``array[target[j]] = new[j]``, whole lane rows; a target past the
+    array writes nothing. XLA's scatter, its targets distinct and NOT
+    flagged sorted: on the chip a row-major array's scatter flagged
+    sorted passes over the whole array (12 ms a chunk over 4 GB),
+    unflagged it writes in place (75 ns a slot: PERF.md, PR 38)."""
+    return array.at[target].set(new, unique_indices=True, mode="drop")
+
+
 def _gather_rows(tables, order: _IdOrder, head: Optional[int] = None):
     """The entries' rows of ``tables`` side by side (the FM's ``[v_e |
     w_e]``, ``[nnz, K + 1]``), in id order, with each touched row of the
     parameters read ONCE: ``rows = [v[ids] | w[ids]]`` at the distinct
-    ids (:func:`_take_distinct`), then one batch-sized gather
-    ``rows[slot]`` whose source is a few MB (a tenth of a gather from the
-    table's cost on the chip). Returns (rows, the entries' rows).
+    ids (:func:`_take_distinct`; :func:`_take_lane_rows` over a packed
+    tree), then one batch-sized gather ``rows[slot]`` whose source is a
+    few MB (a tenth of a gather from the table's cost on the chip).
+    Returns (the :class:`_Read`, the entries' rows).
 
     ``head``: the entries take the first ``head`` columns only (a packed
     row holds the optimizer's state after the weights: the distinct ids'
@@ -479,9 +692,11 @@ def _gather_rows(tables, order: _IdOrder, head: Optional[int] = None):
     for being repeated, so the popular ids of a power law are most of a
     per-entry gather's cost; a batch with no repeated id gathers what a
     per-entry gather would."""
-    rows = _take_distinct(tables, order)
+    read = _take_lane_rows(tables, order) if isinstance(
+        tables, PackedTables) else _Read(_take_distinct(tables, order))
+    rows = read.words
     weights = rows if head in (None, rows.shape[1]) else rows[:, :head]
-    return rows, jnp.take(weights, order.slot, axis=0)
+    return read, jnp.take(weights, order.slot, axis=0)
 
 
 def _row_sums(vw, row_ids, values, num_rows: int):
@@ -508,17 +723,19 @@ def _entries_in_id_order(tables, head: Optional[int], batch):
     (:func:`_in_id_order`) and the rows of ``tables`` (the FM's ``(v,
     w)``, or the one packed array; :func:`_head_tables` gives both
     arguments) side by side for each (:func:`_gather_rows`). Returns
-    (order, rows, vw, row_ids, values): ``rows`` the distinct ids' rows
-    of ``tables``, every column; the last three per entry in id order,
-    ``vw`` the first ``head`` columns."""
+    (order, rows, vw, row_ids, values): ``rows`` the :class:`_Read` of
+    the distinct ids' rows of ``tables``, every column; the last three
+    per entry in id order, ``vw`` the first ``head`` columns."""
     values = batch["values"]
+    num_ids = tables.num_ids if isinstance(
+        tables, PackedTables) else tables[0].shape[0]
     with jax.named_scope("step.gather"):
         # offsets → row ids on device (local per shard under shard_map)
         row_ids = batch["row_ids"] if "row_ids" in batch else \
             expand_row_ids(batch["offsets"], values.shape[0])
     with jax.named_scope("step.order"):
         order, row_ids, values = _in_id_order(
-            batch["indices"], row_ids, values, tables[0].shape[0])
+            batch["indices"], row_ids, values, num_ids)
     with jax.named_scope("step.gather"):
         rows, vw = _gather_rows(tables, order, head)
     return order, rows, vw, row_ids, values
@@ -691,14 +908,49 @@ def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
         denom = jnp.maximum(wsum, 1e-12)
         upd = (-learning_rate / denom) * jnp.concatenate(
             [dv, dw[:, None]], axis=1)
-        groups = _groups(params, SGD_TABLES)
-        if l2:
-            groups = [group._replace(
-                array=group.array * (1.0 - learning_rate * l2))
-                for group in groups]
+        decay = 1.0 - learning_rate * l2
+        if isinstance(params, PackedTables):
+            arrays = [_add_lane_rows(
+                params, order, upd, seen[0], decay if l2 else None)]
+        else:
+            groups = _groups(params, SGD_TABLES)
+            if l2:
+                groups = [group._replace(array=group.array * decay)
+                          for group in groups]
+            arrays = _scatter_add_rows(groups, order, upd)
         return _regroup(
-            params, SGD_TABLES, _scatter_add_rows(groups, order, upd),
+            params, SGD_TABLES, arrays,
             {"b": params["b"] - learning_rate * (gb / denom)})
+
+
+def _add_lane_rows(packed: PackedTables, order: _IdOrder, upd, read: _Read,
+                   decay: Optional[float]):
+    """:func:`_scatter_add_rows` over a packed tree: an id's entries
+    summed first (the same one ``segment_sum``), then ``old + sum``, the
+    float32 add the scatter-add makes, on the words the head read, and
+    the new words put back by the writer every rule shares
+    (:func:`_put_lane_rows`). ``decay``: the weight decay's factor, one
+    scaling pass over the array before the add, as :func:`_sparse_update`
+    makes over tables apart. Returns the new array."""
+    n = order.slot.shape[0]
+    sums = jax.ops.segment_sum(
+        upd, order.slot, num_segments=n, indices_are_sorted=True)
+    # summed FIRST, as the docstring of :func:`_scatter_add_rows` says
+    # why: left to itself the compiler folds ``old + segment_sum(...)``
+    # into a scatter-add of the entries into ``old``, one rounding at the
+    # parameter's magnitude for every entry (17 times the error of one
+    # add against the float64 reference, on the chip: PERF.md, PR 38)
+    sums = lax.optimization_barrier(
+        jnp.pad(sums, ((0, order.ids.shape[0] - n), (0, 0))))
+    if decay is not None:
+        # the touched ids read again from the scaled array: words scaled
+        # here would round with the add (a fused multiply-add), not as
+        # the array's own pass rounds them
+        packed = _regroup(
+            packed, None, [packed.rows * decay], packed.scalars)
+        read = _take_lane_rows(packed, order)
+    words, lanes = read
+    return _put_lane_rows(packed, order, lanes, words + sums)
 
 
 def _set_rows(table, order: _IdOrder, new):
@@ -793,6 +1045,10 @@ def _update_at_distinct(params, order: _IdOrder, grads, seen, wsum, state,
         return {k: v for g in some for k, v in g.widths.items()}
 
     def set_rows_of(some, new):
+        if isinstance(params, PackedTables):  # the one array, or none
+            return [_put_lane_rows(params, order, rows.lanes,
+                                   _join_columns(new, g.widths))
+                    for g in some]
         return [_set_rows(g.array, order, _join_columns(new, g.widths))
                 for g in some]
 
@@ -802,7 +1058,8 @@ def _update_at_distinct(params, order: _IdOrder, grads, seen, wsum, state,
         sums = jax.ops.segment_sum(
             jnp.concatenate(
                 [g if g.ndim == 2 else g[:, None] for g in grads.values()]
-                + [(values != 0).astype(rows.dtype)[:, None]], axis=1),
+                + [(values != 0).astype(rows.words.dtype)[:, None]],
+                axis=1),
             order.slot, num_segments=n, indices_are_sorted=True)
         sums = jnp.pad(sums, ((0, order.ids.shape[0] - n), (0, 0)))
         widths = {name: 0 if g.ndim == 1 else g.shape[1]
@@ -810,7 +1067,7 @@ def _update_at_distinct(params, order: _IdOrder, grads, seen, wsum, state,
         grad = {name: g / denom
                 for name, g in _split_columns(sums, widths).items()}
         live = sums[:, -1] > 0
-        old = _split_columns(rows, widths_of(head))
+        old = _split_columns(rows.words, widths_of(head))
     with jax.named_scope("step.state"):
         if rest:
             old.update(_split_columns(
@@ -1048,23 +1305,87 @@ def make_fm_train_step(
 
 
 @partial(jax.jit, static_argnames=("span",))
-def _rows_at(array, ids, span=None):
+def _rows_at(held, ids, span=None):
     """A logical table's rows at ``ids``: gathered from the array that
-    holds them, the table's columns (``span``; None: the array is the
-    table) cut from the RESULT."""
-    rows = jnp.take(array, ids, axis=0)
-    return rows if span is None else _span(rows, span)
+    holds them, the table's columns (``span``; None: every column) cut
+    from the RESULT. ``held``: the array, or the packed tree, whose lane
+    rows are gathered and the ids' own words cut from them (every word,
+    ``[n, C]``: one program for all the tables of a learner, which cuts
+    a table's columns from what comes back)."""
+    if not isinstance(held, PackedTables):
+        rows = jnp.take(held, ids, axis=0)
+        return rows if span is None else _span(rows, span)
+
+    def words_of(some):
+        lane_rows, places = held.lane_rows_of(some)
+        return _cut_lanes(jnp.take(held.rows, lane_rows, axis=0), places,
+                          held.columns)
+
+    # a chunk of ids a pass: the lane rows of all of them at once lie
+    # beside the table (1.3 GB for the check's 360,000 ids of 17 columns)
+    n = ids.shape[0]
+    chunk = min(n, 4 * _UPDATE_CHUNK)
+    words = lax.map(
+        words_of, jnp.pad(ids, (0, -n % chunk)).reshape(-1, chunk))
+    return words.reshape(-1, held.columns)[:n]
 
 
 @partial(jax.jit, static_argnames=("span",))
-def _row_fingerprints(array, span=None):
-    """``uint32[rows]``: the wrapping sum of the bit patterns of each
-    row of a logical table, read where its columns lie (``span`` as
-    :func:`_rows_at` takes it): a slice inside a reduction, no copy."""
-    bits = lax.bitcast_convert_type(array, jnp.uint32)
+def _row_fingerprints(held, span=None):
+    """The wrapping sum of the bit patterns of each row of a logical
+    table, read where its columns lie (``held`` and ``span`` as
+    :func:`_rows_at` takes them): slices inside a reduction, no copy of a
+    table. ``uint32[ids]``, or, of a packed tree's lane rows, one
+    ``uint32[R]`` for each place of a lane row (:func:`_places_in_id_order`
+    puts them in id order)."""
+    if isinstance(held, PackedTables):
+        # every place's sum in ONE reduction with a result for each, so
+        # that the array is read once (a reduction a place reads it p
+        # times, 28 GB whatever the geometry: 39 ms). A table of one
+        # column is summed over two lanes, the other one masked: a
+        # reduction over one lane is no reduction to the compiler, and p
+        # passes again. (The slice inside the bitcast: converted whole,
+        # the array's bits lie beside it.)
+        first, width = span
+        lanes = held.rows.shape[1]
+        width = max(width, 2)
+        windows = []
+        for at in range(0, held.per_row * held.columns, held.columns):
+            low = min(at + first, lanes - width)
+            bits = lax.bitcast_convert_type(
+                held.rows[:, low:low + width], jnp.uint32)
+            if span[1] < 2:
+                bits = jnp.where(
+                    np.arange(low, low + width) == at + first, bits,
+                    jnp.uint32(0))
+            windows.append(bits)
+        return lax.reduce(
+            tuple(windows), (jnp.uint32(0),) * len(windows),
+            lambda a, b: tuple(x + y for x, y in zip(a, b)), (1,))
+    bits = lax.bitcast_convert_type(held, jnp.uint32)
     if span is not None:
         bits = _span(bits, span)
     return bits if bits.ndim == 1 else jnp.sum(bits, axis=1, dtype=jnp.uint32)
+
+
+@partial(jax.jit, static_argnames=("num_ids",))
+def _places_in_id_order(by_place, num_ids: int):
+    """``by_place``: for each place q of a lane row a vector ``[R]`` whose
+    element r belongs to id ``r * p + q``; returns ``[num_ids]`` in id
+    order. 128 lane rows at a time, a fixed permutation of ``p * 128``
+    lanes: an array ``[R, p]``, its short side minor, is padded to 128
+    lanes on the chip (43 times its size at p = 3). One program for
+    every table of a learner (its code is most of a fingerprint's)."""
+    per_row = len(by_place)
+    if per_row == 1:
+        return by_place[0][:num_ids]
+    blocks = -(-by_place[0].shape[0] // _LANES)
+    side_by_side = jnp.concatenate(
+        [jnp.pad(x, (0, blocks * _LANES - x.shape[0])).reshape(
+            blocks, _LANES) for x in by_place], axis=1)
+    lane_row, place = np.divmod(np.arange(per_row * _LANES), per_row)
+    return jnp.take(side_by_side, place * _LANES + lane_row, axis=1).reshape(
+        -1)[:num_ids]
 
 
 class FMLearner(FeedLearner):
@@ -1264,10 +1585,20 @@ class FMLearner(FeedLearner):
             self.params, PackedTables)
         return _columns(w for _, w in self.table_layout()) if packed else 0
 
+    @property
+    def lane_geometry(self) -> Tuple[int, int]:
+        """(``L``, ``p``): the lanes of one lane row of the packed array
+        and the ids side by side in it (:func:`lane_geometry` of
+        :attr:`row_columns`); (0, 0) when the tables lie apart."""
+        columns = self.row_columns
+        return lane_geometry(columns) if columns else (0, 0)
+
     def epoch_span_args(self) -> Dict:
+        lanes, per_row = self.lane_geometry
         return {"table_shards": self.table_shards,
                 "optimizer": self.optimizer,
-                "row_columns": self.row_columns}
+                "row_columns": self.row_columns,
+                "row_lanes": lanes, "ids_per_lane_row": per_row}
 
     def audit_params(self):
         """The arrays as they lie (a packed row as ``rows``): a sample
@@ -1314,7 +1645,11 @@ class FMLearner(FeedLearner):
         ``dmlc_fit_packed_row_steps_total`` counts the steps whose
         program read and wrote ONE packed row for each touched id (the
         tree the step took was a :class:`PackedTables`): every step on
-        one device, none on a mesh."""
+        one device, none on a mesh. ``dmlc_fit_lane_row_steps_total``
+        counts, from the same tree, the steps that read and wrote that
+        row in one piece, as lanes of a row-major lane row (the one
+        layout a :class:`PackedTables` has since PR 38; a tree of this
+        program's parent has the first counter and not the second)."""
         shards = self.table_shards
         sparse = self.mesh is None or shards > 1
         reg.counter(
@@ -1359,6 +1694,11 @@ class FMLearner(FeedLearner):
             "optimizer steps that read and wrote one packed row (weights "
             "and optimizer state side by side) for each touched id",
             model=self.name).inc(self._packed_steps)
+        reg.counter(
+            "dmlc_fit_lane_row_steps_total",
+            "optimizer steps that read and wrote each touched id's packed "
+            "row as lanes of one row-major lane row, several ids to a row",
+            model=self.name).inc(self._packed_steps)
         self._steps_of.clear()
         self._packed_steps = 0
 
@@ -1388,7 +1728,8 @@ class FMLearner(FeedLearner):
         params = self.params
         if not isinstance(params, PackedTables):
             return {"params": dict(params)}
-        host = np.array(params.rows, copy=True)
+        host = _from_lane_rows(
+            np.asarray(params.rows), params.columns, params.num_ids)
         tables = {name: np.ascontiguousarray(_span(host, params.span(name)))
                   for name, _ in params.layout}
         return {"params": dict(tables, **params.scalars)}
@@ -1443,21 +1784,29 @@ class FMLearner(FeedLearner):
                 if k not in names}
 
     def _held(self, name: str):
-        """(the array that holds logical table ``name``, its columns
-        there; None where the array is the table)."""
+        """(what holds logical table ``name``: the packed tree or the
+        table's own array; its columns among an id's words, None where
+        the array is the table)."""
         if isinstance(self.params, PackedTables):
-            return self.params.rows, self.params.span(name)
+            return self.params, self.params.span(name)
         return self.params[name], None
 
     def table_rows(self, name: str, ids):
         """Logical table ``name`` at ``ids`` (``[n]`` or ``[n, K]``, a
-        device array): one jitted gather from the array that holds it."""
-        array, span = self._held(name)
-        return _rows_at(array, ids, span=span)
+        device array): one jitted gather from the array that holds it
+        (of a packed array: the ids' whole rows, the table's columns cut
+        from them here)."""
+        held, span = self._held(name)
+        if isinstance(held, PackedTables):
+            return _span(_rows_at(held, ids), span)
+        return _rows_at(held, ids, span=span)
 
     def table_fingerprints(self, name: str):
         """``uint32[F]``: the wrapping sum of the bit patterns of each
         row of logical table ``name``, inside one jit, with no copy of a
         table."""
-        array, span = self._held(name)
-        return _row_fingerprints(array, span=span)
+        held, span = self._held(name)
+        prints = _row_fingerprints(held, span=span)
+        if isinstance(held, PackedTables):
+            return _places_in_id_order(prints, held.num_ids)
+        return prints
