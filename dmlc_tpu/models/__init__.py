@@ -9,6 +9,8 @@ so this package ships that learner family TPU-natively:
   batches, data-parallel psum gradient sync over a mesh axis
 - ``fm``: factorization machines (the libfm format's model family), embedding
   table sharded or replicated, same segment-sum sparse kernels
+  (``AdaptiveFMLearner``: difacto's memory-adaptive FM, factors only for
+  the ids seen more than ``v_threshold`` times, in a slot table)
 - ``ffm``: field-aware factorization machines (libffm's model and
   AdaGrad), an entry's field taken from its id's range; the FM's step
   head, chunk loops and stateful-update skeleton at another width
@@ -39,6 +41,9 @@ from dmlc_tpu.models.linear import (
     linear_predict_dense,
 )
 from dmlc_tpu.models.fm import (
+    AdaptiveFMLearner,
+    AdaptiveFMParam,
+    AdaptiveTables,
     FM_FACTOR_PARTITION_RULES,
     FM_PARTITION_RULES,
     FMParam,
@@ -78,6 +83,9 @@ __all__ = [
     "make_hostsync_train_step",
     "make_linear_train_step",
     "linear_predict_dense",
+    "AdaptiveFMLearner",
+    "AdaptiveFMParam",
+    "AdaptiveTables",
     "FM_FACTOR_PARTITION_RULES",
     "FM_PARTITION_RULES",
     "FMParam",

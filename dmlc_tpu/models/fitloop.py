@@ -442,10 +442,18 @@ class FeedLearner:
         """The arrays the audit samples at an epoch's end."""
         return self.params
 
+    def pass_scalars(self) -> Dict:
+        """Device scalars the learner's steps kept running on the device
+        (counts a step adds to in its own state), asked once at a pass's
+        end: they ride to the host with the pass's losses (no read of
+        their own, none inside a pass) and reach :meth:`epoch_closed` in
+        ``sums`` under their names. None by default."""
+        return {}
+
     def epoch_closed(self, reg, nstep: int, sums: Dict) -> None:
         """Inside ``epoch_close``: count what only this model has.
         ``sums``: the pass's sum of each scalar :meth:`train_step`
-        returned (:attr:`EpochMetrics.sums`)."""
+        returned (:attr:`EpochMetrics.sums`), and :meth:`pass_scalars`."""
 
 
 def _snapshot_state(learner, feed, epoch: int, history) -> Dict:
@@ -532,6 +540,7 @@ def fit_feed(learner, feed, epochs: int = 1, log_every: int = 0,
                 "preempted in epoch %d after %d steps; last committed "
                 "snapshot epoch %d"
                 % (epoch, nstep, snapshotter.committed_epoch))
+        acc.add(learner.pass_scalars())
         fl.finish_epoch(
             epoch, nstep, t0, acc, history, feed=feed,
             log_every=log_every, params=learner.audit_params(),

@@ -53,6 +53,21 @@ weights in ``params`` (in the same packed row on one device), reads a
 touched row's state once and SETS weights and state from the rule
 (:func:`_stateful_update`).
 
+A FOURTH storage keeps factors only for the ids that earn them
+(:class:`AdaptiveTables`, :class:`AdaptiveFMLearner`: difacto's
+memory-adaptive constraints ``V_threshold`` and ``l1_shrk``, one device,
+the stateful rule): a base array with five words for every id (``w``,
+``z``, ``n``, an exact count, the number of its factor row) and a table of
+SLOTS, one ``[v | a]`` row each, far smaller than the id space. Its step
+(:func:`_adaptive_step`) reads through that indirection (the distinct
+ids' base rows, then the factor rows at the slots they name), leaves the
+ids without factors out of the forward pass and the update, counts, and
+hands free slots to the ids that cross the threshold, on the device, by a
+prefix sum over the sorted distinct ids (``step.activate``). It shares
+the sort, the lane-row reads and writes, the row sums, the forward and
+backward pass, the id sums and the rule with the dense steps; which step
+runs follows, again, from the tree it is given.
+
 score(x) = b + Σ_i w_i x_i + ½ Σ_k [(Σ_i v_ik x_i)² − Σ_i v_ik² x_i²]
 """
 
@@ -546,13 +561,14 @@ def _in_id_order(indices, row_ids, values, num_features: int):
     return _IdOrder(indices, slot, ids, slot[-1] + 1), row_ids, values
 
 
-def _take_distinct(tables, order: _IdOrder):
+def _take_distinct(tables, order: _IdOrder, sorted_ids: bool = True):
     """``[t[ids] | ...]`` for the 1-D and 2-D ``tables`` of one height,
     side by side (``[n + pad, columns]``), at the batch's distinct ids,
     ``_UPDATE_CHUNK`` slots a pass until the last slot that holds one:
     the loop adapts to what the batch holds. A slot past the distinct ids
-    reads 0."""
-    flags = dict(indices_are_sorted=True, unique_indices=True)
+    reads 0. ``sorted_ids=False``: ``order.ids`` are distinct rows in no
+    order (the factor rows a slot map names: :class:`AdaptiveTables`)."""
+    flags = dict(indices_are_sorted=sorted_ids, unique_indices=True)
 
     def take_chunk(i, rows):
         at = i * _UPDATE_CHUNK
@@ -726,19 +742,26 @@ def _entries_in_id_order(tables, head: Optional[int], batch):
     (order, rows, vw, row_ids, values): ``rows`` the :class:`_Read` of
     the distinct ids' rows of ``tables``, every column; the last three
     per entry in id order, ``vw`` the first ``head`` columns."""
-    values = batch["values"]
     num_ids = tables.num_ids if isinstance(
         tables, PackedTables) else tables[0].shape[0]
+    order, row_ids, values = _batch_in_id_order(batch, num_ids)
+    with jax.named_scope("step.gather"):
+        rows, vw = _gather_rows(tables, order, head)
+    return order, rows, vw, row_ids, values
+
+
+def _batch_in_id_order(batch, num_ids: int):
+    """The batch's entries sorted by feature id: every entry's row
+    (``step.gather``: the offsets' expansion) and the step's one sort
+    (``step.order``, :func:`_in_id_order`). Returns (order, row_ids,
+    values), the last two per entry in id order."""
+    values = batch["values"]
     with jax.named_scope("step.gather"):
         # offsets → row ids on device (local per shard under shard_map)
         row_ids = batch["row_ids"] if "row_ids" in batch else \
             expand_row_ids(batch["offsets"], values.shape[0])
     with jax.named_scope("step.order"):
-        order, row_ids, values = _in_id_order(
-            batch["indices"], row_ids, values, num_ids)
-    with jax.named_scope("step.gather"):
-        rows, vw = _gather_rows(tables, order, head)
-    return order, rows, vw, row_ids, values
+        return _in_id_order(batch["indices"], row_ids, values, num_ids)
 
 
 def _fm_entry_grads(params, batch, objective: str,
@@ -753,6 +776,24 @@ def _fm_entry_grads(params, batch, objective: str,
     seen); ``seen`` = (the distinct ids' rows as the head read them:
     ``[v | w]``, or the whole packed row, state and all; the entries'
     values) is what a stateful rule reads besides (:func:`_stateful_update`).
+    The head is :func:`_entries_in_id_order`, the rest
+    :func:`_fm_row_grads`."""
+    order, rows, vw, row_ids, values = _entries_in_id_order(
+        *_head_tables(params, ("v", "w")), batch)
+    grads = _fm_row_grads(
+        params["b"], vw, row_ids, values, batch, objective, factor_axis)
+    return (*grads, order, (rows, values))
+
+
+def _fm_row_grads(bias, vw, row_ids, values, batch, objective: str,
+                  factor_axis: Optional[str] = None):
+    """Forward and backward of the FM over entries whose rows ``vw`` =
+    ``[v_e | w_e]`` the step's head has gathered (in any order; the
+    heads hand them over in id order): (dw, gb, dv, loss_sum,
+    weight_sum), the per-entry contributions of :func:`_fm_entry_grads`.
+    An entry whose ``v_e`` is 0 adds nothing to its row's interaction
+    term (how :class:`AdaptiveTables`' head leaves out the ids that hold
+    no factors).
 
     Passes that share an index vector are one pass over concatenated
     columns: the three row sums of the forward pass (:func:`_row_sums`),
@@ -760,18 +801,16 @@ def _fm_entry_grads(params, batch, objective: str,
     row sums add a row's entries in id order, not the feed's: the same
     float32 terms in another order.
 
-    ``factor_axis``: ``params["v"]`` holds this chip's columns only and
-    the batch is the whole step's (``row_ids`` given, global); the
-    columns' share of the interaction term is psummed over that axis
-    between forward and backward, under ``step.exchange``.
+    ``factor_axis``: ``vw`` holds this chip's columns of ``v`` only and
+    the batch is the whole step's (``row_ids`` global); the columns'
+    share of the interaction term is psummed over that axis between
+    forward and backward, under ``step.exchange``.
 
     The ``step.*`` scopes name the step's phases in the compiled
     program's metadata (shared with models/linear.py), so a device
     profile can be read by phase; they change no operation."""
     label = batch["label"]
     weight = batch["weight"]
-    order, rows, vw, row_ids, values = _entries_in_id_order(
-        *_head_tables(params, ("v", "w")), batch)
     xv, s, q, linear = _row_sums(vw, row_ids, values, label.shape[0])
     with jax.named_scope("step.forward"):
         interaction = 0.5 * jnp.sum(s * s - q, axis=-1)
@@ -779,7 +818,7 @@ def _fm_entry_grads(params, batch, objective: str,
         with jax.named_scope("step.exchange"):
             interaction = psum(interaction, factor_axis)
     with jax.named_scope("step.forward"):
-        margin = params["b"] + linear + interaction
+        margin = bias + linear + interaction
         loss, gmargin = margin_grad(objective, margin, label)
         loss_sum = jnp.sum(weight * loss)
     with jax.named_scope("step.backward"):
@@ -791,7 +830,7 @@ def _fm_entry_grads(params, batch, objective: str,
         dw = back[:, -1] * values  # [nnz]
         # dv[e,k] = x_e * (s[r,k] − x_e v[i,k]), scaled by wg[r]
         dv = dw[:, None] * (back[:, :-1] - xv)
-    return dw, gb, dv, loss_sum, jnp.sum(weight), order, (rows, values)
+    return dw, gb, dv, loss_sum, jnp.sum(weight)
 
 
 def _scatter_add_rows(groups, order: _IdOrder, upd):
@@ -856,6 +895,18 @@ def _check_rule_placement(optimizer: str, mesh: Optional[Mesh],
           "and updates the rows a batch names; the replicated mesh step "
           "applies a dense psummed gradient and has no such path: train "
           "on one device or with table_sharding='factors'", optimizer)
+
+
+def _check_adaptive_placement(optimizer: str, mesh: Optional[Mesh]) -> None:
+    """A memory-adaptive FM (:class:`AdaptiveTables`) lives on one device
+    and trains by difacto's rule."""
+    check(mesh is None,
+          "a memory-adaptive FM (factor_capacity, v_threshold, l1_shrk) "
+          "keeps a slot map and hands out factor rows inside the step of "
+          "ONE device; a mesh has no such path: train on one device")
+    check(optimizer == "ftrl_adagrad",
+          "a memory-adaptive FM trains by optimizer='ftrl_adagrad' "
+          "(l1_shrk reads FTRL's w), got optimizer=%r", optimizer)
 
 
 def _gather_sections(batch, axis: str):
@@ -997,6 +1048,31 @@ def _join_columns(tables, widths):
          for name, width in widths.items()], axis=1)
 
 
+def _id_sums(grads, values, order: _IdOrder, wsum, dtype):
+    """An id's whole gradient before a rule runs: ``grads`` = {weight
+    table: the entries' contributions, ``[n]`` or ``[n, C]`` in
+    ``order``'s order} summed by sorted slot in ONE ``segment_sum`` with
+    one more column, the count of an id's entries that carry a value.
+    Returns ({table: the distinct ids' MEAN gradients by slot, ``[n +
+    pad]`` or ``[n + pad, C]``}, that count by slot ``[n + pad]`` (an
+    exact whole number of ``dtype``), ``max(wsum, 1e-12)``)."""
+    n = values.shape[0]
+    denom = jnp.maximum(wsum, 1e-12)
+    # the last column counts an id's entries that carry a value
+    sums = jax.ops.segment_sum(
+        jnp.concatenate(
+            [g if g.ndim == 2 else g[:, None] for g in grads.values()]
+            + [(values != 0).astype(dtype)[:, None]],
+            axis=1),
+        order.slot, num_segments=n, indices_are_sorted=True)
+    sums = jnp.pad(sums, ((0, order.ids.shape[0] - n), (0, 0)))
+    widths = {name: 0 if g.ndim == 1 else g.shape[1]
+              for name, g in grads.items()}
+    grad = {name: g / denom
+            for name, g in _split_columns(sums, widths).items()}
+    return grad, sums[:, -1], denom
+
+
 def _update_at_distinct(params, order: _IdOrder, grads, seen, wsum, state,
                         rule):
     """The skeleton of an update by a rule that keeps state for every
@@ -1032,7 +1108,6 @@ def _update_at_distinct(params, order: _IdOrder, grads, seen, wsum, state,
     selects, those arrays' write. The write of an array that holds
     weights and the id sums stay under ``step.update``."""
     rows, values = seen
-    n = values.shape[0]
     groups = _groups(params, tuple(grads) + tuple(state))
     # the head read every array that holds a weight table
     def in_head(group):
@@ -1053,20 +1128,9 @@ def _update_at_distinct(params, order: _IdOrder, grads, seen, wsum, state,
                 for g in some]
 
     with jax.named_scope("step.update"):
-        denom = jnp.maximum(wsum, 1e-12)
-        # the last column counts an id's entries that carry a value
-        sums = jax.ops.segment_sum(
-            jnp.concatenate(
-                [g if g.ndim == 2 else g[:, None] for g in grads.values()]
-                + [(values != 0).astype(rows.words.dtype)[:, None]],
-                axis=1),
-            order.slot, num_segments=n, indices_are_sorted=True)
-        sums = jnp.pad(sums, ((0, order.ids.shape[0] - n), (0, 0)))
-        widths = {name: 0 if g.ndim == 1 else g.shape[1]
-                  for name, g in grads.items()}
-        grad = {name: g / denom
-                for name, g in _split_columns(sums, widths).items()}
-        live = sums[:, -1] > 0
+        grad, valued, denom = _id_sums(
+            grads, values, order, wsum, rows.words.dtype)
+        live = valued > 0
         old = _split_columns(rows.words, widths_of(head))
     with jax.named_scope("step.state"):
         if rest:
@@ -1136,6 +1200,378 @@ def _stateful_update(params, order: _IdOrder, grads, seen,
         return _regroup(
             params, FTRL_TABLES, arrays,
             {"b": params["b"] - learning_rate * (gb / denom)})
+
+
+#: the words an id keeps in the base array of :class:`AdaptiveTables`, in
+#: lane order: FTRL's weight and state (float32 bits), the count of the
+#: entries that named the id, and the factor row it holds (int32; -1: none)
+BASE_WORDS = ("w", "z", "n", "cnt", "slot")
+_BASE_LAYOUT = tuple((name, 0) for name in BASE_WORDS)
+
+#: the logical per-id tables of a memory-adaptive FM, by the names its
+#: equations use: ``cnt`` and ``has_v`` whole numbers, ``v`` the factors an
+#: id holds or would start from, ``a`` their accumulator (0 without a row)
+ADAPTIVE_TABLES = ("w", "z", "n", "cnt", "has_v", "v", "a")
+
+#: whole numbers among :class:`AdaptiveTables`' scalars, beside ``b``
+_ADAPTIVE_COUNTS = ("active_ids", "refused", "counted_rows", "active_entries")
+
+
+class Adaptive(NamedTuple):
+    """difacto's memory-adaptive constraints, named as
+    :class:`AdaptiveFMParam` names them: what the step over
+    :class:`AdaptiveTables` needs beside the rule's :class:`FtrlAdagrad`.
+    The tree carries them (static, as its ``init_scale``)."""
+
+    v_threshold: int
+    l1_shrk: bool
+    #: counts are taken over the first ``count_rows`` rows the tree sees
+    count_rows: int
+
+
+def _split_base(words):
+    """{name: ``[n]``} of :data:`BASE_WORDS` from base rows ``words s32[n,
+    5]``: ``w``, ``z``, ``n`` as the float32 their bits are."""
+    floats = lax.bitcast_convert_type(words[..., :3], jnp.float32)
+    held = {name: floats[..., j] for j, name in enumerate(BASE_WORDS[:3])}
+    held.update(cnt=words[..., 3], slot=words[..., 4])
+    return held
+
+
+def _join_base(held):
+    """:func:`_split_base` back: ``s32[n, 5]``."""
+    floats = jnp.stack([held[name] for name in BASE_WORDS[:3]], axis=1)
+    return jnp.concatenate(
+        [lax.bitcast_convert_type(floats, jnp.int32),
+         held["cnt"][:, None], held["slot"][:, None]], axis=1)
+
+
+def _factor_start(key, ids, num_factors: int, init_scale: float):
+    """``v0 [n, K]``: the factors id ``ids[j]`` starts from when it is
+    given a row, ``init_scale`` times a standard normal draw that is a
+    pure function of ``key`` (``uint32[2]``, the seed's threefry key),
+    the id and the column: the threefry block of the counter (id,
+    column), its bits made a normal as ``jax.random.normal`` makes one.
+    Nothing is stored for an id without a row, and an id draws the same
+    factors whenever and in whatever batch it is activated."""
+    shape = (ids.shape[0], num_factors)
+    bits1, bits2 = jex_random.threefry2x32_p.bind(
+        key[0], key[1],
+        jnp.broadcast_to(ids.astype(jnp.uint32)[:, None], shape),
+        jnp.broadcast_to(lax.iota(jnp.uint32, num_factors)[None, :], shape))
+    # 23 random mantissa bits: [1, 2), then (-1, 1)
+    unit = lax.bitcast_convert_type(
+        ((bits1 ^ bits2) >> 9) | jnp.uint32(0x3F800000), jnp.float32) - 1.0
+    low = np.nextafter(np.float32(-1.0), np.float32(0.0))
+    uniform = jnp.maximum(low, unit * (np.float32(1.0) - low) + low)
+    return init_scale * (np.float32(np.sqrt(2.0)) * lax.erf_inv(uniform))
+
+
+@jax.tree_util.register_pytree_node_class
+class AdaptiveTables(Mapping):
+    """The storage of a memory-adaptive FM (difacto's ``V_threshold`` and
+    ``l1_shrk``: Li et al., WSDM 2016): every id has a row of FIVE words,
+    an id has factors only once it has earned them, and the factors live
+    in a table of SLOTS far smaller than the id space.
+
+    ``base s32[R, 128]``: lane rows (:func:`lane_geometry` of 5: 25 ids a
+    row) of :data:`BASE_WORDS`, ``w``, ``z``, ``n`` as float32 bits beside
+    the exact ``cnt`` and the id's ``slot`` (-1: none); integers, so that
+    no float operation ever meets a count's bits. ``factors f32[capacity,
+    2K]``: slot s holds ``[v | a]`` of the id whose ``slot`` is s, one id
+    a lane row. ``scalars``: ``b`` and the whole numbers ``active_ids``
+    (slots handed out), ``refused``, ``counted_rows``, ``active_entries``
+    (:data:`_ADAPTIVE_COUNTS`, int32; the last wraps). ``key uint32[2]``:
+    the seed's key, from which an id's first factors are drawn when it is
+    activated (:func:`_factor_start`); ``init_scale`` their scale;
+    ``adaptive`` the threshold, ``l1_shrk`` and the rows counts are taken
+    over (:class:`Adaptive`).
+
+    A pytree, as :class:`PackedTables` is: the step takes the storage from
+    the tree it is given. As a mapping it reads as the LOGICAL tables of
+    :data:`ADAPTIVE_TABLES` (copies, through the slot map: for a look at a
+    fitted model or a test) and the scalars."""
+
+    def __init__(self, base, factors, scalars: Dict, key, num_ids: int,
+                 init_scale: float, adaptive: Adaptive):
+        self.base = base
+        self.factors = factors
+        self.scalars = scalars
+        self.key = key
+        self.num_ids = int(num_ids)
+        self.init_scale = float(init_scale)
+        self.adaptive = Adaptive(*adaptive)
+
+    def tree_flatten(self):
+        return ((self.base, self.factors, self.scalars, self.key),
+                (self.num_ids, self.init_scale, self.adaptive))
+
+    @classmethod
+    def tree_unflatten(cls, static, children):
+        return cls(*children, *static)
+
+    def holding(self, base, factors, scalars: Dict) -> "AdaptiveTables":
+        """This tree over other arrays: what a step hands back."""
+        return AdaptiveTables(base, factors, scalars, self.key, self.num_ids,
+                              self.init_scale, self.adaptive)
+
+    @property
+    def base_rows(self) -> PackedTables:
+        """The base array as the packed tree it is, for the lane-row
+        reads and writes every packed learner shares."""
+        return PackedTables(self.base, {}, _BASE_LAYOUT, self.num_ids)
+
+    @property
+    def capacity(self) -> int:
+        return self.factors.shape[0]
+
+    @property
+    def num_factors(self) -> int:
+        return self.factors.shape[1] // 2
+
+    def start(self, ids):
+        """``v0`` of ``ids``: :func:`_factor_start` under this tree's key."""
+        return _factor_start(
+            self.key, ids, self.num_factors, self.init_scale)
+
+    def __getitem__(self, name):
+        if name in self.scalars:
+            return self.scalars[name]
+        if name not in ADAPTIVE_TABLES:
+            raise KeyError(name)
+        return _adaptive_rows_at(
+            self, jnp.arange(self.num_ids, dtype=jnp.int32), name=name)
+
+    def __iter__(self):
+        yield from ADAPTIVE_TABLES
+        yield from self.scalars
+
+    def __len__(self):
+        return len(ADAPTIVE_TABLES) + len(self.scalars)
+
+
+def init_adaptive(num_features: int, num_factors: int, capacity: int,
+                  init_scale: float, adaptive: Adaptive,
+                  seed) -> AdaptiveTables:
+    """A memory-adaptive FM at its start: every id's words 0 and no slot,
+    every factor row 0, nothing counted, the seed's key kept for the
+    draws of :func:`_factor_start`."""
+    lanes, per_row = lane_geometry(len(BASE_WORDS))
+    rows = -(-num_features // per_row)
+    place, column = np.divmod(np.arange(lanes), len(BASE_WORDS))
+    empty = np.where(
+        (place < per_row) & (column == BASE_WORDS.index("slot")), -1, 0
+    ).astype(np.int32)
+    ids = jnp.arange(rows, dtype=jnp.int32)[:, None] * per_row + place
+    scalars = {"b": jnp.zeros((), jnp.float32)}
+    scalars.update({name: jnp.zeros((), jnp.int32)
+                    for name in _ADAPTIVE_COUNTS})
+    return AdaptiveTables(
+        jnp.where(ids < num_features, empty, 0),
+        jnp.zeros((capacity, 2 * num_factors), jnp.float32), scalars,
+        jax.random.key_data(jax.random.PRNGKey(seed)), num_features,
+        init_scale, adaptive)
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _grant_counted(params: AdaptiveTables, cnt, ids, held_rows,
+                   counted_rows) -> AdaptiveTables:
+    """``params`` with every id's count set to ``cnt s32[F]`` and the
+    first ``held_rows`` of ``ids`` (ascending, ``s32[capacity]``) holding
+    factor rows 0, 1, ... in that order, each ``[v0(id) | 0]``; every
+    other id without a row, ``w``, ``z``, ``n`` as they are. The words
+    are set in the lanes where they lie: no array of an id a row."""
+    capacity, num_ids = params.capacity, params.num_ids
+    number = jnp.arange(capacity, dtype=jnp.int32)
+    real = number < held_rows
+    slot = jnp.full((num_ids,), -1, jnp.int32).at[
+        jnp.where(real, ids, num_ids + number)].set(
+            number, mode="drop", unique_indices=True,
+            indices_are_sorted=True)
+    rows, lanes = params.base.shape
+    per_row = lanes // len(BASE_WORDS)
+    place, column = np.divmod(np.arange(lanes), len(BASE_WORDS))
+    words = {name: jnp.pad(of_ids, (0, rows * per_row - num_ids))
+             for name, of_ids in (("cnt", cnt), ("slot", slot))}
+    # both arrays in place, a block of rows a pass; the last block may
+    # overlap the one before
+    block = min(rows, 1 << 15)
+
+    def put_words(i, base):
+        at = jnp.minimum(i * block, rows - block)
+        lane_rows = lax.dynamic_slice_in_dim(base, at, block)
+        for name, padded in words.items():
+            by_row = lax.dynamic_slice_in_dim(
+                padded, at * per_row, block * per_row).reshape(block, per_row)
+            lane_rows = jnp.where(
+                (place < per_row) & (column == BASE_WORDS.index(name)),
+                jnp.take(by_row, np.minimum(place, per_row - 1), axis=1),
+                lane_rows)
+        return lax.dynamic_update_slice_in_dim(base, lane_rows, at, 0)
+
+    base = lax.fori_loop(0, -(-rows // block), put_words, params.base)
+    k = params.num_factors
+    chunk = min(capacity, 4 * _UPDATE_CHUNK)
+
+    def put_factors(i, factors):
+        at = jnp.minimum(i * chunk, capacity - chunk)
+        fresh = jnp.pad(
+            params.start(lax.dynamic_slice_in_dim(ids, at, chunk)),
+            ((0, 0), (0, k)))
+        held = (at + jnp.arange(chunk, dtype=jnp.int32)) < held_rows
+        return lax.dynamic_update_slice_in_dim(
+            factors, jnp.where(held[:, None], fresh, 0.0), at, 0)
+
+    factors = lax.fori_loop(
+        0, -(-capacity // chunk), put_factors, params.factors)
+    return params.holding(base, factors, dict(
+        params.scalars, active_ids=held_rows, counted_rows=counted_rows))
+
+
+class _AdaptiveRead(NamedTuple):
+    """What the head of the step over :class:`AdaptiveTables` read at the
+    batch's distinct ids, by slot of the :class:`_IdOrder`."""
+
+    #: the base lane rows and the ids' five words (:func:`_take_lane_rows`)
+    base: _Read
+    #: {name: ``[n + pad]``} of :data:`BASE_WORDS`
+    held: Dict
+    #: bool[n + pad]: the slot holds a distinct id that has a factor row
+    has: jax.Array
+    #: bool[n + pad] ``u``: the id's factors take part in this step
+    uses: jax.Array
+    #: f32[n + pad, 2K] ``[v | a]`` of the ids that have a row
+    factors: jax.Array
+
+
+def _adaptive_head(params: AdaptiveTables, batch):
+    """:func:`_entries_in_id_order` through a slot map: the sort, the
+    distinct ids' base rows, then the factor rows at the slots those rows
+    name (an id without one names a row past the array, each its own, as
+    the write's idle slots do), and ``[u v | w]`` for every entry, ``u``
+    from the values BEFORE the step. Returns (order, the
+    :class:`_AdaptiveRead`, vw, row_ids, values)."""
+    order, row_ids, values = _batch_in_id_order(batch, params.num_ids)
+    with jax.named_scope("step.gather"):
+        base = _take_lane_rows(params.base_rows, order)
+        held = _split_base(base.words)
+        number = jnp.arange(order.ids.shape[0], dtype=jnp.int32)
+        has = (number < order.distinct) & (held["slot"] >= 0)
+        at = jnp.where(has, held["slot"], params.capacity + number)
+        factors = _take_distinct(
+            (params.factors,), order._replace(ids=at), sorted_ids=False)
+        uses = has & (held["w"] != 0) if params.adaptive.l1_shrk else has
+        k = params.num_factors
+        vw = jnp.concatenate(
+            [jnp.where(uses[:, None], factors[:, :k], 0.0),
+             held["w"][:, None]], axis=1)
+        vw = jnp.take(vw, order.slot, axis=0)
+    return order, _AdaptiveRead(base, held, has, uses, factors), vw, \
+        row_ids, values
+
+
+def _put_factor_rows(params: AdaptiveTables, order: _IdOrder, target, new,
+                     granted):
+    """``factors[target[j]] = new[j]`` for every slot j of the order, whole
+    rows through the one writer (:func:`_write_rows`), ``_UPDATE_CHUNK``
+    slots a pass up to the last slot that holds a distinct id; a
+    ``granted`` slot's row is ``[v0(id) | 0]`` instead. The draws of
+    ``v0`` are made for the chunks that hold an activated id only (none
+    once the counts stand still): ``step.activate``."""
+    k = params.num_factors
+
+    def put_chunk(i, array):
+        at = i * _UPDATE_CHUNK
+
+        def chunk_of(x):
+            return lax.dynamic_slice_in_dim(x, at, _UPDATE_CHUNK)
+
+        taken = chunk_of(granted)
+        with jax.named_scope("step.activate"):
+            fresh = lax.cond(
+                jnp.any(taken),
+                lambda ids: jnp.pad(params.start(ids), ((0, 0), (0, k))),
+                lambda ids: jnp.zeros((_UPDATE_CHUNK, 2 * k), jnp.float32),
+                chunk_of(order.ids))
+        with jax.named_scope("step.update"):
+            rows = jnp.where(taken[:, None], fresh, chunk_of(new))
+            return _write_rows(array, chunk_of(target), rows)
+
+    return lax.fori_loop(0, order.chunks, put_chunk, params.factors)
+
+
+def _adaptive_step(params: AdaptiveTables, batch, objective: str,
+                   learning_rate: float, l2: float, rule: FtrlAdagrad):
+    """One step of difacto's memory-adaptive FM over
+    :class:`AdaptiveTables` (``benchmarks/configs/kdd12-fm-k128-adaptive.
+    json`` states the equations): count, forward and backward with the
+    factors of the ids whose ``u`` is 1, :func:`_ftrl_adagrad` at the
+    distinct ids (``w``, ``z``, ``n`` of every id named with a value,
+    ``v``, ``a`` of those with ``u`` = 1), then the activation: an id
+    named with a value that has no row, whose count passed
+    ``v_threshold`` and (under ``l1_shrk``) whose NEW ``w`` is not 0,
+    takes the next free slot, in id order (a prefix sum over the sorted
+    distinct ids), while there are slots; past that it is refused and
+    may be taken by a later step. Nothing leaves the device.
+
+    The reads are :func:`_adaptive_head`'s; the writes are two: the base
+    lane rows through :func:`_put_lane_rows` and the factor rows whole
+    (:func:`_put_factor_rows`), each XLA's scatter with distinct targets.
+    ``step.activate`` holds the count, the test, the prefix sum and the
+    draws of ``v0``; the other phases are scoped as in the dense step.
+    Returns (the new tree, the step's metrics)."""
+    order, read, vw, row_ids, values = _adaptive_head(params, batch)
+    adaptive, scalars = params.adaptive, params.scalars
+    dw, gb, dv, loss_sum, wsum = _fm_row_grads(
+        scalars["b"], vw, row_ids, values, batch, objective)
+    k = params.num_factors
+    held, has, uses = read.held, read.has, read.uses
+    with jax.named_scope("step.update"):
+        grad, valued, denom = _id_sums(
+            {"v": dv, "w": dw}, values, order, wsum, jnp.float32)
+        live = valued > 0
+    with jax.named_scope("step.state"):
+        old = {name: held[name] for name in ("w", "z", "n")}
+        old.update(v=read.factors[:, :k], a=read.factors[:, k:])
+        takes = dict(w=live, z=live, n=live,
+                     v=(live & uses)[:, None], a=(live & uses)[:, None])
+        new = {name: jnp.where(takes[name], rows, old[name])
+               for name, rows in _ftrl_adagrad(
+                   old, grad, alpha=learning_rate, l2=l2, rule=rule).items()}
+    with jax.named_scope("step.activate"):
+        named = valued.astype(jnp.int32)
+        counting = scalars["counted_rows"] < adaptive.count_rows
+        rows_seen = jnp.where(
+            counting, jnp.sum(batch["weight"] != 0).astype(jnp.int32), 0)
+        cnt = held["cnt"] + jnp.where(counting, named, 0)
+        wants = live & ~has & (cnt > adaptive.v_threshold)
+        if adaptive.l1_shrk:
+            wants = wants & (new["w"] != 0)
+        place = scalars["active_ids"] + jnp.cumsum(
+            wants.astype(jnp.int32)) - 1
+        granted = wants & (place < params.capacity)
+        slot = jnp.where(granted, place, held["slot"])
+        number = jnp.arange(order.ids.shape[0], dtype=jnp.int32)
+        target = jnp.where(has | granted, slot, params.capacity + number)
+        after = {
+            "active_ids": scalars["active_ids"] + jnp.sum(granted),
+            "refused": scalars["refused"] + jnp.sum(wants & ~granted),
+            "counted_rows": scalars["counted_rows"] + rows_seen,
+            "active_entries": scalars["active_entries"] + jnp.sum(
+                jnp.where(uses, named, 0)),
+        }
+    with jax.named_scope("step.update"):
+        base = _put_lane_rows(
+            params.base_rows, order, read.base.lanes,
+            _join_base(dict(new, cnt=cnt, slot=slot)))
+    factors = _put_factor_rows(
+        params, order, target,
+        jnp.concatenate([new["v"], new["a"]], axis=1), granted)
+    with jax.named_scope("step.update"):
+        after["b"] = scalars["b"] - learning_rate * (gb / denom)
+    return (params.holding(base, factors, after),
+            {"loss_sum": loss_sum, "weight_sum": wsum,
+             "touched_rows": order.distinct})
 
 
 def _batch_specs(axis: str):
@@ -1238,6 +1674,11 @@ def make_fm_train_step(
     replicated mesh step reduces the entries to a dense gradient, which
     has no per-row state to meet: it refuses a rule.
 
+    One device, with a ``rule``: ``params`` may be an
+    :class:`AdaptiveTables`, whose step is :func:`_adaptive_step`: the
+    branch is on the tree the step is given, and the tree carries the
+    threshold, ``l1_shrk`` and the rows counts are taken over.
+
     ``donate_batch=True`` (single-device path) donates params AND the
     batch arrays, the same contract as
     :func:`~dmlc_tpu.models.linear.make_linear_train_step`: XLA reuses
@@ -1250,6 +1691,10 @@ def make_fm_train_step(
     _check_rule_placement(optimizer, mesh, table_sharding)
 
     def local(params, batch, factor_axis):
+        if isinstance(params, AdaptiveTables):
+            _check_adaptive_placement(optimizer, mesh)
+            return _adaptive_step(params, batch, objective, learning_rate,
+                                  l2, rule)
         dw, gb, dv, loss_sum, wsum, order, seen = _fm_entry_grads(
             params, batch, objective, factor_axis=factor_axis)
         params = _sparse_update(
@@ -1315,19 +1760,68 @@ def _rows_at(held, ids, span=None):
     if not isinstance(held, PackedTables):
         rows = jnp.take(held, ids, axis=0)
         return rows if span is None else _span(rows, span)
+    return _by_chunks(partial(_lane_words, held), ids)
 
-    def words_of(some):
-        lane_rows, places = held.lane_rows_of(some)
-        return _cut_lanes(jnp.take(held.rows, lane_rows, axis=0), places,
-                          held.columns)
 
-    # a chunk of ids a pass: the lane rows of all of them at once lie
-    # beside the table (1.3 GB for the check's 360,000 ids of 17 columns)
+def _lane_words(held: PackedTables, ids):
+    """``[n, C]``: every word of ``ids`` in a packed tree, their lane rows
+    gathered and each id's own words cut from its row."""
+    lane_rows, places = held.lane_rows_of(ids)
+    return _cut_lanes(jnp.take(held.rows, lane_rows, axis=0), places,
+                      held.columns)
+
+
+def _by_chunks(rows_of, ids):
+    """``rows_of(ids)`` (``[n]`` or ``[n, C]``), a chunk of ids a pass:
+    the lane rows of all of them at once lie beside the table (1.3 GB for
+    the check's 360,000 ids of 17 columns)."""
     n = ids.shape[0]
     chunk = min(n, 4 * _UPDATE_CHUNK)
-    words = lax.map(
-        words_of, jnp.pad(ids, (0, -n % chunk)).reshape(-1, chunk))
-    return words.reshape(-1, held.columns)[:n]
+    rows = lax.map(
+        rows_of, jnp.pad(ids, (0, -n % chunk)).reshape(-1, chunk))
+    return rows.reshape((-1,) + rows.shape[2:])[:n]
+
+
+@partial(jax.jit, static_argnames=("name",))
+def _adaptive_rows_at(params: AdaptiveTables, ids, name: str):
+    """Logical table ``name`` of :data:`ADAPTIVE_TABLES` at ``ids``,
+    through the slot map: ``v`` of an id without a factor row is the
+    ``v0`` it would start from, its ``a`` 0."""
+    k = params.num_factors
+
+    def rows_of(some):
+        held = _split_base(_lane_words(params.base_rows, some))
+        if name in held:
+            return held[name]
+        has = held["slot"] >= 0
+        if name == "has_v":
+            return has.astype(jnp.int32)
+        rows = jnp.take(
+            params.factors, jnp.where(has, held["slot"], 0), axis=0)
+        if name == "v":
+            return jnp.where(has[:, None], rows[:, :k], params.start(some))
+        return jnp.where(has[:, None], rows[:, k:], 0.0)
+
+    return _by_chunks(rows_of, ids)
+
+
+@jax.jit
+def _prints_by_slot(factors, slot_bits):
+    """{``has_v``, ``v``, ``a``: ``uint32[F]``}: the fingerprints of the
+    logical tables of an :class:`AdaptiveTables` that lie behind the slot
+    map, from the ids' ``slot`` words (``slot_bits uint32[F]``, in id
+    order), in ONE program: a factor row's two sums are taken a SLOT and
+    carried to the id that holds it (``capacity`` sums, not ``F x K``
+    draws of ``v0``) through one index; an id without a row reads 0."""
+    has = slot_bits < jnp.uint32(1 << 31)
+    k = factors.shape[1] // 2
+    at = jnp.where(has, slot_bits, 0).astype(jnp.int32)
+    out = {"has_v": has.astype(jnp.uint32)}
+    for name, columns in (("v", factors[:, :k]), ("a", factors[:, k:])):
+        prints = jnp.sum(lax.bitcast_convert_type(columns, jnp.uint32),
+                         axis=1, dtype=jnp.uint32)
+        out[name] = jnp.where(has, jnp.take(prints, at), jnp.uint32(0))
+    return out
 
 
 @partial(jax.jit, static_argnames=("span",))
@@ -1420,7 +1914,13 @@ class FMLearner(FeedLearner):
     reads the tables through :meth:`init_tables`, :meth:`table_names`,
     :meth:`scalars`, :meth:`table_rows` and :meth:`table_fingerprints`
     (``benchmarks/harness/tables.py``), which read them wherever they
-    lie and never make an array of a table's shape."""
+    lie and never make an array of a table's shape.
+
+    :class:`AdaptiveFMLearner` is this learner over a fourth storage, an
+    :class:`AdaptiveTables` (a base row for every id, factor rows only
+    for the ids that earned them, reached through a slot map): what it
+    keeps, what its snapshot holds and what such an indirection owes the
+    five calls are on its docstring."""
 
     name = "fm"
     #: the learner's hyper-parameters' class
@@ -1810,3 +2310,287 @@ class FMLearner(FeedLearner):
         if isinstance(held, PackedTables):
             return _places_in_id_order(prints, held.num_ids)
         return prints
+
+
+class AdaptiveFMParam(FMParam):
+    """:class:`FMParam` and difacto's memory-adaptive constraints
+    (``src/sgd/sgd_param.h``: ``V_threshold``, ``l1_shrk``)."""
+
+    optimizer = field(
+        str, "ftrl_adagrad", enum={"ftrl_adagrad": "ftrl_adagrad"})
+    # an id is given factors once MORE than this many entries named it
+    v_threshold = field(int, 10, lower_bound=0)
+    # ... and, with l1_shrk, only while its linear weight is not 0
+    l1_shrk = field(bool, True)
+    # rows of the factor table: the ids that can hold factors at a time
+    factor_capacity = field(int, 0, lower_bound=0)
+    # counts are taken over the first count_rows rows the learner sees
+    # (difacto pushes them with the first epoch's batches)
+    count_rows = field(int, 0, lower_bound=0)
+
+
+class AdaptiveFMLearner(FMLearner):
+    """difacto's memory-adaptive FM on ONE device (Li et al., WSDM 2016;
+    ``github.com/dmlc/difacto`` ``V_threshold``, ``l1_shrk``): an
+    :class:`FMLearner` under ``optimizer="ftrl_adagrad"`` whose ids hold
+    factors only once they have earned them. Every id keeps FTRL's ``w``,
+    ``z``, ``n``, an exact count ``cnt`` and the number of its factor row;
+    the factors ``[v | a]`` live in a table of ``factor_capacity`` rows,
+    handed out inside the step, on the device, to the ids a batch names
+    whose count passed ``v_threshold`` and (``l1_shrk``) whose ``w`` is
+    not 0. A model whose id space times width is far beyond a chip
+    (54.7 M ids x 128 factors with AdaGrad's accumulator: 56 GB) fits one
+    (:class:`AdaptiveTables`: 1.12 + 4.29 GB at 2^22 rows). Slots are
+    never given back: an id whose ``w`` returns to 0 keeps its row and is
+    left out of the forward pass and the update until ``w`` moves again.
+
+    ``params`` is an :class:`AdaptiveTables`; the step
+    (:func:`_adaptive_step`), the fit loop, the counters of
+    :class:`FMLearner` and, beside them, what :meth:`epoch_closed` lists.
+    A snapshot holds ``w``, ``z``, ``n``, ``cnt`` whole and the factor
+    rows in use with their ids (a logical ``v`` would be ``F x K``), and
+    restores under any capacity that holds them, slots rebuilt in id
+    order. :meth:`start_from_counts` starts from counts taken elsewhere
+    (difacto's counting pass): every id past the threshold holds its row
+    before the first step. A mesh is refused.
+
+    What a learner with such an indirection owes the benchmark's five
+    calls: ``table_names`` are the LOGICAL tables (:data:`ADAPTIVE_TABLES`,
+    the slot map itself is not one); ``table_rows("v", ids)`` answers for
+    an id without a row with the factors it would start from, so the
+    reference needs no generator; a fingerprint of ``v`` or ``a`` is
+    taken a slot and carried to the ids; ``scalars`` holds the counts the
+    step keeps (``active_ids``, ``refused``, ``counted_rows``)."""
+
+    param_class = AdaptiveFMParam
+
+    def __init__(self, mesh: Optional[Mesh] = None, **hyper):
+        super().__init__(mesh, **hyper)
+        check(self.param.factor_capacity > 0,
+              "factor_capacity required: the rows of the factor table")
+        check(self.param.count_rows > 0,
+              "count_rows required: the rows the ids' counts are taken "
+              "over (the data's rows: its first epoch)")
+        # the step's running counts as the last pass end read them
+        self._seen = dict.fromkeys(
+            ("active_ids", "refused", "active_entries"), 0)
+        # (the tree, its fingerprints behind the slot map not yet asked for)
+        self._slot_prints = (None, {})
+
+    def check_mesh(self, mesh: Mesh) -> None:
+        _check_adaptive_placement(self.optimizer, mesh)
+
+    @property
+    def adaptive(self) -> Adaptive:
+        return Adaptive(**{f: getattr(self.param, f)
+                           for f in Adaptive._fields})
+
+    @property
+    def row_columns(self) -> int:
+        """0: no one row holds an id's weights and state."""
+        return 0
+
+    def _initialiser(self, num_features: int):
+        return partial(
+            init_adaptive, num_features, self.param.num_factors,
+            self.param.factor_capacity, self.param.init_scale, self.adaptive)
+
+    def _make_step(self, num_features: int):
+        return make_fm_train_step(
+            None, num_features, objective=self.param.objective,
+            learning_rate=self.param.learning_rate, l2=self.param.l2,
+            donate_batch=True, rule=self.rule)
+
+    def init_tables(self, seed, num_features: int = 0) -> None:
+        super().init_tables(seed, num_features)
+        self._seen = dict.fromkeys(self._seen, 0)
+
+    def start_from_counts(self, cnt, counted_rows: int) -> None:
+        """Counts taken elsewhere (difacto's counting pass over the data,
+        the epoch of an earlier run): ``cnt`` (``[F]`` whole numbers, a
+        host array) becomes every id's count, ``counted_rows`` the rows
+        they were taken over, and every id whose count passed
+        ``v_threshold`` holds a factor row ``[v0(id) | 0]``, rows handed
+        out in id order as a restore hands them; a capacity too small
+        for them is refused by name. Weights and state stay as they are
+        (after :meth:`init_tables`: 0), so under ``l1_shrk`` a row is
+        masked until its id's ``w`` moves. One program for every ``cnt``."""
+        nf, capacity = self._nf, self.param.factor_capacity
+        cnt = np.asarray(cnt)
+        check(cnt.shape == (nf,),
+              "counts for %s ids, this learner trains %d", cnt.shape, nf)
+        ids = np.flatnonzero(cnt > self.param.v_threshold).astype(np.int32)
+        check(len(ids) <= capacity,
+              "%d ids were counted past v_threshold=%d, factor_capacity=%d "
+              "has no room for them", len(ids), self.param.v_threshold,
+              capacity)
+        self.params = _grant_counted(
+            self.params, jnp.asarray(cnt, jnp.int32),
+            jnp.asarray(np.pad(ids, (0, capacity - len(ids)))),
+            jnp.int32(len(ids)), jnp.int32(counted_rows))
+        self._seen["active_ids"] = len(ids)
+
+    def epoch_span_args(self) -> Dict:
+        return dict(
+            super().epoch_span_args(),
+            v_threshold=self.param.v_threshold, l1_shrk=self.param.l1_shrk,
+            factor_capacity=self.param.factor_capacity,
+            factor_columns=2 * self.param.num_factors,
+            base_columns=len(BASE_WORDS))
+
+    def audit_params(self):
+        params = self.params
+        return dict(params.scalars, base=params.base, factors=params.factors)
+
+    def state_bytes(self) -> int:
+        """``z`` and ``n`` of every id and ``a`` of every factor row."""
+        if self.params is None:
+            return 0
+        return 4 * (2 * self._nf
+                    + self.param.factor_capacity * self.param.num_factors)
+
+    def pass_scalars(self) -> Dict:
+        """The step's running counts, summed on the device since the
+        learner's start: read once a pass, with its losses."""
+        return {name: self.params.scalars[name] for name in self._seen}
+
+    def epoch_closed(self, reg, nstep: int, sums: Dict) -> None:
+        """:class:`FMLearner`'s counters, and the memory-adaptive ones,
+        from the running counts the pass's end read (:meth:`pass_scalars`;
+        a pass's share is the difference to the last read):
+        ``dmlc_fit_adaptive_steps_total`` (steps through a slot map: every
+        one), ``dmlc_fit_active_entries_total`` (entries whose id's
+        factors took part, ``u`` = 1; over ``dmlc_fit_entries_total`` the
+        share of the batch that ran at full width),
+        ``dmlc_fit_activations_total`` / ``..._refused_total`` (ids given
+        a factor row; ids that had earned one when none was free) and the
+        gauge ``dmlc_fit_factor_in_use_rows``."""
+        super().epoch_closed(reg, nstep, sums)
+        reg.counter(
+            "dmlc_fit_adaptive_steps_total",
+            "optimizer steps that read factor rows through a slot map and "
+            "handed out rows to the ids that earned them",
+            model=self.name).inc(nstep)
+        now = {name: int(sums.get(name, self._seen[name]))
+               for name in self._seen}
+        # int32 on the device: a long run's count of entries wraps
+        passed = {name: (now[name] - self._seen[name]) % (1 << 32)
+                  for name in now}
+        self._seen = now
+        reg.counter(
+            "dmlc_fit_active_entries_total",
+            "entries of the steps' batches whose id held factors that "
+            "took part in the step",
+            model=self.name).inc(passed["active_entries"])
+        reg.counter(
+            "dmlc_fit_activations_total",
+            "ids given a factor row by a step",
+            model=self.name).inc(passed["active_ids"])
+        reg.counter(
+            "dmlc_fit_activations_refused_total",
+            "ids that had earned a factor row in a step that had none "
+            "free (counted each step they ask)",
+            model=self.name).inc(passed["refused"])
+        reg.gauge(
+            "dmlc_fit_factor_in_use_rows",
+            "factor rows handed out, of factor_capacity",
+            model=self.name).set(now["active_ids"])
+
+    def snapshot_model(self) -> Dict:
+        """``w``, ``z``, ``n``, ``cnt`` whole, the scalars, the seed's
+        key, and the factor rows in use as (``factor_ids`` ascending,
+        their ``v`` rows, their ``a`` rows): host arrays, whatever the
+        capacity and the order the rows were handed out in."""
+        params = self.params
+        words = _from_lane_rows(
+            np.asarray(params.base), len(BASE_WORDS), params.num_ids)
+        held = {name: np.ascontiguousarray(words[:, j])
+                for j, name in enumerate(BASE_WORDS)}
+        ids = np.flatnonzero(held["slot"] >= 0).astype(np.int32)
+        rows = np.asarray(jnp.take(
+            params.factors, jnp.asarray(held["slot"][ids]), axis=0))
+        k = params.num_factors
+        model = {name: held[name].view(np.float32)
+                 for name in ("w", "z", "n")}
+        model.update(
+            cnt=held["cnt"], factor_ids=ids,
+            v=np.ascontiguousarray(rows[:, :k]),
+            a=np.ascontiguousarray(rows[:, k:]), key=np.asarray(params.key),
+            **{name: np.asarray(value)
+               for name, value in params.scalars.items()})
+        return {"params": model}
+
+    def restore_snapshot_model(self, model: Dict) -> None:
+        """A snapshot's host arrays into this learner's storage: the
+        factor rows into the first slots, in id order."""
+        params = {name: np.asarray(value)
+                  for name, value in model["params"].items()}
+        ids = params["factor_ids"]
+        k, capacity = self.param.num_factors, self.param.factor_capacity
+        check(params["v"].shape == (len(ids), k),
+              "snapshot holds factor rows of shape %s, this learner "
+              "trains %d factors", params["v"].shape, k)
+        check(len(ids) <= capacity,
+              "snapshot holds %d factor rows, factor_capacity=%d has no "
+              "room for them", len(ids), capacity)
+        nf = self.param.num_features or len(params["w"])
+        check(len(params["w"]) == nf,
+              "snapshot holds %d ids, this learner trains %d",
+              len(params["w"]), nf)
+        slot = np.full(nf, -1, np.int32)
+        slot[ids] = np.arange(len(ids), dtype=np.int32)
+        words = {name: params[name].astype(np.float32).view(np.int32)
+                 for name in ("w", "z", "n")}
+        words.update(cnt=params["cnt"].astype(np.int32), slot=slot)
+        scalars = {"b": jnp.asarray(params["b"], jnp.float32)}
+        scalars.update(
+            {name: jnp.asarray(params.get(name, 0), jnp.int32)
+             for name in _ADAPTIVE_COUNTS})
+        scalars["active_ids"] = jnp.asarray(len(ids), jnp.int32)
+        self._nf = nf
+        self.params = AdaptiveTables(
+            jnp.asarray(PackedTables.pack(words, _BASE_LAYOUT).rows),
+            jnp.pad(
+                jnp.asarray(np.concatenate(
+                    [params["v"], params["a"]], axis=1), jnp.float32),
+                ((0, capacity - len(ids)), (0, 0))),
+            scalars, jnp.asarray(params["key"], jnp.uint32), nf,
+            self.param.init_scale, self.adaptive)
+        self._seen = {name: int(scalars[name]) for name in self._seen}
+
+    def predict_batch(self, batch) -> np.ndarray:
+        _, _, vw, row_ids, values = _adaptive_head(self.params, batch)
+        _, s, q, linear = _row_sums(
+            vw, row_ids, values, int(batch["label"].shape[0]))
+        return np.asarray(
+            self.params["b"] + linear + 0.5 * jnp.sum(s * s - q, axis=-1))
+
+    def table_names(self) -> Tuple[str, ...]:
+        return ADAPTIVE_TABLES
+
+    def scalars(self) -> Dict[str, float]:
+        return {name: float(self.params.scalars[name])
+                for name in ("b", "active_ids", "refused", "counted_rows")}
+
+    def table_rows(self, name: str, ids):
+        return _adaptive_rows_at(self.params, ids, name=name)
+
+    def table_fingerprints(self, name: str):
+        """A word of the base rows by :func:`_row_fingerprints`; the three
+        tables behind the slot map (``has_v``, ``v``, ``a``) from ONE
+        program a tree (:func:`_prints_by_slot`), each handed out once:
+        asked for all three of one tree, the slot words are read once."""
+        params = self.params
+
+        def words(held):
+            base = params.base_rows
+            return _places_in_id_order(
+                _row_fingerprints(base, span=base.span(held)), base.num_ids)
+
+        if name in BASE_WORDS:
+            return words(name)
+        tree, kept = self._slot_prints
+        if tree is not params or name not in kept:
+            kept = _prints_by_slot(params.factors, words("slot"))
+        self._slot_prints = (params, kept)
+        return kept.pop(name)
