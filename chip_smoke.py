@@ -29,6 +29,7 @@ Run it through the chip tool: ``chiprun -- python3 chip_smoke.py``
 ``.gitignore`` lists: the native library is rebuilt from ``cpp/`` here.
 """
 
+import functools
 import json
 import math
 import os
@@ -620,6 +621,90 @@ def kernels(ctx):
               "use_pallas=True %s step disagrees with the XLA step "
               "(loss %.6f vs %.6f)", layout, outs[True][1], outs[False][1])
         facts["train_step_pallas_" + layout] = "matches the XLA step"
+    facts.update(dma_row_writer())
+    return facts
+
+
+def dma_row_writer(interpret=False, chunk=2048, passes=13, height=65539,
+                   cells=((7812351, "float32"), (18228818, "float32"),
+                          (2187459, "int32"))):
+    """The FM step's writer of pure writes (``models/fm.py``
+    ``_write_rows`` on a TPU at 128 lanes: one DMA a distinct lane row)
+    as the step calls it, once a chunk inside a loop that carries the
+    donated array: equal to XLA's scatter to the bit on a table of
+    ``height`` rows (no multiple of 8; a lane row's two dtypes), and
+    compiled by Mosaic IN PLACE at the ``cells``' sizes (kdd12-fm,
+    kdd12-fm-difacto, the memory-adaptive FM's base rows): the array
+    aliased to the result whole, nothing of its size beside it. The
+    keywords are the CPU test's (tests/test_chip_smoke.py), which runs
+    the first half small in Pallas' interpreter."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dmlc_tpu.models import fm
+
+    def put_all(array, target, new, platform):
+        def put(i, array):
+            return fm._write_rows(
+                array, jax.lax.dynamic_slice_in_dim(target, i * chunk, chunk),
+                jax.lax.dynamic_slice_in_dim(new, i * chunk, chunk),
+                platform=platform, interpret=interpret)
+
+        return jax.lax.fori_loop(0, passes, put, array)
+
+    def program(platform):
+        return jax.jit(functools.partial(put_all, platform=platform),
+                       donate_argnums=0)
+
+    def bits(array):
+        return np.asarray(array).view(np.uint32)
+
+    rng = np.random.RandomState(41)
+    slots = passes * chunk
+    # as ``_put_lane_rows`` hands them over: distinct, the idle slots (one
+    # in thirteen here) naming the row ``height`` + their own number
+    target = height + np.arange(slots, dtype=np.int32)
+    live = rng.rand(slots) < 12.0 / 13.0
+    target[live] = rng.choice(height, int(live.sum()), replace=False)
+    for kind in (np.float32, np.int32):
+        table, new = (rng.randint(-2 ** 31, 2 ** 31, (n, 128)).astype(
+            np.int32).view(kind) for n in (height, slots))
+        got, want = (bits(program(platform)(
+            jnp.asarray(table), jnp.asarray(target), jnp.asarray(new)))
+            for platform in ("tpu", None))
+        check(np.array_equal(got, want),
+              "the DMA row writer (%s) disagrees with XLA's scatter in %d "
+              "of %d rows", kind.__name__,
+              int((got != want).any(axis=1).sum()), height)
+        check(np.array_equal(got[target[live]], bits(new)[live])
+              and int((got != bits(table)).any(axis=1).sum())
+              <= int(live.sum()),
+              "the DMA row writer (%s) wrote a row it was not given",
+              kind.__name__)
+    facts = {"dma_row_writer": "matches XLA's scatter to the bit (%d live "
+             "of %d slots, f32 and s32)" % (int(live.sum()), slots)}
+    if interpret:
+        return facts
+    jax.config.update("jax_enable_compilation_cache", False)  # a cached
+    # executable answers no memory_analysis
+    try:
+        for rows, kind in cells:
+            compiled = program("tpu").lower(
+                jax.ShapeDtypeStruct((rows, 128), kind),
+                jax.ShapeDtypeStruct((slots,), jnp.int32),
+                jax.ShapeDtypeStruct((slots, 128), kind)).compile()
+            memory = compiled.memory_analysis()
+            check("tpu_custom_call" in compiled.as_text()
+                  and memory.alias_size_in_bytes >= rows * 128 * 4
+                  and memory.temp_size_in_bytes < 1 << 20,
+                  "the DMA row writer over %s[%d,128] is not in place: "
+                  "alias %d bytes, temp %d", kind, rows,
+                  memory.alias_size_in_bytes, memory.temp_size_in_bytes)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    facts["dma_row_writer_in_place"] = "compiled at %s" % ", ".join(
+        "%s[%d,128]" % (kind, rows) for rows, kind in cells)
     return facts
 
 

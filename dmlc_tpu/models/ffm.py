@@ -82,6 +82,7 @@ from dmlc_tpu.models.fm import (
     _regroup,
     init_packed,
     _update_at_distinct,
+    _write_rows,
 )
 from dmlc_tpu.models.linear import margin_grad
 from dmlc_tpu.parallel.partition import match_partition_rules
@@ -275,6 +276,8 @@ def make_ffm_train_step(
     param_specs=None,
     donate_batch: bool = False,
     table_sharding: str = "replicated",
+    platform: Optional[str] = None,
+    interpret: bool = False,
 ):
     """Jitted FFM step over COO batches, ``(params, batch) -> (params,
     metrics)`` with ``params`` = {``v``, ``a``} or the packed ``[v | a]``
@@ -288,17 +291,20 @@ def make_ffm_train_step(
     ``a``'s write under ``step.state``, ``v``'s write and the id sums
     under ``step.update``; over a packed row the rule alone under
     ``step.state``, the one row write under ``step.update``), so a mesh of
-    replicas, whose step applies a dense psummed gradient, is refused."""
+    replicas, whose step applies a dense psummed gradient, is refused.
+    ``platform`` and ``interpret`` as ``make_fm_train_step`` takes them:
+    a packed tree's lane rows go back through the same ``_write_rows``."""
     check(num_features > 0, "num_features required")
     _check_rule_placement(OPTIMIZER, mesh, table_sharding)
     lows = field_lows(field_sizes, num_features)
     rule = partial(_adagrad, learning_rate=learning_rate, l2=l2)
+    write = partial(_write_rows, platform=platform, interpret=interpret)
 
     def local(params, batch, factor_axis):
         dv, loss_sum, wsum, order, seen = _ffm_entry_grads(
             params, batch, lows, objective, factor_axis)
         arrays, _ = _update_at_distinct(
-            params, order, {"v": dv}, seen, wsum, ("a",), rule)
+            params, order, {"v": dv}, seen, wsum, ("a",), rule, write)
         params = _regroup(params, FFM_TABLES, arrays, {})
         return params, {"loss_sum": loss_sum, "weight_sum": wsum,
                         "touched_rows": order.distinct}
@@ -368,7 +374,8 @@ class FFMLearner(FMLearner):
             objective=self.param.objective,
             learning_rate=self.param.learning_rate, l2=self.param.l2,
             axis=self.axis, donate_batch=self.mesh is None,
-            table_sharding=self.param.table_sharding)
+            table_sharding=self.param.table_sharding,
+            platform=self._step_platform)
 
     def epoch_span_args(self) -> Dict:
         return dict(super().epoch_span_args(), fields=self.fields)

@@ -96,6 +96,7 @@ from dmlc_tpu.parallel.partition import (
     sharding_tree,
 )
 from dmlc_tpu.params.parameter import Parameter, field
+from dmlc_tpu.utils.jax_compat import import_pallas
 from dmlc_tpu.utils.logging import check
 
 
@@ -628,7 +629,105 @@ def _take_lane_rows(packed: PackedTables, order: _IdOrder) -> _Read:
         jnp.zeros((slots, packed.rows.shape[1]), dtype)))
 
 
-def _put_lane_rows(packed: PackedTables, order: _IdOrder, lanes, new):
+def row_writer(platform: Optional[str], lanes: int) -> str:
+    """How :func:`_write_rows` writes whole rows of ``lanes`` lanes into an
+    array on the devices of ``platform``: ``"dma"`` (one async copy a row,
+    a Pallas TPU kernel) on a TPU at 128 lanes, ``"scatter"`` (XLA's)
+    everywhere else: the CPU, and 256 lanes, where Mosaic refuses a slice
+    of one row. From those two facts alone: no option chooses."""
+    return "dma" if platform == "tpu" and lanes == _LANES else "scatter"
+
+
+def _write_rows(array, target, new, platform: Optional[str] = None,
+                interpret: bool = False):
+    """``array[target[j]] = new[j]``, whole lane rows, the targets
+    distinct; a target past the array writes nothing. ``platform``: that
+    of the devices ``array`` lies on, as whoever built the step saw it
+    (None: not said, and so the scatter); :func:`row_writer` chooses by
+    it and the row's lanes.
+
+    The portable form, and what the kernel's tests compare with, is XLA's
+    scatter, its targets NOT flagged sorted: on the chip a row-major
+    array's scatter flagged sorted passes over the whole array (12 ms a
+    chunk over 4 GB), unflagged it writes in place at 75 ns a slot
+    whether the slot writes or not (PERF.md, PR 38). The DMA writer pays
+    for the rows it writes, 29-31 ns each (PERF.md, PR 41).
+
+    ``interpret``: the kernel, where it is chosen, runs in Pallas'
+    interpreter (the CPU tests pass it; nothing infers it)."""
+    if row_writer(platform, array.shape[1]) == "dma":
+        return _dma_write_rows(array, target, new, interpret)
+    return array.at[target].set(new, unique_indices=True, mode="drop")
+
+
+#: row copies the DMA writer keeps in flight, a semaphore each (on the
+#: v5e 8 write a step's 24,500 rows in 0.81 ms, 16 and 32 in 0.755, 64 in
+#: 0.764; 16 with the drain a loop, as it is now, 0.775: PERF.md, PR 41)
+_DMA_IN_FLIGHT = 16
+
+
+def _dma_write_rows(array, target, new, interpret: bool = False):
+    """:func:`_write_rows` as pure writes: ``new [slots, L]`` in VMEM,
+    ``target s32[slots]`` scalar-prefetched, ``array`` left where it lies
+    (aliased to the output, so a donated array is written in place and
+    nothing passes over it) and, for each slot whose target lies inside
+    it, ONE async copy of the slot's row to its target row,
+    :data:`_DMA_IN_FLIGHT` at a time: a copy waits for the copy that
+    last used its semaphore, and all are waited for before the kernel
+    ends. The targets are distinct, so copies in flight never meet."""
+    pl, pltpu = import_pallas()
+    height = array.shape[0]
+
+    def kernel(target_ref, new_ref, _, out_ref, sems):
+        def copy(slot, row, number):
+            return pltpu.make_async_copy(
+                new_ref.at[pl.ds(slot, 1)], out_ref.at[pl.ds(row, 1)],
+                sems.at[number % _DMA_IN_FLIGHT])
+
+        def wait_for(number, if_):
+            # every copy moves one row: any of them stands for the one
+            # whose semaphore this is
+            @pl.when(if_)
+            def _():
+                copy(0, 0, number).wait()
+
+        def put(slot, started):
+            row = target_ref[slot]
+            live = row < height
+
+            @pl.when(live)
+            def _():
+                wait_for(started, started >= _DMA_IN_FLIGHT)
+                copy(slot, row, started).start()
+
+            return started + live.astype(jnp.int32)
+
+        def drain(number, started):
+            wait_for(number, number < started)
+            return started
+
+        # the drain is a loop too: every ``when`` and every copy the
+        # kernel's text holds is traced and lowered in each process (8 ms
+        # apiece on the chip's host: PERF.md, PR 41)
+        started = lax.fori_loop(0, target_ref.shape[0], put, jnp.int32(0))
+        lax.fori_loop(0, _DMA_IN_FLIGHT, drain, started)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[pl.BlockSpec(new.shape, lambda i, target: (0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA((_DMA_IN_FLIGHT,))]),
+        out_shape=jax.ShapeDtypeStruct(array.shape, array.dtype),
+        input_output_aliases={2: 0},  # counted with the prefetched targets
+        interpret=interpret, name="write_rows",
+    )(target, new, array)
+
+
+def _put_lane_rows(packed: PackedTables, order: _IdOrder, lanes, new,
+                   write=_write_rows):
     """``new [n + pad, C]``, the distinct ids' new words by slot, put
     back into ``packed.rows`` (in place when the caller donated it): each
     distinct LANE row written once, whole, with no read of anybody else's
@@ -640,7 +739,8 @@ def _put_lane_rows(packed: PackedTables, order: _IdOrder, lanes, new):
     place, so that the lanes of ids the batch does not name go back as
     they were read; the run's other slots write nothing (they name a row
     past the array, as do the slots past the distinct ids and the slots
-    of ids past the table)."""
+    of ids past the table). ``write``: :func:`_write_rows` as the step's
+    builder bound it."""
     lane_rows, places = packed.lane_rows_of(order.ids)
     columns, per_row = packed.columns, packed.per_row
     height, width = packed.rows.shape
@@ -676,18 +776,9 @@ def _put_lane_rows(packed: PackedTables, order: _IdOrder, lanes, new):
             mine = (chunk_of(lane_rows, ahead) == here)[:, None] & (
                 chunk_of(places, ahead)[:, None] == place_of[None, :])
             merged = jnp.where(mine, words, merged)
-        return _write_rows(array, chunk_of(target), merged)
+        return write(array, chunk_of(target), merged)
 
     return lax.fori_loop(0, order.chunks, put_chunk, packed.rows)
-
-
-def _write_rows(array, target, new):
-    """``array[target[j]] = new[j]``, whole lane rows; a target past the
-    array writes nothing. XLA's scatter, its targets distinct and NOT
-    flagged sorted: on the chip a row-major array's scatter flagged
-    sorted passes over the whole array (12 ms a chunk over 4 GB),
-    unflagged it writes in place (75 ns a slot: PERF.md, PR 38)."""
-    return array.at[target].set(new, unique_indices=True, mode="drop")
 
 
 def _gather_rows(tables, order: _IdOrder, head: Optional[int] = None):
@@ -937,7 +1028,8 @@ def exchange_bytes(batch, shards: int) -> int:
 
 
 def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
-                   l2: float, seen=None, rule: Optional[FtrlAdagrad] = None):
+                   l2: float, seen=None, rule: Optional[FtrlAdagrad] = None,
+                   write=_write_rows):
     """The step's update from per-entry contributions ``grads`` =
     (dw, gb, dv, weight_sum), the entries in ``order``'s order: under
     ``step.update`` scaled by ``-learning_rate / weight_sum`` and
@@ -950,10 +1042,12 @@ def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
     before the scatter-add: ``v - lr*(g + l2*v) = v*(1 - lr*l2) - lr*g``.
 
     With a ``rule`` (``optimizer="ftrl_adagrad"``) the update is
-    :func:`_stateful_update`'s, which sets rows where this one adds."""
+    :func:`_stateful_update`'s, which sets rows where this one adds.
+    ``write``: what writes a packed tree's lane rows
+    (:func:`_put_lane_rows`)."""
     if rule is not None:
         return _stateful_update(
-            params, order, grads, seen, learning_rate, l2, rule)
+            params, order, grads, seen, learning_rate, l2, rule, write)
     dw, gb, dv, wsum = grads
     with jax.named_scope("step.update"):
         denom = jnp.maximum(wsum, 1e-12)
@@ -962,7 +1056,7 @@ def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
         decay = 1.0 - learning_rate * l2
         if isinstance(params, PackedTables):
             arrays = [_add_lane_rows(
-                params, order, upd, seen[0], decay if l2 else None)]
+                params, order, upd, seen[0], decay if l2 else None, write)]
         else:
             groups = _groups(params, SGD_TABLES)
             if l2:
@@ -975,7 +1069,7 @@ def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
 
 
 def _add_lane_rows(packed: PackedTables, order: _IdOrder, upd, read: _Read,
-                   decay: Optional[float]):
+                   decay: Optional[float], write=_write_rows):
     """:func:`_scatter_add_rows` over a packed tree: an id's entries
     summed first (the same one ``segment_sum``), then ``old + sum``, the
     float32 add the scatter-add makes, on the words the head read, and
@@ -1001,7 +1095,7 @@ def _add_lane_rows(packed: PackedTables, order: _IdOrder, upd, read: _Read,
             packed, None, [packed.rows * decay], packed.scalars)
         read = _take_lane_rows(packed, order)
     words, lanes = read
-    return _put_lane_rows(packed, order, lanes, words + sums)
+    return _put_lane_rows(packed, order, lanes, words + sums, write)
 
 
 def _set_rows(table, order: _IdOrder, new):
@@ -1074,7 +1168,7 @@ def _id_sums(grads, values, order: _IdOrder, wsum, dtype):
 
 
 def _update_at_distinct(params, order: _IdOrder, grads, seen, wsum, state,
-                        rule):
+                        rule, write=_write_rows):
     """The skeleton of an update by a rule that keeps state for every
     parameter row, written once for the rules of this module and of
     models/ffm.py. ``grads``: {weight table: the entries' contributions
@@ -1099,7 +1193,8 @@ def _update_at_distinct(params, order: _IdOrder, grads, seen, wsum, state,
 
     The reads and the writes go by physical array (:func:`_groups`). A
     packed row came whole in the head's read, state and all, and goes
-    back in ONE set of all its columns. Tables that lie apart: the head
+    back in ONE set of all its columns (:func:`_put_lane_rows`, through
+    ``write``). Tables that lie apart: the head
     read the weights, the state's arrays are read here
     (:func:`_take_distinct`), and every array is set on its own.
 
@@ -1122,7 +1217,7 @@ def _update_at_distinct(params, order: _IdOrder, grads, seen, wsum, state,
     def set_rows_of(some, new):
         if isinstance(params, PackedTables):  # the one array, or none
             return [_put_lane_rows(params, order, rows.lanes,
-                                   _join_columns(new, g.widths))
+                                   _join_columns(new, g.widths), write)
                     for g in some]
         return [_set_rows(g.array, order, _join_columns(new, g.widths))
                 for g in some]
@@ -1167,7 +1262,8 @@ def _ftrl_adagrad(old, grad, alpha: float, l2: float, rule: FtrlAdagrad):
 
 
 def _stateful_update(params, order: _IdOrder, grads, seen,
-                     learning_rate: float, l2: float, rule: FtrlAdagrad):
+                     learning_rate: float, l2: float, rule: FtrlAdagrad,
+                     write=_write_rows):
     """The step's update under ``optimizer="ftrl_adagrad"``, difacto's
     rule (github.com/dmlc/difacto ``src/sgd/sgd_updater.cc``; Li et al.,
     WSDM 2016), from what :func:`_sparse_update` takes and ``seen`` = (the
@@ -1195,7 +1291,7 @@ def _stateful_update(params, order: _IdOrder, grads, seen,
     dw, gb, dv, wsum = grads
     arrays, denom = _update_at_distinct(
         params, order, {"v": dv, "w": dw}, seen, wsum, ("a", "z", "n"),
-        partial(_ftrl_adagrad, alpha=learning_rate, l2=l2, rule=rule))
+        partial(_ftrl_adagrad, alpha=learning_rate, l2=l2, rule=rule), write)
     with jax.named_scope("step.update"):
         return _regroup(
             params, FTRL_TABLES, arrays,
@@ -1471,7 +1567,7 @@ def _adaptive_head(params: AdaptiveTables, batch):
 
 
 def _put_factor_rows(params: AdaptiveTables, order: _IdOrder, target, new,
-                     granted):
+                     granted, write=_write_rows):
     """``factors[target[j]] = new[j]`` for every slot j of the order, whole
     rows through the one writer (:func:`_write_rows`), ``_UPDATE_CHUNK``
     slots a pass up to the last slot that holds a distinct id; a
@@ -1495,13 +1591,14 @@ def _put_factor_rows(params: AdaptiveTables, order: _IdOrder, target, new,
                 chunk_of(order.ids))
         with jax.named_scope("step.update"):
             rows = jnp.where(taken[:, None], fresh, chunk_of(new))
-            return _write_rows(array, chunk_of(target), rows)
+            return write(array, chunk_of(target), rows)
 
     return lax.fori_loop(0, order.chunks, put_chunk, params.factors)
 
 
 def _adaptive_step(params: AdaptiveTables, batch, objective: str,
-                   learning_rate: float, l2: float, rule: FtrlAdagrad):
+                   learning_rate: float, l2: float, rule: FtrlAdagrad,
+                   write=_write_rows):
     """One step of difacto's memory-adaptive FM over
     :class:`AdaptiveTables` (``benchmarks/configs/kdd12-fm-k128-adaptive.
     json`` states the equations): count, forward and backward with the
@@ -1516,7 +1613,9 @@ def _adaptive_step(params: AdaptiveTables, batch, objective: str,
 
     The reads are :func:`_adaptive_head`'s; the writes are two: the base
     lane rows through :func:`_put_lane_rows` and the factor rows whole
-    (:func:`_put_factor_rows`), each XLA's scatter with distinct targets.
+    (:func:`_put_factor_rows`), each through ``write`` (:func:`_write_rows`
+    as the builder bound it: the base rows have 128 lanes, the factor rows
+    ``2K``).
     ``step.activate`` holds the count, the test, the prefix sum and the
     draws of ``v0``; the other phases are scoped as in the dense step.
     Returns (the new tree, the step's metrics)."""
@@ -1563,10 +1662,10 @@ def _adaptive_step(params: AdaptiveTables, batch, objective: str,
     with jax.named_scope("step.update"):
         base = _put_lane_rows(
             params.base_rows, order, read.base.lanes,
-            _join_base(dict(new, cnt=cnt, slot=slot)))
+            _join_base(dict(new, cnt=cnt, slot=slot)), write)
     factors = _put_factor_rows(
         params, order, target,
-        jnp.concatenate([new["v"], new["a"]], axis=1), granted)
+        jnp.concatenate([new["v"], new["a"]], axis=1), granted, write)
     with jax.named_scope("step.update"):
         after["b"] = scalars["b"] - learning_rate * (gb / denom)
     return (params.holding(base, factors, after),
@@ -1633,6 +1732,8 @@ def make_fm_train_step(
     donate_batch: bool = False,
     table_sharding: str = "replicated",
     rule: Optional[FtrlAdagrad] = None,
+    platform: Optional[str] = None,
+    interpret: bool = False,
 ):
     """Jitted FM step over COO batches: ``(params, batch) -> (params,
     metrics)``, metrics = ``loss_sum``, ``weight_sum`` and
@@ -1685,21 +1786,32 @@ def make_fm_train_step(
     the H2D landing buffers and scatters into the factor table in place
     (without it the step copies the table first) — only for streaming
     callers that rebind params each step and never touch a batch after
-    its step (DeviceFeed loops, FMLearner)."""
+    its step (DeviceFeed loops, FMLearner).
+
+    ``platform``: that of the devices ``params`` lie on, as the caller
+    saw it (a learner reads it off its params). A packed tree's lane
+    rows, and an :class:`AdaptiveTables`' rows, go back through
+    :func:`_write_rows`, which chooses its writer by the platform and the
+    row's lanes (:func:`row_writer`). **None (the default): not said,
+    which means XLA's scatter, the portable form, on whatever device**: a
+    direct caller on a TPU who wants the DMA writer says ``"tpu"``.
+    ``interpret`` is passed on to the writer (the CPU tests' way to the
+    kernel)."""
     check(num_features > 0, "num_features required")
     optimizer = "sgd" if rule is None else "ftrl_adagrad"
     _check_rule_placement(optimizer, mesh, table_sharding)
+    write = partial(_write_rows, platform=platform, interpret=interpret)
 
     def local(params, batch, factor_axis):
         if isinstance(params, AdaptiveTables):
             _check_adaptive_placement(optimizer, mesh)
             return _adaptive_step(params, batch, objective, learning_rate,
-                                  l2, rule)
+                                  l2, rule, write)
         dw, gb, dv, loss_sum, wsum, order, seen = _fm_entry_grads(
             params, batch, objective, factor_axis=factor_axis)
         params = _sparse_update(
             params, order, (dw, gb, dv, wsum), learning_rate, l2,
-            seen, rule)
+            seen, rule, write)
         return params, {"loss_sum": loss_sum, "weight_sum": wsum,
                         "touched_rows": order.distinct}
 
@@ -1940,6 +2052,8 @@ class FMLearner(FeedLearner):
         self._bytes_of: Dict[int, int] = {}
         # of those steps, the ones that took a packed tree
         self._packed_steps = 0
+        # the platform the builder of ``_step`` was handed (``_ensure``)
+        self._step_platform: Optional[str] = None
         super().__init__(mesh)
 
     @property
@@ -2028,6 +2142,7 @@ class FMLearner(FeedLearner):
             donate_batch=self.mesh is None,
             table_sharding=self.param.table_sharding,
             rule=self.rule,
+            platform=self._step_platform,
         )
 
     def param_shardings(self):
@@ -2055,11 +2170,19 @@ class FMLearner(FeedLearner):
             self._initialiser(nf), out_shardings=self.param_shardings())(
                 jnp.uint32(int(seed) % (1 << 32)))
         self._nf = nf
+        if row_writer(self._params_platform(), self._written_lanes) == "dma":
+            # the step will trace the kernel: its import here, while the
+            # device writes the tables (the call above has only started
+            # that), and not where the step's first trace would wait for it
+            import_pallas()
 
     def _ensure(self, num_features: int):
         if self.params is None:
             self.init_tables(0, num_features)
         if self._step is None:
+            # kept: what is counted and reported (:attr:`row_writer`) is
+            # what the step's builder was handed
+            self._step_platform = self._params_platform()
             self._step = self._make_step(
                 self._nf or self.param.num_features or num_features)
 
@@ -2093,12 +2216,40 @@ class FMLearner(FeedLearner):
         columns = self.row_columns
         return lane_geometry(columns) if columns else (0, 0)
 
+    def _params_platform(self) -> Optional[str]:
+        """The platform of the devices the params lie on (None before
+        there are any): what the step's builder is told."""
+        if self.params is None:
+            return None
+        leaf = jax.tree_util.tree_leaves(self.params)[0]
+        return next(iter(leaf.devices())).platform
+
+    @property
+    def _written_lanes(self) -> int:
+        """The lanes of the lane rows the step writes whole through
+        :func:`_write_rows`; 0 when the tables lie apart."""
+        return self.lane_geometry[0]
+
+    @property
+    def row_writer(self) -> str:
+        """What the step writes those lane rows back by: :func:`row_writer`
+        of the platform its builder was handed and their lanes (``"dma"``
+        / ``"scatter"``); before a step is built, of what a builder would
+        be handed now (no params yet: nothing, so ``"scatter"``).
+        ``"none"`` when the tables lie apart, whose writes are no whole
+        rows."""
+        lanes = self._written_lanes
+        told = (self._params_platform() if self._step is None
+                else self._step_platform)
+        return row_writer(told, lanes) if lanes else "none"
+
     def epoch_span_args(self) -> Dict:
         lanes, per_row = self.lane_geometry
         return {"table_shards": self.table_shards,
                 "optimizer": self.optimizer,
                 "row_columns": self.row_columns,
-                "row_lanes": lanes, "ids_per_lane_row": per_row}
+                "row_lanes": lanes, "ids_per_lane_row": per_row,
+                "row_writer": self.row_writer}
 
     def audit_params(self):
         """The arrays as they lie (a packed row as ``rows``): a sample
@@ -2149,7 +2300,11 @@ class FMLearner(FeedLearner):
         counts, from the same tree, the steps that read and wrote that
         row in one piece, as lanes of a row-major lane row (the one
         layout a :class:`PackedTables` has since PR 38; a tree of this
-        program's parent has the first counter and not the second)."""
+        program's parent has the first counter and not the second).
+        ``dmlc_fit_dma_row_write_steps_total`` counts the steps whose
+        lane rows went back by one DMA a row and not by XLA's scatter
+        (:attr:`row_writer`: the platform the step's builder was handed
+        and the lanes it chose by)."""
         shards = self.table_shards
         sparse = self.mesh is None or shards > 1
         reg.counter(
@@ -2199,6 +2354,11 @@ class FMLearner(FeedLearner):
             "optimizer steps that read and wrote each touched id's packed "
             "row as lanes of one row-major lane row, several ids to a row",
             model=self.name).inc(self._packed_steps)
+        reg.counter(
+            "dmlc_fit_dma_row_write_steps_total",
+            "optimizer steps that wrote each distinct lane row back by one "
+            "DMA of the row, not by a scatter",
+            model=self.name).inc(nstep if self.row_writer == "dma" else 0)
         self._steps_of.clear()
         self._packed_steps = 0
 
@@ -2390,6 +2550,12 @@ class AdaptiveFMLearner(FMLearner):
         """0: no one row holds an id's weights and state."""
         return 0
 
+    @property
+    def _written_lanes(self) -> int:
+        """The base rows' (the factor rows, ``2K`` wide, go through the
+        same writer at their own width)."""
+        return lane_geometry(len(BASE_WORDS))[0]
+
     def _initialiser(self, num_features: int):
         return partial(
             init_adaptive, num_features, self.param.num_factors,
@@ -2399,7 +2565,8 @@ class AdaptiveFMLearner(FMLearner):
         return make_fm_train_step(
             None, num_features, objective=self.param.objective,
             learning_rate=self.param.learning_rate, l2=self.param.l2,
-            donate_batch=True, rule=self.rule)
+            donate_batch=True, rule=self.rule,
+            platform=self._step_platform)
 
     def init_tables(self, seed, num_features: int = 0) -> None:
         super().init_tables(seed, num_features)

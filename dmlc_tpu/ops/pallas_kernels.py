@@ -46,10 +46,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
 from dmlc_tpu.ops.objectives import margin_loss_grad
+from dmlc_tpu.utils.jax_compat import import_pallas
+
+pl, pltpu = import_pallas()
 
 _LANE = 128
 _TILE_B = 512

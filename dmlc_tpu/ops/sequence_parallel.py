@@ -432,6 +432,9 @@ def make_pallas_flash_local(causal: bool = False, block_sizes=None):
     """
     import math
 
+    from dmlc_tpu.utils.jax_compat import import_pallas
+
+    import_pallas()  # before jax's own kernel imports it whole
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         BlockSizes,
         flash_attention,

@@ -47,3 +47,17 @@ def test_script_prints_no_result_off_the_chip():
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""  # no phase ran, no JSON line
     assert "no TPU" in proc.stderr
+
+
+def test_the_dma_row_writers_check_holds_small_in_the_interpreter():
+    """The one piece of the kernels phase that has a CPU form: the FM
+    step's DMA row writer against XLA's scatter, here a few dozen rows in
+    Pallas' interpreter; Mosaic's compile at the cells' sizes is the
+    chip's (a TPU's compiler does not belong in the CPU suite)."""
+    sys.path.insert(0, REPO)
+    import chip_smoke
+
+    facts = chip_smoke.dma_row_writer(
+        interpret=True, chunk=32, passes=3, height=131)
+    assert list(facts) == ["dma_row_writer"]
+    assert "96 slots" in facts["dma_row_writer"]

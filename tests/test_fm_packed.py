@@ -742,8 +742,7 @@ def test_g_lane_rows_whole_shared_partly_filled_and_left_alone(case):
 
 @pytest.mark.parametrize("past", [(), (1,), (2, 700), (5000,)])
 @pytest.mark.parametrize("case", WIDE)
-def test_h_every_slot_hands_the_writer_a_target_of_its_own(
-        case, past, monkeypatch):
+def test_h_every_slot_hands_the_writer_a_target_of_its_own(case, past):
     """The writer is XLA's scatter under ``unique_indices``, so no two
     slots of a chunk may name one row, the slots that write nothing
     included: a run's other slots, the slots past the distinct ids, and
@@ -758,13 +757,10 @@ def test_h_every_slot_hands_the_writer_a_target_of_its_own(
     named = np.r_[inside, F + np.asarray(past, np.int64)].astype(np.int32)
     idx = np.random.default_rng(len(past)).permutation(np.tile(named, 3))
     seen = []
-    write = fm_module._write_rows
 
     def recorded(array, target, new):
         jax.debug.callback(lambda t: seen.append(np.asarray(t)), target)
-        return write(array, target, new)
-
-    monkeypatch.setattr(fm_module, "_write_rows", recorded)
+        return fm_module._write_rows(array, target, new)
 
     @jax.jit
     def add_one(packed, idx):
@@ -772,7 +768,7 @@ def test_h_every_slot_hands_the_writer_a_target_of_its_own(
             idx, jnp.zeros_like(idx), jnp.ones(idx.shape, jnp.float32), F)
         read = fm_module._take_lane_rows(packed, order)
         return fm_module._put_lane_rows(
-            packed, order, read.lanes, read.words + 1.0)
+            packed, order, read.lanes, read.words + 1.0, recorded)
 
     before = np.asarray(packed.rows).copy()
     after = np.asarray(add_one(packed, jnp.asarray(idx)))

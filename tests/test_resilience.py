@@ -5,6 +5,7 @@ no-op path), hedged calls, and the WebHDFS CREATE/APPEND retry split."""
 
 import http.client
 import io
+import os
 import random
 import threading
 import urllib.error
@@ -369,11 +370,25 @@ class TestDisabledPath:
 
         tracemalloc.start()
         loop(1000)  # first traced pass pays tracemalloc's frame records
-        before, _ = tracemalloc.get_traced_memory()
+        before = tracemalloc.take_snapshot()
         loop(1000)
-        after, _ = tracemalloc.get_traced_memory()
+        after = tracemalloc.take_snapshot()
         tracemalloc.stop()
-        assert after - before == 0
+        # what the loop's own code holds, by the file that allocated it
+        # (the idiom of tests/test_obs.py): tracemalloc traces every
+        # thread of the process, and an xdist worker has one no test can
+        # join, execnet's receiver, which unpacks the scheduler's next
+        # message whenever it comes (found in PR 41: +882 bytes from
+        # xdist/remote.py and execnet/gateway_base.py inside one test of
+        # 180; here 152 bytes once in two whole runs)
+        only = [tracemalloc.Filter(True, os.path.join(
+                    os.path.dirname(resilience.__file__), "*")),
+                tracemalloc.Filter(True, __file__)]
+        changed = [
+            str(stat) for stat in after.filter_traces(only).compare_to(
+                before.filter_traces(only), "lineno")
+            if stat.size_diff != 0]
+        assert changed == []
 
     def test_env_arms_on_first_use(self, monkeypatch):
         monkeypatch.setenv("DMLC_TPU_FAULTS", "t.env:nth=1")
