@@ -622,6 +622,7 @@ def kernels(ctx):
               "(loss %.6f vs %.6f)", layout, outs[True][1], outs[False][1])
         facts["train_step_pallas_" + layout] = "matches the XLA step"
     facts.update(dma_row_writer())
+    facts.update(dlrm_step())
     return facts
 
 
@@ -706,6 +707,85 @@ def dma_row_writer(interpret=False, chunk=2048, passes=13, height=65539,
     facts["dma_row_writer_in_place"] = "compiled at %s" % ", ".join(
         "%s[%d,128]" % (kind, rows) for rows, kind in cells)
     return facts
+
+
+def dlrm_step(steps=3, rehearse=False, seed=42):
+    """``models/dlrm.py``'s step at the ``criteo-dlrm`` cell's full size
+    (33,762,591 ids of 16 columns as lane rows, the published MLPs,
+    batches of 8192 rows of 39 entries laid out as the feed lays them)
+    for ``steps`` steps, against the configuration's float64 reference:
+    each step's loss, every dense parameter and every row the batches
+    touch, under the cell's own limits. ``rehearse``: at the
+    configuration's rehearse size (the CPU test's,
+    tests/test_chip_smoke.py)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    bench = os.path.join(HERE, "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        from harness import spec
+
+        cell = spec.Cell("criteo-dlrm.libsvm", rehearse=rehearse)
+    finally:
+        sys.path.remove(bench)
+    from dmlc_tpu.device.csr import round_up_bucket
+
+    cfg = cell.cfg
+    rows = int(cfg["batch_rows_per_chip"])
+    data = cell.config.rows(dict(cfg, rows=steps * rows), seed)
+    model = cell.config.learner(cfg, None)
+    model.init_tables(seed)
+    touched = np.unique(data["ids"])
+    at = jnp.asarray(touched, jnp.int32)
+
+    def logical():
+        out = {name: np.asarray(model.table_rows(name, at), np.float64)
+               for name in model.table_names()}
+        out.update({k: np.float64(v) for k, v in model.scalars().items()})
+        return out
+
+    before = logical()
+    width = data["ids"].shape[1]
+    pad = round_up_bucket(rows * width) - rows * width
+    losses, batches = [], []
+    for i in range(steps):
+        part = slice(i * rows, (i + 1) * rows)
+        batches.append({
+            "label": data["label"][part], "values": data["values"][part],
+            "ids": np.searchsorted(touched, data["ids"][part])})
+        model._ensure(int(cfg["num_features"]))
+        sums = model.train_step({
+            "label": jnp.asarray(data["label"][part], jnp.float32),
+            "weight": jnp.ones((rows,), jnp.float32),
+            "indices": jnp.asarray(np.pad(
+                data["ids"][part].ravel(), (0, pad)), jnp.int32),
+            "values": jnp.asarray(np.pad(
+                data["values"][part].ravel(), (0, pad)), jnp.float32),
+            "offsets": jnp.arange(rows + 1, dtype=jnp.int32) * width})
+        losses.append(float(sums["loss_sum"]) / float(sums["weight_sum"]))
+        check(int(sums["left_out"]) == 0, "the step left %d entries out",
+              int(sums["left_out"]))
+    ref_losses, ref = cell.config.reference_steps(cfg, before, batches)
+    after = logical()
+    loss_rel = max(_rel(a, b) for a, b in zip(losses, ref_losses))
+    update_rel = {
+        k: float(np.max(np.abs(after[k] - ref[k]))
+                 / max(np.max(np.abs(ref[k] - before[k])), 1e-30))
+        for k in before}
+    worst = max(update_rel, key=update_rel.get)
+    check(loss_rel <= cfg["check"]["loss_rel_tol"]
+          and update_rel[worst] <= cfg["check"]["update_rel_tol"],
+          "the DLRM step is off its float64 reference: loss_rel %.3g "
+          "(limit %g), update_rel %.3g on %s (limit %g)", loss_rel,
+          cfg["check"]["loss_rel_tol"], update_rel[worst], worst,
+          cfg["check"]["update_rel_tol"])
+    return {"dlrm_step": "%d steps of %d rows over %s[%d,128] match the "
+            "float64 reference: loss_rel %.2g, update_rel %.2g (%s), "
+            "row writer %s" % (
+                steps, rows, model.params.rows.dtype,
+                model.params.rows.shape[0], loss_rel, update_rel[worst],
+                worst, model.row_writer)}
 
 
 def staging_pool(ctx):

@@ -14,6 +14,11 @@ so this package ships that learner family TPU-natively:
 - ``ffm``: field-aware factorization machines (libffm's model and
   AdaGrad), an entry's field taken from its id's range; the FM's step
   head, chunk loops and stateful-update skeleton at another width
+- ``dlrm``: a dense net over the table's rows (DLRM: two MLPs and a dot
+  interaction, plain SGD on the dense parameters and on the rows a batch
+  names), on the FM's skeleton. NOT imported here: ``from
+  dmlc_tpu.models.dlrm import DLRMLearner`` by who asks for it, so that
+  every other learner's process imports what it did
 - ``gbdt``: histogram gradient-boosted trees — the xgboost-over-rabit
   workload the reference backbone was built for, with per-level histogram
   psum standing in for rabit's allreduce

@@ -249,7 +249,9 @@ class PackedTables(Mapping):
         lane   0        C        2C            p*C      L
         row r  | id r*p | id r*p+1 | ... | id r*p+p-1 | 0 |
 
-    ``scalars`` = {name: f32[]} (the FM's ``b``). What a device that
+    ``scalars`` = {name: array}: every leaf that is no per-id table (the
+    FM's ``b``, ``f32[]``; a DLRM's dense net, a matrix or a vector a
+    name: models/dlrm.py). What a device that
     holds whole rows of every table keeps, so that a step reads each
     touched id's words once and writes them once, in one piece of one
     tile. A pytree: ``rows`` and the scalars are its leaves, the layout
@@ -362,7 +364,7 @@ _INIT_BLOCK = 1 << 18
 
 
 def init_packed(num_features: int, layout, draw, fill: Dict, scalars: Dict,
-                seed) -> PackedTables:
+                seed, scale=None) -> PackedTables:
     """A learner's tables at their start as ONE packed array of lane rows
     (:class:`PackedTables`), written in place a block of whole lane rows
     a pass: every id's words at their tables' ``fill`` values (0 where a
@@ -376,7 +378,10 @@ def init_packed(num_features: int, layout, draw, fill: Dict, scalars: Dict,
     (``init_fm_params``: ``init_scale * normal``). A block's key carries
     its offset (:data:`_OFFSET_DRAWS`), so id i holds row i of
     ``draw(PRNGKey(seed), (num_features, width))`` to the bit; the last
-    lane row's places past the last id hold 0, as the unused lanes do."""
+    lane row's places past the last id hold 0, as the unused lanes do.
+    ``scale(ids) -> f32`` of ``ids``' shape: what an id's draws are
+    multiplied by, for a start whose spread differs from id to id
+    (models/dlrm.py: by the table an id belongs to)."""
     name, width = layout[0]
     columns = _columns(w for _, w in layout)
     lanes, per_row = lane_geometry(columns)
@@ -409,10 +414,16 @@ def init_packed(num_features: int, layout, draw, fill: Dict, scalars: Dict,
         # (cut into an id's 16 columns first, each piece is padded to 128
         # lanes and turned on its own: 140 ms of 200 at 35 columns)
         draws = draw(key, (block, per_row * width)).T
-        lanes_first = jnp.where(drawn[:, None],
-                                jnp.take(draws, source, axis=0),
-                                filled[:, None])
-        ids = (at + jnp.arange(block)) * per_row + place[:, None]
+
+        def ids_of():
+            return (at + jnp.arange(block)) * per_row + place[:, None]
+
+        picked = jnp.take(draws, source, axis=0)
+        if scale is not None:
+            by = scale(ids_of())
+            picked = jnp.where(by != 0, picked * by, 0.0)  # no -0.0
+        lanes_first = jnp.where(drawn[:, None], picked, filled[:, None])
+        ids = ids_of()
         return lax.dynamic_update_slice(
             array, jnp.where(ids < num_features, lanes_first, 0.0).T,
             (at, 0))
@@ -545,9 +556,17 @@ def _in_id_order(indices, row_ids, values, num_features: int):
     side by side, so an entry's slot is the count of distinct ids before
     it, sorted by construction. A padded entry (value 0, feature 0) sorts
     to the front, reads row 0 and adds 0 to it."""
-    n = indices.shape[0]
     indices, row_ids, values = lax.sort(
         (indices, row_ids, values), num_keys=1)
+    return _slots_in_id_order(indices, num_features), row_ids, values
+
+
+def _slots_in_id_order(indices, num_features: int) -> _IdOrder:
+    """The :class:`_IdOrder` of entries whose feature ids ``indices``
+    ARE ascending: what :func:`_in_id_order` does after its sort (a step
+    whose entries carry other payloads sorts them itself:
+    models/dlrm.py)."""
+    n = indices.shape[0]
     first = jnp.concatenate(
         [jnp.ones((1,), bool), indices[1:] != indices[:-1]])
     slot = jnp.cumsum(first.astype(jnp.int32)) - 1
@@ -559,7 +578,7 @@ def _in_id_order(indices, row_ids, values, num_features: int):
     past = num_features + jnp.arange(n + pad, dtype=jnp.int32)
     ids = jnp.concatenate(
         [lax.sort(jnp.where(first, indices, past[:n])), past[n:]])
-    return _IdOrder(indices, slot, ids, slot[-1] + 1), row_ids, values
+    return _IdOrder(indices, slot, ids, slot[-1] + 1)
 
 
 def _take_distinct(tables, order: _IdOrder, sorted_ids: bool = True):
@@ -781,6 +800,15 @@ def _put_lane_rows(packed: PackedTables, order: _IdOrder, lanes, new,
     return lax.fori_loop(0, order.chunks, put_chunk, packed.rows)
 
 
+def _read_distinct(tables, order: _IdOrder) -> _Read:
+    """The distinct ids' rows of ``tables`` (the arrays side by side, or
+    the one packed tree: :func:`_head_tables` gives either), each touched
+    row of the parameters read ONCE."""
+    if isinstance(tables, PackedTables):
+        return _take_lane_rows(tables, order)
+    return _Read(_take_distinct(tables, order))
+
+
 def _gather_rows(tables, order: _IdOrder, head: Optional[int] = None):
     """The entries' rows of ``tables`` side by side (the FM's ``[v_e |
     w_e]``, ``[nnz, K + 1]``), in id order, with each touched row of the
@@ -799,8 +827,7 @@ def _gather_rows(tables, order: _IdOrder, head: Optional[int] = None):
     for being repeated, so the popular ids of a power law are most of a
     per-entry gather's cost; a batch with no repeated id gathers what a
     per-entry gather would."""
-    read = _take_lane_rows(tables, order) if isinstance(
-        tables, PackedTables) else _Read(_take_distinct(tables, order))
+    read = _read_distinct(tables, order)
     rows = read.words
     weights = rows if head in (None, rows.shape[1]) else rows[:, :head]
     return read, jnp.take(weights, order.slot, axis=0)

@@ -61,3 +61,17 @@ def test_the_dma_row_writers_check_holds_small_in_the_interpreter():
         interpret=True, chunk=32, passes=3, height=131)
     assert list(facts) == ["dma_row_writer"]
     assert "96 slots" in facts["dma_row_writer"]
+
+
+def test_the_dlrm_steps_check_holds_at_the_rehearse_size():
+    """``chip_smoke.dlrm_step`` on the CPU at the ``criteo-dlrm``
+    configuration's rehearse size (small tables, the published MLPs,
+    batches of 1024): the same comparison with the float64 reference the
+    chip makes at 33.8 M ids, under the cell's own limits."""
+    sys.path.insert(0, REPO)
+    import chip_smoke
+
+    facts = chip_smoke.dlrm_step(steps=2, rehearse=True)
+    assert list(facts) == ["dlrm_step"]
+    assert "2 steps of 1024 rows" in facts["dlrm_step"]
+    assert "row writer scatter" in facts["dlrm_step"]
