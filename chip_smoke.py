@@ -622,8 +622,78 @@ def kernels(ctx):
               "(loss %.6f vs %.6f)", layout, outs[True][1], outs[False][1])
         facts["train_step_pallas_" + layout] = "matches the XLA step"
     facts.update(dma_row_writer())
+    facts.update(run_sums())
     facts.update(dlrm_step())
     return facts
+
+
+def run_sums(interpret=False, rows=8192, calls=30, seed=45):
+    """The SGD step's id sums as a TPU takes them (``models/fm.py``
+    ``_sum_by_slot``: the segmented scan ``run_sums`` along each id's
+    sorted run, each run's total laid at its slot) against XLA's
+    ``segment_sum`` by sorted slot, at the ``criteo-dlrm`` cell's
+    ``[212992,16]``: the table ids of ``rows`` rows of the configuration's
+    power law in id order (some ids thousands of entries long). Both
+    timed over ``calls`` calls; they agree within float32 rounding of a
+    tree of adds. The keywords are the CPU test's
+    (tests/test_chip_smoke.py), which runs it small at the configuration's
+    rehearse size in Pallas' interpreter."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dmlc_tpu.models import fm
+
+    bench = os.path.join(HERE, "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        from harness import spec
+
+        cell = spec.Cell("criteo-dlrm.libsvm", rehearse=interpret)
+        ids = cell.config.rows(dict(cell.cfg, rows=rows), seed)["ids"]
+    finally:
+        sys.path.remove(bench)
+    ids = np.sort(ids[:, int(cell.cfg["dense_features"]):].ravel())
+    order = fm._slots_in_id_order(
+        jnp.asarray(ids, jnp.int32), int(cell.cfg["num_features"]))
+    upd = np.random.RandomState(seed).randn(ids.size, int(
+        cell.cfg["num_factors"])).astype(np.float32) * np.float32(1e-3)
+    slot = np.asarray(order.slot)
+    want = np.zeros((order.ids.shape[0], upd.shape[1]))
+    np.add.at(want, slot, upd.astype(np.float64))
+    size = np.zeros(order.ids.shape[0])
+    np.add.at(size, slot, np.abs(upd).astype(np.float64).sum(axis=1))
+    outs, ms = {}, {}
+    on_device = jnp.asarray(upd)
+    for name, platform in (("scan", "tpu"), ("segment_sum", None)):
+        summed = jax.jit(functools.partial(
+            fm._sum_by_slot, platform=platform, interpret=interpret))
+        got = jax.block_until_ready(summed(on_device, order))
+        t = time.perf_counter()
+        for _ in range(calls):
+            got = summed(on_device, order)
+        jax.block_until_ready(got)
+        ms[name] = (time.perf_counter() - t) / calls * 1e3
+        outs[name] = np.asarray(got, np.float64)
+    # a tree of adds in a block of entries, one add a block crossed
+    depth = math.log2(fm._RUN_BLOCK) + -(-ids.size // fm._RUN_BLOCK) + 2
+    eps = float(np.finfo(np.float32).eps)
+    off = np.abs(outs["scan"] - want) / (depth * eps * size[:, None] + 1e-30)
+    diff = float(np.max(np.abs(outs["scan"] - outs["segment_sum"])))
+    check(float(np.max(off)) <= 1.0
+          and not outs["scan"][int(order.distinct):].any(),
+          "the run sums are off the float64 sums by %.3g of their float32 "
+          "bound (largest |scan - segment_sum| %.3g)", float(np.max(off)),
+          diff)
+    say("run_sums over [%d,%d], %d distinct ids: scan %.3f ms, "
+        "segment_sum %.3f ms, largest |difference| %.3g", ids.size,
+        upd.shape[1], int(order.distinct), ms["scan"], ms["segment_sum"],
+        diff)
+    return {"run_sums": "[%d,%d] over %d runs (longest %d): scan %.3f ms, "
+            "segment_sum %.3f ms, largest |difference| %.3g" % (
+                ids.size, upd.shape[1], int(order.distinct),
+                int(np.bincount(slot).max()), ms["scan"],
+                ms["segment_sum"], diff)}
 
 
 def dma_row_writer(interpret=False, chunk=2048, passes=13, height=65539,

@@ -47,9 +47,10 @@ that the interaction is a product and a sum over whole vectors and the
 triangle is cut along major axes; every matrix product at
 ``precision=HIGHEST`` (float32 as the configuration states it: six
 bfloat16 passes on the MXU). ``step.update``: each entry's gradient
-brought to id order, an id's entries summed, ``old + sum``, and the
-distinct lane rows written back (models/fm.py ``_add_lane_rows``: the
-writer every rule shares, on a TPU one DMA a row).
+brought to id order, an id's entries summed (on a TPU by a segmented
+scan along each id's run), ``old + sum``, and the distinct lane rows
+written back (models/fm.py ``_add_lane_rows``: the writer every rule
+shares, on a TPU one DMA a row).
 
 How the batch is laid out. A batch whose every row lists its D dense ids
 in order and then one id of every table in order (what a LIBSVM file of
@@ -89,6 +90,7 @@ from dmlc_tpu.models.fm import (
     _regroup,
     _scatter_add_rows,
     _slots_in_id_order,
+    _summer,
     _write_rows,
     init_packed,
 )
@@ -352,6 +354,7 @@ def make_dlrm_train_step(
     donate_batch: bool = False,
     platform: Optional[str] = None,
     interpret: bool = False,
+    id_sums: Optional[Dict[int, str]] = None,
 ):
     """Jitted DLRM step over COO batches on ONE device, ``(params, batch)
     -> (params, metrics)``. ``params``: the dict of
@@ -361,8 +364,9 @@ def make_dlrm_train_step(
     its reads and writes from the tree it is given, as the FM's does).
     Metrics: the FM's ``loss_sum``, ``weight_sum``, ``touched_rows``, and
     ``left_out``, the valued table entries that took no part
-    (:func:`_lay_out`). ``donate_batch``, ``platform`` and ``interpret``
-    as :func:`~dmlc_tpu.models.fm.make_fm_train_step` takes them."""
+    (:func:`_lay_out`). ``donate_batch``, ``platform``, ``interpret`` and
+    ``id_sums`` as :func:`~dmlc_tpu.models.fm.make_fm_train_step` takes
+    them."""
     check(num_features > 0, "num_features required")
     sizes = tuple(int(n) for n in field_sizes)
     lows = table_lows(dense_features, sizes)
@@ -396,7 +400,8 @@ def make_dlrm_train_step(
             upd = upd.at[head.place].get(mode="promise_in_bounds")
             if isinstance(params, PackedTables):
                 arrays = [_add_lane_rows(
-                    params, head.order, upd, head.read, None, write)]
+                    params, head.order, upd, head.read, None, write,
+                    _summer(platform, interpret, id_sums, batch))]
             else:
                 arrays = _scatter_add_rows(
                     _groups(params, DLRM_TABLES), head.order, upd)
@@ -491,7 +496,7 @@ class DLRMLearner(FMLearner):
         return make_dlrm_train_step(
             num_features, self.param.dense_features, self.param.field_sizes,
             learning_rate=self.param.learning_rate, donate_batch=True,
-            platform=self._step_platform)
+            platform=self._step_platform, id_sums=self._id_sums)
 
     def epoch_span_args(self) -> Dict:
         return dict(super().epoch_span_args(), fields=self.fields,

@@ -1054,9 +1054,190 @@ def exchange_bytes(batch, shards: int) -> int:
     return gathered // shards + int(batch["label"].nbytes)
 
 
+def id_summer(platform: Optional[str], entries: int, columns: int,
+              slots: int = 0) -> str:
+    """How :func:`_sum_by_slot` sums ``entries`` entries of ``columns``
+    float32 columns into ``slots`` slots on the devices of ``platform``:
+    ``"scan"`` (a segmented scan along each id's sorted run, a Pallas TPU
+    kernel) on a TPU where the kernel's output fits
+    :data:`_RUN_SUMS_VMEM`, ``"segment_sum"`` (XLA's scatter-add by sorted
+    slot) everywhere else. From the platform and the static shapes alone:
+    no option chooses."""
+    fits = _run_sums_vmem(entries, columns, slots) <= _RUN_SUMS_VMEM
+    return "scan" if platform == "tpu" and fits else "segment_sum"
+
+
+def _sum_by_slot(upd, order: _IdOrder, platform: Optional[str] = None,
+                 interpret: bool = False, chosen=None):
+    """``[n + pad, C]``: slot j holds the sum of the entries of ``upd``
+    (``[n, C]``, in ``order``'s order) whose slot is j, and a slot past the
+    distinct ids 0. ``platform``: that of the devices ``upd`` lies on, as
+    whoever built the step saw it (None: not said, and so the
+    scatter-add); :func:`id_summer` chooses by it and the shapes.
+    ``chosen``: called with the form at the trace, where the step's
+    builder keeps a record of it (what the learner counts by).
+
+    The portable form, and what the scan's tests compare with, is
+    ``segment_sum`` by sorted slot: on the chip a scatter-add whose
+    neighbouring entries name one row runs one after another, 14 ns an
+    entry where an id's run is thousands long (``criteo-dlrm``: 2.98 ms of
+    a 9.25 ms step; PERF.md, section 5). The scan (:func:`_scan_runs`)
+    costs the same whatever the runs' lengths. Both add in float32; only
+    the order of the adds differs.
+
+    ``interpret``: the kernel, where it is chosen, runs in Pallas'
+    interpreter (the CPU tests pass it; nothing infers it)."""
+    n, columns = upd.shape
+    form = id_summer(platform, n, columns, order.ids.shape[0])
+    if chosen is not None:
+        chosen(form)
+    if form == "scan":
+        return _scan_runs(upd, order, interpret)
+    sums = jax.ops.segment_sum(
+        upd, order.slot, num_segments=n, indices_are_sorted=True)
+    return jnp.pad(sums, ((0, order.ids.shape[0] - n), (0, 0)))
+
+
+def _summer(platform: Optional[str], interpret: bool,
+            id_sums: Optional[Dict[int, str]], batch):
+    """:func:`_sum_by_slot` as a step's builder binds it for one trace:
+    the form it takes goes into ``id_sums`` (None: no record) under the
+    entry count of ``batch``, the key a learner counts its steps by."""
+    chosen = None if id_sums is None else partial(
+        id_sums.__setitem__, batch["indices"].shape[0])
+    return partial(_sum_by_slot, platform=platform, interpret=interpret,
+                   chosen=chosen)
+
+
+#: entries the run-sum kernel takes in one grid step (PERF.md, section 5)
+_RUN_BLOCK = 2048
+
+#: the most VMEM the run-sum kernel's output may hold, both its buffers:
+#: with the 16 MiB it asks for besides, 80 MiB of a v5e core's 128. At 16
+#: columns that is 522,112 entries (20,081 DLRM rows of 26 table ids), at
+#: 17 (24 sublanes) 347,264 (31,569 FM rows of 11); past it the sums go by
+#: ``segment_sum`` (PERF.md, section 3)
+_RUN_SUMS_VMEM = 64 << 20
+
+
+def _run_sums_width(entries: int, slots: int) -> int:
+    """Lanes of the run-sum kernel's output: every slot, and the window
+    the last block lays its totals by (:func:`_scan_runs`)."""
+    return -(-max(slots, entries + _RUN_BLOCK + 128) // 128) * 128
+
+
+def _run_sums_vmem(entries: int, columns: int, slots: int = 0) -> int:
+    """Bytes of VMEM the run-sum kernel's output takes: two buffers of
+    ``f32[columns, width]``, the columns padded to sublanes of 8."""
+    return 2 * (-(-columns // 8) * 8) * _run_sums_width(entries, slots) * 4
+
+
+def _scan_runs(upd, order: _IdOrder, interpret: bool = False):
+    """:func:`_sum_by_slot` as one Pallas TPU kernel: the entries of
+    ``upd`` (``[n, C]``) laid along the lanes (``[C, n]``: a pass over
+    ``[n, 16]`` as it lies pays for 128 lanes a row), :data:`_RUN_BLOCK`
+    of them a grid step, the steps in order, and the sums by slot kept in
+    VMEM until the last step.
+
+    In a block: (1) the inclusive scan along each run, log2(block) adds
+    of the block moved d = 1, 2, 4, ... places, each taken where the
+    entry d places before shares the slot (the slots are sorted, so that
+    test alone says whether it lies in the same run), and the last sum
+    and slot of the block before, carried in scratch, added to the run
+    the block starts with; a run's last entry then holds its total.
+    (2) Those totals to the block's front, in order: a run's last entry
+    moves left by the entries before it in the block that end no run,
+    one bit of that count a pass, the lowest first, so that no two ever
+    meet. (3) They are laid at their slots: the first is the slot of the
+    block's first entry (the runs that ended before the block), and the
+    rest follow it. Slots no run ends at hold 0."""
+    pl, pltpu = import_pallas()
+    n, columns = upd.shape
+    slots = order.ids.shape[0]
+    block = _RUN_BLOCK
+    blocks = -(-n // block)
+    pad = blocks * block - n
+    # a block's totals are laid by an aligned window one tile wider
+    window = block + 128
+    width = _run_sums_width(n, slots)
+    lanes = jnp.pad(upd, ((0, pad), (0, 0))).T
+    # the padded entries name slot -1 and the carry starts at -2: no run
+    keys = jnp.pad(order.slot, (0, pad), constant_values=-1)
+    ends = jnp.concatenate(
+        [order.slot[1:] != order.slot[:-1], jnp.ones((1,), bool)])
+    ends = jnp.pad(ends.astype(jnp.int32), (0, pad))[None]
+
+    def kernel(first_ref, key_ref, end_ref, x_ref, out_ref, carry_ref,
+               carry_key_ref):
+        step = pl.program_id(0)
+
+        @pl.when(step == 0)
+        def _():
+            carry_ref[...] = jnp.zeros(carry_ref.shape, carry_ref.dtype)
+            carry_key_ref[...] = jnp.full(carry_key_ref.shape, -2, jnp.int32)
+            out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+        x, key = x_ref[...], key_ref[...]
+        lane = lax.broadcasted_iota(jnp.int32, key.shape, 1)
+        d = 1
+        while d < block:
+            same = (lane >= d) & (pltpu.roll(key, d, 1) == key)
+            x = x + jnp.where(same, pltpu.roll(x, d, 1), 0)
+            d *= 2
+        x = x + jnp.where(key == carry_key_ref[...], carry_ref[...], 0)
+        carry_ref[...] = x[:, block - 1:]
+        carry_key_ref[...] = key[:, block - 1:]
+
+        first = first_ref[step]
+        live = end_ref[...] != 0
+        left = lane - (key - first)
+        d = 1
+        while d < block:
+            back = block - d  # a roll by ``back`` moves lane i + d to i
+            going = live & ((left & d) != 0)
+            came = (lane < back) & (
+                pltpu.roll(going.astype(jnp.int32), back, 1) != 0)
+            x = jnp.where(came, pltpu.roll(x, back, 1), x)
+            left = jnp.where(came, pltpu.roll(left, back, 1), left)
+            live = (live & ~going) | came
+            d *= 2
+
+        offset = first % 128
+        at = pl.multiple_of(first - offset, 128)
+        x = pltpu.roll(jnp.concatenate(
+            [x, jnp.zeros((columns, 128), x.dtype)], axis=1), offset, 1)
+        live = pltpu.roll(jnp.concatenate(
+            [live.astype(jnp.int32), jnp.zeros((1, 128), jnp.int32)],
+            axis=1), offset, 1) != 0
+        out_ref[:, pl.ds(at, window)] = jnp.where(
+            live, x, out_ref[:, pl.ds(at, window)])
+
+    # the sums stay in VMEM, two buffers of them (id_summer bounds them)
+    held = _run_sums_vmem(n, columns, slots)
+    by_slot = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(blocks,),
+            in_specs=[pl.BlockSpec((1, block), lambda i, first: (0, i)),
+                      pl.BlockSpec((1, block), lambda i, first: (0, i)),
+                      pl.BlockSpec((columns, block),
+                                   lambda i, first: (0, i))],
+            out_specs=pl.BlockSpec((columns, width),
+                                   lambda i, first: (0, 0)),
+            scratch_shapes=[pltpu.VMEM((columns, 1), upd.dtype),
+                            pltpu.VMEM((1, 1), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((columns, width), upd.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=held + (16 << 20)),
+        interpret=interpret, name="run_sums",
+    )(keys[::block], keys[None], ends, lanes)
+    return by_slot[:, :slots].T
+
+
 def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
                    l2: float, seen=None, rule: Optional[FtrlAdagrad] = None,
-                   write=_write_rows):
+                   write=_write_rows, sum_ids=_sum_by_slot):
     """The step's update from per-entry contributions ``grads`` =
     (dw, gb, dv, weight_sum), the entries in ``order``'s order: under
     ``step.update`` scaled by ``-learning_rate / weight_sum`` and
@@ -1071,7 +1252,9 @@ def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
     With a ``rule`` (``optimizer="ftrl_adagrad"``) the update is
     :func:`_stateful_update`'s, which sets rows where this one adds.
     ``write``: what writes a packed tree's lane rows
-    (:func:`_put_lane_rows`)."""
+    (:func:`_put_lane_rows`); ``sum_ids``: what sums an id's entries
+    before they meet its lane row (:func:`_sum_by_slot`), both as the
+    step's builder bound them."""
     if rule is not None:
         return _stateful_update(
             params, order, grads, seen, learning_rate, l2, rule, write)
@@ -1083,7 +1266,8 @@ def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
         decay = 1.0 - learning_rate * l2
         if isinstance(params, PackedTables):
             arrays = [_add_lane_rows(
-                params, order, upd, seen[0], decay if l2 else None, write)]
+                params, order, upd, seen[0], decay if l2 else None, write,
+                sum_ids)]
         else:
             groups = _groups(params, SGD_TABLES)
             if l2:
@@ -1096,24 +1280,22 @@ def _sparse_update(params, order: _IdOrder, grads, learning_rate: float,
 
 
 def _add_lane_rows(packed: PackedTables, order: _IdOrder, upd, read: _Read,
-                   decay: Optional[float], write=_write_rows):
+                   decay: Optional[float], write=_write_rows,
+                   sum_ids=_sum_by_slot):
     """:func:`_scatter_add_rows` over a packed tree: an id's entries
-    summed first (the same one ``segment_sum``), then ``old + sum``, the
-    float32 add the scatter-add makes, on the words the head read, and
-    the new words put back by the writer every rule shares
-    (:func:`_put_lane_rows`). ``decay``: the weight decay's factor, one
-    scaling pass over the array before the add, as :func:`_sparse_update`
-    makes over tables apart. Returns the new array."""
-    n = order.slot.shape[0]
-    sums = jax.ops.segment_sum(
-        upd, order.slot, num_segments=n, indices_are_sorted=True)
+    summed first (``sum_ids``: :func:`_sum_by_slot` as the step's builder
+    bound it), then ``old + sum``, the float32 add the scatter-add makes,
+    on the words the head read, and the new words put back by the writer
+    every rule shares (:func:`_put_lane_rows`). ``decay``: the weight
+    decay's factor, one scaling pass over the array before the add, as
+    :func:`_sparse_update` makes over tables apart. Returns the new
+    array."""
     # summed FIRST, as the docstring of :func:`_scatter_add_rows` says
     # why: left to itself the compiler folds ``old + segment_sum(...)``
     # into a scatter-add of the entries into ``old``, one rounding at the
     # parameter's magnitude for every entry (17 times the error of one
     # add against the float64 reference, on the chip: PERF.md, PR 38)
-    sums = lax.optimization_barrier(
-        jnp.pad(sums, ((0, order.ids.shape[0] - n), (0, 0))))
+    sums = lax.optimization_barrier(sum_ids(upd, order))
     if decay is not None:
         # the touched ids read again from the scaled array: words scaled
         # here would round with the add (a fused multiply-add), not as
@@ -1761,6 +1943,7 @@ def make_fm_train_step(
     rule: Optional[FtrlAdagrad] = None,
     platform: Optional[str] = None,
     interpret: bool = False,
+    id_sums: Optional[Dict[int, str]] = None,
 ):
     """Jitted FM step over COO batches: ``(params, batch) -> (params,
     metrics)``, metrics = ``loss_sum``, ``weight_sum`` and
@@ -1821,9 +2004,13 @@ def make_fm_train_step(
     :func:`_write_rows`, which chooses its writer by the platform and the
     row's lanes (:func:`row_writer`). **None (the default): not said,
     which means XLA's scatter, the portable form, on whatever device**: a
-    direct caller on a TPU who wants the DMA writer says ``"tpu"``.
-    ``interpret`` is passed on to the writer (the CPU tests' way to the
-    kernel)."""
+    direct caller on a TPU who wants the DMA writer says ``"tpu"``. The
+    SGD step over a packed tree sums an id's entries by what the same
+    platform chooses (:func:`_sum_by_slot`: a segmented scan on a TPU).
+    ``interpret`` is passed on to the writer and the summer (the CPU
+    tests' way to the kernels). ``id_sums``: where the step records, at
+    each trace, what it summed the entries by, under the batch's entry
+    count (:func:`_summer`)."""
     check(num_features > 0, "num_features required")
     optimizer = "sgd" if rule is None else "ftrl_adagrad"
     _check_rule_placement(optimizer, mesh, table_sharding)
@@ -1838,7 +2025,7 @@ def make_fm_train_step(
             params, batch, objective, factor_axis=factor_axis)
         params = _sparse_update(
             params, order, (dw, gb, dv, wsum), learning_rate, l2,
-            seen, rule, write)
+            seen, rule, write, _summer(platform, interpret, id_sums, batch))
         return params, {"loss_sum": loss_sum, "weight_sum": wsum,
                         "touched_rows": order.distinct}
 
@@ -2079,8 +2266,10 @@ class FMLearner(FeedLearner):
         self._bytes_of: Dict[int, int] = {}
         # of those steps, the ones that took a packed tree
         self._packed_steps = 0
-        # the platform the builder of ``_step`` was handed (``_ensure``)
+        # the platform the builder of ``_step`` was handed (``_ensure``),
+        # and the step's record of what its traces summed entries by
         self._step_platform: Optional[str] = None
+        self._id_sums: Dict[int, str] = {}
         super().__init__(mesh)
 
     @property
@@ -2170,6 +2359,7 @@ class FMLearner(FeedLearner):
             table_sharding=self.param.table_sharding,
             rule=self.rule,
             platform=self._step_platform,
+            id_sums=self._id_sums,
         )
 
     def param_shardings(self):
@@ -2208,8 +2398,9 @@ class FMLearner(FeedLearner):
             self.init_tables(0, num_features)
         if self._step is None:
             # kept: what is counted and reported (:attr:`row_writer`) is
-            # what the step's builder was handed
+            # what the step's builder was handed, and what its traces chose
             self._step_platform = self._params_platform()
+            self._id_sums = {}
             self._step = self._make_step(
                 self._nf or self.param.num_features or num_features)
 
@@ -2270,13 +2461,25 @@ class FMLearner(FeedLearner):
                 else self._step_platform)
         return row_writer(told, lanes) if lanes else "none"
 
+    @property
+    def id_sum(self) -> str:
+        """What the step summed an id's entries by before they met its
+        row, as its traces recorded (:func:`_summer`): ``"scan"`` or
+        ``"segment_sum"``, ``"scan/segment_sum"`` where batches of some
+        sizes took each. ``"segment_sum"`` where nothing is recorded: every
+        path that keeps no record (a rule, tables that lie apart, a mesh)
+        sums by it, and so does a pass whose span opens before the step's
+        first trace says it (a learner's first; the counter, counted at
+        the pass's close, goes by the record)."""
+        return "/".join(sorted(set(self._id_sums.values()))) or "segment_sum"
+
     def epoch_span_args(self) -> Dict:
         lanes, per_row = self.lane_geometry
         return {"table_shards": self.table_shards,
                 "optimizer": self.optimizer,
                 "row_columns": self.row_columns,
                 "row_lanes": lanes, "ids_per_lane_row": per_row,
-                "row_writer": self.row_writer}
+                "row_writer": self.row_writer, "id_sum": self.id_sum}
 
     def audit_params(self):
         """The arrays as they lie (a packed row as ``rows``): a sample
@@ -2331,7 +2534,10 @@ class FMLearner(FeedLearner):
         ``dmlc_fit_dma_row_write_steps_total`` counts the steps whose
         lane rows went back by one DMA a row and not by XLA's scatter
         (:attr:`row_writer`: the platform the step's builder was handed
-        and the lanes it chose by)."""
+        and the lanes it chose by). ``dmlc_fit_run_sum_steps_total``
+        counts the steps that summed each id's entries by a segmented scan
+        along its sorted run and not by a scatter-add: those of the entry
+        counts whose trace recorded the scan (:attr:`id_sum`)."""
         shards = self.table_shards
         sparse = self.mesh is None or shards > 1
         reg.counter(
@@ -2386,6 +2592,13 @@ class FMLearner(FeedLearner):
             "optimizer steps that wrote each distinct lane row back by one "
             "DMA of the row, not by a scatter",
             model=self.name).inc(nstep if self.row_writer == "dma" else 0)
+        reg.counter(
+            "dmlc_fit_run_sum_steps_total",
+            "optimizer steps that summed each id's entries by a segmented "
+            "scan along its sorted run, not by a scatter-add",
+            model=self.name).inc(sum(
+                n for b, n in self._steps_of.items()
+                if self._id_sums.get(b) == "scan"))
         self._steps_of.clear()
         self._packed_steps = 0
 

@@ -75,3 +75,16 @@ def test_the_dlrm_steps_check_holds_at_the_rehearse_size():
     assert list(facts) == ["dlrm_step"]
     assert "2 steps of 1024 rows" in facts["dlrm_step"]
     assert "row writer scatter" in facts["dlrm_step"]
+
+
+def test_the_run_sums_check_holds_small_in_the_interpreter():
+    """``chip_smoke.run_sums`` on the CPU: the scan in Pallas' interpreter
+    against ``segment_sum`` over the table ids of 64 rows of the
+    ``criteo-dlrm`` configuration at its rehearse size; on the chip the
+    same at the cell's ``[212992,16]``, both timed."""
+    sys.path.insert(0, REPO)
+    import chip_smoke
+
+    facts = chip_smoke.run_sums(interpret=True, rows=64, calls=1)
+    assert list(facts) == ["run_sums"]
+    assert facts["run_sums"].startswith("[1664,16] over ")

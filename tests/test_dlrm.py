@@ -26,7 +26,7 @@ from jax.sharding import Mesh
 from dmlc_tpu import obs
 from dmlc_tpu.data import create_parser
 from dmlc_tpu.device import BatchSpec, DeviceFeed
-from dmlc_tpu.models import dlrm
+from dmlc_tpu.models import dlrm, fm
 from dmlc_tpu.models.dlrm import (
     DLRMLearner,
     dense_shapes,
@@ -388,11 +388,14 @@ def test_e_a_snapshot_restores_across_the_layouts(trained, cfg, data):
         _learner(cfg).restore_snapshot_model({"params": bad})
 
 
-def test_e_the_dma_row_writer_writes_what_the_scatter_writes(cfg, data):
+def test_e_the_dma_row_writer_writes_what_the_scatter_writes(
+        cfg, data, monkeypatch):
     """The step as a TPU builds it (``platform="tpu"``: the distinct lane
     rows go back by the DMA writer of models/fm.py), the kernel in
     Pallas' interpreter, beside the step that scatters: the same tree to
-    the bit after two steps."""
+    the bit after two steps. An id's entries are summed alike on both
+    sides (the scan a TPU sums them by is tests/test_fm_run_sums.py's)."""
+    monkeypatch.setattr(fm, "id_summer", lambda *_: "segment_sum")
     model = _learner(cfg)
     model.init_tables(4)
     steps = [make_dlrm_train_step(
@@ -638,6 +641,7 @@ def test_j_span_arguments_counters_and_gauge(cfg, data):
     assert grew("dmlc_fit_lane_row_steps_total") == steps
     assert grew("dmlc_fit_sparse_update_steps_total") == steps
     assert grew("dmlc_fit_dma_row_write_steps_total") == 0  # the CPU scatters
+    assert grew("dmlc_fit_run_sum_steps_total") == 0  # and segment_sums
     assert after["dmlc_fit_dense_param_bytes"] == 4 * model.dense_params
     epochs = [e for e in spans if e["name"] == "epoch" and e.get("ph") == "X"]
     args = epochs[-1]["args"]
@@ -648,6 +652,7 @@ def test_j_span_arguments_counters_and_gauge(cfg, data):
     assert (args["row_columns"], args["row_lanes"],
             args["ids_per_lane_row"]) == (K, 128, 128 // K)
     assert args["row_writer"] == "scatter"
+    assert args["id_sum"] == "segment_sum"
 
 
 def test_j_predict_is_the_forward_pass(trained, cfg, data):

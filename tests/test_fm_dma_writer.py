@@ -320,6 +320,9 @@ def test_b_only_a_learner_that_will_trace_the_kernel_imports_pallas(
 def test_c_steps_through_the_kernel_equal_the_scatters_to_the_bit(
         case, monkeypatch):
     monkeypatch.setattr(fm_module, "_UPDATE_CHUNK", CHUNK)
+    # the writer alone differs: an id's entries summed alike on both sides
+    # (the scan a TPU's SGD step sums by is tests/test_fm_run_sums.py's)
+    monkeypatch.setattr(fm_module, "id_summer", lambda *_: "segment_sum")
     optimizer, k = case.split("-")
     ours = theirs = _packed(optimizer, int(k))
     kernel = _fm_step(optimizer, platform="tpu", interpret=True)
@@ -387,11 +390,14 @@ def test_d_the_counter_and_the_span_argument(platform, tmp_path, monkeypatch):
     plain = FMLearner(**hyper)
     want = plain.fit_uri(path, batch_size=ROWS, epochs=2)
     if platform == "tpu":
-        # the tree reported on a TPU, the kernel in the interpreter
+        # the tree reported on a TPU, the kernel in the interpreter, an
+        # id's entries summed as on the CPU (the scan's counter and span
+        # argument are tests/test_fm_run_sums.py's)
         monkeypatch.setattr(FMLearner, "_params_platform", lambda _: "tpu")
         monkeypatch.setattr(
             fm_module, "make_fm_train_step",
             partial(make_fm_train_step, interpret=True))
+        monkeypatch.setattr(fm_module, "id_summer", lambda *_: "segment_sum")
     model = FMLearner(**hyper)
     spans = []
     obs_trace.add_listener(spans.append)

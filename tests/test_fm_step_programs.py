@@ -1,7 +1,8 @@
-"""The compiled step of every sparse-table learner the benchmark's older
-cells run (FM dense and with the difacto rule, field-aware FM, the
-memory-adaptive FM, the FM on a mesh), held to a digest of its lowered
-text at small sizes on the CPU.
+"""The compiled step of every sparse-table learner the benchmark's cells
+run (FM dense and with the difacto rule, field-aware FM, the
+memory-adaptive FM, the FM on a mesh, DLRM), held to a digest of its
+lowered text at small sizes on the CPU, the portable path every learner
+takes off a TPU.
 
 What it is for: a PR that touches ``models/fm.py`` or ``models/ffm.py``
 and means to leave these steps alone (PR 42 split two helpers there for a
@@ -28,13 +29,17 @@ import pytest
 from jax.sharding import Mesh
 
 from dmlc_tpu.models import AdaptiveFMLearner, FFMLearner, FMLearner
+from dmlc_tpu.models.dlrm import DLRMLearner
 
 #: the jax whose lowering the digests below are of
 PINNED_JAX = "0.9.0"
 
 #: sha256 of the lowered text of each step, by the cell that runs it; as
 #: they were at PR 41 (f7cb7a0) and are after PR 42
+#: (``criteo-dlrm``'s as commit 6e56501 lowers it)
 PROGRAMS = {
+    "criteo-dlrm":
+        "9f9d91438c393165d39356ac5e2cb664eafe3bab9931ff50622c07013fd83d49",
     "kdd12-ffm":
         "1879677400c087ddcea5790c15aa724b46ba50dd7b03ef1b09d4ceffd1e3ba9a",
     "kdd12-fm":
@@ -62,17 +67,29 @@ def _learner(name):
         return AdaptiveFMLearner(
             num_features=1003, num_factors=16, factor_capacity=64,
             count_rows=4096, **rule)
+    if name == "criteo-dlrm":
+        sizes = DLRM_TABLES
+        return DLRMLearner(
+            num_factors=8, field_sizes=sizes, mlp_bot=(13, 16, 8),
+            mlp_top=(8 + len(sizes) * (len(sizes) + 1) // 2, 8, 1))
     return FMLearner(
         mesh=Mesh(np.asarray(jax.devices()[:2]), ("dp",)),
         num_features=1003, num_factors=16, table_sharding="factors")
 
 
+#: the test-size DLRM's tables: 13 dense ids and these rows make 1 + 13 +
+#: 28 ids; a row of 16 entries (the step holds both of its layouts)
+DLRM_TABLES = (3, 5, 20)
+
+
 def _step_text(name) -> str:
     """The lowered text of the learner's own step over a batch of 8 rows."""
+    nnz = 3 if name != "criteo-dlrm" else 13 + len(DLRM_TABLES)
     batch = {
         "label": jnp.zeros((8,)), "weight": jnp.ones((8,)),
-        "indices": jnp.zeros((24,), jnp.int32), "values": jnp.ones((24,)),
-        "offsets": jnp.arange(9, dtype=jnp.int32) * 3}
+        "indices": jnp.zeros((8 * nnz,), jnp.int32),
+        "values": jnp.ones((8 * nnz,)),
+        "offsets": jnp.arange(9, dtype=jnp.int32) * nnz}
     model = _learner(name)
     model.init_tables(0)
     model._ensure(1003)
