@@ -847,7 +847,7 @@ def _bench_device_feed(path: str) -> dict:
                 out["csr_nnz_per_device_8shard"] = sharded.nnz_bucket
                 # shipped per entry: indices + values (8 B); the row
                 # mapping crosses H2D as per-shard CSR offsets (4 B/row),
-                # not per-entry row_ids (device/feed._put_csr)
+                # not per-entry row_ids (device/feed._to_device)
                 rows_local = batch_rows // shards
                 out["csr_h2d_bytes_per_device"] = (
                     sharded.nnz_bucket * 8 + (rows_local + 1) * 4

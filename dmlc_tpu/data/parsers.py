@@ -587,11 +587,14 @@ class NativePipelineParser:
             raise
 
     def read_batch_dense(self, batch_size: int, num_features: int):
-        """→ (x [batch,F] f32, labels, weights, valid_rows) or None at end
-        of stream. Short final batch is zero-padded (weight 0 rows)."""
+        """→ DenseBatch (x [batch,F] f32, labels, weights, num_rows) or None
+        at end of stream. Short final batch is zero-padded (weight 0)."""
+        from dmlc_tpu.device.csr import DenseBatch
+
         if self._stage(batch_size) is None:
             return None
-        return self._pipe.fetch_batch_dense(batch_size, num_features)
+        return DenseBatch(self._pipe.fetch_batch_dense(batch_size,
+                                                       num_features))
 
     def read_batch_coo(
         self, batch_size: int, nnz_bucket=None, nnz_floor: int = 256

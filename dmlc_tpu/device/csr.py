@@ -17,12 +17,13 @@ Policy here:
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, fields
 from typing import Optional
 
 import numpy as np
 
-from dmlc_tpu.data.row_block import RowBlock
+from dmlc_tpu import obs
+from dmlc_tpu.data.row_block import RowBlock, RowBlockContainer
 from dmlc_tpu.utils.logging import check
 
 
@@ -75,53 +76,74 @@ class DeviceCSRBatch:
         return len(self.indices)
 
 
-def _staging(pool, shape, dtype):
-    """A zeroed staging array: from the feed's FixedShapePool when given
-    (host-buffer reuse — the allocation retired, the zero-fill kept),
-    else a fresh np.zeros."""
+class DenseBatch(tuple):
+    """A static-shape dense batch ``(x [batch, F] f32, labels, weights,
+    num_rows)``: rows past ``num_rows`` are zero with weight 0."""
+
+    num_rows = property(lambda self: self[3])
+
+
+def _pieces(rows) -> list:
+    """``[(block, lo, hi)]``, rows lo:hi of each block in turn: a RowBlock
+    whole, or the pieces a :class:`BlockBatcher` cut a batch from."""
+    return [(rows, 0, len(rows))] if isinstance(rows, RowBlock) else rows
+
+
+def _joined(pieces) -> RowBlockContainer:
+    """The pieces' rows in one container (``to_block`` copies them)."""
+    out = RowBlockContainer()
+    for block, lo, hi in _pieces(pieces):
+        out.push_block(block.slice(lo, hi))
+    return out
+
+
+def _staging(pool, shape, dtype, fill=0):
+    """A staging array filled with ``fill``: from the feed's
+    FixedShapePool when given (host-buffer reuse — the allocation
+    retired, the fill kept), else a fresh np.full."""
     if pool is None:
-        return np.zeros(shape, dtype=dtype)
+        return np.full(shape, fill, dtype=dtype)
     buf = pool.acquire(shape, dtype)
-    buf.fill(0)
+    buf.fill(fill)
     return buf
 
 
 def pad_to_bucket(
-    block: RowBlock,
+    block,
     batch_size: int,
     nnz_bucket: Optional[int] = None,
     nnz_floor: int = 256,
     pool=None,
 ) -> DeviceCSRBatch:
-    """Pad a host RowBlock slice into a static-shape DeviceCSRBatch.
-    ``pool`` (device/feed.FixedShapePool) recycles the staging arrays."""
-    n = len(block)
+    """Pad a host RowBlock slice, or a batch's pieces, into a static-shape
+    DeviceCSRBatch. ``pool`` (device/feed.FixedShapePool) recycles the
+    staging arrays."""
+    pieces = _pieces(block)
+    n = sum(hi - lo for _, lo, hi in pieces)
     check(n <= batch_size, "block larger than batch_size")
-    nnz = block.num_nonzero
+    nnz = sum(int(b.offset[hi] - b.offset[lo]) for b, lo, hi in pieces)
     bucket = nnz_bucket if nnz_bucket is not None else round_up_bucket(nnz, nnz_floor)
     check(nnz <= bucket, "nnz exceeds bucket")
 
     labels = _staging(pool, batch_size, np.float32)
-    labels[:n] = block.label
     weights = _staging(pool, batch_size, np.float32)
-    weights[:n] = 1.0 if block.weight is None else block.weight
-
     indices = _staging(pool, bucket, np.int32)
     values = _staging(pool, bucket, np.float32)
     row_ids = _staging(pool, bucket, np.int32)
-    indices[:nnz] = block.index
-    values[:nnz] = (
-        np.ones(nnz, dtype=np.float32) if block.value is None else block.value
-    )
-    row_ids[:nnz] = np.repeat(
-        np.arange(n, dtype=np.int32), np.diff(block.offset).astype(np.int64)
-    )
-    if pool is None:
-        offsets = np.full(batch_size + 1, nnz, dtype=np.int32)
-    else:
-        offsets = pool.acquire(batch_size + 1, np.int32)
-        offsets.fill(nnz)
-    offsets[: n + 1] = np.asarray(block.offset[: n + 1], dtype=np.int32)
+    offsets = _staging(pool, batch_size + 1, np.int32, fill=nnz)
+    row = pos = 0
+    for b, lo, hi in pieces:
+        start, end = int(b.offset[lo]), int(b.offset[hi])
+        rows, ents = slice(row, row + hi - lo), slice(pos, pos + end - start)
+        labels[rows] = b.label[lo:hi]
+        weights[rows] = 1.0 if b.weight is None else b.weight[lo:hi]
+        indices[ents] = b.index[start:end]
+        values[ents] = 1.0 if b.value is None else b.value[start:end]
+        row_ids[ents] = np.repeat(
+            np.arange(row, rows.stop, dtype=np.int32),
+            np.diff(b.offset[lo:hi + 1]))
+        offsets[rows] = b.offset[lo:hi] - start + pos
+        row, pos = rows.stop, ents.stop
     return DeviceCSRBatch(
         labels=labels,
         weights=weights,
@@ -165,24 +187,28 @@ class ShardedCSRBatch:
 
 
 def pad_to_bucket_sharded(
-    block: RowBlock,
+    block,
     batch_size: int,
     num_shards: int,
     nnz_bucket: Optional[int] = None,
     nnz_floor: int = 256,
+    pool=None,
 ) -> ShardedCSRBatch:
-    """Partition a RowBlock's entries by destination shard (row-range
-    split) into per-shard padded sections — the pure-Python twin of
-    pipeline.cc FetchBatchCooSharded."""
+    """Partition a RowBlock's entries, or a batch's pieces' joined, by
+    destination shard (row-range split) into per-shard padded sections —
+    the pure-Python twin of pipeline.cc FetchBatchCooSharded. ``pool``
+    (device/feed.FixedShapePool) recycles the staging arrays."""
+    if not isinstance(block, RowBlock):
+        block = _joined(block).to_block()
     n = len(block)
     check(n <= batch_size, "block larger than batch_size")
     check(batch_size % num_shards == 0,
           "batch_size %d must divide over %d shards", batch_size, num_shards)
     rows_per_shard = batch_size // num_shards
 
-    labels = np.zeros(batch_size, dtype=np.float32)
+    labels = _staging(pool, batch_size, np.float32)
     labels[:n] = block.label
-    weights = np.zeros(batch_size, dtype=np.float32)
+    weights = _staging(pool, batch_size, np.float32)
     weights[:n] = 1.0 if block.weight is None else block.weight
 
     rows = np.repeat(
@@ -204,10 +230,10 @@ def pad_to_bucket_sharded(
     check(int(counts.max() if len(rows) else 0) <= bucket,
           "a shard's nnz exceeds the bucket")
 
-    indices = np.zeros(num_shards * bucket, dtype=np.int32)
-    values = np.zeros(num_shards * bucket, dtype=np.float32)
-    row_ids = np.zeros(num_shards * bucket, dtype=np.int32)
-    offsets = np.zeros(num_shards * (rows_per_shard + 1), dtype=np.int32)
+    indices = _staging(pool, num_shards * bucket, np.int32)
+    values = _staging(pool, num_shards * bucket, np.float32)
+    row_ids = _staging(pool, num_shards * bucket, np.int32)
+    offsets = _staging(pool, num_shards * (rows_per_shard + 1), np.int32)
     # entries arrive row-major, so each shard's entries are contiguous
     start = 0
     for s in range(num_shards):
@@ -239,25 +265,125 @@ def pad_to_bucket_sharded(
 
 
 def block_to_dense(
-    block: RowBlock, batch_size: int, num_features: int, pool=None
+    block, batch_size: int, num_features: int, pool=None
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Densify a RowBlock into fixed [batch, num_features] — the right layout
-    when the feature dim is small/dense (e.g. HIGGS's 28), letting the MXU do
-    a plain matmul instead of gather+segment-sum. ``pool``
-    (device/feed.FixedShapePool) recycles the staging arrays."""
-    n = len(block)
+    """Densify a RowBlock, or a batch's pieces, into fixed [batch,
+    num_features] — the right layout when the feature dim is small/dense
+    (e.g. HIGGS's 28), letting the MXU do a plain matmul instead of
+    gather+segment-sum. ``pool`` (device/feed.FixedShapePool) recycles
+    the staging arrays."""
+    pieces = _pieces(block)
+    n = sum(hi - lo for _, lo, hi in pieces)
     check(n <= batch_size, "block larger than batch_size")
     x = _staging(pool, (batch_size, num_features), np.float32)
-    rows = np.repeat(np.arange(n), np.diff(block.offset).astype(np.int64))
-    vals = (
-        np.ones(block.num_nonzero, dtype=np.float32)
-        if block.value is None
-        else block.value
-    )
-    keep = block.index < num_features
-    x[rows[keep], block.index[keep]] = vals[keep]
     labels = _staging(pool, batch_size, np.float32)
-    labels[:n] = block.label
     weights = _staging(pool, batch_size, np.float32)
-    weights[:n] = 1.0 if block.weight is None else block.weight
+    row = 0
+    for b, lo, hi in pieces:
+        start, end = int(b.offset[lo]), int(b.offset[hi])
+        rows = slice(row, row + hi - lo)
+        at = np.repeat(np.arange(row, rows.stop), np.diff(b.offset[lo:hi + 1]))
+        index = b.index[start:end]
+        keep = index < num_features
+        x[at[keep], index[keep]] = (
+            1.0 if b.value is None else b.value[start:end][keep])
+        labels[rows] = b.label[lo:hi]
+        weights[rows] = 1.0 if b.weight is None else b.weight[lo:hi]
+        row = rows.stop
     return x, labels, weights
+
+
+class BlockBatcher:
+    """The batch fetch of a parser that has no native one: its RowBlocks
+    cut into batches, each copied once from the blocks into the pool's
+    buffers on the calling thread (the feed's producer), under the
+    ``stage`` span. The reads are NativePipelineParser's, with its
+    signatures and batches. Each batch also carries ``flow_ids`` and
+    ``seq_ids`` (the chunks whose rows first appear in it, closed and
+    acked on delivery) and ``staging`` (the pooled buffers the consumer
+    retires). Under ``drop_remainder`` a short last batch is dropped and
+    the chunks only it reached are acked here."""
+
+    def __init__(self, parser, pool, stage_hist, drop_remainder: bool,
+                 audit, ack=None):
+        self.parser = parser
+        self._pool = pool
+        self._hist = stage_hist
+        self._drop_remainder = drop_remainder
+        self._audit = audit
+        self._ack = ack
+        self.restart(0)
+
+    def restart(self, npass: int) -> None:
+        """Begin pass ``npass`` of the parser, which is at its start."""
+        self._blocks = iter(self.parser)
+        self._head = None  # (block, its first row not yet batched)
+        self._flows, self._seqs = [], []
+        self._pass, self._batch = npass, 0
+
+    def _take(self, batch_size: int):
+        """(pieces ``[(block, lo, hi)]``, rows) of the next batch, short
+        only at the end of the pass; None where no batch is left."""
+        pieces, rows = [], 0
+        while rows < batch_size:
+            if self._head is None:
+                block = next(self._blocks, None)
+                if block is None:
+                    break
+                if getattr(block, "flow_id", 0):
+                    self._flows.append(block.flow_id)
+                if getattr(block, "seq_id", None) is not None:
+                    self._seqs.append(block.seq_id)
+                self._head = (block, 0)
+            block, lo = self._head
+            hi = min(len(block), lo + batch_size - rows)
+            pieces.append((block, lo, hi))
+            rows += hi - lo
+            self._head = None if hi == len(block) else (block, hi)
+        if rows == batch_size or (rows and not self._drop_remainder):
+            return pieces, rows
+        if self._ack is not None:
+            # chunks only a dropped remainder reached (or empty ones) were
+            # visited: unacked, the dispatcher would requeue them forever
+            for sid in self._seqs:
+                self._ack(sid)
+        self._seqs = []
+        return None
+
+    def _stage(self, batch_size: int, make):
+        """``make(pieces, rows)`` → the next batch, staged into the pool;
+        None at the end of the pass."""
+        taken = self._take(batch_size)
+        if taken is None:
+            return None
+        pieces, rows = taken
+        if self._audit.enabled:
+            self._audit.note_batch(self._batch, _joined(pieces))
+        with obs.span("stage", hist=self._hist, rows=rows, pass_=self._pass,
+                      batch=self._batch):
+            for fid in self._flows:
+                obs.flow_step(fid, "chunk")
+            batch = make(pieces, rows)
+        arrays = batch if isinstance(batch, DenseBatch) else [
+            getattr(batch, f.name) for f in fields(batch)]
+        batch.staging = tuple(a for a in arrays if isinstance(a, np.ndarray))
+        batch.flow_ids, batch.seq_ids = tuple(self._flows), tuple(self._seqs)
+        self._flows, self._seqs = [], []
+        self._batch += 1
+        return batch
+
+    def read_batch_dense(self, batch_size: int, num_features: int):
+        return self._stage(batch_size, lambda pieces, rows: DenseBatch(
+            block_to_dense(pieces, batch_size, num_features, pool=self._pool)
+            + (rows,)))
+
+    def read_batch_coo(self, batch_size: int, nnz_bucket=None,
+                       nnz_floor: int = 256):
+        return self._stage(batch_size, lambda pieces, rows: pad_to_bucket(
+            pieces, batch_size, nnz_bucket, nnz_floor, pool=self._pool))
+
+    def read_batch_coo_sharded(self, batch_size: int, num_shards: int,
+                               nnz_bucket=None, nnz_floor: int = 256):
+        return self._stage(batch_size, lambda pieces, rows: (
+            pad_to_bucket_sharded(pieces, batch_size, num_shards,
+                                  nnz_bucket, nnz_floor, pool=self._pool)))

@@ -9,7 +9,7 @@ and keeps ``spec.prefetch`` batches in flight (default 1 — the classic
 double-buffer; deeper windows pin more HBM but hide per-batch dispatch/DMA
 latency) so H2D DMA overlaps both host parsing and the previous step's
 compute. ``host_prefetch`` separately bounds the host-side ThreadedIter
-queue of parsed-but-undispatched blocks. At the end of a pass the producer
+queue of staged-but-undispatched batches. At the end of a pass the producer
 thread rewinds the parser itself and stages the next pass's first batches
 while the device drains this one's last steps, so ``before_first`` after a
 whole pass has only its bookkeeping left (docs/pipeline.md, "Restarting a
@@ -25,6 +25,7 @@ Batch layouts:
 
 from __future__ import annotations
 
+import functools
 import itertools
 import os
 import threading
@@ -40,14 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from dmlc_tpu import obs
 from dmlc_tpu.obs import audit, device_telemetry, flight
 from dmlc_tpu.data.parsers import Parser, ThreadedParser, create_parser
-from dmlc_tpu.data.row_block import RowBlockContainer
-from dmlc_tpu.device.csr import (
-    DeviceCSRBatch,
-    ShardedCSRBatch,
-    block_to_dense,
-    pad_to_bucket,
-    pad_to_bucket_sharded,
-)
+from dmlc_tpu.device.csr import BlockBatcher, DenseBatch, ShardedCSRBatch
 from dmlc_tpu.params.knobs import default_host_prefetch, default_prefetch
 from dmlc_tpu.utils.logging import check
 from dmlc_tpu.utils.threaded_iter import ThreadedIter
@@ -161,6 +155,7 @@ class FixedShapePool:
 
     def __init__(self, recycle: bool = True):
         self.recycle = recycle
+        self._lock = threading.Lock()  # producer acquires, consumer retires
         self._free: dict = {}  # key -> [np.ndarray]
         pid = "p%d" % next(_POOL_IDS)
         reg = obs.registry()
@@ -195,23 +190,25 @@ class FixedShapePool:
 
     @property
     def shape_keys(self) -> frozenset:
-        return frozenset(self._shapes)
+        with self._lock:
+            return frozenset(self._shapes)
 
     def acquire(self, shape, dtype) -> np.ndarray:
         key = self._key(shape, dtype)
-        self._shapes.add(key)
-        if self.recycle:
-            self._acquires += 1
-            if self._acquires % self.LEAK_CHECK_EVERY == 0:
-                self._leak_check()
-            free = self._free.get(key)
-            if free:
-                buf = free.pop()
-                self._pooled_ids.discard(id(buf))
-                self.reused += 1
-                self._m_reused.inc()
-                return buf
-        self.allocated += 1
+        with self._lock:
+            self._shapes.add(key)
+            if self.recycle:
+                self._acquires += 1
+                if self._acquires % self.LEAK_CHECK_EVERY == 0:
+                    self._leak_check()
+                free = self._free.get(key)
+                if free:
+                    buf = free.pop()
+                    self._pooled_ids.discard(id(buf))
+                    self.reused += 1
+                    self._m_reused.inc()
+                    return buf
+            self.allocated += 1
         self._m_allocated.inc()
         return np.empty(key[0], dtype=dtype)
 
@@ -234,19 +231,21 @@ class FixedShapePool:
         in-flight batch."""
         if not self.recycle:
             return
-        if not all(g.is_ready() for g in guards):
-            self.dropped += len(bufs)
-            return
-        for buf in bufs:
-            bid = id(buf)
-            if bid in self._pooled_ids:
-                self.double_retired += 1
-                continue
-            self._pooled_ids.add(bid)
-            self.retired += 1
-            self._free.setdefault(
-                self._key(buf.shape, buf.dtype), []
-            ).append(buf)
+        landed = all(g.is_ready() for g in guards)
+        with self._lock:
+            if not landed:
+                self.dropped += len(bufs)
+                return
+            for buf in bufs:
+                bid = id(buf)
+                if bid in self._pooled_ids:
+                    self.double_retired += 1
+                    continue
+                self._pooled_ids.add(bid)
+                self.retired += 1
+                self._free.setdefault(
+                    self._key(buf.shape, buf.dtype), []
+                ).append(buf)
 
     def _leak_check(self) -> None:
         """Fire one ``pool.leak`` flight event when the outstanding buffer
@@ -272,15 +271,16 @@ class FixedShapePool:
             self._leak_strikes = 0
 
     def stats(self) -> dict:
-        return {
-            "shapes": len(self._shapes),
-            "allocated": self.allocated,
-            "reused": self.reused,
-            "retired": self.retired,
-            "dropped": self.dropped,
-            "double_retired": self.double_retired,
-            "outstanding": self.outstanding,
-        }
+        with self._lock:
+            return {
+                "shapes": len(self._shapes),
+                "allocated": self.allocated,
+                "reused": self.reused,
+                "retired": self.retired,
+                "dropped": self.dropped,
+                "double_retired": self.double_retired,
+                "outstanding": self.outstanding,
+            }
 
 
 def stall_breakdown(stats: dict) -> str:
@@ -321,9 +321,9 @@ def stall_breakdown(stats: dict) -> str:
 
 
 class _HostStage:
-    """The feed's host side: the parser's blocks re-batched into fixed-size
-    host batches, on the producer thread (inline with ``host_prefetch=0``),
-    and the parser's rewind at the end of a pass.
+    """The feed's host side: fixed-shape batches from ``reader`` (the
+    parser's batch fetch, or a BlockBatcher's) on the producer thread
+    (inline with ``host_prefetch=0``), and the parser's end-of-pass rewind.
 
     Held by the feed and by the producer thread, and holds no reference to
     the feed: a feed that is dropped is collected, and its ThreadedIter
@@ -333,7 +333,7 @@ class _HostStage:
     _FACTS = ("pipeline", "bytes_read", "plan", "host_batch_ns")
 
     def __init__(self, parser, spec: BatchSpec, shards: int, host_batch_ns,
-                 cpu):
+                 cpu, pool, stage_hist):
         self.parser = parser
         self.spec = spec
         self.shards = shards
@@ -365,6 +365,11 @@ class _HostStage:
         # ``produce`` span: the producer's own rewind moves it on, and the
         # feed sets it where it restarts the producer itself
         self.pass_ = 0
+        self.reader = parser
+        if not getattr(parser, "supports_batch_fetch", False):
+            self.reader = BlockBatcher(
+                parser, pool, stage_hist, spec.drop_remainder, self.audit,
+                self.ack_seq if self.ack else None)
 
     @property
     def rewinds_itself(self) -> bool:
@@ -374,15 +379,6 @@ class _HostStage:
         armed audit (its batch chain is keyed per epoch and closes at the
         boundary the consumer draws)."""
         return self.ack is None and not self.audit.enabled
-
-    def use_native(self) -> bool:
-        """Native C++ re-batch + densify/COO-pad (pipeline.cc StageBatch):
-        no RowBlockContainer copies, no numpy scatter — the feed-side answer
-        to the parse-vs-feed throughput cliff (BASELINE.md)."""
-        return (
-            getattr(self.parser, "supports_batch_fetch", False)
-            and self.spec.layout in ("dense", "csr")
-        )
 
     # ---- the end of a pass ----------------------------------------------
     def _live(self, fact):
@@ -419,17 +415,30 @@ class _HostStage:
             self.pass_ += 1
         return True
 
-    # ---- re-batch parser blocks into fixed-size slices ------------------
+    # ---- fixed-shape batches --------------------------------------------
     def _host_batches(self) -> Iterator:
         from dmlc_tpu.resilience import faultpoint
 
-        if self.use_native():
-            producer = self._host_batches_native()
+        spec, reader, npass = self.spec, self.reader, self.pass_
+        bs = spec.batch_size
+        if reader is not self.parser:
+            reader.restart(npass)
+        if spec.layout == "dense":
+            check(spec.num_features > 0, "dense layout requires num_features")
+            read = functools.partial(
+                reader.read_batch_dense, bs, spec.num_features)
+        elif spec.layout != "csr":
+            raise ValueError(f"unknown layout {spec.layout!r}")
+        elif self.shards > 1:
+            # mesh csr: entries partitioned per shard on the host so each
+            # device receives only its own nnz
+            read = functools.partial(reader.read_batch_coo_sharded, bs,
+                                     self.shards, nnz_bucket=spec.nnz_bucket)
         else:
-            producer = self._host_batches_python()
+            read = functools.partial(reader.read_batch_coo, bs,
+                                     nnz_bucket=spec.nnz_bucket)
         cpu = self.cpu
         cpu_at = time.thread_time_ns()
-        npass = self.pass_
         for nbatch in itertools.count():
             faultpoint("device.feed")
             # the batch's life begins here, under the identifier every
@@ -439,93 +448,15 @@ class _HostStage:
             with obs.span("produce", hist=self.host_batch_ns, pass_=npass,
                           batch=nbatch):
                 try:
-                    item = next(producer)
-                except StopIteration:
-                    return
+                    batch = read()
                 finally:
                     if cpu is not None:
                         now = time.thread_time_ns()
                         cpu.observe(now - cpu_at)
                         cpu_at = now
-            yield item
-
-    def _host_batches_python(self) -> Iterator:
-        bs = self.spec.batch_size
-        bidx = 0  # per-epoch batch index (audit batch-chain key)
-        pending = RowBlockContainer()
-        # flow ids (and dispatcher chunk seq ids) of parser chunks not yet
-        # represented in an emitted batch; rebatching is N:M, so each
-        # chunk's ids ride the first slice it contributes rows to
-        flows = []
-        seqs = []
-        for block in self.parser:
-            fid = getattr(block, "flow_id", 0)
-            if fid:
-                flows.append(fid)
-            sid = getattr(block, "seq_id", None)
-            if sid is not None:
-                seqs.append(sid)
-            pending.push_block(block)
-            if len(pending) < bs:
-                continue
-            # Finalize once, emit every full slice, keep only the tail.
-            whole = pending.to_block()
-            nfull = len(whole) // bs
-            for k in range(nfull):
-                piece = whole.slice(k * bs, (k + 1) * bs)
-                if flows:
-                    piece.flow_ids = tuple(flows)
-                    flows = []
-                if seqs:
-                    piece.seq_ids = tuple(seqs)
-                    seqs = []
-                self.audit.note_batch(bidx, piece)
-                bidx += 1
-                yield piece
-            pending = RowBlockContainer()
-            if len(whole) > nfull * bs:
-                pending.push_block(whole.slice(nfull * bs, len(whole)))
-        if len(pending) and not self.spec.drop_remainder:
-            tail = pending.to_block()
-            if flows:
-                tail.flow_ids = tuple(flows)
-            if seqs:
-                tail.seq_ids = tuple(seqs)
-                seqs = []
-            self.audit.note_batch(bidx, tail)
-            yield tail
-        if seqs and self.ack is not None:
-            # chunks whose rows only ever reached a dropped remainder (or
-            # an empty chunk) still count as visited — ack them here or
-            # the dispatcher would requeue them forever
-            for sid in seqs:
-                self.ack_seq(sid)
-
-    def _host_batches_native(self) -> Iterator:
-        spec = self.spec
-        bs = spec.batch_size
-        shards = self.shards
-        while True:
-            if spec.layout == "dense":
-                check(spec.num_features > 0,
-                      "dense layout requires num_features")
-                out = self.parser.read_batch_dense(bs, spec.num_features)
-            elif shards > 1:
-                # mesh csr: entries partitioned per shard on the host so
-                # each device receives only its own nnz
-                out = self.parser.read_batch_coo_sharded(
-                    bs, shards, nnz_bucket=spec.nnz_bucket
-                )
-            else:
-                out = self.parser.read_batch_coo(
-                    bs, nnz_bucket=spec.nnz_bucket
-                )
-            if out is None:
+            if batch is None or (batch.num_rows < bs and spec.drop_remainder):
                 return
-            rows = out[3] if spec.layout == "dense" else out.num_rows
-            if rows < bs and spec.drop_remainder:
-                return
-            yield out
+            yield batch
 
     def ack_seq(self, sid) -> None:
         """Report one dispatcher chunk consumed; best-effort — a dead
@@ -599,7 +530,8 @@ class DeviceFeed:
         # cpu backend may alias numpy buffers zero-copy through the jit
         # boundary (_put_tree), where reuse would rewrite delivered
         # batches — there the pool only does shape accounting
-        self.pool = FixedShapePool(recycle=jax.default_backend() != "cpu")
+        self.pool = pool = FixedShapePool(
+            recycle=jax.default_backend() != "cpu")
         # per-stage wall time (SURVEY §5.1: "where does feed time go?")
         # lives in the obs registry as per-batch histograms; the host stage
         # observes on the ThreadedIter thread, the rest on the consuming
@@ -622,12 +554,12 @@ class DeviceFeed:
                 "dmlc_feed_consume_ns",
                 "per-batch time the consumer held the batch", feed=fid),
         }
-        # the parts of a batch on the consumer thread that stats() does
-        # not window: each is the ``hist=`` of the span of its name
+        # the parts of a batch that stats() does not window: each is the
+        # ``hist=`` of the span of its name
         self._h_stage = reg.histogram(
             "dmlc_feed_stage_ns",
-            "per-batch padding / densifying on the consumer thread (the "
-            "Python re-batch producer's batches only)", feed=fid)
+            "per-batch padding / densifying on the producer thread (the "
+            "batches a BlockBatcher stages only)", feed=fid)
         self._h_put = reg.histogram(
             "dmlc_feed_put_ns",
             "per-batch device_put submission alone", feed=fid)
@@ -682,7 +614,8 @@ class DeviceFeed:
         self._sync_host = host_prefetch <= 0
         self._host = host = _HostStage(
             self._parser, spec, self._shards, self._stage["host_batch_ns"],
-            None if self._sync_host else self._m_cpu["feed_producer"])
+            None if self._sync_host else self._m_cpu["feed_producer"],
+            self.pool, self._h_stage)
         if self._sync_host:
             # synchronous host stage: on a 1-core host the prefetch
             # thread cannot overlap anything and only adds context
@@ -694,10 +627,9 @@ class DeviceFeed:
                 host._host_batches, max_capacity=host_prefetch,
                 name="device-feed",
                 rewind=host.rewind if host.rewinds_itself else None,
+                # a batch staged and never taken: its buffers were not read
+                discard=lambda b: pool.retire(getattr(b, "staging", ()), ()),
             )
-
-    def _use_native_batches(self) -> bool:
-        return self._host.use_native()
 
     def _axis_shards(self) -> int:
         """How many shard sections THIS process builds along the batch
@@ -738,15 +670,13 @@ class DeviceFeed:
 
     def _put_tree_raw(self, arrays: dict, specs: dict) -> dict:
         if self._mesh is None:
-            if jax.default_backend() == "cpu" and \
-                    os.environ.get("DMLC_TPU_FEED_PUT") != "1":
+            if jax.default_backend() == "cpu":
                 # CPU single-device: the jit boundary performs the
                 # (aligned, possibly zero-copy) ingest itself — an eager
                 # device_put is one extra full copy on the same core the
                 # parse/densify pipeline runs on (measured ~15% of the
                 # recordio->SGD epoch). On an accelerator the eager put
                 # IS the async H2D overlap, so only cpu skips.
-                # DMLC_TPU_FEED_PUT=1 restores the put for A/B.
                 return arrays
             self._m_dispatches.inc()
             return jax.device_put(arrays)
@@ -829,95 +759,31 @@ class DeviceFeed:
                 for k, v in arrays.items()
             }
 
-    def _to_device(self, block, flows=(), nbatch=0):
-        """→ (device batch, staging buffers to retire — () when the host
-        arrays came from the native pipeline or no pooled path).
-        ``flows``: flow ids of the chunks in ``block`` — stepped inside
-        the ``stage`` span so the pool staging slice joins the arrow
-        chain (python paths only; native batches carry no flows).
-        ``nbatch``: the batch's place in its pass, for that span."""
-        spec = self.spec
-        if isinstance(block, tuple):  # native dense batch, pre-densified
-            x, labels, weights, rows = block
-            out = self._put_tree(
-                {"x": x, "label": labels, "weight": weights},
-                {"x": P(self._axis), "label": P(self._axis),
-                 "weight": P(self._axis)},
-                nbatch,
-            )
-            out["num_rows"] = rows
-            return out, ()
-        if isinstance(block, (DeviceCSRBatch, ShardedCSRBatch)):
-            # native COO batch: staged in C++, no pooled staging to retire
-            return self._put_csr(block, nbatch), ()
-        if spec.layout == "dense":
-            check(spec.num_features > 0, "dense layout requires num_features")
-            with obs.span("stage", hist=self._h_stage, rows=len(block),
-                          pass_=self._pass, batch=nbatch):
-                for fid in flows:
-                    obs.flow_step(fid, "chunk")
-                x, labels, weights = block_to_dense(
-                    block, spec.batch_size, spec.num_features, pool=self.pool
-                )
-            out = self._put_tree(
-                {"x": x, "label": labels, "weight": weights},
-                {"x": P(self._axis), "label": P(self._axis),
-                 "weight": P(self._axis)},
-                nbatch,
-            )
-            out["num_rows"] = len(block)
-            return out, (x, labels, weights)
-        if spec.layout == "csr":
-            shards = self._shards
-            with obs.span("stage", hist=self._h_stage, rows=len(block),
-                          pass_=self._pass, batch=nbatch):
-                for fid in flows:
-                    obs.flow_step(fid, "chunk")
-                if shards > 1:
-                    batch = pad_to_bucket_sharded(
-                        block, spec.batch_size, shards,
-                        nnz_bucket=spec.nnz_bucket,
-                    )
-                    bufs = ()
-                else:
-                    batch = pad_to_bucket(
-                        block, spec.batch_size, nnz_bucket=spec.nnz_bucket,
-                        pool=self.pool,
-                    )
-                    bufs = (batch.labels, batch.weights, batch.indices,
-                            batch.values, batch.row_ids, batch.offsets)
-            return self._put_csr(batch, nbatch), bufs
-        raise ValueError(f"unknown layout {spec.layout!r}")
-
-    def _put_csr(self, batch, nbatch: int = 0):
-        # ShardedCSRBatch: per-shard entry sections — P(axis) on the flat
-        # entry arrays ships each device only its own nnz (H2D ∝
-        # global_nnz / world). DeviceCSRBatch (no mesh / single shard):
-        # entries replicated. Either way the row mapping crosses H2D as
-        # the small CSR ``offsets`` array (∝ rows), NOT the per-entry
-        # ``row_ids`` (∝ nnz); the train step expands row ids on device
-        # (ops.spmv.expand_row_ids) where the cumsum is effectively free.
-        sharded = isinstance(batch, ShardedCSRBatch)
-        entry_spec = P(self._axis) if sharded else P()
-        out = self._put_tree(
-            {
-                "label": batch.labels,
-                "weight": batch.weights,
-                "indices": batch.indices,
-                "values": batch.values,
-                "offsets": batch.offsets,
-            },
-            {
-                "label": P(self._axis),
-                "weight": P(self._axis),
-                "indices": entry_spec,
-                "values": entry_spec,
-                "offsets": entry_spec,
-            },
-            nbatch,
-        )
-        out["num_rows"] = batch.num_rows
-        out["num_nonzero"] = batch.num_nonzero
+    def _to_device(self, batch, nbatch: int = 0) -> dict:
+        """The host batch in one transfer → its device arrays, with its row
+        count (and a CSR batch's entry count). ``nbatch``: the batch's
+        place in its pass, for the ``put`` span. A CSR batch's row mapping
+        crosses as the small ``offsets`` (∝ rows), NOT the per-entry
+        ``row_ids`` (∝ nnz): the step expands row ids on device
+        (ops.spmv.expand_row_ids). A ShardedCSRBatch's entry arrays go
+        P(axis), each device receiving its own nnz only (H2D ∝
+        global_nnz / world); a DeviceCSRBatch's are replicated."""
+        rows = P(self._axis)
+        if isinstance(batch, DenseBatch):
+            arrays = {"x": batch[0], "label": batch[1], "weight": batch[2]}
+            specs = dict.fromkeys(arrays, rows)
+            counts = {"num_rows": batch.num_rows}
+        else:
+            arrays = {"label": batch.labels, "weight": batch.weights,
+                      "indices": batch.indices, "values": batch.values,
+                      "offsets": batch.offsets}
+            entries = rows if isinstance(batch, ShardedCSRBatch) else P()
+            specs = {"label": rows, "weight": rows, "indices": entries,
+                     "values": entries, "offsets": entries}
+            counts = {"num_rows": batch.num_rows,
+                      "num_nonzero": batch.num_nonzero}
+        out = self._put_tree(arrays, specs, nbatch)
+        out.update(counts)
         return out
 
     def _deliver(self, entry):
@@ -1004,29 +870,25 @@ class DeviceFeed:
                     # the wait for the producer's queue
                     with obs.span("take", hist=wait_hist, pass_=npass,
                                   batch=nbatch):
-                        block = next(it)
+                        host = next(it)
                 except StopIteration:
                     break
-                flows = getattr(block, "flow_ids", ())
-                seqs = getattr(block, "seq_ids", ())
+                # a BlockBatcher's batch carries its chunks' ids and its
+                # pooled buffers, the native stager's neither
+                flows = getattr(host, "flow_ids", ())
+                seqs = getattr(host, "seq_ids", ())
                 with obs.span("dispatch", hist=stage["dispatch_ns"],
                               pass_=npass, batch=nbatch):
                     for fid in flows:
                         obs.flow_step(fid, "chunk")
-                    batch_bufs = self._to_device(block, flows, nbatch)
-                    # async dispatch; the entry keeps the chunk ids so
-                    # _consume can close flows and ack seqs on delivery,
+                    # async dispatch; the entry keeps the buffers to retire,
+                    # the chunk ids _consume closes and acks on delivery,
                     # and the batch's number for its consume span
-                    pending.append(batch_bufs + (flows, seqs, nbatch))
+                    pending.append((self._to_device(host, nbatch),
+                                    getattr(host, "staging", ()), flows,
+                                    seqs, nbatch))
                 self._m_batches.inc()
-                # row accounting across block shapes: native dense tuple
-                # carries its count at [3], padded batches as num_rows,
-                # python RowBlocks via len()
-                if isinstance(block, tuple):
-                    self._m_rows.inc(int(block[3]))
-                else:
-                    self._m_rows.inc(
-                        int(getattr(block, "num_rows", 0) or len(block)))
+                self._m_rows.inc(int(host.num_rows))
                 nbatch += 1
             if len(pending) > window:
                 yield from _consume(pending.popleft())

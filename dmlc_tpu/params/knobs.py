@@ -640,7 +640,6 @@ KNOWN_KNOBS = (
     "DMLC_TPU_HOST_PREFETCH",
     "DMLC_TPU_READAHEAD_MB",
     "DMLC_TPU_READAHEAD_CONNS",
-    "DMLC_TPU_FEED_PUT",
     # vectorized parse path
     "DMLC_TPU_PARSE_BACKEND",
     "DMLC_TPU_PARSE_PROCS",

@@ -41,8 +41,8 @@ class _Passes:
         self.ahead = 0
 
 
-def _produce(make_iter, rewind, q: "queue.Queue", stop: threading.Event,
-             passes: _Passes) -> None:
+def _produce(make_iter, rewind, discard, q: "queue.Queue",
+             stop: threading.Event, passes: _Passes) -> None:
     """The producer thread's body. A function of its arguments and no
     method: the thread holds no reference to its ThreadedIter, so an
     iterator whose owner is dropped is collected and stops its thread."""
@@ -60,6 +60,7 @@ def _produce(make_iter, rewind, q: "queue.Queue", stop: threading.Event,
         while True:
             for item in make_iter():
                 if not put(item):
+                    discard(item)
                     return
             again = False
             if rewind is not None:
@@ -90,6 +91,8 @@ class ThreadedIter(Generic[T]):
     Iteration still ends at the mark; :meth:`advance` then begins the
     pass that is already staged. A ``rewind`` that returns false ends the
     producer as if none were given.
+    ``discard`` gets each item made and never taken: where the producer
+    is stopped, and the rest of a pass :meth:`advance` leaves.
     """
 
     def __init__(
@@ -98,9 +101,11 @@ class ThreadedIter(Generic[T]):
         max_capacity: int = 8,
         name: str = "threaded-iter",
         rewind: Optional[Callable[[], bool]] = None,
+        discard: Callable[[T], None] = lambda item: None,
     ):
         self._make_iter = make_iter
         self._rewind = rewind
+        self._discard = discard
         self._cap = max_capacity
         self._name = name
         self._thread: Optional[threading.Thread] = None
@@ -113,14 +118,18 @@ class ThreadedIter(Generic[T]):
     def _shutdown_producer(self) -> None:
         if self._thread is not None:
             self._stop.set()
-            # Drain so a blocked put() notices the stop flag promptly.
-            try:
-                while True:
-                    self._queue.get_nowait()
-            except queue.Empty:
-                pass
+            # Drain so a blocked put() notices the stop flag promptly, and
+            # again for what a put already under way left
+            self._drain()
             self._thread.join()
+            self._drain()
             self._thread = None
+
+    def _drain(self) -> None:
+        while not self._queue.empty():  # the producer only ever adds
+            item = self._queue.get_nowait()
+            if item is not _END and not isinstance(item, _Exc):
+                self._discard(item)
 
     # ---- consumer API --------------------------------------------------
     def before_first(self) -> None:
@@ -132,8 +141,8 @@ class ThreadedIter(Generic[T]):
         self._finished = False
         self._thread = threading.Thread(
             target=_produce,
-            args=(self._make_iter, self._rewind, self._queue, self._stop,
-                  self._passes),
+            args=(self._make_iter, self._rewind, self._discard, self._queue,
+                  self._stop, self._passes),
             name=self._name,
             daemon=True,
         )
@@ -154,8 +163,8 @@ class ThreadedIter(Generic[T]):
         if not wound:
             self._shutdown_producer()
             return False
-        while not self._finished:
-            self.next()
+        while (item := self.next()) is not None:
+            self._discard(item)
         self._finished = False
         return True
 

@@ -7,6 +7,8 @@ normally win the create_parser routing.
 """
 
 import gc
+import queue
+import sys
 import threading
 import time
 
@@ -16,6 +18,7 @@ import pytest
 
 from dmlc_tpu.data.parsers import LibSVMParser
 from dmlc_tpu.data.pipeline import PipelinedParser
+from dmlc_tpu.device.csr import BlockBatcher
 from dmlc_tpu.device.feed import (
     BatchSpec,
     DeviceFeed,
@@ -24,6 +27,7 @@ from dmlc_tpu.device.feed import (
 )
 from dmlc_tpu.io.input_split import create_input_split
 from dmlc_tpu.io.readahead import OrderedWindow
+from dmlc_tpu.obs import audit
 from dmlc_tpu.obs import trace as obs_trace
 from dmlc_tpu.params.knobs import (
     default_host_prefetch,
@@ -234,10 +238,11 @@ class TestPythonProducerPuts:
         """The whole pytree crosses in ONE device_put per batch —
         dispatches/batch > 1 is the per-array regression the sentry
         gates (dmlc_feed_h2d_dispatches_total)."""
-        # on the cpu backend the eager put is skipped unless forced
-        monkeypatch.setenv("DMLC_TPU_FEED_PUT", "1")
         spec = BatchSpec(batch_size=512, layout="csr", num_features=40)
         feed = DeviceFeed(_base_parser(svm_path), spec, host_prefetch=0)
+        # on the cpu backend the eager put is skipped: the feed puts as
+        # on an accelerator once it is built (its pool does not recycle)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         batches = sum(1 for _ in feed)
         assert batches > 0
         assert feed._m_dispatches.value == batches
@@ -272,6 +277,98 @@ class TestPythonProducerPuts:
             assert got[k].sharding == ref.sharding
             assert np.array_equal(np.asarray(got[k]), np.asarray(ref))
         feed.close()
+
+
+def _blocks(rows=(7, 5, 9, 4, 6, 8), seed=3):
+    """RowBlocks of the given row counts; the third has no values, the
+    fourth no weights."""
+    from dmlc_tpu.data.row_block import RowBlock
+
+    rng = np.random.RandomState(seed)
+    out = []
+    for k, n in enumerate(rows):
+        counts = rng.randint(0, 6, size=n)
+        nnz = int(counts.sum())
+        out.append(RowBlock(
+            offset=np.concatenate([[0], np.cumsum(counts)]),
+            label=rng.rand(n).astype(np.float32),
+            index=rng.randint(0, 40, size=nnz).astype(np.uint32),
+            value=None if k == 2 else rng.rand(nnz).astype(np.float32),
+            weight=None if k == 3 else rng.rand(n).astype(np.float32)))
+    return out
+
+
+def _staged_bytes(out):
+    arrays = out if isinstance(out, tuple) else [
+        v for v in vars(out).values() if isinstance(v, np.ndarray)]
+    meta = () if isinstance(out, tuple) else (out.num_rows, out.num_nonzero)
+    return [(a.dtype.str, a.shape, a.tobytes()) for a in arrays], meta
+
+
+class TestStagingFromPieces:
+    """A batch staged from its pieces — rows of a run of blocks, the first
+    and the last cut — is the batch staged from one block of the same
+    rows, byte for byte."""
+
+    @pytest.mark.parametrize("npieces", [1, 2, 5])
+    @pytest.mark.parametrize("layout", ["dense", "csr", "csr-sharded"])
+    def test_pieces_stage_as_their_concatenation(self, layout, npieces):
+        from dmlc_tpu.data.row_block import RowBlockContainer
+        from dmlc_tpu.device.csr import (
+            block_to_dense, pad_to_bucket, pad_to_bucket_sharded)
+
+        blocks = _blocks()
+        # two batches in a row: the first ends inside block npieces - 1,
+        # which the second begins with
+        cut = 2
+        first = [(blocks[0], 2, cut + 3 if npieces == 1 else len(blocks[0]))]
+        first += [(b, 0, len(b)) for b in blocks[1:npieces - 1]]
+        if npieces > 1:
+            first.append((blocks[npieces - 1], 0, cut))
+        last_block, split = first[-1][0], first[-1][2]
+        second = [(last_block, split, len(last_block)),
+                  (blocks[npieces], 0, len(blocks[npieces]))]
+        pool = FixedShapePool(recycle=True)
+        for pieces in (first, second):
+            assert len(pieces) in (npieces, 2)
+            whole = RowBlockContainer()
+            for b, lo, hi in pieces:
+                whole.push_block(b.slice(lo, hi))
+            whole = whole.to_block()
+            if layout == "dense":
+                got = block_to_dense(pieces, 32, 40, pool=pool)
+                want = block_to_dense(whole, 32, 40)
+            elif layout == "csr":
+                got = pad_to_bucket(pieces, 32, pool=pool)
+                want = pad_to_bucket(whole, 32)
+            else:
+                got = pad_to_bucket_sharded(pieces, 32, 2, pool=pool)
+                want = pad_to_bucket_sharded(whole, 32, 2)
+            assert _staged_bytes(got) == _staged_bytes(want)
+            # the next batch is staged into this one's buffers, dirty
+            staged = got if layout == "dense" else [
+                v for v in vars(got).values() if isinstance(v, np.ndarray)]
+            for buf in staged:
+                buf.fill(7)
+            pool.retire(staged, ())
+        assert pool.reused > 0
+
+    def test_batches_carry_their_chunks_and_ack_a_dropped_tail(self):
+        blocks = _blocks(rows=(10, 20, 3, 0, 5))
+        for k, b in enumerate(blocks):
+            b.flow_id, b.seq_id = 100 + k, k
+        acked = []
+        batcher = BlockBatcher(blocks, None, None, True,
+                               audit.auditor(), acked.append)
+        got = []
+        while (batch := batcher.read_batch_coo(16)) is not None:
+            got.append((batch.num_rows, batch.flow_ids, batch.seq_ids))
+        # each chunk's ids ride the first batch its rows reach; the last
+        # 6 rows are dropped, and the chunks only they reached are acked
+        assert got == [(16, (100, 101), (0, 1)), (16, (102,), (2,))]
+        assert acked == [3, 4]
+        batcher.restart(1)
+        assert batcher.read_batch_coo(16).seq_ids == (0, 1)
 
 
 class TestFixedShapePool:
@@ -398,6 +495,46 @@ class TestFixedShapePool:
         finally:
             flight.reset()
 
+    def test_acquired_on_one_thread_retired_on_another(self):
+        """The feed's producer acquires and its consumer retires, through
+        a queue of depth 4: no buffer is handed out twice, none is
+        rewritten while it is held, and every one comes back."""
+        pool = FixedShapePool(recycle=True)
+        handoff = queue.Queue(4)
+        held, lock, twice = set(), threading.Lock(), []
+
+        def produce():
+            for i in range(3000):
+                bufs = [pool.acquire(16 + i % 3, np.int64) for _ in range(2)]
+                with lock:
+                    twice.extend(i for b in bufs if id(b) in held)
+                    held.update(id(b) for b in bufs)
+                for b in bufs:
+                    b.fill(i)
+                handoff.put((i, bufs))
+            handoff.put(None)
+
+        landed = [self._guard(lambda: True)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)  # switch threads as often as can be
+        try:
+            producer = threading.Thread(target=produce)
+            producer.start()
+            while (item := handoff.get(timeout=60)) is not None:
+                i, bufs = item
+                assert all((b == i).all() for b in bufs), i
+                with lock:
+                    held.difference_update(id(b) for b in bufs)
+                pool.retire(bufs, landed)
+            producer.join(timeout=60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not producer.is_alive()
+        assert twice == []
+        assert pool.outstanding == 0 and pool.reused > 0
+        stats = pool.stats()
+        assert stats["allocated"] + stats["reused"] == stats["retired"] == 6000
+
     def test_healthy_churn_never_trips_leak_sentinel(self, tmp_path):
         from dmlc_tpu.obs import flight
 
@@ -414,7 +551,7 @@ class TestFixedShapePool:
             flight.reset()
 
 
-def _ids_of(seen, names=("feed_batch", "dispatch", "consume", "stage")):
+def _ids_of(seen, names=("feed_batch", "dispatch", "consume")):
     """{span name: [(pass_, batch), ...]} in the order the spans closed."""
     out = {}
     for e in seen:
@@ -598,7 +735,7 @@ class TestProducerRewindsItself:
         feed = source()
         # on the cpu backend the pool only counts shapes; recycle as an
         # accelerator's does (each batch is copied while it is held)
-        feed.pool = FixedShapePool(recycle=True)
+        feed.pool.recycle = True
         assert len(_collect(feed)) == self.BATCHES
         thread = feed._host_iter._thread
         assert thread.is_alive()  # parked on the pass it staged
@@ -661,8 +798,8 @@ class TestProducerRewindsItself:
 
 # the consumer thread's spans of one batch, and where each lies
 _BATCH_TREE = {
-    "take": "feed_batch", "dispatch": "feed_batch", "stage": "dispatch",
-    "put": "dispatch", "deliver": None, "consume": None,
+    "take": "feed_batch", "dispatch": "feed_batch", "put": "dispatch",
+    "deliver": None, "consume": None,
 }
 
 
@@ -698,16 +835,15 @@ class TestBatchSpans:
     @pytest.mark.parametrize("host_prefetch", [2, 0])
     def test_the_span_tree_of_a_batch(self, source, host_prefetch):
         feed, spans = self._traced(source, host_prefetch=host_prefetch)
-        python_path = not feed._use_native_batches()
+        batched = isinstance(feed._host.reader, BlockBatcher)
         feed.close()
         by_name = {}
         for e in spans:
             by_name.setdefault(e["name"], []).append(e)
         want = set(_BATCH_TREE) | {"produce", "feed_batch"}
-        if not python_path:
-            want.discard("stage")  # the native stager pads in C++
         assert want <= set(by_name)
-        assert ("stage" in by_name) == python_path
+        # the native stager pads in C++, a BlockBatcher under ``stage``
+        assert ("stage" in by_name) == batched
         consumer = {e["tid"] for e in by_name["consume"]}
         assert len(consumer) == 1
         producer = {e["tid"] for e in by_name["produce"]}
@@ -741,6 +877,14 @@ class TestBatchSpans:
         for ident in ahead:
             del produced[ident]
         assert sorted(produced) == batches
+        if batched:  # staged inside its own produce span, on its thread
+            staged = {ids(e): e for e in by_name["stage"]}
+            assert set(staged) == set(produced) | set(ahead)
+            for ident, e in produced.items():
+                assert staged[ident]["tid"] == e["tid"]
+                assert _inside(staged[ident], e), ident
+                assert staged[ident]["args"]["rows"] == min(
+                    64, ROWS - 64 * ident[1])
         if host_prefetch == 0:
             takes = {ids(e): e for e in by_name["take"]}
             for ident, e in produced.items():
@@ -809,11 +953,11 @@ class TestBatchSpans:
 
         monkeypatch.setattr(obs, "span", span)
         feed = source()
-        python_path = not feed._use_native_batches()
+        batched = isinstance(feed._host.reader, BlockBatcher)
         n = sum(1 for _ in feed)
         assert n == self.BATCHES
         timed = {"produce", "take", "dispatch", "put", "consume"}
-        if python_path:
+        if batched:
             timed.add("stage")
         assert {k for k, v in inert.items() if v == {False}} == timed
         # (the Python parser's own spans have no counter either)
@@ -823,13 +967,17 @@ class TestBatchSpans:
             "host_wait": feed._stage["host_wait_ns"].count,
             "dispatch": feed._stage["dispatch_ns"].count,
             "consume": feed._stage["consume_ns"].count,
-            "put": feed._h_put.count, "stage": feed._h_stage.count,
+            "put": feed._h_put.count,
         }
         # one wait more than batches: the one that found the pass's end
         assert counts == {
-            "host_wait": n + 1, "dispatch": n, "consume": n, "put": n,
-            "stage": n if python_path else 0}
+            "host_wait": n + 1, "dispatch": n, "consume": n, "put": n}
         assert feed._stage["host_batch_ns"].count >= n
+        # staged on the producer, which may have staged the next pass's
+        # head: once a batch it produced, never on the consumer
+        staged = feed._h_stage.count
+        assert (n <= staged < feed._stage["host_batch_ns"].count
+                if batched else staged == 0)
         assert feed._h_put.sum <= feed._stage["dispatch_ns"].sum
         assert feed._m_unlanded.value == 0
         feed.close()

@@ -858,9 +858,11 @@ class TestBatchIdentityAndEpochBoundary:
 
     def test_python_rebatch_path_carries_ids_on_stage(
             self, tmp_path, spans, monkeypatch):
+        from dmlc_tpu.device.csr import BlockBatcher
+
         monkeypatch.setenv("DMLC_TPU_NATIVE", "0")
         feed = self._feed(tmp_path, "dense")
-        assert not feed._use_native_batches()
+        assert isinstance(feed._host.reader, BlockBatcher)
         for _ in feed:
             pass
         feed.before_first()
@@ -869,7 +871,9 @@ class TestBatchIdentityAndEpochBoundary:
         feed.close()
         staged = [(e["args"]["pass_"], e["args"]["batch"])
                   for e in spans if e["name"] == "stage"]
-        assert staged == [(p, b) for p in (0, 1) for b in range(5)]
+        # the producer stages on into the head of a third pass
+        assert staged[:10] == [(p, b) for p in (0, 1) for b in range(5)]
+        assert staged[10:] == [(2, b) for b in range(len(staged) - 10)]
 
     def test_tracing_off_sets_no_batch(self, tmp_path):
         from dmlc_tpu import obs

@@ -448,7 +448,7 @@ def _oracle_losses(uri, world, layout, feats, epochs=2):
                     cont.push_row(lab, ids, value=vals)
             merged_block = cont.to_block()
             if layout == "dense":
-                from dmlc_tpu.device.feed import block_to_dense
+                from dmlc_tpu.device.csr import block_to_dense
 
                 x, labels, weights = block_to_dense(
                     merged_block, 64 * world, feats)

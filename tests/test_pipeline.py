@@ -393,10 +393,11 @@ def test_device_feed_native_path_matches_legacy(svm_file):
     import jax
 
     from dmlc_tpu.device import BatchSpec, DeviceFeed
+    from dmlc_tpu.device.csr import BlockBatcher
 
     spec = BatchSpec(batch_size=128, layout="dense", num_features=6)
     feed_native = DeviceFeed(create_parser(svm_file, 0, 1), spec)
-    assert feed_native._use_native_batches()
+    assert feed_native._host.reader is feed_native._parser
     native_batches = [jax.device_get(b["x"]) for b in feed_native]
     feed_native.close()
 
@@ -405,7 +406,7 @@ def test_device_feed_native_path_matches_legacy(svm_file):
         py_parser = create_parser(svm_file, 0, 1)
         assert not isinstance(py_parser, NativePipelineParser)
         feed_py = DeviceFeed(py_parser, spec)
-        assert not feed_py._use_native_batches()
+        assert isinstance(feed_py._host.reader, BlockBatcher)
         py_batches = [jax.device_get(b["x"]) for b in feed_py]
         feed_py.close()
     finally:
