@@ -61,14 +61,18 @@ backward operations carry the scope their ``back`` is called under
 FIRST, before ``transpose(jvp(.))``, so each part's backward pass is
 called under that part's own scope. ``step.order``: the batch laid out as
 ``x [D, rows]`` and ``ids [rows * P]`` (P = the bags' sizes summed; place
-``row * P + start of f's bag + member`` holds the row's id, 0 where it
-names none: such a place adds a zero vector), then the FM's sort by id
-over those ``rows x P`` places ONLY, with each entry's place as the
-payload, and the sort that brings every entry's slot back to its place.
+``row * P + f`` with one id a table, and with bags member-major, place
+``(start of f's bag + member) * rows + row``, so that each member of a bag
+is a whole ``[rows, K]`` slab of the rows ``step.gather`` brings; the
+place holds the row's id, 0 where it names none: such a place adds a zero
+vector), then the FM's sort by id over those ``rows x P`` places ONLY,
+taken row by row, with each entry's place as the payload, and the sort
+that brings every entry's slot back to its place.
 ``step.gather``: the one read at the distinct ids (models/fm.py
 ``_read_distinct``) and each place's K words, in place order.
-``step.pool`` (``dcn`` only): the bags' sums
-and, backward, each bag's gradient handed to its members.
+``step.pool`` (``dcn`` only): the bags' sums, each a sum of its members'
+aligned slabs along the major axis, and, backward, each bag's gradient
+broadcast over its members' slabs: no pad and no relayout.
 ``step.cross`` (``dcn`` only): the cross net, forward and backward.
 ``step.dense``: both MLPs (under ``dot`` the interaction too), the loss,
 their backward pass and the dense parameters' rule; activations are
@@ -276,22 +280,27 @@ def _bag_starts(bags) -> np.ndarray:
 def _lay_out(batch, dense: int, lows, sizes, bags=None):
     """The batch as the model reads it: ``x f32[dense, rows]`` (a row's
     dense features down a column; 0 where the row has no such entry),
-    ``ids s32[rows * P]`` (P = the bags' sizes summed, one a table when
-    ``bags`` is None; place ``row * P + the start of f's bag + member``
-    holds the row's member-th id of table f, 0 where it names none) and
-    the count of valued table entries that found no place (an id past its
-    bag's size: with bags of one, a second id of one table in one row).
+    ``ids s32[rows * P]`` (P = the bags' sizes summed) and the count of
+    valued table entries that found no place (an id past its bag's size:
+    with bags of one, a second id of one table in one row). With ``bags``
+    None (one id a table) the places are row-major: place ``row * P + f``
+    holds the row's id of table f. With ``bags`` given they are
+    member-major: place ``(the start of f's bag + member) * rows + row``
+    holds the row's member-th id of table f, so that a member's places
+    are one run of ``rows`` (:func:`_pool`). 0 where the row names none.
 
     Two ways to it, chosen on the device by what the batch holds (the
     module's docstring): a batch whose every row lists ``1..dense`` and
-    then each table's bag, in order, is cut by a reshape; any other by a
-    scatter-add of the dense values and a scatter of the ids: with bags of
-    one a scatter-max, else each entry's rank among the row's entries of
-    its table (a stable sort by row and table), the entries past the bag
-    left out."""
+    then each table's bag, in order, is cut by a reshape (member-major:
+    the ``[rows, P]`` block of ids transposed); any other by a scatter-add
+    of the dense values and a scatter of the ids: with bags of one a
+    scatter-max, else each entry's rank among the row's entries of its
+    table (a stable sort by row and table), the entries past the bag left
+    out."""
     indices, values = batch["indices"], batch["values"]
     rows = batch["label"].shape[0]
     fields = len(sizes)
+    member = bags is not None
     bags = (1,) * fields if bags is None else tuple(bags)
     places = sum(bags)
     width = dense + places
@@ -309,7 +318,8 @@ def _lay_out(batch, dense: int, lows, sizes, bags=None):
         ].add(values)[:-1].reshape(rows, dense).T
         if places == fields:
             ids = jnp.zeros((rows * fields + 1,), indices.dtype).at[
-                jnp.where(named, row_ids * fields + table, rows * fields)
+                jnp.where(named, table * rows + row_ids if member else
+                          row_ids * fields + table, rows * fields)
             ].max(indices)[:-1]
             return x, ids, jnp.sum(named) - jnp.sum(ids != 0)
         # a bag's members in the order the row lists them: sorted by (row,
@@ -323,8 +333,8 @@ def _lay_out(batch, dense: int, lows, sizes, bags=None):
         rank = at - lax.cummax(jnp.where(first, at, 0), axis=0)
         table = key % fields
         kept = (key < rows * fields) & (rank < jnp.asarray(bags)[table])
-        place = (key // fields) * places + jnp.asarray(_bag_starts(bags))[
-            table] + rank
+        place = (jnp.asarray(_bag_starts(bags))[table] + rank) * rows + \
+            key // fields
         ids = jnp.zeros((rows * places + 1,), indices.dtype).at[
             jnp.where(kept, place, rows * places)].set(held)[:-1]
         return x, ids, jnp.sum(named) - jnp.sum(kept)
@@ -335,8 +345,9 @@ def _lay_out(batch, dense: int, lows, sizes, bags=None):
     i = indices[:rows * width].reshape(rows, width)
 
     def cut():
-        return (v[:, :dense].T,
-                jnp.where(v[:, dense:] != 0, i[:, dense:], 0).reshape(-1),
+        x = v[:, :dense].T
+        ids = jnp.where(v[:, dense:] != 0, i[:, dense:], 0)
+        return (x, (ids.T if member else ids).reshape(-1),
                 jnp.zeros((), jnp.int32))
 
     # every place's first and last id: its table's
@@ -403,14 +414,27 @@ def dcn_forward(dense: Dict, emb, x):
 
 def _pool(ids, bags, rows):
     """``[fields, K, batch rows]``: each bag's sum of its places' rows
-    (``rows [places, K]``, place ``r * P + start of f's bag + member``);
-    a place that holds no id adds 0, whatever row 0 holds."""
-    k = rows.shape[1]
-    held = jnp.where((ids != 0)[:, None], rows, 0.0).reshape(
-        -1, sum(bags), k)
+    (``rows [places, K]`` member-major, place ``(start of f's bag +
+    member) * batch rows + r``: :func:`_lay_out`); a place that holds no
+    id adds 0, whatever row 0 holds.
+
+    As ``[P, batch rows, K]`` (a free reshape while the batch's rows are
+    a multiple of the chip's 8 sublanes) a member is one whole slab and a
+    bag a run of slabs along the major axis: its sum adds aligned tiles,
+    and the backward pass jax makes of the split, the sums and the stack
+    (a broadcast of each bag's gradient over its slabs, concatenated)
+    neither pads nor lays the rows out anew. Each bag masks its own slabs:
+    the chip's compiler then makes the masks, the sums and the stack one
+    pass over the rows, where one masked copy of them all, read by every
+    bag's sum, was laid out anew for the sums."""
+    p, k = sum(bags), rows.shape[1]
+    cuts = _bag_starts(bags)[1:]
+    slabs = jnp.split(rows.reshape(p, -1, k), cuts, axis=0)
+    named = jnp.split((ids != 0).reshape(p, -1, 1), cuts, axis=0)
     pooled = jnp.stack(
-        [held[:, at] if n == 1 else jnp.sum(held[:, at:at + n], axis=1)
-         for at, n in zip(_bag_starts(bags), bags)])  # [fields, rows, K]
+        [jnp.where(m, s, 0.0).reshape(s.shape[1:]) if n == 1 else
+         jnp.sum(jnp.where(m, s, 0.0), axis=0)
+         for s, m, n in zip(slabs, named, bags)])  # [fields, rows, K]
     return pooled.transpose(0, 2, 1)
 
 
@@ -432,15 +456,28 @@ def _named(ids, fields: int):
     return (ids != 0).reshape(-1, fields).T[:, None, :]
 
 
-def _order_places(ids, num_ids: int):
+def _order_places(ids, num_ids: int, batch_rows: Optional[int] = None):
     """The FM's step head over the table entries alone: ``ids
     s32[places]`` sorted, each entry's place its payload. Returns (the
     :class:`~dmlc_tpu.models.fm._IdOrder`, every entry's place in id
     order, every place's slot): the second sort brings the slots, which
     are by id order, back to place order (a sort of two ``s32`` arrays
-    where a scatter by place costs several times as much on the chip)."""
+    where a scatter by place costs several times as much on the chip).
+
+    ``batch_rows``: the batch's rows where the places are member-major
+    (:func:`_lay_out`). The stable sort then takes them row by row, so
+    that an id's entries lie, and their sums add, in (row, member) order
+    as under row-major places: a member's place would move with the
+    ranks of the bag's other members, which a slice of a table that
+    holds some of them does not see."""
+    if batch_rows is not None:
+        ids = ids.reshape(-1, batch_rows).T.reshape(-1)
     places = jnp.arange(ids.shape[0], dtype=jnp.int32)
     sorted_ids, place = lax.sort((ids, places), num_keys=1)
+    if batch_rows is not None:
+        # the row-major place the sort carried, as the member-major one
+        per_row = ids.shape[0] // batch_rows
+        place = place % per_row * batch_rows + place // per_row
     order = _slots_in_id_order(sorted_ids, num_ids)
     _, slot = lax.sort((place, order.slot), num_keys=1)
     return order, place, slot
@@ -451,8 +488,8 @@ class _Head(NamedTuple):
 
     #: f32[dense features, rows] the rows' dense features
     x: jax.Array
-    #: s32[places] place ``row * P + the start of f's bag + member``
-    #: holds the row's member-th id of table f, 0 where it names none
+    #: s32[places] every place's id, 0 where it names none; places
+    #: row-major, or member-major under bags (:func:`_lay_out`)
     ids: jax.Array
     #: s32[] valued table entries that found no place
     left_out: jax.Array
@@ -477,7 +514,8 @@ def _head(params, batch, dense_features: int, lows, sizes,
         tables, PackedTables) else tables[0].shape[0]
     with jax.named_scope("step.order"):
         x, ids, left_out = _lay_out(batch, dense_features, lows, sizes, bags)
-        order, place, slot = _order_places(ids, num_ids)
+        order, place, slot = _order_places(
+            ids, num_ids, None if bags is None else x.shape[1])
     with jax.named_scope("step.gather"):
         read = _read_distinct(tables, order)
         # a slot and a place lie inside their arrays by construction:
@@ -629,7 +667,8 @@ def make_dlrm_train_step(
                     partial(_pool, head.ids, bags), head.rows)
                 if multi.any():
                     metrics["bag_entries"] = jnp.sum(
-                        (head.ids.reshape(-1, multi.size) != 0) & multi)
+                        (head.ids.reshape(multi.size, -1) != 0)
+                        & multi[:, None])
             loss_sum, wsum, grads, by_table = _dcn_grads(
                 dense, vectors, head.x, label, weight)
         else:
@@ -821,7 +860,9 @@ class DLRMLearner(FMLearner):
                     dense_features=self.param.dense_features,
                     dense_params=self.dense_params,
                     interaction=self.param.interaction,
-                    bag_ids=sum(self.bags))
+                    bag_ids=sum(self.bags), place_order=(
+                        "member" if self.param.interaction == "dcn"
+                        else "row"))
 
     def epoch_closed(self, reg, nstep: int, sums: Dict) -> None:
         """The FM's counters under ``model="dlrm"``, and

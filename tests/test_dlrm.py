@@ -653,6 +653,7 @@ def test_j_span_arguments_counters_and_gauge(cfg, data):
             args["ids_per_lane_row"]) == (K, 128, 128 // K)
     assert args["row_writer"] == "scatter"
     assert args["id_sum"] == "segment_sum"
+    assert args["place_order"] == "row"
 
 
 def test_j_predict_is_the_forward_pass(trained, cfg, data):

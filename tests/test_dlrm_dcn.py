@@ -195,32 +195,109 @@ def test_a_rows_no_batch_names_keep_their_bits(trained, cfg, data):
     assert not np.asarray(model.table_rows("emb", every))[:DENSE + 1].any()
 
 
+@pytest.mark.parametrize("whole", [True, False])
+def test_a_predict_is_the_forward_pass(harness, trained, cfg, data, whole):
+    """``predict_batch`` reads the places as the step lays them: the
+    margins of a batch (every row whole, by the reshape; or a twentieth of
+    the bags' members left out, by the scatters) are the float64 forward
+    pass over the bags' sums of the trained rows."""
+    model, config = trained.model, harness.config
+    ids, values = data["ids"][:ROWS], data["values"][:ROWS].copy()
+    if not whole:
+        values[~data["keep"][:ROWS]] = 0.0
+    p = {k: np.asarray(v, np.float64) for k, v in model.scalars().items()}
+    emb = np.asarray(model.table_rows("emb", jnp.asarray(
+        ids[:, DENSE:].ravel(), jnp.int32)), np.float64).reshape(ROWS, -1, K)
+    e = np.add.reduceat(emb * (values[:, DENSE:] != 0)[:, :, None],
+                        dlrm._bag_starts(cfg["bag_sizes"]), axis=1)
+    z, _ = config._mlp_forward(p, "bot", values[:, :DENSE].astype(
+        np.float64), last_relu=True)
+    top_in, _ = config._cross_forward(
+        p, np.concatenate([z, e.reshape(ROWS, -1)], axis=1),
+        cfg["dcn_num_layers"])
+    s, _ = config._mlp_forward(p, "top", top_in, last_relu=False)
+    np.testing.assert_allclose(
+        model.predict_batch(_arrays(ids, values, data["label"][:ROWS])),
+        s[:, 0], rtol=2e-4, atol=2e-5)
+
+
 def test_b_pooling_sums_a_bag_an_id_twice_and_a_missing_member(cfg):
-    """``_pool`` against float64 sums: a bag that names one id twice
-    counts it twice; a place that names no id (id 0) adds 0 whatever row
-    0 holds; backward, every member of a bag takes the bag's gradient and
-    a missing one none."""
-    bags = (3, 1, 2)
+    """``_pool`` over member-major places against float64 sums: a bag
+    that names one id twice counts it twice; a place that names no id (id
+    0) adds 0 whatever row 0 holds; backward, every member of a bag takes
+    the bag's gradient and a missing one none."""
+    bags, r = (3, 1, 2), 4
     rng = np.random.default_rng(3)
-    rows = rng.standard_normal((4 * 6, K)).astype(np.float32)
-    ids = rng.integers(1, 50, 4 * 6).astype(np.int32)
-    ids[0] = ids[1] = 17   # row 0, bag 0: the same id twice
-    rows[1] = rows[0]
-    ids[5] = 0             # row 0, bag 2: a missing member
-    ids[6 + 3] = 0         # row 1, the bag of one: no id at all
-    pooled, back = jax.vjp(partial(dlrm._pool, jnp.asarray(ids), bags),
-                           jnp.asarray(rows))
-    held = np.where(ids[:, None] != 0, rows, 0.0).astype(np.float64)
-    want = np.add.reduceat(held.reshape(4, 6, K), [0, 3, 4], axis=1)
+    # [place, row]: the program's member-major order
+    rows = rng.standard_normal((6, r, K)).astype(np.float32)
+    ids = rng.integers(1, 50, (6, r)).astype(np.int32)
+    ids[0, 0] = ids[1, 0] = 17   # row 0, bag 0: the same id twice
+    rows[1, 0] = rows[0, 0]
+    ids[5, 0] = 0                # row 0, bag 2: a missing member
+    ids[3, 1] = 0                # row 1, the bag of one: no id at all
+    pooled, back = jax.vjp(
+        partial(dlrm._pool, jnp.asarray(ids.reshape(-1)), bags),
+        jnp.asarray(rows.reshape(-1, K)))
+    # the row-major view [row, place] the reference sums along
+    held = np.where(ids.T[:, :, None] != 0, rows.transpose(1, 0, 2),
+                    0.0).astype(np.float64)
+    want = np.add.reduceat(held, [0, 3, 4], axis=1)
     np.testing.assert_allclose(
         np.asarray(pooled), want.transpose(1, 2, 0), rtol=1e-6, atol=1e-7)
-    assert np.allclose(np.asarray(pooled)[0, :, 0], 2 * rows[0] + rows[2])
+    assert np.allclose(np.asarray(pooled)[0, :, 0],
+                       2 * rows[0, 0] + rows[2, 0])
     assert not np.asarray(pooled)[1, :, 1].any()
     grad = rng.standard_normal(pooled.shape).astype(np.float32)
     (got,) = back(jnp.asarray(grad))
-    by_member = np.repeat(grad.transpose(2, 0, 1), bags, axis=1).reshape(
-        -1, K) * (ids != 0)[:, None]
-    np.testing.assert_array_equal(np.asarray(got), by_member)
+    by_member = np.repeat(grad.transpose(0, 2, 1), bags, axis=0) * (
+        ids != 0)[:, :, None]
+    np.testing.assert_array_equal(np.asarray(got), by_member.reshape(-1, K))
+
+
+@pytest.mark.parametrize("bags_given", ["bags", "none", "ones"])
+def test_b_the_layout_is_the_same_by_the_reshape_and_by_the_scatters(
+        harness, cfg, bags_given):
+    """``_lay_out``'s ids and dense features by the reshape (every row
+    whole and in order) and by the scatters (the same rows with their
+    dense entries listed last to first; and with one bag member dropped,
+    the bag's later members moving up a place): with the configuration's
+    bags member-major, place ``(start of f's bag + member) * rows + row``;
+    with one id a table and ``bags`` None row-major, ``row * F + f``, as
+    before bags came; with ``bags`` of one given, member-major too."""
+    sizes = cfg["field_sizes"]
+    data = harness.config.rows(dict(cfg, rows=ROWS), 7)
+    ids, values = data["ids"], data["values"]
+    bags = tuple(cfg["bag_sizes"])
+    if bags_given != "bags":
+        # one id a table: each bag's first member
+        keep = np.r_[0:DENSE, DENSE + dlrm._bag_starts(bags)]
+        ids, values = ids[:, keep], values[:, keep]
+        bags = None if bags_given == "none" else (1,) * len(sizes)
+    assert np.all(values[:, DENSE:] != 0)
+    lay = jax.jit(lambda batch: dlrm._lay_out(
+        batch, DENSE, table_lows(DENSE, sizes), sizes, bags))
+    block = ids[:, DENSE:]  # [rows, P]: each row's places in order
+
+    def laid(block):
+        return (block if bags is None else block.T).reshape(-1)
+
+    reverse = np.r_[np.arange(DENSE)[::-1], DENSE:ids.shape[1]]
+    for order in (np.arange(ids.shape[1]), reverse):
+        x, got, left_out = lay(_arrays(ids[:, order], values[:, order],
+                                       data["label"]))
+        np.testing.assert_array_equal(np.asarray(got), laid(block))
+        np.testing.assert_array_equal(np.asarray(x), values[:, :DENSE].T)
+        assert int(left_out) == 0
+    # row 3 lacks the first member of table 0's bag (of 3 under bags)
+    dropped = values.copy()
+    dropped[3, DENSE] = 0.0
+    want = block.copy()
+    n = 1 if bags is None else bags[0]
+    want[3, :n] = np.r_[block[3, 1:n], 0]
+    x, got, left_out = lay(_arrays(ids, dropped, data["label"]))
+    np.testing.assert_array_equal(np.asarray(got), laid(want))
+    np.testing.assert_array_equal(np.asarray(x), values[:, :DENSE].T)
+    assert int(left_out) == 0
 
 
 def test_b_the_cross_net_forward_and_backward_is_the_references(
@@ -712,6 +789,48 @@ class TestLoweredStep:
         assert dots and all("HIGHEST" in line for line in dots)
 
 
+def _made_under(jaxpr, scope, inside=False):
+    """[(primitive, operand shapes, result shapes)] of each equation of
+    ``jaxpr`` (and of the jaxprs inside it) made under ``scope``, forward
+    or backward."""
+    found = []
+    for eqn in jaxpr.eqns:
+        under = inside or scope in str(eqn.source_info.name_stack).split("/")
+        if under:
+            found.append((eqn.primitive.name,
+                          [getattr(v, "aval", v).shape for v in eqn.invars],
+                          [v.aval.shape for v in eqn.outvars]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _made_under(sub, scope, under)
+    return found
+
+
+def test_g_the_pool_adds_whole_slabs(cfg):
+    """``step.pool`` of the ``dcn`` step, forward and backward, holds no
+    ``pad`` (jax's transpose of a slice) and no reshape of the places' rows
+    ``[P * R, K]`` to ``[R, P, K]`` (a relayout on the chip at 214 places
+    a row): the rows are ``[P, R, K]``, a bag's members whole ``[R, K]``
+    slabs that its sum adds and its backward pass concatenates."""
+    model = _learner(cfg)
+    model.init_tables(0)
+    model._ensure(cfg["num_features"])
+    n = ROWS * cfg["nnz_per_row"]
+    batch = {
+        "label": jnp.zeros(ROWS), "weight": jnp.ones(ROWS),
+        "indices": jnp.zeros(n + 256, jnp.int32), "values": jnp.ones(n + 256),
+        "offsets": jnp.zeros(ROWS + 1, jnp.int32)}
+    step = model._step
+    while hasattr(step, "__wrapped__"):
+        step = step.__wrapped__
+    pool = _made_under(jax.make_jaxpr(step)(model.params, batch).jaxpr,
+                       "step.pool")
+    places = sum(cfg["bag_sizes"])
+    assert ("reshape", [(places * ROWS, K)], [(places, ROWS, K)]) in pool
+    assert not [e for e in pool if e[0] == "pad"]
+    assert not [e for e in pool if e[0] == "reshape"
+                and e[2] == [(ROWS, places, K)]]
+
+
 def test_h_span_arguments_counters_and_gauges(cfg, data):
     """``fit_uri`` end to end: the ``epoch`` span's new arguments, the
     bag, cross-net and stateful counters, the two state gauges."""
@@ -753,6 +872,7 @@ def test_h_span_arguments_counters_and_gauges(cfg, data):
     assert args["optimizer"] == "adagrad" and args["interaction"] == "dcn"
     assert args["bag_ids"] == 214 and args["dense_params"] == \
         model.dense_params
+    assert args["place_order"] == "member"
 
 
 def test_h_the_published_net_is_counted(cfg):
